@@ -7,10 +7,12 @@ rendezvous (reference 3.multiprocessing_distributed.py:84,102).
 
 TPU-native: a single process already drives all local chips, so a local spawn
 is unnecessary for TPU (SURVEY.md §2b process-manager row) — but the
-capability is preserved for parity and for CPU-simulation of multi-host runs:
-with --nprocs N this script forks N children, each claiming an equal slice of
-CPU devices, rendezvousing over loopback TCP via jax.distributed (the tcp://
-analog). With --nprocs 1 (TPU default) it trains directly.
+capability is preserved for parity as the CPU simulation of a multi-host run:
+with TPU_DIST_NPROCS_SPAWN=N (and JAX_PLATFORMS=cpu) this script forks N
+children that rendezvous over loopback TCP via jax.distributed (the tcp://
+analog). Unset (the TPU default) it trains directly. Nothing here gives a
+child its own chip, so the spawn refuses any platform but cpu: on a TPU host
+the first child would hold the chip and the other N-1 would hang on it.
 """
 
 import os
@@ -45,6 +47,12 @@ def spawn(nprocs: int, argv):
 if __name__ == "__main__":
     nprocs = int(os.environ.pop("TPU_DIST_NPROCS_SPAWN", "0"))
     if nprocs > 1 and "TPU_DIST_PROCESS_ID" not in os.environ:
+        if os.environ.get("JAX_PLATFORMS") != "cpu":
+            raise SystemExit(
+                f"TPU_DIST_NPROCS_SPAWN={nprocs} is the CPU simulation of "
+                "several hosts: run it with JAX_PLATFORMS=cpu. On a TPU "
+                "host one process drives every local chip; N children "
+                "would all wait for the chip the first one holds.")
         spawn(nprocs, sys.argv[1:])
         sys.exit(0)
     cfg = parse_config(defaults=DEFAULTS, description=__doc__)

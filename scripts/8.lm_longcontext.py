@@ -134,15 +134,11 @@ def main():
         seed = 3
         prompt = jnp.asarray([[seed, (seed * 5 + 7) % trainer.vocab_size]],
                              jnp.int32)
-        # sp's model closes over mesh axis names (ring attention); decode
-        # with the full-attention equivalent — same weights, same math.
-        # trainer._sp_ctor already encodes the dense-vs-MoE class choice
-        # with the right ctor kwargs (one definition, lm_loop._build_steps);
-        # tiny_lm's **_ catch-all would otherwise silently swallow MoE
-        # kwargs and build a model that cannot apply the trained params.
-        # Dense AND MoE models decode through the KV cache (round-5:
-        # models.transformer.attend_maybe_cached is shared).
-        gen_model = trainer._sp_ctor() if trainer.use_sp else trainer.model
+        # trainer.decode_model: the trained weights' single-device twin
+        # (sp's ring attention and the mesh-bound flash kernel both stay
+        # behind in training). Dense AND MoE models decode through the KV
+        # cache (models.transformer.attend_maybe_cached is shared).
+        gen_model = trainer.decode_model
         out = np.asarray(generate(gen_model, host_params, prompt, steps=n,
                                   use_cache=True))
         follows = sum(int(out[0, i + 1])
@@ -177,8 +173,7 @@ def main():
             sp_n = 1
         step = sp_n * page_size
         serve_len = (cfg.seq_len // step) * step
-        serve_model = (trainer._sp_ctor() if trainer.use_sp
-                       else trainer.model)
+        serve_model = trainer.decode_model
         mesh = (make_mesh((sp_n,), (SP_AXIS,),
                           devices=jax.local_devices()[:sp_n])
                 if sp_n > 1 else None)

@@ -15,11 +15,6 @@ cd "$(dirname "$0")/.."
 # same lint result — no second full sweep of the call graph).
 python -m tools.distlint --sarif-out distlint.sarif --with-debt "$@"
 
-# Bench-trajectory gate (tools/bench_track.py, stdlib-only): the newest
-# checked-in BENCH_r*.json must not have dropped >5% below the metric's
-# trailing best — the apex-data_prefetcher class of silent regression.
-python tools/bench_track.py --check
-
 # Supervisor-policy gate (round 10) + consensus-policy gate (round 13),
 # jax-free BY CONSTRUCTION: the elastic supervisor AND its cross-host
 # consensus must keep working on a bare login/CI host (no jax installed),

@@ -1,20 +1,19 @@
 """Bench regression tracker (tools/bench_track.py) — no jax.
 
-Covers: the checked-in BENCH_r*.json history parsing (the real
-140.8k -> 174.6k trajectory), the threshold check against an
+Covers: a five-round BENCH_r*.json history parsing into its trend (the
+shape the driver's old per-round records had; the values are a fixture,
+not measurements), the threshold check against an
 injected-regression fixture (nonzero exit — the acceptance bar),
 --headline appending the run under test, --json output shape, and
 malformed/non-bench files being skipped rather than crashing."""
 
 import json
 import os
-import shutil
 
 import pytest
 
 from tools.bench_track import load_points, main, track
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HEADLINE = "cifar10_resnet50_images_per_sec_per_chip"
 
 
@@ -28,21 +27,29 @@ def _write_round(dirpath, n, value, metric=HEADLINE, **parsed_extra):
     return path
 
 
-def test_checked_in_history_reports_trend(capsys):
-    """The repo's own BENCH_r01..r05 parse into the known trajectory and
-    pass the gate (r05 is the trailing best)."""
-    assert main(["--dir", ROOT, "--check"]) == 0
+# a flat four rounds and a fifth that is the trailing best
+_HISTORY = (140821.2, 141819.6, 141109.8, 140270.5, 174621.9)
+
+
+def _write_history(dirpath):
+    for n, value in enumerate(_HISTORY, start=1):
+        _write_round(dirpath, n, value)
+
+
+def test_checked_in_history_reports_trend(tmp_path, capsys):
+    """A five-round history parses into its trajectory and passes the
+    gate (r05 is the trailing best)."""
+    _write_history(str(tmp_path))
+    assert main(["--dir", str(tmp_path), "--check"]) == 0
     out = capsys.readouterr().out
     assert HEADLINE in out
-    assert "140,821.2" in out and "174,621.9" in out  # 140.8k -> 174.6k
+    assert "140,821.2" in out and "174,621.9" in out  # first -> latest
     assert "ok: latest" in out
 
 
 def test_injected_regression_exits_nonzero(tmp_path, capsys):
     """ACCEPTANCE: a fabricated regressed round fails --check."""
-    for f in os.listdir(ROOT):
-        if f.startswith("BENCH_r") and f.endswith(".json"):
-            shutil.copy(os.path.join(ROOT, f), tmp_path)
+    _write_history(str(tmp_path))
     _write_round(str(tmp_path), 6, 100000.0)  # -42.7% vs r05's 174.6k
     assert main(["--dir", str(tmp_path), "--check"]) == 1
     err = capsys.readouterr().err

@@ -1,0 +1,159 @@
+"""The main path's Pallas kernels, compiled for a described TPU v5e.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached (``jax.experimental.topologies``): nothing runs, but
+what Mosaic would refuse on the chip — a block not aligned to the tiling,
+more fast memory than a kernel may use — it refuses here, on the CPU, at no
+chip time. Each case is a kernel the trainers or the server really call, at
+the widths they call it with (bench.py's LM default: 8 layers, d1024, 8 heads
+of 128, L2048, V32000, batch 8; also 16 heads of 64 at L16384, and the
+serving pool's pages). Interpret-mode tests cannot see any of this.
+
+A compile that passes is not a chip run: it says nothing about results or
+times (``python chip_smoke.py`` is that proof).
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The devices of a described v5e:2x2, with the persistent compilation
+    cache off around the module: such a compile can be written to the cache
+    but not read back without a chip."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield list(topo.devices)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _flash_train(b, l, h, d):
+    from tpu_dist.ops.flash_attention import flash_attention_fn
+
+    attn = flash_attention_fn(block_k=1024, interpret=False)
+    qkv = [((b, l, h, d), jnp.bfloat16)] * 3
+
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    return fwd_bwd, qkv
+
+
+def _flash_prefill(l):
+    """The serve prefill's attention: one padded prompt, forward only."""
+    from tpu_dist.ops.flash_attention import flash_attention_fn
+
+    attn = flash_attention_fn(block_k=1024, interpret=False)
+    return (lambda q, k, v: attn(q, k, v)), [((1, l, 8, 128), jnp.bfloat16)] * 3
+
+
+def _paged_int8(b, l):
+    """The int8-KV decode tick over gathered pages (max_pages x 16 rows)."""
+    from tpu_dist.ops.paged_attention import int8kv_paged_flash_attention_fn
+
+    attn = int8kv_paged_flash_attention_fn(interpret=False)
+    h, d = 8, 128
+    return attn, [((b, 1, h, d), jnp.bfloat16),
+                  ((b, l, h, d), jnp.int8), ((b, l, h), jnp.float32),
+                  ((b, l, h, d), jnp.int8), ((b, l, h), jnp.float32),
+                  ((b,), jnp.int32)]
+
+
+def _quant_matmul(m, k, n):
+    from tpu_dist.ops.pallas_quant import fused_quant_matmul
+
+    return (lambda x, w: fused_quant_matmul(x, w, False)), \
+        [((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16)]
+
+
+def _adamw(shape):
+    from tpu_dist.ops.pallas_adamw import fused_adamw_leaf
+
+    f32 = (shape, jnp.float32)
+    return (lambda p, g, m, v, s: fused_adamw_leaf(p, g, m, v, s,
+                                                   interpret=False)), \
+        [f32, f32, f32, f32, ((1, 8), jnp.float32)]
+
+
+def _sgd(shape):
+    from tpu_dist.ops.pallas_sgd import fused_sgd_leaf
+
+    f32 = (shape, jnp.float32)
+    return (lambda p, g, m: fused_sgd_leaf(p, g, m, 0.1, 0.9, 1e-4,
+                                           interpret=False)), [f32, f32, f32]
+
+
+CASES = {
+    "flash_train_b8_l2048_h8_d128": lambda: _flash_train(8, 2048, 8, 128),
+    "flash_train_b1_l16384_h16_d64": lambda: _flash_train(1, 16384, 16, 64),
+    "flash_prefill_l1024": lambda: _flash_prefill(1024),
+    "paged_int8_b8_l2048": lambda: _paged_int8(8, 2048),
+    "paged_int8_b32_l4096": lambda: _paged_int8(32, 4096),
+    "quant_matmul_mlp_16384x1024x4096": lambda: _quant_matmul(16384, 1024, 4096),
+    "quant_matmul_decode_8x1024x4096": lambda: _quant_matmul(8, 1024, 4096),
+    "quant_matmul_head_16384x1024x32000":
+        lambda: _quant_matmul(16384, 1024, 32000),
+    "adamw_mlp_1024x4096": lambda: _adamw((1024, 4096)),
+    "adamw_embedding_32000x1024": lambda: _adamw((32000, 1024)),
+    "sgd_conv_3x3x512x512": lambda: _sgd((3, 3, 512, 512)),
+    "sgd_bias_10": lambda: _sgd((10,)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, v5e):
+    fn, shapes = CASES[case]()
+    chip = SingleDeviceSharding(v5e[0])
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text(), \
+        f"{case}: no Mosaic kernel in the compiled program"
+    # the program around the kernel fits one chip's 16 GB with room to spare
+    ma = compiled.memory_analysis()
+    total = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+             + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert total < 16 * 2**30, f"{case}: {total / 2**30:.1f} GB"
+
+
+def test_flash_under_a_four_chip_gspmd_step_runs_per_shard(v5e):
+    """The fault PR 21 found on the way to four chips: GSPMD cannot
+    partition a Mosaic kernel, so ``--attn flash`` under dp/tp/fsdp never
+    compiled for more than one chip. ``flash_attention_fn(mesh=, spec=)``
+    runs the kernel per shard; bare, the compiler still refuses."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpu_dist.ops.flash_attention import flash_attention_fn
+    from tpu_dist.parallel.mesh import make_mesh
+
+    mesh = make_mesh((2, 2), ("data", "model"), devices=v5e)
+    spec = P("data", None, "model", None)   # batch rows x heads
+    qkv = [jax.ShapeDtypeStruct((8, 2048, 8, 128), jnp.bfloat16,
+                                sharding=NamedSharding(mesh, spec))] * 3
+
+    def step(attn):
+        loss = lambda q, k, v: attn(q, k, v).astype(jnp.float32).sum()
+        return jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(*qkv)
+
+    bound = flash_attention_fn(block_k=1024, interpret=False, mesh=mesh,
+                               spec=spec)
+    assert "tpu_custom_call" in step(bound).compile().as_text()
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        step(flash_attention_fn(block_k=1024, interpret=False)).compile()

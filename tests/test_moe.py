@@ -195,7 +195,7 @@ def test_top2_capacity_overflow_drops_second_choice():
 # test_expert_parallel_matches_dp (same (data=1, expert=8) mesh, loss
 # parity vs dp) and test_moe_tp_composition_matches_dp
 def test_ep_actually_shards_expert_compute():
-    """'EP is EP' (VERDICT r2 weak #5): on the SAME (data=1, expert=8) mesh
+    """'EP is EP': on the SAME (data=1, expert=8) mesh
     with the SAME global batch, expert-sharding the params must cut the
     per-device compiled FLOPs (each device runs only its experts' MLPs) and
     live temp memory, not just the parameter bytes. GSPMD lowers the
@@ -242,7 +242,7 @@ def test_ep_actually_shards_expert_compute():
 
 @pytest.mark.slow  # tier-1 budget (PR 3): heavy; covered by cheaper siblings in-budget
 def test_moe_remat_matches_no_remat(moe_setup):
-    """--remat with MoE (VERDICT r3 #4): per-block rematerialization must
+    """--remat with MoE: per-block rematerialization must
     change memory, never math — identical loss/metrics and updated params,
     with the sown aux-loss/router-mass intermediates surviving nn.remat."""
     _, _, tx, inputs, targets = moe_setup
@@ -284,7 +284,7 @@ def test_moe_remat_matches_no_remat(moe_setup):
 
 @pytest.mark.slow  # tier-1 budget (PR 20): composition of two single-axis parities that stay in-budget (test_expert_parallel_matches_dp, test_lm.py::test_tp_matches_dp) — the PR 11 dp x tp convention
 def test_moe_tp_composition_matches_dp(moe_setup):
-    """MoE x TP (VERDICT r3 #4): a (data=2, expert=2, model=2) mesh with
+    """MoE x TP: a (data=2, expert=2, model=2) mesh with
     expert weights Megatron-split over 'model' on top of their 'expert'
     shard must reproduce the replicated-DP step."""
     from tpu_dist.parallel.ep import shard_state_ep
@@ -326,7 +326,7 @@ def test_moe_tp_composition_matches_dp(moe_setup):
 
 
 def test_moe_analytical_flops_accounting():
-    """The MoE MFU formula (VERDICT r3 #4): counts top_k-activated expert
+    """The MoE MFU formula: counts top_k-activated expert
     params (not all E) plus the dispatch/combine einsum term, and feeds a
     real (non-None) TFLOP/s figure through LMTrainer._mfu."""
     from tpu_dist.utils.mfu import lm_flops_per_token, moe_lm_flops_per_token
@@ -525,15 +525,6 @@ def test_moe_pp_tp_trains_via_lm_trainer():
     round-5 composition reachable end to end, not just via the pp.py
     makers (guard regression: the 'MoE + pure tensor parallelism' check
     must exempt pipeline meshes)."""
-    from tpu_dist._compat import PARTIAL_MANUAL_SHARD_MAP
-    if not PARTIAL_MANUAL_SHARD_MAP:
-        # the same gate test_pp's pp x tp test carries (PR 1 contract:
-        # _pp_shard_map raises cleanly on old jax, tests skip) — it was
-        # missing here and only surfaced once the tier-1 budget fix let
-        # the suite actually reach this file
-        pytest.skip("pp x tp needs partial-manual shard_map (jax >= 0.6); "
-                    "this jax's experimental shard_map aborts in the SPMD "
-                    "partitioner (_compat.PARTIAL_MANUAL_SHARD_MAP)")
     from tpu_dist.configs import LMConfig
     from tpu_dist.engine.lm_loop import LMTrainer
 
@@ -619,13 +610,6 @@ def test_moe_pp_tp_matches_pp(schedule):
     ON, so the only variable is the 'model' partitioning (pp == dp is
     covered by test_moe_pp_gpipe_matches_dp; aux is schedule-geometry
     dependent, see test_moe_pp_1f1b_matches_gpipe_with_aux)."""
-    from tpu_dist._compat import PARTIAL_MANUAL_SHARD_MAP
-    if not PARTIAL_MANUAL_SHARD_MAP:
-        # see test_moe_pp_tp_trains_via_lm_trainer: the test_pp gate,
-        # restored here once tier-1 started reaching this file
-        pytest.skip("pp x tp needs partial-manual shard_map (jax >= 0.6); "
-                    "this jax's experimental shard_map aborts in the SPMD "
-                    "partitioner (_compat.PARTIAL_MANUAL_SHARD_MAP)")
     from tpu_dist.parallel.pp import (make_lm_pp_1f1b_train_step,
                                       make_lm_pp_train_step,
                                       shard_state_pp, stack_pipeline_params,

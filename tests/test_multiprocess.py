@@ -1,4 +1,4 @@
-"""True multi-process jax.distributed execution (VERDICT r1 missing #2).
+"""True multi-process jax.distributed execution.
 
 The reference's identity is multi-process distributed training
 (reference 2.distributed.py:98 env:// rendezvous,
@@ -23,14 +23,6 @@ import sys
 
 import numpy as np
 import pytest
-
-from tpu_dist._compat import CPU_MULTIPROCESS
-
-pytestmark = pytest.mark.skipif(
-    not CPU_MULTIPROCESS,
-    reason="this jax's CPU backend has no multi-process computations "
-           "(_compat.CPU_MULTIPROCESS); the spawned workers would all "
-           "die with INVALID_ARGUMENT at the first collective")
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(ROOT, "tests", "mp_worker.py")
@@ -141,8 +133,8 @@ def test_multiprocess_windowed_device_data_matches(runs, tmp_path):
 
 
 def test_multiprocess_lm_params_match_single_process(tmp_path):
-    """The LM engine across 2 REAL processes == 1 process (VERDICT r2 #1's
-    bit-match requirement): same corpus, same sampler rows, same final
+    """The LM engine across 2 REAL processes == 1 process (the bit-match
+    requirement): same corpus, same sampler rows, same final
     parameters — including the HBM-resident windowed path, whose (K, B)
     index windows cross make_array_from_process_local_data."""
     worker = os.path.join(ROOT, "tests", "mp_lm_worker.py")
@@ -183,7 +175,7 @@ def test_multiprocess_lm_loss_chunk_matches_full(tmp_path):
 @pytest.mark.parametrize("mode", ["tp", "sp", "pp", "ep"])
 def test_multiprocess_model_parallel_matches_single(tmp_path, mode):
     """TP / SP / PP / EP train steps with the MODEL axis spanning 2 REAL
-    processes == the same mesh in one process (VERDICT r2 weak #4 — the
+    processes == the same mesh in one process (the
     last untested distribution regime): Megatron collectives, the ring
     ppermute, the pipeline stage hop, and the MoE expert dispatch each
     cross a jax.distributed process boundary."""

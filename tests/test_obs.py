@@ -3,8 +3,8 @@
 Covers: schema round-trip for every declared event type, tracer span
 nesting + accumulation, watchdog firing on an injected stall (and staying
 silent on a healthy loop) WITHOUT killing the run, the skew monitor's
-straggler math (single-process inline; 2 real processes via mp_obs_worker
-behind the CPU_MULTIPROCESS gate), both engines' CPU smoke runs producing
+straggler math (single-process inline; 2 real processes via
+mp_obs_worker), both engines' CPU smoke runs producing
 fully-populated step records, the epoch-CSV-as-sink parity, and the static
 schema checker as a plain test (tier-1 schema-drift tripwire)."""
 
@@ -248,10 +248,6 @@ def test_skew_monitor_two_real_processes(tmp_path):
     """Straggler detection over an actual process boundary: process 1
     reports 3x step times; every process's allgathered stats must agree
     that process 1 is the straggler (reuses the mp_worker spawn pattern)."""
-    from tpu_dist._compat import CPU_MULTIPROCESS
-    if not CPU_MULTIPROCESS:
-        pytest.skip("this jax's CPU backend has no multi-process "
-                    "computations (_compat.CPU_MULTIPROCESS)")
     from test_multiprocess import run_workers  # tests/ is on sys.path
 
     worker = os.path.join(ROOT, "tests", "mp_obs_worker.py")

@@ -224,7 +224,7 @@ def test_pp_1f1b_activation_memory_independent_of_microbatches():
 
 @pytest.mark.parametrize("schedule", ["gpipe", "1f1b"])
 def test_pp_dead_work_gated_per_stage(schedule):
-    """Dead-work gating (VERDICT r3 #1): in the optimized HLO every matmul
+    """Dead-work gating: in the optimized HLO every matmul
     — the full-vocab head, the embedding vjp scatter, AND the per-tick
     block compute — sits inside a lax.cond branch, so a stage executes the
     embed/head work only if it owns it and skips bubble ticks entirely.
@@ -266,11 +266,6 @@ def test_pp_tp_composition_matches_dp(schedule):
     pipeline schedule stays manual (shard_map) while 'model' runs as a
     GSPMD auto axis, so each stage's block math is Megatron-sharded —
     weights verifiably split over BOTH stage and model axes."""
-    from tpu_dist._compat import PARTIAL_MANUAL_SHARD_MAP
-    if not PARTIAL_MANUAL_SHARD_MAP:
-        pytest.skip("pp x tp needs partial-manual shard_map (jax >= 0.6); "
-                    "this jax's experimental shard_map aborts in the SPMD "
-                    "partitioner (_compat.PARTIAL_MANUAL_SHARD_MAP)")
     lm, params, tx, inputs, targets = _setup()
     key = jax.random.PRNGKey(1)
 

@@ -1,4 +1,4 @@
-"""Cookbook smoke tests (VERDICT r1 #9): every scripts/N.py entrypoint runs.
+"""Cookbook smoke tests: every scripts/N.py entrypoint runs.
 
 The suite otherwise tests the library; these run the actual CLI surface the
 README advertises — parser, per-variant defaults, launch.initialize, Trainer
@@ -51,14 +51,25 @@ def test_script_2_distributed(tmp_path):
 
 
 def test_script_3_spawn_two_processes(tmp_path):
-    from tpu_dist._compat import CPU_MULTIPROCESS
-    if not CPU_MULTIPROCESS:
-        pytest.skip("this jax's CPU backend has no multi-process "
-                    "computations (_compat.CPU_MULTIPROCESS)")
     out = run_script(tmp_path, "3.multiprocessing_spawn.py",
                      TINY + ck(tmp_path),
                      env_extra={"TPU_DIST_NPROCS_SPAWN": "2"})
     assert "best_acc1" in out
+
+
+def test_script_3_spawn_refuses_any_platform_but_cpu(tmp_path):
+    """Nothing gives a spawned child its own chip: off the CPU simulation
+    the parent stops with a message before any child can hang on a held
+    chip (and before it touches a backend itself)."""
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("TPU_DIST") and k != "XLA_FLAGS"}
+    env.update(JAX_PLATFORMS="tpu", TPU_DIST_NPROCS_SPAWN="2")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(SCRIPTS, "3.multiprocessing_spawn.py")],
+        env=env, cwd=str(tmp_path), capture_output=True, text=True,
+        timeout=120)
+    assert proc.returncode != 0
+    assert "JAX_PLATFORMS=cpu" in proc.stderr and "[proc" not in proc.stdout
 
 
 @pytest.mark.slow  # tier-1 budget (PR 3): heavy; covered by cheaper siblings in-budget
@@ -154,7 +165,7 @@ def test_tool_data_rate(tmp_path):
 def test_telemetry_csv_and_peak_hbm_column(tmp_path):
     """--telemetry-csv samples the 500ms device/host CSV (reference
     statistics.sh analog, C22) and the per-epoch CSV carries the peak-HBM
-    column (VERDICT r4 #5; empty value on CPU, where the backend exposes no
+    column (empty value on CPU, where the backend exposes no
     memory counters — the COLUMN must still exist)."""
     import csv as csv_mod
 

@@ -772,13 +772,6 @@ def test_elastic_shrink_after_rendezvous_loss_real_script(tmp_path):
     # completes a real single-process distributed init + training run
     import socket
 
-    from tpu_dist._compat import CPU_MULTIPROCESS
-    if not CPU_MULTIPROCESS:
-        pytest.skip("this jax's CPU backend refuses multi-process runs "
-                    "before rendezvous (_compat.CPU_MULTIPROCESS), so the "
-                    "2-process launch dies as 'crash', not 'rendezvous'; "
-                    "the shrink policy is covered by the fake-child twin")
-
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]

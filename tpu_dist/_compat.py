@@ -1,90 +1,23 @@
-"""jax version bridge (single home for every API the repo needs that moved).
+"""The one import site for the two JAX entry points every layer shares.
 
-The framework targets current jax (``jax.shard_map`` with ``check_vma``,
-``jax_num_cpu_devices``); CI containers sometimes carry an older wheel where
-the same features live under different names (``jax.experimental.shard_map``
-with ``check_rep``, ``XLA_FLAGS=--xla_force_host_platform_device_count``).
-Every call site imports from here so the difference is absorbed ONCE instead
-of leaking try/excepts through the engines.
+The repo targets the installed jax (0.9: top-level ``jax.shard_map`` with
+``check_vma``, the ``jax_num_cpu_devices`` option). Call sites import from
+here so the spelling lives once.
 """
 
 from __future__ import annotations
 
-import os
-
 import jax
-
-try:  # jax >= 0.6: top-level export, varying-manual-axes check is check_vma
-    from jax import shard_map as _shard_map
-    _CHECK_KW = "check_vma"
-except ImportError:  # older jax: experimental home, same check named check_rep
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
-# Partial-manual shard_map (manual 'data'/'stage' with a GSPMD *auto*
-# 'model' axis — the pp x tp composition) only works on the current-jax
-# implementation: the experimental one lowers axis_index to a PartitionId
-# the SPMD partitioner rejects, and resharding auto-axis operands inside
-# the manual region trips an XLA IsManualSubgroup CHECK (process abort).
-# Callers gate the composition on this flag to fail cleanly instead.
-PARTIAL_MANUAL_SHARD_MAP = _CHECK_KW == "check_vma"
-
-# True multi-process execution on the CPU backend (jax.distributed over
-# loopback with cross-process collectives — the multi-host simulation the
-# mp tests spawn) needs the current-jax CPU collectives; the older wheel's
-# CPU backend raises "Multiprocess computations aren't implemented".
-# Single-process virtual-device meshes are unaffected.
-CPU_MULTIPROCESS = _CHECK_KW == "check_vma"
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True, **kwargs):
-    """`jax.shard_map` signature (keyword mesh/specs, ``check_vma``,
-    ``axis_names`` = the MANUAL axes), executed by whichever implementation
-    this jax ships. Older jax spells the manual-axes selection as its
-    complement (``auto`` = the GSPMD axes), so translate through the mesh."""
-    kwargs[_CHECK_KW] = check_vma
-    if _CHECK_KW == "check_rep" and "axis_names" in kwargs:
-        # NOTE: the repo's only axis_names caller (_pp_shard_map) refuses
-        # old jax first (PARTIAL_MANUAL_SHARD_MAP) because a non-empty
-        # 'auto' set aborts in the old SPMD partitioner; this translation
-        # is kept for the all-axes-manual case (auto = {}), which old jax
-        # runs fine
-        manual = kwargs.pop("axis_names")
-        kwargs["auto"] = frozenset(mesh.axis_names) - frozenset(manual)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
+    """``jax.shard_map`` with keyword mesh/specs; ``axis_names`` = the
+    MANUAL axes (the rest stay GSPMD auto axes)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma, **kwargs)
 
 
 def set_cpu_device_count(n: int) -> None:
     """Request ``n`` virtual CPU devices. Must run before the backend
-    initializes (conftest / driver entry time). Newer jax has a config
-    option; older jax only reads the XLA host-platform flag."""
-    try:
-        jax.config.update("jax_num_cpu_devices", n)
-    except AttributeError:
-        import re
-        try:  # flags are parsed once at backend init — writing them later
-            # is a silent no-op, so refuse loudly instead (the caller
-            # would otherwise die far downstream at "need N devices")
-            from jax._src import xla_bridge as _xb
-            initialized = bool(getattr(_xb, "_backends", None))
-        except Exception:
-            initialized = False
-        if initialized:
-            raise RuntimeError(
-                f"set_cpu_device_count({n}): this jax has no "
-                "jax_num_cpu_devices option and a backend is already "
-                "initialized, so the XLA_FLAGS fallback "
-                "(--xla_force_host_platform_device_count) can no longer "
-                "take effect. Call set_cpu_device_count before anything "
-                "touches jax.devices()/jit.")
-        flags = os.environ.get("XLA_FLAGS", "")
-        flag = f"--xla_force_host_platform_device_count={n}"
-        if "xla_force_host_platform_device_count" in flags:
-            # a stale count (e.g. a leftover =2 from a manual run) must be
-            # REPLACED, or every mesh sized for n devices fails to build
-            flags = re.sub(r"--xla_force_host_platform_device_count=\d+",
-                           flag, flags)
-            os.environ["XLA_FLAGS"] = flags
-        else:
-            os.environ["XLA_FLAGS"] = (flags + " " + flag).strip()
+    initializes (conftest / driver entry time)."""
+    jax.config.update("jax_num_cpu_devices", n)

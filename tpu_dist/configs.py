@@ -105,8 +105,9 @@ class TrainConfig:
     # -- dispatch/data-path tuning (TPU-only; no reference analog — its
     #    per-batch host loop was the bottleneck the prefetcher fought, C13)
     steps_per_dispatch: int = 1        # K optimizer steps per XLA dispatch
-                                       # (lax.scan window; amortizes controller
-                                       # latency — requires variant 'jit')
+                                       # (lax.scan window; amortizes per-
+                                       # dispatch host latency — requires
+                                       # variant 'jit')
     grad_accum_steps: int = 1          # microbatches per optimizer step: the
                                        # global batch is split into N
                                        # sequential microbatches whose grads
@@ -276,10 +277,12 @@ class LMConfig:
     optimizer: str = "sgd"         # sgd | adamw (decoupled, b2=0.95 LM
                                    # convention — ops.optim.make_optimizer)
                                    # | fused_adamw (Pallas single-pass
-                                   # kernel, ops.pallas_adamw; measured
-                                   # SLOWER than adamw at 0.9B — BASELINE.md
-                                   # round-5 — kept as the apex-FusedAdam
-                                   # capability analog)
+                                   # kernel, ops.pallas_adamw; an earlier
+                                   # round found it SLOWER than adamw at
+                                   # 0.9B — a lead, not measured on the
+                                   # installed machine, ROADMAP D5 — kept
+                                   # as the apex-FusedAdam capability
+                                   # analog)
     lr: float = 3e-2
     momentum: float = 0.9
     adam_b1: float = 0.9

@@ -1,4 +1,4 @@
-"""LMTrainer: the language-model twin of engine.loop.Trainer (VERDICT r2 #1).
+"""LMTrainer: the language-model twin of engine.loop.Trainer.
 
 Round 2 drove the LM parallelism surface (dp/tp/sp/pp/ep/fsdp, flash, remat)
 from a fixed-batch demo loop in scripts/8; this module gives the LM family
@@ -46,6 +46,7 @@ from tpu_dist.obs import (HealthError, RunObs, faults, profile_session,
 from tpu_dist.ops import lm_lr_schedule, make_optimizer, make_policy
 from tpu_dist.parallel.mesh import make_mesh, replicated
 from tpu_dist.parallel.supervisor import PREEMPT_SNAPSHOT_RC
+from tpu_dist.runtime import pallas_interpret
 from tpu_dist.utils.meters import MeterBank
 
 
@@ -126,10 +127,18 @@ class LMTrainer:
         self.local_batch = cfg.batch_size // nprocs
 
         # ---- model ----
-        self.model, self._model_ctor_kw = self._build_model()
-        params = self.model.init(
-            {"params": jax.random.PRNGKey(seed)},
-            np.zeros((1, cfg.seq_len), np.int32), train=False)["params"]
+        self.model, self._model_ctor_kw, self.decode_model = \
+            self._build_model()
+        # init through the single-device twin (a one-row dummy batch does
+        # not divide over a mesh-bound attention kernel's data axis), as ONE
+        # compiled program: eager flax init dispatches some sixty tiny
+        # programs, which on the chip is most of a cold start's compile
+        # count (87 -> 11 in the smoke's LM phase). On the CPU backend the
+        # values are bitwise the eager ones (dense, int8 and MoE checked);
+        # on the chip they differ in the last bits, as any fused program may.
+        params = jax.jit(lambda key: self.decode_model.init(
+            {"params": key}, np.zeros((1, cfg.seq_len), np.int32),
+            train=False)["params"])(jax.random.PRNGKey(seed))
         if cfg.pretrained:
             # warm-start BEFORE any pipeline stacking, so the donor must be
             # an UNSTACKED (per-block) param tree. Non-pp runs save exactly
@@ -163,7 +172,7 @@ class LMTrainer:
         self.steps_per_epoch = max(
             1, -(-len(self.train_ds) // cfg.batch_size))
         # warmup + constant/cosine/step LR as a pure function of the step
-        # count inside the jitted update (VERDICT r3 #2); the count lives in
+        # count inside the jitted update; the count lives in
         # the checkpointed optax state, so --resume continues the trajectory
         total_steps = (cfg.lr_decay_steps or cfg.max_steps
                        or cfg.epochs * self.steps_per_epoch)
@@ -188,7 +197,7 @@ class LMTrainer:
                                  weight_decay=cfg.weight_decay,
                                  clip_norm=0.0 if self.use_pp
                                  else cfg.grad_clip,
-                                 interpret=jax.default_backend() != "tpu")
+                                 interpret=pallas_interpret())
         else:
             self.tx = make_optimizer(cfg.lr, cfg.momentum, cfg.weight_decay,
                                      schedule=self.lr_schedule,
@@ -256,7 +265,7 @@ class LMTrainer:
                 self.val_ds.rows_array(), replicated(self.mesh))
             # every mode gets the K-steps-per-dispatch window path: the jit
             # modes via the GSPMD step, sp/pp via a lax.scan over index
-            # windows INSIDE their shard_map programs (VERDICT r3 #3)
+            # windows INSIDE their shard_map programs
             if self.use_pp:
                 from tpu_dist.parallel.pp import (
                     make_lm_pp_indexed_eval_step,
@@ -470,12 +479,24 @@ class LMTrainer:
         cfg = self.cfg
         import jax.numpy as jnp
 
+        train_attn_fn = None  # set where training needs a mesh-bound twin
         if cfg.attn == "blockwise":
             from tpu_dist.ops.flash_attention import blockwise_attention_fn
             attn_fn = blockwise_attention_fn(cfg.attn_block)
         elif cfg.attn == "flash":
             from tpu_dist.ops.flash_attention import flash_attention_fn
             attn_fn = flash_attention_fn(block_k=cfg.attn_block)
+            if not (self.use_sp or self.use_pp or self.use_ring
+                    or self.use_bucket):
+                # the compiler-partitioned modes (dp/fsdp/tp/ep): a Mosaic
+                # kernel cannot be partitioned by GSPMD, so on a multi-
+                # device mesh the kernel runs per shard — batch rows over
+                # 'data', heads over 'model'. The manual modes above
+                # already call it from inside their own shard_map.
+                train_attn_fn = flash_attention_fn(
+                    block_k=cfg.attn_block, mesh=self.mesh,
+                    spec=P("data", None,
+                           "model" if self.use_tp else None, None))
         elif cfg.attn == "full":
             from tpu_dist.models.transformer import full_attention
             attn_fn = full_attention
@@ -489,7 +510,8 @@ class LMTrainer:
         lm_kw = dict(vocab_size=self.vocab_size, num_layers=cfg.num_layers,
                      d_model=cfg.d_model, num_heads=cfg.num_heads,
                      max_len=cfg.seq_len, dtype=self.policy.compute_dtype,
-                     attn_fn=attn_fn, remat=cfg.remat, quant=cfg.quant)
+                     attn_fn=train_attn_fn or attn_fn, remat=cfg.remat,
+                     quant=cfg.quant)
         if cfg.num_experts:
             from tpu_dist.models.moe import MoETransformerLM
             # the MoE knobs ride in the ctor kwargs so EVERY mode (jit, sp
@@ -502,7 +524,11 @@ class LMTrainer:
         else:
             from tpu_dist.models.transformer import tiny_lm
             model = tiny_lm(**lm_kw)
-        return model, lm_kw
+        # generate/serve apply these weights on ONE device: same attention
+        # math, no training mesh bound into the kernel call
+        decode_model = (model if train_attn_fn is None
+                        else model.clone(attn_fn=attn_fn))
+        return model, lm_kw, decode_model
 
     def _build_steps(self):
         cfg = self.cfg
@@ -532,6 +558,9 @@ class LMTrainer:
             ctor = partial(MoETransformerLM if cfg.num_experts else tiny_lm,
                            **kw)
             self._sp_ctor = ctor  # the windowed sp steps rebind it per-axis
+            # sp's training model closes over mesh axis names (ring
+            # attention); decode with the full-attention equivalent
+            self.decode_model = ctor()
             self.train_step = make_lm_sp_train_step(
                 ctor, self.tx, self.mesh, loss_chunk=cfg.loss_chunk,
                 aux_weight=cfg.moe_aux_weight, health=cfg.health)
@@ -1107,7 +1136,7 @@ class LMTrainer:
         + top_k-activated expert params + the GShard dispatch/combine
         einsums) — XLA's cost model counts scan bodies once and cannot cost
         Pallas custom calls, so it understates flash runs, and it cannot
-        see how many experts a token activates (VERDICT r3 #4)."""
+        see how many experts a token activates."""
         from tpu_dist.utils.mfu import peak_tflops_for
         if not self._device_step_flops():
             return None, None

@@ -40,6 +40,7 @@ from tpu_dist.obs import (HealthError, RunObs, faults, profile_session,
 from tpu_dist.ops import LossScaleState, make_optimizer, make_policy, step_decay_schedule
 from tpu_dist.parallel.mesh import batch_sharding, make_mesh, replicated
 from tpu_dist.parallel.supervisor import PREEMPT_SNAPSHOT_RC
+from tpu_dist.runtime import pallas_interpret
 from tpu_dist.utils.meters import MeterBank
 
 
@@ -194,7 +195,7 @@ class Trainer:
         if cfg.optimizer == "fused_sgd":  # validated at __init__ entry
             from tpu_dist.ops.pallas_sgd import FusedSGD
             self.tx = FusedSGD(self.schedule, cfg.momentum, cfg.weight_decay,
-                               interpret=jax.default_backend() == "cpu")
+                               interpret=pallas_interpret())
         else:
             self.tx = make_optimizer(
                 cfg.lr, cfg.momentum, cfg.weight_decay, self.steps_per_epoch,
@@ -263,7 +264,7 @@ class Trainer:
                 health=cfg.health)
         self.eval_step = make_eval_step(self.model, eval_transform, self.mesh)
 
-        # K-steps-per-dispatch window (VERDICT r1 #3: the bench's multi-step
+        # K-steps-per-dispatch window (the bench's multi-step
         # machinery wired into real training). Math is identical to K
         # sequential dispatches; only the host round-trip count changes.
         self.k = cfg.steps_per_dispatch
@@ -737,7 +738,7 @@ class Trainer:
                           for i in range(0, len(batches), self.k))]
 
     def _train_epoch_windowed(self, epoch: int) -> Dict[str, float]:
-        """K-steps-per-dispatch epoch (VERDICT r1 #3): same math as the
+        """K-steps-per-dispatch epoch: same math as the
         per-batch loop, ~1/K the host round-trips, and (device mode) only
         index windows cross the host->device link."""
         cfg = self.cfg
@@ -971,9 +972,9 @@ class Trainer:
             acc1 = self.validate(epoch)
             epoch_secs = time.time() - t0
             # end-to-end train-phase rate (loader + dispatch + device), the
-            # number the bench's device rate is compared against in
-            # BASELINE.md; counts only batches actually trained (a resumed
-            # mid-epoch runs fewer than steps_per_epoch)
+            # number the bench's device rate is compared against; counts
+            # only batches actually trained (a resumed mid-epoch runs
+            # fewer than steps_per_epoch)
             train_imgs = train_metrics.get(
                 "batches", self.steps_per_epoch) * cfg.batch_size
             train_ips = train_imgs / max(train_secs, 1e-9)
@@ -981,7 +982,7 @@ class Trainer:
             self.best_acc1 = max(acc1, self.best_acc1)
             # the epoch record; the legacy CSV row (reference format
             # [wall start, epoch seconds] + train-img/s and peak-HBM
-            # columns, VERDICT r4 #5) renders from THIS event via the
+            # columns) renders from THIS event via the
             # EpochCsvSink the obs layer registered — one source of truth.
             # hbm: allocator truth when the backend exposes it, else XLA's
             # static per-program analysis (empty when neither exists)

@@ -234,10 +234,10 @@ def make_multi_train_step(model, tx, transform, mesh: Mesh,
     """K optimizer steps in ONE dispatch: lax.scan over stacked batches.
 
     signature: (state, images_u8 (K,B,...), labels (K,B), rng) -> (state,
-    metrics summed over the K steps). The TPU-idiomatic answer to dispatch
-    latency on a remote/high-latency controller link (the reference's analog
-    concern was CUDA-stream overlap, C13): the whole window executes on-device
-    with zero host round-trips. K is a trace-time constant (leading dim).
+    metrics summed over the K steps). The TPU-idiomatic answer to per-
+    dispatch host latency (the reference's analog concern was CUDA-stream
+    overlap, C13): the whole window executes on-device with zero host
+    round-trips. K is a trace-time constant (leading dim).
     """
     plan = Plan(engine="image", window="stacked", data_axis=data_axis,
                 donate=donate, health=health)

@@ -66,7 +66,8 @@ __all__ = ["EVENT_SCHEMA", "EpochCsvSink", "FlightRecorder",
 def effective_peak_tflops() -> tuple:
     """(peak_tflops, is_nominal): the device's published bf16 peak, or the
     nominal fallback that keeps per-step MFU non-null on CPU/virtual
-    backends (MFU then reads as model TFLOP/s per chip)."""
+    backends (MFU then reads as model TFLOP/s per chip). An unlisted TPU
+    raises (utils.mfu.lookup_peak)."""
     import jax
     from tpu_dist.utils.mfu import peak_tflops_for
 

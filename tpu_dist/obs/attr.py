@@ -54,7 +54,7 @@ _SHAPE_RE = re.compile(
     r"\b(" + "|".join(sorted(_DTYPES, key=len, reverse=True))
     + r")\[([0-9,]*)\]")
 _COMP_RE = re.compile(r"^\s*(ENTRY\s+)?%?([\w.\-]+)\s*\(.*->.*\{\s*$")
-_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s*(.*)$")
+_INSTR_RE = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*(.*)$")
 _OPCODE_RE = re.compile(r"\s*([A-Za-z][\w\-]*)")
 _OPNAME_RE = re.compile(r'op_name="([^"]*)"')
 _CALLS_RE = re.compile(r"calls=%?([\w.\-]+)")
@@ -107,15 +107,41 @@ def _split_output_shape(rest: str):
     return (rest, "") if i < 0 else (rest[:i], rest[i:])
 
 
-class _Instr:
-    __slots__ = ("opcode", "out_shape", "tail", "op_name", "line")
+def _operand_tokens(tail: str) -> List[str]:
+    """The top-level comma-separated operands of 'opcode(a, b), attrs...'
+    (``tail`` starts at the opening paren). Shape literals carry commas
+    inside [] and {}, so split at bracket depth 1 only."""
+    if not tail.startswith("("):
+        return []
+    out, depth, start = [], 0, 1
+    for i, ch in enumerate(tail):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+            if depth == 0:
+                out.append(tail[start:i])
+                break
+        elif ch == "," and depth == 1:
+            out.append(tail[start:i])
+            start = i + 1
+    return [t.strip() for t in out if t.strip()]
 
-    def __init__(self, opcode, out_shape, tail, op_name, line):
+
+class _Instr:
+    __slots__ = ("opcode", "out_shape", "tail", "op_name", "operands")
+
+    def __init__(self, opcode, out_shape, tail, op_name, operands):
         self.opcode = opcode
         self.out_shape = out_shape
         self.tail = tail          # everything after the opcode (operands+attrs)
         self.op_name = op_name
-        self.line = line
+        self.operands = operands  # one shape segment per operand
+
+    @property
+    def io_shapes(self) -> str:
+        """Result + operand shape text: what crosses the op's boundary."""
+        return " ".join([self.out_shape, *self.operands])
 
 
 def _parse_computations(hlo_text: str):
@@ -123,11 +149,13 @@ def _parse_computations(hlo_text: str):
     comps: Dict[str, List[_Instr]] = {}
     entry = None
     cur: Optional[List[_Instr]] = None
+    shapes: Dict[str, str] = {}   # instruction name -> its result shape
     for raw in hlo_text.splitlines():
         m = _COMP_RE.match(raw)
         if m and "=" not in raw.split("(")[0]:
             name = m.group(2)
             cur = comps.setdefault(name, [])
+            shapes = {}
             if m.group(1):
                 entry = name
             continue
@@ -139,7 +167,7 @@ def _parse_computations(hlo_text: str):
         mi = _INSTR_RE.match(raw)
         if not mi:
             continue
-        rest = mi.group(1)
+        rest = mi.group(2)
         # metadata can quote arbitrary jax scope strings — take op_name
         # out first, then drop the block so it can't read as shapes
         mo = _OPNAME_RE.search(rest)
@@ -149,20 +177,25 @@ def _parse_computations(hlo_text: str):
         mop = _OPCODE_RE.match(tail)
         if not mop:
             continue
-        cur.append(_Instr(mop.group(1), shape_seg, tail[mop.end():],
-                          op_name, rest))
+        shapes[mi.group(1)] = shape_seg
+        tail = tail[mop.end():]
+        # operands print as 'f32[4,8]{1,0} %a' or, on current XLA, as the
+        # bare name '%a' — resolve those against the defining instruction
+        # (HLO is in def-before-use order within a computation)
+        operands = [t if _SHAPE_RE.search(t) else shapes.get(t.lstrip("%"), "")
+                    for t in _operand_tokens(tail)]
+        cur.append(_Instr(mop.group(1), shape_seg, tail, op_name, operands))
     return comps, entry
 
 
 def _dot_flops(instr: _Instr) -> float:
-    """2 * |output| * K, K = product of the lhs contracting dim sizes
-    (operand shapes are inline in optimized HLO call sites)."""
+    """2 * |output| * K, K = product of the lhs contracting dim sizes."""
     out = sum(_dims(m.group(2)) for m in _SHAPE_RE.finditer(instr.out_shape))
-    operands = [m for m in _SHAPE_RE.finditer(instr.tail)]
+    lhs = _SHAPE_RE.search(instr.operands[0]) if instr.operands else None
     mc = _LHS_CDIMS_RE.search(instr.tail)
-    if not operands or mc is None:
+    if lhs is None or mc is None:
         return 2.0 * out
-    lhs_dims = [int(d) for d in operands[0].group(2).split(",") if d]
+    lhs_dims = [int(d) for d in lhs.group(2).split(",") if d]
     k = 1
     for i in (int(x) for x in mc.group(1).split(",") if x):
         if i < len(lhs_dims):
@@ -174,11 +207,12 @@ def _conv_flops(instr: _Instr) -> float:
     """2 * |output| * (kernel spatial x in-channels) — prod(kernel)/C_out,
     with C_out read off the dim_labels 'o' position."""
     out = sum(_dims(m.group(2)) for m in _SHAPE_RE.finditer(instr.out_shape))
-    operands = [m for m in _SHAPE_RE.finditer(instr.tail)]
+    rhs = (_SHAPE_RE.search(instr.operands[1])
+           if len(instr.operands) > 1 else None)
     ml = _DIM_LABELS_RE.search(instr.tail)
-    if len(operands) < 2 or ml is None:
+    if rhs is None or ml is None:
         return 2.0 * out
-    kernel = [int(d) for d in operands[1].group(2).split(",") if d]
+    kernel = [int(d) for d in rhs.group(2).split(",") if d]
     o_pos = ml.group(2).find("o")
     c_out = kernel[o_pos] if 0 <= o_pos < len(kernel) else 1
     import math
@@ -242,8 +276,7 @@ def _walk(name: str, comps: dict, acc: dict, fusion_cat: Optional[str],
             cat = _categorize(instr) if fusion_cat is None else fusion_cat
             if fusion_cat is None:
                 # the fusion boundary is where HBM traffic happens
-                _add(acc, cat,
-                     0.0, _shapes_bytes(instr.out_shape + instr.tail))
+                _add(acc, cat, 0.0, _shapes_bytes(instr.io_shapes))
             mc = _CALLS_RE.search(instr.tail)
             if mc:
                 _walk(mc.group(1), comps, acc, cat, visiting)
@@ -264,7 +297,7 @@ def _walk(name: str, comps: dict, acc: dict, fusion_cat: Optional[str],
         if fusion_cat is not None and cat in ("elementwise", "custom-call"):
             cat = fusion_cat  # fusion residue
         nbytes = (0.0 if fusion_cat is not None
-                  else _shapes_bytes(instr.out_shape + instr.tail))
+                  else _shapes_bytes(instr.io_shapes))
         _add(acc, cat, _instr_flops(instr), nbytes)
 
 
@@ -313,15 +346,18 @@ PEAK_GBPS = (
 def effective_peak_gbps() -> tuple:
     """(peak_gbps, is_nominal): published HBM bandwidth of device 0, or the
     ``TPU_DIST_NOMINAL_PEAK_GBPS`` fallback (default 1.0) that keeps the
-    roofline's memory bound non-null on CPU/virtual backends."""
+    roofline's memory bound non-null on CPU/virtual backends. An unlisted
+    TPU raises (utils.mfu.lookup_peak)."""
     import os
 
     import jax
 
-    kind = getattr(jax.devices()[0], "device_kind", "").lower()
-    for key, peak in PEAK_GBPS:
-        if key in kind:
-            return peak, False
+    from tpu_dist.utils.mfu import lookup_peak
+
+    peak = lookup_peak(getattr(jax.devices()[0], "device_kind", ""),
+                       PEAK_GBPS, "HBM GB/s")
+    if peak:
+        return peak, False
     return float(os.environ.get("TPU_DIST_NOMINAL_PEAK_GBPS", "1.0")), True
 
 

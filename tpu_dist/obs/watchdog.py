@@ -1,8 +1,8 @@
 """Hang watchdog: stack + HBM dump when a step stops completing.
 
 A hung collective (one host dropped out), a deadlocked loader thread, or a
-device queue stuck behind a tunneled controller all present the same way: a
-training loop that silently stops printing, forever. The reference cookbook
+wedged device queue all present the same way: a training loop that silently
+stops printing, forever. The reference cookbook
 — and rounds 1-5 of this repo — would sit there until someone killed the
 job with zero forensic record.
 

@@ -30,6 +30,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from tpu_dist.runtime import pallas_interpret
+
 NEG_INF = -1e30  # avoids -inf - -inf = nan in the online max updates
 
 
@@ -516,8 +518,7 @@ def int8kv_flash_attention_fn(block_q: int = 1024, block_k: int | None = None,
         from jax.experimental.pallas import tpu as pltpu
 
         kq, ks, vq, vs = kv
-        use_interpret = (interpret if interpret is not None
-                         else jax.default_backend() != "tpu")
+        use_interpret = pallas_interpret(interpret)
         b, lq, h, d = q.shape
         lk = kq.shape[1]
         bq, bk = _blocks(lq, lk, block_q, block_k)
@@ -562,11 +563,20 @@ def int8kv_flash_attention_fn(block_q: int = 1024, block_k: int | None = None,
 @functools.lru_cache(maxsize=None)
 def flash_attention_fn(block_q: int = 1024, block_k: int | None = None,
                        interpret: bool | None = None,
-                       recompute_block: int | None = None):
+                       recompute_block: int | None = None,
+                       mesh=None, spec=None):
     """Returns attn(q, k, v, causal=True, q_offset=0, kv_offset=0) backed by
     the Pallas FlashAttention-2 kernels (forward AND backward — the backward
     recomputes scores from the stashed logsumexp, it does not re-run a full
     blockwise forward).
+
+    ``mesh`` + ``spec`` (a PartitionSpec over the (B, L, H, D) operands) are
+    for a model traced under a compiler-partitioned (GSPMD) step on more
+    than one device: the TPU compiler refuses to partition a Mosaic kernel
+    ("cannot be automatically partitioned"), so the returned attn runs the
+    kernel per shard inside ``shard_map`` — attention is independent across
+    batch rows and heads, so sharding those two axes needs no collective.
+    Leave both unset on one device or inside an already-manual program.
 
     ``interpret=None`` auto-selects interpreter mode off-TPU so the same
     code runs in the CPU test mesh. ``recompute_block`` is a legacy alias
@@ -584,10 +594,7 @@ def flash_attention_fn(block_q: int = 1024, block_k: int | None = None,
     if block_k is None:
         block_k = 1024
 
-    def pick_interpret():
-        if interpret is not None:
-            return interpret
-        return jax.default_backend() != "tpu"
+    pick_interpret = functools.partial(pallas_interpret, interpret)
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
     def attn_core(q, k, v, causal, q_offset, kv_offset):
@@ -611,4 +618,17 @@ def flash_attention_fn(block_q: int = 1024, block_k: int | None = None,
     def attn(q, k, v, *, causal: bool = True, q_offset=0, kv_offset=0):
         return attn_core(q, k, v, causal, q_offset, kv_offset)
 
-    return attn
+    if mesh is None or mesh.devices.size == 1:
+        return attn
+
+    from tpu_dist._compat import shard_map
+
+    def attn_per_shard(q, k, v, *, causal: bool = True, q_offset=0,
+                       kv_offset=0):
+        return shard_map(
+            functools.partial(attn, causal=causal, q_offset=q_offset,
+                              kv_offset=kv_offset),
+            mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+            check_vma=False)(q, k, v)
+
+    return attn_per_shard

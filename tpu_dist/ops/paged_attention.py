@@ -62,6 +62,7 @@ from jax.sharding import PartitionSpec as P
 from tpu_dist._compat import shard_map
 from tpu_dist.ops.flash_attention import _STAT_LANES, NEG_INF, _blocks, _fold
 from tpu_dist.parallel.mesh import SP_AXIS
+from tpu_dist.runtime import pallas_interpret
 
 
 def pages_for(length: int, page_size: int) -> int:
@@ -349,8 +350,7 @@ def int8kv_paged_flash_attention_fn(block_k: int = 512,
         import jax.experimental.pallas as pl
         from jax.experimental.pallas import tpu as pltpu
 
-        use_interpret = (interpret if interpret is not None
-                         else jax.default_backend() != "tpu")
+        use_interpret = pallas_interpret(interpret)
         b, lq, h, d = q.shape
         if lq != 1:
             raise ValueError(f"paged decode kernel is one query per row "

@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from tpu_dist.runtime import pallas_interpret
+
 _INT8_MAX = 127.0
 _EPS = 1e-8          # all-zero rows/channels: scale floor keeps q = 0
 BLOCK_M = 128        # default output tile rows per grid cell
@@ -168,12 +170,6 @@ def _fused_quant_matmul_2d(x2, w, interpret: bool):
     return out[:m, :n]
 
 
-def _pick_interpret(interpret):
-    if interpret is not None:
-        return interpret
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def fused_quant_matmul(x, w, interpret=None):
     """``quant_matmul(x, w, 'int8')`` as one fused Pallas kernel.
@@ -185,7 +181,7 @@ def fused_quant_matmul(x, w, interpret=None):
     the straight-through estimator — the vjp of the FP matmul on the
     unquantized operands. ``interpret=None`` auto-selects interpreter mode
     off-TPU (the pallas_adamw convention)."""
-    return _fused_fwd_impl(x, w, _pick_interpret(interpret))
+    return _fused_fwd_impl(x, w, pallas_interpret(interpret))
 
 
 def _fused_fwd_impl(x, w, interpret: bool):
@@ -195,7 +191,7 @@ def _fused_fwd_impl(x, w, interpret: bool):
 
 
 def _fused_fwd(x, w, interpret):
-    return _fused_fwd_impl(x, w, _pick_interpret(interpret)), (x, w)
+    return _fused_fwd_impl(x, w, pallas_interpret(interpret)), (x, w)
 
 
 def _fused_bwd(interpret, res, g):

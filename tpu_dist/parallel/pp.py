@@ -26,7 +26,7 @@ schedulers — ONE shard_map program per device where
   the (exactly-zero elsewhere) embed/head gradients restores the replicated
   update. Block gradients stay stage-local. At a real vocabulary the head
   is ~25% of model FLOPs, so this gating is what makes S stages cost ~1x
-  head work instead of Sx (VERDICT r3 weak #1).
+  head work instead of Sx.
 
 Composes with data parallelism as a ('data', 'stage') mesh: batch rows
 shard over 'data', gradients pmean over 'data' exactly like the other
@@ -173,15 +173,6 @@ def _pp_shard_map(mesh: Mesh, per_device, in_specs, out_specs,
     math Megatron-style over 'model' (pp x tp composition; round-2 gap)."""
     kwargs = {}
     if _uses_tp(mesh):
-        from tpu_dist._compat import PARTIAL_MANUAL_SHARD_MAP
-        if not PARTIAL_MANUAL_SHARD_MAP:
-            raise RuntimeError(
-                "pp x tp needs partial-manual shard_map (an auto 'model' "
-                "axis inside the manual pipeline program); this jax "
-                f"({jax.__version__}) only ships the experimental "
-                "shard_map, whose SPMD partitioner aborts on that "
-                "composition. Upgrade jax, or drop the 'model' axis "
-                "(plain pp) / the 'stage' axis (plain tp).")
         kwargs["axis_names"] = frozenset({data_axis, stage_axis})
     return shard_map(per_device, mesh=mesh, in_specs=in_specs,
                      out_specs=out_specs, check_vma=False, **kwargs)
@@ -509,7 +500,7 @@ def make_lm_pp_1f1b_train_step(model, tx, mesh: Mesh, num_microbatches: int,
                                loss_chunk: int = 0,
                                grad_clip: float = 0.0,
                                health: str = "record") -> Callable:
-    """1F1B pipeline train step (PipeDream-flush schedule, VERDICT r2 #4).
+    """1F1B pipeline train step (PipeDream-flush schedule).
 
     Same signature/state layout as :func:`make_lm_pp_train_step`, different
     schedule: each of the ``M + 2(S-1)`` lockstep ticks runs ONE forward and
@@ -529,7 +520,7 @@ def make_lm_pp_1f1b_train_step(model, tx, mesh: Mesh, num_microbatches: int,
     mean; block grads stay stage-local, embed/head grads psum over 'stage',
     everything pmeans over 'data'.
 
-    Round 5 closes the three 1f1b composition holes (VERDICT r4 #2): MoE
+    Round 5 closes the three 1f1b composition holes: MoE
     router aux losses thread through the manual vjp as an explicit
     cotangent, ``loss_chunk`` > 0 runs the chunked CE (ops.fused_xent) on
     the last-stage head, and ``grad_clip`` > 0 clips by the cross-stage
@@ -817,8 +808,7 @@ def make_lm_pp_indexed_multi_train_step(model, tx, mesh: Mesh,
                                         grad_clip: float = 0.0,
                                         health: str = "record"
                                         ) -> Callable:
-    """K pipeline optimizer steps per dispatch from HBM-resident rows
-    (VERDICT r3 #3): a lax.scan over (K, B) index windows INSIDE the
+    """K pipeline optimizer steps per dispatch from HBM-resident rows: a lax.scan over (K, B) index windows INSIDE the
     shard_map program, so pipeline runs amortize the host round-trip the
     same way the jit modes do.
 

@@ -76,6 +76,12 @@ def ring_attention(q, k, v, axis_name: str = SEQ_AXIS, causal: bool = True):
     o0 = jnp.zeros((b, lq, h, d), jnp.float32)
     m0 = jnp.full((b, h, lq), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, h, lq), jnp.float32)
+    # the accumulators leave the first fold varying over every manual axis
+    # q/k/v vary over, and shard_map's check_vma wants the scan carry typed
+    # the same going in (empty under check_vma=False: nothing to cast)
+    vma = tuple(jax.typeof(q).vma | jax.typeof(k).vma | jax.typeof(v).vma)
+    if vma:
+        o0, m0, l0 = (lax.pcast(x, vma, to="varying") for x in (o0, m0, l0))
     (o, m, l, _, _), _ = lax.scan(fold, (o0, m0, l0, k, v),
                                   jnp.arange(axis_size))
     # rows with no visible keys (can't happen causally: every row sees itself)

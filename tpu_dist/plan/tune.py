@@ -39,7 +39,8 @@ TUNE_VERSION = 1
 # int8 MXU dots run up to 2x the bf16 rate, but ONLY when the quantize/
 # dequant ladder stays in VMEM (the fused Pallas kernel, PR 9); the
 # reference einsum path pays int8/int32 HBM round trips that eat the gain
-# (BASELINE.md round-9 measurement). Encoded as compute-peak factors.
+# (a lead from an earlier round, not measured on the installed machine).
+# Encoded as compute-peak factors.
 _COMPUTE_FACTOR = {
     ("none", False): 1.0, ("none", True): 1.0,
     ("int8", False): 1.0, ("int8", True): 2.0,
@@ -54,8 +55,8 @@ _WEIGHT_BYTES_FACTOR = {
     ("int8_wo", False): 0.5, ("int8_wo", True): 0.5,
 }
 
-# per-dispatch host latency the window amortizes (seconds; the remote-
-# controller figure the K-step window exists for — BASELINE.md round 3)
+# per-dispatch host latency the window amortizes (seconds; a modelling
+# constant, not measured on the installed machine)
 _DISPATCH_S = 2e-3
 # fraction of the bucketed grad sync the XLA scheduler overlaps with
 # compute (DDP's design point; the monolithic allreduce overlaps nothing)
@@ -66,14 +67,14 @@ _BUCKET_OVERLAP = 0.7
 from tpu_dist.obs.attr import PEAK_GBPS        # noqa: E402
 
 
-def _peak_tflops_table():
-    """utils.mfu.PEAK_TFLOPS — via the file itself when jax is absent:
-    mfu.py's module body is stdlib-only, but the ``tpu_dist.utils``
-    PACKAGE __init__ imports the jax-bound meters, which the lint gate's
-    no-jax blocker (rightly) refuses."""
+def _mfu_module():
+    """utils.mfu (PEAK_TFLOPS + lookup_peak) — via the file itself when
+    jax is absent: mfu.py's module body is stdlib-only, but the
+    ``tpu_dist.utils`` PACKAGE __init__ imports the jax-bound meters, which
+    the lint gate's no-jax blocker (rightly) refuses."""
     try:
-        from tpu_dist.utils.mfu import PEAK_TFLOPS
-        return PEAK_TFLOPS
+        from tpu_dist.utils import mfu
+        return mfu
     except ImportError:
         import importlib.util
         import os
@@ -82,25 +83,20 @@ def _peak_tflops_table():
         spec = importlib.util.spec_from_file_location("_tpu_dist_mfu", path)
         mod = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(mod)
-        return mod.PEAK_TFLOPS
+        return mod
 
 
 _FALLBACK_TFLOPS = 1.0   # nominal peaks keep CPU/virtual runs rankable
 _FALLBACK_GBPS = 1.0     # (the TPU_DIST_NOMINAL_* convention)
 
 
-def _peak_for(kind: str, table) -> Optional[float]:
-    kind = (kind or "").lower()
-    for key, peak in table:
-        if key in kind:
-            return peak
-    return None
-
-
 def device_peaks(device_kind: str) -> dict:
-    """{'tflops', 'gbps', 'nominal'} for a device-kind string."""
-    tf = _peak_for(device_kind, _peak_tflops_table())
-    gb = _peak_for(device_kind, PEAK_GBPS)
+    """{'tflops', 'gbps', 'nominal'} for a device-kind string. Non-TPU
+    kinds get the flagged nominal fallback; an unlisted TPU kind raises
+    (utils.mfu.lookup_peak)."""
+    mfu = _mfu_module()
+    tf = mfu.lookup_peak(device_kind, mfu.PEAK_TFLOPS, "bf16 peak TFLOP/s")
+    gb = mfu.lookup_peak(device_kind, PEAK_GBPS, "HBM GB/s")
     return {"tflops": tf or _FALLBACK_TFLOPS, "gbps": gb or _FALLBACK_GBPS,
             "nominal": tf is None or gb is None}
 

@@ -57,11 +57,14 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(
 
 def _scrubbed_env(extra: Dict[str, str]) -> Dict[str, str]:
     """A child env with no inherited TPU_DIST/XLA state (the test
-    harness's own knobs must not leak into the simulated hosts).
+    harness's own knobs must not leak into the simulated hosts — its
+    cheap-compile switch included: the scenarios' tick margins were tuned
+    against hosts that compile at the normal cost).
     ``python -m tpu_dist.sim.worker`` must resolve from any cwd, so the
     package root rides PYTHONPATH."""
     env = {k: v for k, v in os.environ.items()
-           if not k.startswith("TPU_DIST") and k != "XLA_FLAGS"}
+           if not k.startswith("TPU_DIST")
+           and k not in ("XLA_FLAGS", "JAX_DISABLE_MOST_OPTIMIZATIONS")}
     env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = _REPO_ROOT + (
         os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")

@@ -1,13 +1,12 @@
-"""MFU accounting: XLA-cost-model FLOPs vs device peak (VERDICT r1 #4).
+"""MFU accounting: model FLOPs vs device peak.
 
 Shared by bench.py and the LM/image trainers so every throughput number can
 carry a model-FLOPs-utilization figure. Peaks are public bf16 spec-sheet
-numbers per chip; override with BENCH_PEAK_TFLOPS for unlisted devices.
+numbers per chip, keyed by ``device_kind``; a TPU that is not in the table
+is an error (:func:`lookup_peak`), never a default.
 """
 
 from __future__ import annotations
-
-import os
 
 # bf16 peak TFLOP/s per chip by device kind (public spec sheets)
 PEAK_TFLOPS = (
@@ -20,15 +19,27 @@ PEAK_TFLOPS = (
 )
 
 
-def peak_tflops_for(device) -> float | None:
-    env = os.environ.get("BENCH_PEAK_TFLOPS")
-    if env:
-        return float(env)
-    kind = getattr(device, "device_kind", "").lower()
-    for key, peak in PEAK_TFLOPS:
+def lookup_peak(device_kind: str, table, what: str) -> float | None:
+    """The published peak for a ``device_kind`` string from one of the
+    substring-keyed tables (PEAK_TFLOPS here, obs.attr.PEAK_GBPS). A
+    non-TPU kind (``cpu``, a tuner's ``unknown``) has no published peak:
+    None, and the caller flags whatever nominal value it substitutes. A
+    TPU kind the table does not list raises — a utilization against a
+    made-up peak must not be printable on the chip."""
+    kind = (device_kind or "").lower()
+    for key, peak in table:
         if key in kind:
             return peak
+    if kind.startswith("tpu"):
+        raise ValueError(
+            f"no published {what} for TPU device kind {device_kind!r}: "
+            "add it to the peaks table, with its source")
     return None
+
+
+def peak_tflops_for(device) -> float | None:
+    return lookup_peak(getattr(device, "device_kind", ""), PEAK_TFLOPS,
+                       "bf16 peak TFLOP/s")
 
 
 def lm_flops_per_token(params, num_layers: int, seq_len: int,
@@ -56,8 +67,8 @@ def moe_lm_flops_per_token(params, num_layers: int, seq_len: int,
                            router_top_k: int, total_tokens: int,
                            group_size: int = 512,
                            capacity_factor: float = 1.25) -> float:
-    """Analytical model FLOPs per trained token for the MoE LM (VERDICT r3
-    #4 — the XLA-cost-model fallback understates scan bodies and cannot see
+    """Analytical model FLOPs per trained token for the MoE LM (the
+    XLA-cost-model fallback understates scan bodies and cannot see
     how many experts a token activates). Terms, all fwd+bwd (x6 per
     multiply-add pair, the same convention as lm_flops_per_token):
 
