@@ -211,6 +211,12 @@ def main():
               f"{st['chunk_ticks']} chunk ticks, occupancy "
               f"{st['occupancy'] * 100:.0f}%, {follows}/{total} generated "
               "tokens follow the affine rule")
+        if cfg.ledger_path:
+            # the program's spans (train.* and now serve.*), beside the
+            # ledger as RunObs.run_end left them after training
+            from tpu_dist.obs import SPANS_SUFFIX, trace
+            n = trace.ring().dump(cfg.ledger_path + SPANS_SUFFIX)
+            print(f"{n} program spans -> {cfg.ledger_path + SPANS_SUFFIX}")
 
 
 if __name__ == "__main__":

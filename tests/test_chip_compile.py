@@ -157,3 +157,73 @@ def test_flash_under_a_four_chip_gspmd_step_runs_per_shard(v5e):
     assert "tpu_custom_call" in step(bound).compile().as_text()
     with pytest.raises(NotImplementedError, match="shard_map"):
         step(flash_attention_fn(block_k=1024, interpret=False)).compile()
+
+
+def _cellbench():
+    """The cell benchmark's own AOT helpers (``_cell``, ``_lm``,
+    ``_lm_train_step``), imported by path: tests/benchmarks is no package."""
+    import importlib.util
+
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "benchmarks", "test_cellbench_aot_compile.py")
+    spec = importlib.util.spec_from_file_location("cellbench_aot", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _serving_tick(aot, devices):
+    """The serving cell's decode tick, lowered for shapes on one chip."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpu_dist.engine.kv_cache import PagedKVPool
+    from tpu_dist.engine.serve import _tick_program
+    from tpu_dist.parallel.mesh import make_mesh
+
+    cell = aot._cell("cerebras-gpt-1.3b.serve-chat")
+    s, srv = cell.config, cell.workload["serve"]
+    mesh = make_mesh((1,), ("data",), devices=devices[:1])
+    chip = NamedSharding(mesh, P())
+    model = aot._lm(s, cell.workload["engine"], mesh)
+    shapes = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+    params = shapes(jax.eval_shape(lambda k: jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16), model.init(
+            {"params": k}, jnp.zeros((1, 8), jnp.int32),
+            train=False)["params"]), jax.random.PRNGKey(0)))
+    layers = shapes(jax.eval_shape(lambda: PagedKVPool(
+        s["num_layers"], srv["num_pages"], srv["page_size"], s["num_heads"],
+        s["head_dim"], dtype=jnp.bfloat16).layers()))
+    n = srv["max_slots"]
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip)
+    return _tick_program(model, 0.0, 0, 0.0, None).lower(
+        params, layers, i32(n, srv["max_len"] // srv["page_size"]), i32(n),
+        i32(n), rng).compile()
+
+
+def test_timed_programs_carry_the_programs_scopes_for_v5e(v5e):
+    """The names the traced runs' readers look for survive the TPU
+    compiler's fusion: the serving tick's optimized HLO holds
+    ``paged_read`` in its ``op_name``s, the LM step's ``flash_attention``
+    (on the Mosaic custom-calls, forward and backward), ``loss`` and
+    ``optimizer``. Metadata only: the instructions are the parent's."""
+    import re
+
+    aot = _cellbench()
+    op_names = lambda compiled: re.findall(r'op_name="([^"]*)"',
+                                           compiled.as_text())
+    tick = op_names(_serving_tick(aot, v5e))
+    assert sum("/paged_read/" in n for n in tick) >= 24 * 4   # every layer
+    cell = aot._cell("cerebras-gpt-1.3b-depthcut.train")
+    e = cell.workload["engine"]
+    compiled = aot._lm_train_step(cell.config, e, v5e[:1], e["batch_size"],
+                                  False)
+    kernels = re.findall(r'custom-call\(.*custom_call_target="tpu_custom_call"'
+                         r'.*op_name="([^"]*)"', compiled.as_text())
+    assert len(kernels) == 3 * cell.config["num_layers"]
+    assert all("/flash_attention/" in n for n in kernels)
+    assert sum("transpose(jvp(" in n for n in kernels) == 2 * len(kernels) // 3
+    step = op_names(compiled)
+    for scope in ("jvp(loss)/", "transpose(jvp(loss))/", "/optimizer/"):
+        assert sum(scope in n for n in step) >= 3, scope

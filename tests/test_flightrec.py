@@ -57,6 +57,60 @@ def test_trigger_writes_bundle_and_diagnosis_event(tmp_path):
     assert diag["step"] == 7 and diag["trace"] == "disabled"
 
 
+def _step(led, i):
+    led.emit("step", step=i, loss=1.0, throughput=10.0, unit="tok/s",
+             data_s=0.0, dispatch_s=0.0, device_s=0.0, comm_s=None, mfu=None)
+
+
+def test_bundle_carries_the_span_rings_tail(tmp_path):
+    from tpu_dist.obs import trace
+
+    fr = FlightRecorder(dir=str(tmp_path / "fr"), trace_steps=0)
+    with trace.ring().span("train.epoch", epoch=3):
+        with trace.ring().span("train.wait"):
+            pass
+        bundle = fr.trigger("manual")
+    assert "spans_tail.jsonl" in _manifest(bundle)["files"]
+    rows = [json.loads(ln) for ln in
+            open(os.path.join(bundle, "spans_tail.jsonl"))]
+    closed = [r for r in rows if not r.get("open")]
+    assert closed[-1]["name"] == "train.wait"
+    (still_open,) = [r for r in rows if r.get("open")]
+    assert still_open["name"] == "train.epoch" and still_open["epoch"] == 3
+    assert closed[-1]["parent"] == still_open["sid"]
+
+
+def test_a_profiler_session_already_running_is_skipped_not_fatal(tmp_path):
+    """Somebody else (a benchmark's --trace 1, a user) holds the global
+    profiler when the recorder's window comes due: it records
+    ``trace_skipped`` and goes on, and the other session is untouched."""
+    import jax
+
+    led = Ledger(str(tmp_path / "run.jsonl"))
+    fr = FlightRecorder(dir=str(tmp_path / "fr"), ledger=led, trace_steps=2)
+    led.add_sink(fr.sink)
+    jax.profiler.start_trace(str(tmp_path / "theirs"))
+    try:
+        bundle = fr.trigger("manual")
+        assert _manifest(bundle)["trace"]["status"] == "armed"
+        for i in range(3):
+            _step(led, i)               # the window would start here
+        m = _manifest(bundle)
+        assert m["trace"]["status"] == "trace_skipped", m["trace"]
+        assert "already" in m["trace"]["why"].lower()
+        assert fr._trace is None        # nothing left armed or active
+    finally:
+        jax.profiler.stop_trace()       # theirs: still running, stops clean
+    assert os.path.isdir(tmp_path / "theirs" / "plugins")
+    # and the recorder is whole: the next trigger's window is its own
+    fr.cooldown_s = 0.0
+    bundle2 = fr.trigger("manual")
+    for i in range(3, 7):
+        _step(led, i)
+    assert _manifest(bundle2)["trace"]["status"] == "captured"
+    led.close()
+
+
 def test_sink_auto_triggers_on_stall_health_and_skew_spike(tmp_path):
     path = str(tmp_path / "run.jsonl")
     led = Ledger(path)

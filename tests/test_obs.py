@@ -17,7 +17,7 @@ import numpy as np
 import pytest
 
 from tpu_dist.obs import (EVENT_SCHEMA, EpochCsvSink, Ledger, ProgressSink,
-                          SkewMonitor, StepTracer, Watchdog,
+                          SkewMonitor, StepTracer, Watchdog, trace,
                           per_process_path, phase_totals, read_ledger)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -157,7 +157,7 @@ def test_progress_sink_renders_step_line():
 
 # ---------------------------------------------------------------- tracer
 def test_tracer_span_nesting_and_accumulation():
-    tr = StepTracer()
+    tr = StepTracer(prefix="train.")
     with tr.span("data"):
         time.sleep(0.02)
         with tr.span("decode"):
@@ -171,17 +171,75 @@ def test_tracer_span_nesting_and_accumulation():
     assert ph["data"] >= 0.03
     # pop() reset
     assert tr.pop() == {}
-    tr.add("device", 1.5)
-    tr.add("device", 0.5)
-    assert tr.pop() == {"device": 2.0}
+    # the same spans are in the process-wide ring, under the prefix, and
+    # the sums are the ring's own lengths
+    mine = trace.ring().tail(3)
+    assert [sp.name for sp in mine] == ["train.decode", "train.data",
+                                        "train.data"]
+    assert ph["data"] == pytest.approx(
+        sum(sp.end - sp.start for sp in mine if sp.name == "train.data"))
+    assert not hasattr(tr, "add") and not hasattr(tr, "phases")
 
 
-def test_tracer_span_annotation_flag_off_by_default():
-    # annotate=False must not import/require a live profiler
-    tr = StepTracer(annotate=False)
-    with tr.span("dispatch"):
-        pass
-    assert "dispatch" in tr.pop()
+def test_ring_keeps_parents_and_attrs_and_is_bounded():
+    r = trace.SpanRing(size=8)
+    clock = iter(range(100))
+    now = lambda: float(next(clock))
+    with r.span("serve.step", now=now, tick=7) as step:
+        assert [sp.name for sp in r.open_stack()] == ["serve.step"]
+        with r.span("serve.evict", now=now) as ev:
+            ev.attrs["n"] = 2          # what the phase found out
+        with r.span("serve.tick", now=now, rids=[3, 4]):
+            with r.span("tick.wait", now=now):
+                pass
+    evict, wait, tick, top = r.snapshot()   # a child closes before its parent
+    assert (evict.name, evict.attrs) == ("serve.evict", {"n": 2})
+    assert top.parent is None and top.attrs == {"tick": 7}
+    assert evict.parent == tick.parent == top.sid == step.sid
+    assert wait.parent == tick.sid and tick.attrs == {"rids": [3, 4]}
+    for child, parent in ((evict, top), (wait, tick), (tick, top)):
+        assert parent.start <= child.start <= child.end <= parent.end
+    assert r.open_stack() == []
+    for i in range(20):                      # bounded: the oldest fall out
+        with r.span("x", now=now, i=i):
+            pass
+    assert len(r.snapshot()) == 8
+    assert [sp.attrs["i"] for sp in r.snapshot()] == list(range(12, 20))
+
+
+def test_ring_dump_is_jsonl_and_timed_iter_spans_the_wait(tmp_path):
+    tr = StepTracer(prefix="t.")
+    assert list(tr.timed_iter("data", iter([1, 2, 3]))) == [1, 2, 3]
+    assert "data" in tr.pop()
+    path = str(tmp_path / "spans.jsonl")
+    n = trace.ring().dump(path)
+    rows = [json.loads(line) for line in open(path)]
+    assert len(rows) == n and rows[-1]["name"] == "t.data"
+    assert {"sid", "name", "start", "end", "parent"} <= set(rows[-1])
+
+
+def test_every_span_is_in_a_profiler_sessions_trace(tmp_path):
+    """No switch: a session that somebody else started (here the test)
+    holds the program's spans, with the ring's own ids."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with trace.ring().span("serve.step", tick=1) as outer:
+            with StepTracer(prefix="train.").span("dispatch") as inner:
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (pb,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    seen = {ev.name: dict(ev.stats).get("sid")
+            for plane in ProfileData.from_file(pb).planes
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("tpu_dist:")}
+    assert seen == {"tpu_dist:serve.step": outer.sid,
+                    "tpu_dist:train.dispatch": inner.sid}
 
 
 # -------------------------------------------------------------- watchdog
@@ -226,6 +284,65 @@ def test_watchdog_silent_on_healthy_loop_and_when_paused(tmp_path):
     wd.stop()
     led.close()
     assert not [r for r in read_ledger(led.path) if r["event"] == "stall"]
+
+
+def _stalling_loop(tmp_path, span_name, **attrs):
+    """A loop that beats a few times and then sits inside one span for
+    ten times the watchdog's (shortened) threshold, with a flight recorder
+    on the ledger as the engines wire it."""
+    import io
+
+    from tpu_dist.obs import FlightRecorder
+
+    led = Ledger(str(tmp_path / "wd.jsonl"))
+    fr = FlightRecorder(dir=str(tmp_path / "fr"), ledger=led, trace_steps=2)
+    led.add_sink(fr.sink)
+    err = io.StringIO()
+    wd = Watchdog(factor=2.0, ledger=led, min_timeout_s=0.05, poll_s=0.01,
+                  stream=err)
+    tr = StepTracer(prefix="train.")
+    for _ in range(5):
+        wd.step_done(0.005)
+    with tr.span(span_name, **attrs):
+        deadline = time.monotonic() + 5.0
+        while (wd.stall_count + wd.compile_waits == 0
+               and time.monotonic() < deadline):
+            time.sleep(0.01)            # polls; no real seconds are slept
+        time.sleep(0.05)                # a second threshold: still one note
+    wd.stop()
+    led.close()
+    return wd, fr, err.getvalue(), read_ledger(led.path)
+
+
+def test_watchdog_reads_a_long_first_dispatch_as_a_compilation(tmp_path):
+    wd, fr, err, recs = _stalling_loop(tmp_path, "dispatch", step=0,
+                                       first_call=True)
+    assert (wd.compile_waits, wd.stall_count) == (1, 0)
+    assert err.count("a compilation, not a stall") == 1
+    assert "train.dispatch" in err and "NO STEP COMPLETED" not in err
+    assert not [r for r in recs if r["event"] in ("stall", "diagnosis")]
+    assert fr.bundles == [] and fr._trace is None    # no window armed
+
+
+@pytest.mark.parametrize("span_name,attrs", [
+    ("wait", {}),                                    # the drain's device_get
+    ("dispatch", {"step": 9, "first_call": False}),  # a compiled program
+])
+def test_watchdog_names_the_open_span_of_a_real_stall(tmp_path, span_name,
+                                                      attrs):
+    wd, fr, err, recs = _stalling_loop(tmp_path, span_name, **attrs)
+    assert (wd.compile_waits, wd.stall_count) == (0, 1)
+    head = err.split("--- thread")[0]       # before the thread stacks
+    assert "NO STEP COMPLETED" in head
+    assert "open spans (outermost first):" in head and "last spans:" in head
+    assert f"train.{span_name} [" in head.split("last spans:")[0]
+    (stall,) = [r for r in recs if r["event"] == "stall"]
+    assert stall["open_span"] == f"train.{span_name}"
+    assert len(fr.bundles) == 1             # the stall armed the recorder
+    tail = [json.loads(line) for line in
+            open(os.path.join(fr.bundles[0], "spans_tail.jsonl"))]
+    assert any(r.get("open") and r["name"] == f"train.{span_name}"
+               for r in tail)
 
 
 # ------------------------------------------------------------------ skew
@@ -323,12 +440,27 @@ def test_image_engine_ledger_smoke(tmp_path):
                       checkpoint_dir=str(tmp_path / "ck"),
                       ledger_path=path, log_csv=str(tmp_path / "ep.csv"),
                       skew_every=2)
+    with trace.ring().span("mark") as mark:   # the ring is the process's
+        pass
     Trainer(cfg).fit()
     recs = read_ledger(path)
     _assert_run_shape(recs)
     _assert_step_records_complete(recs, "img/s")
     assert [r for r in recs if r["event"] == "skew"]
     assert [r for r in recs if r["event"] == "ckpt"]
+    # run_end left the program's spans beside the ledger: the drain's wait
+    # and emit under their epoch, each dispatch with its step
+    from tpu_dist.obs import SPANS_SUFFIX
+
+    mine = [sp for sp in map(json.loads, open(path + SPANS_SUFFIX))
+            if sp["sid"] > mark.sid]
+    assert {"train.epoch", "train.data", "train.dispatch", "train.wait",
+            "train.emit"} <= {sp["name"] for sp in mine}
+    steps = {r["step"] for r in recs if r["event"] == "step"}
+    assert {sp["step"] for sp in mine
+            if sp["name"] == "train.dispatch"} == steps
+    assert [sp["first_call"] for sp in mine
+            if sp["name"] == "train.dispatch"][:2] == [True, False]
     # the legacy CSV rendered as a sink, same values as the epoch event
     import csv
 

@@ -6,6 +6,7 @@ import types
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 
 from tpu_dist import runtime
@@ -22,7 +23,8 @@ def test_pallas_interpret_one_rule(monkeypatch):
 @pytest.fixture
 def cache_config():
     keys = ("jax_compilation_cache_dir",
-            "jax_persistent_cache_min_compile_time_secs")
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_compilation_cache_include_metadata_in_key")
     was = {k: getattr(jax.config, k) for k in keys}
     yield
     for k, v in was.items():
@@ -42,6 +44,30 @@ def test_compile_cache_placed_from_outside_or_inside_the_checkout(
     assert jax.config.jax_compilation_cache_dir == runtime.DEFAULT_CACHE_DIR
     assert runtime.DEFAULT_CACHE_DIR.endswith("/.jax_cache")
     assert runtime.enable_compile_cache() == runtime.DEFAULT_CACHE_DIR
+
+
+def test_a_cached_executable_is_keyed_on_its_names_too(cache_config):
+    """Two programs that differ only in a ``jax.named_scope`` (metadata)
+    must not share a cache entry: the one read back would carry the other's
+    ``op_name``s, which the trace readers join on (PR 24)."""
+    import jax.numpy as jnp
+    from jax._src import cache_key, compiler
+
+    runtime.enable_compile_cache()
+
+    def key(scope):
+        def f(x):
+            with jax.named_scope(scope):
+                return x * 2.0
+        lowered = jax.jit(f).lower(jnp.ones((4,)))
+        dev = jax.devices()[:1]
+        return cache_key.get(
+            lowered.compiler_ir("stablehlo"), np.array(dev),
+            compiler.get_compile_options(1, 1), dev[0].client)
+
+    assert key("paged_read") != key("unnamed")
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", False)
+    assert key("paged_read") == key("unnamed")      # JAX's default
 
 
 @pytest.mark.parametrize("kind,expect", [

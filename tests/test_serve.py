@@ -761,3 +761,110 @@ def test_kv_cache_event_carries_serving_plane_fields(tmp_path):
     assert max(depths) > 0               # backlog was visible mid-flight
     assert kv[-1]["chunks_pending"] == 0
     assert kv[-1]["chunk_ticks"] == 5    # ceil(17/4)
+
+
+# ------------------------------------------------------ program spans
+@pytest.fixture(scope="module")
+def span_lm():
+    return _lm_and_params(seed=31)
+
+
+_SPAN_MODES = {
+    "plain": dict(max_slots=2, page_size=8, num_pages=16),
+    "traced": dict(max_slots=2, page_size=8, num_pages=16),   # + a ledger
+    "chunked": dict(max_slots=2, page_size=4, num_pages=32, prefill_chunk=8),
+    "speculative": dict(max_slots=2, page_size=8, num_pages=16, spec_k=3),
+}
+
+
+@pytest.mark.parametrize("mode", list(_SPAN_MODES))
+def test_step_spans_nest_and_every_token_has_a_time(span_lm, mode):
+    """One ``serve.step`` a ``step()``, its phases as children that lie
+    inside it, a request's spans under one identifier, and per token one
+    engine-clock time that lies inside the span that produced it: all on
+    the engine's (here virtual, strictly increasing) clock."""
+    from tpu_dist.obs import trace
+
+    lm, params = span_lm
+    clock = itertools.count()
+    ledger = Ledger(None) if mode == "traced" else None
+    eng = ServeEngine(lm, params, ServeConfig(**_SPAN_MODES[mode]),
+                      ledger=ledger, now_fn=lambda: float(next(clock)))
+    prompts = [((np.arange(13, dtype=np.int32) * 5 + 2) % V),
+               ((np.arange(18, dtype=np.int32) * 3 + 7) % V)]
+    with trace.ring().span("mark") as mark:
+        pass
+    for i, p in enumerate(prompts):
+        assert eng.submit(DecodeRequest(i, p, 6))
+    comps, n_steps = [], 0
+    while eng.queue or any(s is not None for s in eng.slots):
+        comps += eng.step()
+        n_steps += 1
+    spans = [sp for sp in trace.ring().snapshot() if sp.sid > mark.sid]
+    by_sid = {sp.sid: sp for sp in spans}
+    kids = {}
+    for sp in spans:                      # ring order: by closing time
+        kids.setdefault(sp.parent, []).append(sp)
+    names = lambda sid: [k.name for k in sorted(kids.get(sid, ()),
+                                                key=lambda k: k.start)]
+    steps = [sp for sp in spans if sp.name == "serve.step"]
+    assert len(steps) == n_steps and all(s.parent is None for s in steps)
+    assert [s.attrs["tick"] for s in steps] == sorted(
+        s.attrs["tick"] for s in steps)
+    chunked = mode == "chunked"
+    for st in steps:
+        assert set(st.attrs) == {"tick", "n_active", "queue_depth"}
+        got = names(st.sid)
+        assert got[:2] == ["serve.evict", "serve.admit"]
+        assert got[2:] in ([], ["serve.tick"],
+                           *([["serve.prefill"],
+                              ["serve.prefill", "serve.tick"]]
+                             if chunked else []))
+    for sp in spans:                      # children lie inside parents
+        if sp.parent is not None:
+            par = by_sid[sp.parent]
+            assert par.start < sp.start < sp.end < par.end, (sp, par)
+    prefills = [sp for sp in spans if sp.name == "serve.prefill"]
+    for pf in prefills:
+        assert by_sid[pf.parent].name == ("serve.step" if chunked
+                                          else "serve.admit")
+        assert {"rid", "trace_id", "prompt_len", "bucket",
+                "shared_len"} <= set(pf.attrs)
+        want = (eng.tracer.trace_id(pf.attrs["rid"]) if mode == "traced"
+                else pf.attrs["rid"])
+        assert pf.attrs["trace_id"] == want
+        assert names(pf.sid) in (["prefill.dispatch", "prefill.wait"],
+                                 *([["prefill.dispatch"]] if chunked else []))
+    assert len(prefills) == (2 + 3 if chunked else 2)   # ceil(13/8)+ceil(18/8)
+    assert sum(sp.attrs["n"] for sp in spans
+               if sp.name == "serve.admit") == 2
+    assert sum(sp.attrs["n"] for sp in spans
+               if sp.name == "serve.evict") == 2
+    ticks = [sp for sp in spans if sp.name == "serve.tick"]
+    assert len(ticks) == eng.ticks
+    for tk in ticks:
+        assert names(tk.sid) == ["tick.build", "tick.dispatch", "tick.wait",
+                                 "tick.emit"]
+        assert set(tk.attrs["rids"]) <= {0, 1}
+        assert ("trace_ids" in tk.attrs) == (mode == "traced")
+    # a program's first dispatch, and only that one, says it compiles
+    first = [sp.attrs["first_call"] for sp in spans
+             if sp.name == "tick.dispatch"]
+    assert first == [True] + [False] * (len(ticks) - 1)
+    # per-token times
+    assert sorted(c.rid for c in comps) == [0, 1]
+    for c in comps:
+        assert c.token_ts.dtype == np.float64
+        assert len(c.token_ts) == c.n_generated == 6
+        assert c.first_token_ts == c.token_ts[0]
+        assert c.finish_ts == c.token_ts[-1]
+        assert np.all(np.diff(c.token_ts) >= 0)
+        last_pf = max((pf for pf in prefills if pf.attrs["rid"] == c.rid),
+                      key=lambda pf: pf.start)
+        assert last_pf.start < c.token_ts[0] < last_pf.end
+        mine = [tk for tk in ticks if c.rid in tk.attrs["rids"]]
+        for t in c.token_ts[1:]:
+            assert any(tk.start < t < tk.end for tk in mine)
+        if mode != "speculative":           # one token a tick
+            assert len(mine) == c.n_generated - 1
+            assert len(set(c.token_ts)) == c.n_generated

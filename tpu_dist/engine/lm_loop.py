@@ -42,7 +42,7 @@ from tpu_dist.engine.lm_steps import (LM_METRIC_KEYS, make_lm_batches,
                                       make_lm_train_step)
 from tpu_dist.engine.state import TrainState
 from tpu_dist.obs import (HealthError, RunObs, faults, profile_session,
-                          step_annotation)
+                          step_annotation, trace)
 from tpu_dist.ops import lm_lr_schedule, make_optimizer, make_policy
 from tpu_dist.parallel.mesh import make_mesh, replicated
 from tpu_dist.parallel.supervisor import PREEMPT_SNAPSHOT_RC
@@ -256,6 +256,7 @@ class LMTrainer:
         self._train_rows_dev = None
         self._val_rows_dev = None
         self._prefetched_windows = None
+        self._dispatched_windows = set()  # window lengths dispatched once
         if self.device_data:
             # distlint: disable=DL008 -- one-time whole-dataset HBM residency at init; per-step uploads don't exist in this mode
             self._train_rows_dev = jax.device_put(
@@ -702,13 +703,23 @@ class LMTrainer:
         ``skip`` a non-finite record stays out of the meter averages (its
         update was already zeroed on device), and under ``halt`` the
         sentry raises out of the loop."""
-        import math
-
-        with self.obs.tracer.span("device"):
+        tr = self.obs.tracer
+        with tr.span("wait"):
             # distlint: disable=DL002 -- THE drain boundary: the one sanctioned fetch point of the loop
             fetched = jax.device_get([m for m, _ in pending])
-        device_s = self.obs.tracer.pop().get("device", 0.0)
+        device_s = tr.pop().get("wait", 0.0)
         total_steps = sum(info["n_steps"] for _, info in pending) or 1
+        # everything from the transfer's return to this function's: the
+        # step records and their fan-out to the ledger's sinks, health,
+        # heartbeat (what the observability costs a drain)
+        with tr.span("emit", step=pending[-1][1]["step"], steps=total_steps):
+            self._emit_records(fetched, pending, meters, device_s,
+                               total_steps)
+
+    def _emit_records(self, fetched, pending, meters, device_s: float,
+                      total_steps: int) -> None:
+        import math
+
         from tpu_dist.utils.telemetry import device_memory_stats
         hbm = device_memory_stats()
         for m, (_, info) in zip(fetched, pending):
@@ -764,8 +775,12 @@ class LMTrainer:
 
     # ------------------------------------------------------------------
     def train_epoch(self, epoch: int) -> Dict[str, float]:
-        if self.device_data:
-            return self._train_epoch_windowed(epoch)
+        with trace.ring().span("train.epoch", epoch=epoch):
+            if self.device_data:
+                return self._train_epoch_windowed(epoch)
+            return self._train_epoch_batched(epoch)
+
+    def _train_epoch_batched(self, epoch: int) -> Dict[str, float]:
         cfg = self.cfg
         idx, _ = self._epoch_indices(self.train_ds, True, epoch)
         nb = len(idx)
@@ -802,7 +817,8 @@ class LMTrainer:
         tokens_per_batch = cfg.batch_size * cfg.seq_len
         tr = self.obs.tracer
         end = time.time()
-        for i, inputs_d, targets_d in stream_prefetch(batches()):
+        for i, inputs_d, targets_d in tr.timed_iter(
+                "data", stream_prefetch(batches())):
             data_s = time.time() - end
             meters.update("Data", data_s)
             gstep = epoch * self.steps_per_epoch + i
@@ -816,8 +832,8 @@ class LMTrainer:
             if self.obs.preempt_pending():
                 self._preempt_snapshot(pending, meters)  # raises SystemExit
             was_cold = not self._warmed  # this dispatch carries the compile
-            with step_annotation(gstep, self.obs.profiling), \
-                    tr.span("dispatch"):
+            with step_annotation(gstep), \
+                    tr.span("dispatch", step=gstep, first_call=was_cold):
                 self.state, metrics = self.train_step(
                     self.state, inputs_d, targets_d, self.rng)
             dispatch_s = tr.pop().get("dispatch", 0.0)
@@ -924,7 +940,7 @@ class LMTrainer:
         tokens_per_batch = cfg.batch_size * cfg.seq_len
         tr = self.obs.tracer
         end = time.time()
-        for n, idx_dev in windows:
+        for n, idx_dev in tr.timed_iter("data", windows):
             data_s = time.time() - end
             meters.update("Data", data_s / n, n)
             effects = self.obs.fire_step_faults(
@@ -938,8 +954,12 @@ class LMTrainer:
             if self.obs.preempt_pending():
                 self._preempt_snapshot(pending, meters)  # raises SystemExit
             was_cold = not self._warmed  # this dispatch carries the compile
-            with step_annotation(epoch * self.steps_per_epoch + done,
-                                 self.obs.profiling), tr.span("dispatch"):
+            # each window length is a compiled program of its own
+            first_call = n not in self._dispatched_windows
+            self._dispatched_windows.add(n)
+            gstep = epoch * self.steps_per_epoch + done
+            with step_annotation(gstep), \
+                    tr.span("dispatch", step=gstep, first_call=first_call):
                 self.state, metrics = self.window_step(
                     self.state, self._train_rows_dev, idx_dev, self.rng)
             dispatch_s = tr.pop().get("dispatch", 0.0)

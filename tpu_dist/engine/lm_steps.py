@@ -141,10 +141,12 @@ def _lm_objective_metrics(model, params, out, targets, loss_chunk: int):
     jit and sp step fns so the two objectives cannot drift — the eval twin
     is _lm_eval_metrics."""
     mask = jnp.ones(targets.shape, jnp.float32)
-    if loss_chunk:
-        return _chunked_loss_metrics(model, params, out, targets, mask,
-                                     loss_chunk)
-    return lm_loss_and_metrics(out, targets, mask)
+    # named in the compiled program, for a trace's readers (metadata only)
+    with jax.named_scope("loss"):
+        if loss_chunk:
+            return _chunked_loss_metrics(model, params, out, targets, mask,
+                                         loss_chunk)
+        return lm_loss_and_metrics(out, targets, mask)
 
 
 def _lm_grads_and_metrics(model, aux_weight: float, params, inputs, targets,

@@ -36,7 +36,7 @@ from tpu_dist.engine.steps import (make_eval_step, make_indexed_multi_train_step
                                    make_shard_map_train_step, make_train_step)
 from tpu_dist.models import create_model
 from tpu_dist.obs import (HealthError, RunObs, faults, profile_session,
-                          step_annotation)
+                          step_annotation, trace)
 from tpu_dist.ops import LossScaleState, make_optimizer, make_policy, step_decay_schedule
 from tpu_dist.parallel.mesh import batch_sharding, make_mesh, replicated
 from tpu_dist.parallel.supervisor import PREEMPT_SNAPSHOT_RC
@@ -306,6 +306,7 @@ class Trainer:
         self._train_data_dev = None
         self._val_data_dev = None
         self._prefetched_windows = None  # (epoch, [(n, device idx window)])
+        self._dispatched_windows = set()  # window lengths dispatched once
         if self.device_data:
             # whole training set resident in HBM (rows packed into i32 words
             # for native 32-bit gathers), replicated per chip; per-step
@@ -477,13 +478,23 @@ class Trainer:
         consumes them here — under ``skip`` a non-finite record is kept
         out of the meter averages (its update was already zeroed on
         device), and under ``halt`` the sentry raises out of the loop."""
-        import math
-
-        with self.obs.tracer.span("device"):
+        tr = self.obs.tracer
+        with tr.span("wait"):
             # distlint: disable=DL002 -- THE drain boundary: the one sanctioned fetch point of the loop
             fetched = jax.device_get([m for m, _ in pending])
-        device_s = self.obs.tracer.pop().get("device", 0.0)
+        device_s = tr.pop().get("wait", 0.0)
         total_steps = sum(info["n_steps"] for _, info in pending) or 1
+        # everything from the transfer's return to this function's: the
+        # step records and their fan-out to the ledger's sinks, health,
+        # heartbeat (what the observability costs a drain)
+        with tr.span("emit", step=pending[-1][1]["step"], steps=total_steps):
+            self._emit_records(fetched, pending, meters, device_s,
+                               total_steps)
+
+    def _emit_records(self, fetched, pending, meters, device_s: float,
+                      total_steps: int) -> None:
+        import math
+
         from tpu_dist.utils.telemetry import device_memory_stats
         hbm = device_memory_stats()
         for m, (_, info) in zip(fetched, pending):
@@ -580,8 +591,12 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def train_epoch(self, epoch: int) -> Dict[str, float]:
-        if self.k > 1 or self.device_data:
-            return self._train_epoch_windowed(epoch)
+        with trace.ring().span("train.epoch", epoch=epoch):
+            if self.k > 1 or self.device_data:
+                return self._train_epoch_windowed(epoch)
+            return self._train_epoch_batched(epoch)
+
+    def _train_epoch_batched(self, epoch: int) -> Dict[str, float]:
         cfg = self.cfg
         loader = self._loader(self.train_ds, True, epoch)
         nb = len(loader)
@@ -609,7 +624,7 @@ class Trainer:
         else:
             it = prefetch_to_device(iter(loader), self.batch_sharding)
         tr = self.obs.tracer
-        for i, (images, labels) in enumerate(it):
+        for i, (images, labels) in enumerate(tr.timed_iter("data", it)):
             if i < skip:  # step-exact resume of a mid-epoch checkpoint
                 end = time.time()
                 continue
@@ -626,8 +641,8 @@ class Trainer:
             if self.obs.preempt_pending():
                 self._preempt_snapshot(pending, meters)  # raises SystemExit
             was_cold = self._program_hbm is None  # this dispatch compiles
-            with step_annotation(gstep, self.obs.profiling), \
-                    tr.span("dispatch"):
+            with step_annotation(gstep), \
+                    tr.span("dispatch", step=gstep, first_call=was_cold):
                 self.state, metrics = self.train_step(
                     self.state, images, labels, self.rng)
             dispatch_s = tr.pop().get("dispatch", 0.0)
@@ -775,7 +790,7 @@ class Trainer:
         last_print = skip - 1
         tr = self.obs.tracer
         end = time.time()
-        for n, dev_payload in windows:
+        for n, dev_payload in tr.timed_iter("data", windows):
             # per-BATCH seconds (window seconds / n, weighted n) so the
             # printed avg keeps the per-batch path's meaning:
             # avg(Time) = wall / batches in both paths
@@ -792,8 +807,12 @@ class Trainer:
             if self.obs.preempt_pending():
                 self._preempt_snapshot(pending, meters)  # raises SystemExit
             was_cold = self._program_hbm is None  # this dispatch compiles
-            with step_annotation(epoch * self.steps_per_epoch + done,
-                                 self.obs.profiling), tr.span("dispatch"):
+            # each window length is a compiled program of its own
+            first_call = n not in self._dispatched_windows
+            self._dispatched_windows.add(n)
+            gstep = epoch * self.steps_per_epoch + done
+            with step_annotation(gstep), \
+                    tr.span("dispatch", step=gstep, first_call=first_call):
                 self.state, metrics = dispatch(self.state, dev_payload)
             dispatch_s = tr.pop().get("dispatch", 0.0)
             if self._program_hbm is None:

@@ -83,6 +83,7 @@ from tpu_dist._compat import shard_map
 from tpu_dist.engine.generate import (_quantize_for_decode, _refuse_wo_tree,
                                       _sample, prepare_draft)
 from tpu_dist.engine.kv_cache import PagedKVPool, PrefixMatch
+from tpu_dist.obs import trace
 from tpu_dist.obs.reqtrace import RequestTracer
 from tpu_dist.ops.paged_attention import cow_fork_pages
 from tpu_dist.parallel.mesh import SP_AXIS
@@ -119,6 +120,10 @@ class Completion:
     start_ts: float              # left the queue (prefill start)
     first_token_ts: float
     finish_ts: float
+    # one engine-clock time per generated token (the clock read after the
+    # device_get that brought it): token_ts[0] is first_token_ts,
+    # token_ts[-1] is finish_ts
+    token_ts: np.ndarray
 
     @property
     def queue_wait_s(self) -> float:
@@ -176,6 +181,8 @@ class _Slot:
     pages: List[int]
     block_table: np.ndarray      # (max_pages_per_seq,) int32
     buf: np.ndarray              # (prompt + max_new,) int32
+    token_ts: np.ndarray         # (max_new,) float64: when each token came
+    trace_id: object             # the id this request's spans share
     prompt_len: int
     admit_ts: float
     start_ts: float
@@ -605,6 +612,9 @@ class ServeEngine:
         # the pool's prefix/CoW work happens inside admission — bind the
         # trace context so hits and forks surface as detail spans
         self.pool.bind_trace(self.tracer, self._now)
+        # program spans (obs.trace): always on, on this engine's clock
+        self._span = partial(trace.ring().span, now=now_fn)
+        self._dispatched = set()     # programs called once (so compiled)
         # counters / SLO state
         self.ticks = 0
         self.completed = 0
@@ -733,25 +743,44 @@ class ServeEngine:
             self._in_breach = False
             self.shedding = False
 
+    def _trace_id(self, rid):
+        """The identifier a request's spans share."""
+        return self.tracer.trace_id(rid) if self.tracer is not None else rid
+
+    def _first_call(self, program) -> bool:
+        """True the first time ``program`` (a name, with its bucket where
+        it has one) is dispatched: that call compiles, and the watchdog
+        reads a long ``*.dispatch`` span with this set as a compilation."""
+        if program in self._dispatched:
+            return False
+        self._dispatched.add(program)
+        return True
+
     # -- the scheduler iteration -----------------------------------------
     def step(self) -> List[Completion]:
         """One iteration: evict finished sequences (freeing their slots
         and pages), admit + prefill from the queue into the free slots,
         then run one decode tick over the packed active set. Returns the
         completions evicted this iteration."""
-        completions = self._evict()
-        self._admit()
-        self._chunk_tick()
-        self._tick()
-        self._decay_wait_if_idle()
-        every = self.cfg.kv_event_every
-        # keyed on DECODE ticks, deduplicated: idle iterations don't
-        # advance the counter and must neither spam one event per loop
-        # nor re-emit the same tick's snapshot
-        if (every > 0 and self.ticks % every == 0
-                and self.ticks != self._last_kv_tick):
-            self._last_kv_tick = self.ticks
-            self._emit_kv_cache()
+        with self._span("serve.step", tick=self.ticks,
+                        n_active=sum(s is not None for s in self.slots),
+                        queue_depth=len(self.queue)):
+            with self._span("serve.evict") as sp:
+                completions = self._evict()
+                sp.attrs["n"] = len(completions)
+            with self._span("serve.admit") as sp:
+                sp.attrs["n"] = self._admit()
+            self._chunk_tick()
+            self._tick()
+            self._decay_wait_if_idle()
+            every = self.cfg.kv_event_every
+            # keyed on DECODE ticks, deduplicated: idle iterations don't
+            # advance the counter and must neither spam one event per loop
+            # nor re-emit the same tick's snapshot
+            if (every > 0 and self.ticks % every == 0
+                    and self.ticks != self._last_kv_tick):
+                self._last_kv_tick = self.ticks
+                self._emit_kv_cache()
         return completions
 
     def run(self, requests=(), max_ticks: int = 100_000) -> List[Completion]:
@@ -853,7 +882,8 @@ class ServeEngine:
                 prompt_len=slot.prompt_len, n_generated=slot.generated,
                 admit_ts=slot.admit_ts, start_ts=slot.start_ts,
                 first_token_ts=slot.first_token_ts,
-                finish_ts=slot.finish_ts)
+                finish_ts=slot.finish_ts,
+                token_ts=slot.token_ts[:slot.generated].copy())
             self.completed += 1
             out.append(comp)
             if self.ledger is not None:
@@ -883,10 +913,12 @@ class ServeEngine:
                                tenant=slot.req.tenant, **tr.attrs())
         return out
 
-    def _admit(self) -> None:
+    def _admit(self) -> int:
+        """Fill free slots from the queue; returns how many it admitted."""
         if self.cfg.refill == "drain" and any(
                 s is not None for s in self.slots):
-            return  # static batching: refill only once the batch drained
+            return 0  # static batching: refill only once the batch drained
+        admitted = 0
         for i in range(len(self.slots)):
             if not self.queue:
                 break
@@ -937,6 +969,7 @@ class ServeEngine:
                     self.pool.unshare(match)
                 break  # pool pressure: leave it queued, decode on
             self.queue.popleft()
+            admitted += 1
             now = self._now()
             self._observe_wait(now - enq_ts)
             if self.tracer is not None:
@@ -959,6 +992,18 @@ class ServeEngine:
                                     match)
             else:
                 self._prefill(i, req, prompt, fresh, enq_ts, now, match)
+        return admitted
+
+    def _new_slot(self, req, prompt, pages, bt, enq_ts, start_ts,
+                  **fields) -> _Slot:
+        p = prompt.size
+        slot = _Slot(req=req, pages=pages, block_table=bt,
+                     buf=np.zeros((p + req.max_new_tokens,), np.int32),
+                     token_ts=np.zeros((req.max_new_tokens,), np.float64),
+                     trace_id=self._trace_id(req.rid), prompt_len=p,
+                     admit_ts=enq_ts, start_ts=start_ts, position=p, **fields)
+        slot.buf[:p] = prompt
+        return slot
 
     def _prefill(self, slot_idx, req, prompt, fresh, enq_ts, start_ts,
                  match: Optional[PrefixMatch] = None):
@@ -981,69 +1026,76 @@ class ServeEngine:
         bt[:len(bt_pages)] = bt_pages
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :p] = prompt
-        program = _prefill_program(self.model, self.cfg.temperature,
-                                   self.cfg.top_k, self.cfg.top_p,
-                                   self.sp_mesh)
-        # recompile sentry (analysis.proglint PL005): prefill specializes
-        # per bucket BY DESIGN, so its allowed trace-cache size is the
-        # bucket-ladder length, not 1 (no-op when the audit is off)
-        register_audit_program("serve_prefill", program,
-                               allowed=len(self.buckets))
-        tok, new_layers, self._rng = program(
-            self.params, self.pool.layers(),
-            jnp.asarray(self.pool.flat_block_table(bt[None])),
-            jnp.int32(p), jnp.int32(shared_len), jnp.asarray(padded),
-            self._rng)
-        self.pool.adopt(new_layers)
-        self.prefill_token_work += bucket
-        if self.draft_pool is not None:
-            # the draft's prompt rows, through the same block table (the
-            # pools share page indices); shared rows were written by the
-            # earlier prefix owner's draft prefill, so the mask matches
-            dprog = _draft_prefill_program(self.draft_model)
-            self.draft_pool.adopt(dprog(
-                self.draft_params, self.draft_pool.layers(),
-                jnp.asarray(bt[None]), jnp.int32(p), jnp.int32(shared_len),
-                jnp.asarray(padded)))
-        if self.cfg.prefix_cache:
-            # index this prompt's freshly-written pages for future sharers
-            # (shared slots are already indexed by their original writer)
-            self.pool.register_prefix(prompt, bt_pages,
-                                      skip_slots=len(shared))
-            self.prompt_pages += self.pool.pages_needed(p)
-            self.shared_prompt_pages += len(shared)
-        self.prefills += 1
-        # the scheduler IS the drain boundary: the first token decides
-        # done/eos and the TTFT stamp before the next iteration
-        # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
-        tok = int(jax.device_get(tok))
-        now = self._now()
-        slot = _Slot(req=req, pages=shared + fresh, block_table=bt,
-                     buf=np.zeros((p + req.max_new_tokens,), np.int32),
-                     prompt_len=p, admit_ts=enq_ts, start_ts=start_ts,
-                     position=p, generated=1, first_token_ts=now,
-                     cow_pending=cow, win_start_ts=now)
-        slot.buf[:p] = prompt
-        slot.buf[p] = tok
-        if (slot.generated >= req.max_new_tokens
-                or tok == self.cfg.eos_id):
-            slot.done = True
-            slot.finish_ts = now
-        self.slots[slot_idx] = slot
-        if self.tracer is not None:
-            # prefill span: queue-exit -> first token, carrying the knobs
-            # that explain a slow one (bucket padding, fresh vs shared
-            # pages, a pending CoW fork)
-            tr = self.tracer
-            tid, sid, par = tr.ids(req.rid, "prefill")
-            tr.ledger.emit("span", trace_id=tid, span_id=sid,
-                           parent_id=par, name="prefill", rid=req.rid,
-                           start=round(start_ts, 6), end=round(now, 6),
-                           bucket=bucket, prompt_len=p,
-                           pages_fresh=len(fresh),
-                           pages_shared=len(shared),
-                           shared_len=shared_len, cow=cow is not None,
-                           tenant=req.tenant, **tr.attrs())
+        with self._span("serve.prefill", rid=req.rid,
+                        trace_id=self._trace_id(req.rid), prompt_len=p,
+                        bucket=bucket, shared_len=shared_len):
+            with self._span("prefill.dispatch", first_call=self._first_call(
+                    ("prefill", bucket))):
+                program = _prefill_program(self.model, self.cfg.temperature,
+                                           self.cfg.top_k, self.cfg.top_p,
+                                           self.sp_mesh)
+                # recompile sentry (analysis.proglint PL005): prefill
+                # specializes per bucket BY DESIGN, so its allowed trace-cache
+                # size is the bucket-ladder length, not 1 (no-op when the
+                # audit is off)
+                register_audit_program("serve_prefill", program,
+                                       allowed=len(self.buckets))
+                tok, new_layers, self._rng = program(
+                    self.params, self.pool.layers(),
+                    jnp.asarray(self.pool.flat_block_table(bt[None])),
+                    jnp.int32(p), jnp.int32(shared_len), jnp.asarray(padded),
+                    self._rng)
+                self.pool.adopt(new_layers)
+                self.prefill_token_work += bucket
+                if self.draft_pool is not None:
+                    # the draft's prompt rows, through the same block table
+                    # (the pools share page indices); shared rows were written
+                    # by the earlier prefix owner's draft prefill, so the mask
+                    # matches
+                    dprog = _draft_prefill_program(self.draft_model)
+                    self.draft_pool.adopt(dprog(
+                        self.draft_params, self.draft_pool.layers(),
+                        jnp.asarray(bt[None]), jnp.int32(p),
+                        jnp.int32(shared_len), jnp.asarray(padded)))
+                if self.cfg.prefix_cache:
+                    # index this prompt's freshly-written pages for future
+                    # sharers (shared slots are already indexed by their
+                    # original writer)
+                    self.pool.register_prefix(prompt, bt_pages,
+                                              skip_slots=len(shared))
+                    self.prompt_pages += self.pool.pages_needed(p)
+                    self.shared_prompt_pages += len(shared)
+                self.prefills += 1
+            with self._span("prefill.wait"):
+                # the scheduler IS the drain boundary: the first token
+                # decides done/eos and the TTFT stamp before the next iteration
+                # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
+                tok = int(jax.device_get(tok))
+            now = self._now()
+            slot = self._new_slot(req, prompt, shared + fresh, bt, enq_ts,
+                                  start_ts, generated=1, first_token_ts=now,
+                                  cow_pending=cow, win_start_ts=now)
+            slot.buf[p] = tok
+            slot.token_ts[0] = now
+            if (slot.generated >= req.max_new_tokens
+                    or tok == self.cfg.eos_id):
+                slot.done = True
+                slot.finish_ts = now
+            self.slots[slot_idx] = slot
+            if self.tracer is not None:
+                # prefill span: queue-exit -> first token, carrying the knobs
+                # that explain a slow one (bucket padding, fresh vs shared
+                # pages, a pending CoW fork)
+                tr = self.tracer
+                tid, sid, par = tr.ids(req.rid, "prefill")
+                tr.ledger.emit("span", trace_id=tid, span_id=sid,
+                               parent_id=par, name="prefill", rid=req.rid,
+                               start=round(start_ts, 6), end=round(now, 6),
+                               bucket=bucket, prompt_len=p,
+                               pages_fresh=len(fresh),
+                               pages_shared=len(shared),
+                               shared_len=shared_len, cow=cow is not None,
+                               tenant=req.tenant, **tr.attrs())
 
     # -- chunked prefill ---------------------------------------------------
     def _begin_chunked(self, slot_idx, req, prompt, fresh, enq_ts, start_ts,
@@ -1068,19 +1120,15 @@ class ServeEngine:
         bt = np.full((self.max_pages_per_seq,), self.pool.num_pages,
                      np.int32)
         bt[:len(bt_pages)] = bt_pages
-        slot = _Slot(req=req, pages=shared + fresh, block_table=bt,
-                     buf=np.zeros((p + req.max_new_tokens,), np.int32),
-                     prompt_len=p, admit_ts=enq_ts, start_ts=start_ts,
-                     position=p, generated=0, cow_pending=cow,
-                     # start at the chunk holding the first NON-shared row
-                     # (a fully-shared prompt still runs its last chunk:
-                     # writes are masked, but the final chunk's logits are
-                     # where the first token comes from)
-                     chunk_next=min(shared_len, p - 1) // chunk * chunk,
-                     shared_len=shared_len,
-                     n_fresh=len(fresh), n_shared=len(shared))
-        slot.buf[:p] = prompt
-        self.slots[slot_idx] = slot
+        self.slots[slot_idx] = self._new_slot(
+            req, prompt, shared + fresh, bt, enq_ts, start_ts, generated=0,
+            cow_pending=cow,
+            # start at the chunk holding the first NON-shared row (a
+            # fully-shared prompt still runs its last chunk: writes are
+            # masked, but the final chunk's logits are where the first
+            # token comes from)
+            chunk_next=min(shared_len, p - 1) // chunk * chunk,
+            shared_len=shared_len, n_fresh=len(fresh), n_shared=len(shared))
 
     def _chunk_tick(self) -> None:
         """At most ONE prefill chunk per scheduler iteration — the knob
@@ -1093,6 +1141,47 @@ class ServeEngine:
                 return
 
     def _run_chunk(self, slot_idx: int, s: _Slot) -> None:
+        cfg = self.cfg
+        chunk = cfg.prefill_chunk
+        p = s.prompt_len
+        with self._span("serve.prefill", rid=s.req.rid, trace_id=s.trace_id,
+                        prompt_len=p, bucket=chunk, shared_len=s.shared_len,
+                        chunk_start=s.chunk_next):
+            with self._span("prefill.dispatch",
+                            first_call=self._first_call("chunk_prefill")):
+                tok = self._dispatch_chunk(s)
+            if tok is None:
+                return      # not the last chunk: nothing to wait for yet
+            with self._span("prefill.wait"):
+                # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
+                tok = int(jax.device_get(tok))
+            now = self._now()
+            s.buf[p] = tok
+            s.generated = 1
+            s.first_token_ts = s.token_ts[0] = now
+            s.win_start_ts = now
+            if s.generated >= s.req.max_new_tokens or tok == cfg.eos_id:
+                s.done = True
+                s.finish_ts = now
+            if self.tracer is not None:
+                tr = self.tracer
+                tid, sid, par = tr.ids(s.req.rid, "prefill")
+                first = min(s.shared_len, p - 1) // chunk * chunk
+                tr.ledger.emit("span", trace_id=tid, span_id=sid,
+                               parent_id=par, name="prefill", rid=s.req.rid,
+                               start=round(s.start_ts, 6), end=round(now, 6),
+                               mode="chunked", chunk=chunk,
+                               chunks=-(-(p - first) // chunk),
+                               prompt_len=p, pages_fresh=s.n_fresh,
+                               pages_shared=s.n_shared,
+                               shared_len=s.shared_len,
+                               cow=s.cow_pending is not None,
+                               tenant=s.req.tenant, **tr.attrs())
+
+    def _dispatch_chunk(self, s: _Slot):
+        """Dispatch the slot's next prefill chunk; on the final chunk also
+        the first token's sampling, whose device value is returned (None
+        before that)."""
         cfg = self.cfg
         chunk = cfg.prefill_chunk
         p = s.prompt_len
@@ -1113,7 +1202,7 @@ class ServeEngine:
         self.prefill_token_work += chunk
         if start + chunk < p:
             s.chunk_next = start + chunk
-            return
+            return None
         # final chunk: the prompt is fully resident — sample the first
         # token (ONE rng consumption per admit, same as monolithic),
         # index the pages for future sharers, open the decode life
@@ -1144,30 +1233,7 @@ class ServeEngine:
             self.prompt_pages += self.pool.pages_needed(p)
             self.shared_prompt_pages += s.n_shared
         self.prefills += 1
-        # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
-        tok = int(jax.device_get(tok))
-        now = self._now()
-        s.buf[p] = tok
-        s.generated = 1
-        s.first_token_ts = now
-        s.win_start_ts = now
-        if s.generated >= s.req.max_new_tokens or tok == cfg.eos_id:
-            s.done = True
-            s.finish_ts = now
-        if self.tracer is not None:
-            tr = self.tracer
-            tid, sid, par = tr.ids(s.req.rid, "prefill")
-            first = min(s.shared_len, p - 1) // chunk * chunk
-            tr.ledger.emit("span", trace_id=tid, span_id=sid,
-                           parent_id=par, name="prefill", rid=s.req.rid,
-                           start=round(s.start_ts, 6), end=round(now, 6),
-                           mode="chunked", chunk=chunk,
-                           chunks=-(-(p - first) // chunk),
-                           prompt_len=p, pages_fresh=s.n_fresh,
-                           pages_shared=s.n_shared,
-                           shared_len=s.shared_len,
-                           cow=s.cow_pending is not None,
-                           tenant=s.req.tenant, **tr.attrs())
+        return tok
 
     # -- sequence-parallel prefill -----------------------------------------
     def _prefill_sp(self, slot_idx, req, prompt, fresh, enq_ts, start_ts,
@@ -1190,57 +1256,62 @@ class ServeEngine:
         bt[:len(bt_pages)] = bt_pages
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :p] = prompt
-        program = _sp_prefill_program(self.model, self.sp_mesh,
-                                      self.cfg.temperature, self.cfg.top_k,
-                                      self.cfg.top_p)
-        # specializes per sp bucket, same contract as serve_prefill
-        register_audit_program("serve_sp_prefill", program,
-                               allowed=max(len(self.sp_buckets), 1))
-        tok, new_layers, self._rng = program(
-            self.params, self.pool.layers(),
-            jnp.asarray(self.pool.flat_block_table(bt[None])),
-            jnp.int32(p), jnp.int32(shared_len), jnp.asarray(padded),
-            self._rng)
-        self.pool.adopt(new_layers)
-        if self.cfg.prefix_cache:
-            self.pool.register_prefix(prompt, bt_pages,
-                                      skip_slots=len(shared))
-            self.prompt_pages += self.pool.pages_needed(p)
-            self.shared_prompt_pages += len(shared)
-        self.prefills += 1
-        self.sp_prefills += 1
-        # each device touches bucket/n rows: that's the wall the scheduler
-        # waited behind, so that's what the virtual clock charges
-        self.prefill_token_work += bucket // self.sp_n
-        # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
-        tok = int(jax.device_get(tok))
-        now = self._now()
-        slot = _Slot(req=req, pages=shared + fresh, block_table=bt,
-                     buf=np.zeros((p + req.max_new_tokens,), np.int32),
-                     prompt_len=p, admit_ts=enq_ts, start_ts=start_ts,
-                     position=p, generated=1, first_token_ts=now,
-                     cow_pending=cow, shared_len=shared_len,
-                     n_fresh=len(fresh), n_shared=len(shared),
-                     win_start_ts=now)
-        slot.buf[:p] = prompt
-        slot.buf[p] = tok
-        if (slot.generated >= req.max_new_tokens
-                or tok == self.cfg.eos_id):
-            slot.done = True
-            slot.finish_ts = now
-        self.slots[slot_idx] = slot
-        if self.tracer is not None:
-            tr = self.tracer
-            tid, sid, par = tr.ids(req.rid, "prefill")
-            tr.ledger.emit("span", trace_id=tid, span_id=sid,
-                           parent_id=par, name="prefill", rid=req.rid,
-                           start=round(start_ts, 6), end=round(now, 6),
-                           mode="sp", sp_devices=self.sp_n,
-                           bucket=bucket, prompt_len=p,
-                           pages_fresh=len(fresh),
-                           pages_shared=len(shared),
-                           shared_len=shared_len, cow=cow is not None,
-                           tenant=req.tenant, **tr.attrs())
+        with self._span("serve.prefill", rid=req.rid,
+                        trace_id=self._trace_id(req.rid), prompt_len=p,
+                        bucket=bucket, shared_len=shared_len):
+            with self._span("prefill.dispatch", first_call=self._first_call(
+                    ("sp_prefill", bucket))):
+                program = _sp_prefill_program(
+                    self.model, self.sp_mesh, self.cfg.temperature,
+                    self.cfg.top_k, self.cfg.top_p)
+                # specializes per sp bucket, same contract as serve_prefill
+                register_audit_program("serve_sp_prefill", program,
+                                       allowed=max(len(self.sp_buckets), 1))
+                tok, new_layers, self._rng = program(
+                    self.params, self.pool.layers(),
+                    jnp.asarray(self.pool.flat_block_table(bt[None])),
+                    jnp.int32(p), jnp.int32(shared_len), jnp.asarray(padded),
+                    self._rng)
+                self.pool.adopt(new_layers)
+                if self.cfg.prefix_cache:
+                    self.pool.register_prefix(prompt, bt_pages,
+                                              skip_slots=len(shared))
+                    self.prompt_pages += self.pool.pages_needed(p)
+                    self.shared_prompt_pages += len(shared)
+                self.prefills += 1
+                self.sp_prefills += 1
+                # each device touches bucket/n rows: that's the wall the
+                # scheduler waited behind, so that's what the virtual clock
+                # charges
+                self.prefill_token_work += bucket // self.sp_n
+            with self._span("prefill.wait"):
+                # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
+                tok = int(jax.device_get(tok))
+            now = self._now()
+            slot = self._new_slot(req, prompt, shared + fresh, bt, enq_ts,
+                                  start_ts, generated=1, first_token_ts=now,
+                                  cow_pending=cow, shared_len=shared_len,
+                                  n_fresh=len(fresh), n_shared=len(shared),
+                                  win_start_ts=now)
+            slot.buf[p] = tok
+            slot.token_ts[0] = now
+            if (slot.generated >= req.max_new_tokens
+                    or tok == self.cfg.eos_id):
+                slot.done = True
+                slot.finish_ts = now
+            self.slots[slot_idx] = slot
+            if self.tracer is not None:
+                tr = self.tracer
+                tid, sid, par = tr.ids(req.rid, "prefill")
+                tr.ledger.emit("span", trace_id=tid, span_id=sid,
+                               parent_id=par, name="prefill", rid=req.rid,
+                               start=round(start_ts, 6), end=round(now, 6),
+                               mode="sp", sp_devices=self.sp_n,
+                               bucket=bucket, prompt_len=p,
+                               pages_fresh=len(fresh),
+                               pages_shared=len(shared),
+                               shared_len=shared_len, cow=cow is not None,
+                               tenant=req.tenant, **tr.attrs())
 
     def _resolve_cow(self, active) -> None:
         """Fork every pending shared frontier page before this tick's
@@ -1270,56 +1341,21 @@ class ServeEngine:
                   if s is not None and not s.done and s.chunk_next < 0]
         if not active:
             return
-        self._resolve_cow(active)
-        if self.cfg.spec_k > 0:
-            return self._tick_spec(active)
-        n = len(self.slots)
-        tokens = np.zeros((n,), np.int32)
-        positions = np.zeros((n,), np.int32)
-        bts = np.full((n, self.max_pages_per_seq), self.pool.num_pages,
-                      np.int32)
-        for i, s in active:
-            tokens[i] = s.buf[s.prompt_len + s.generated - 1]
-            positions[i] = s.position
-            bts[i] = s.block_table
-        program = _tick_program(self.model, self.cfg.temperature,
-                                self.cfg.top_k, self.cfg.top_p,
-                                self.sp_mesh)
-        # tick shapes are occupancy-invariant (inactive slots ride the
-        # trash page), so ANY cache growth is a retrace hazard: allowed=1
-        register_audit_program("serve_tick", program)
-        nxt, new_layers, self._rng = program(
-            self.params, self.pool.layers(),
-            jnp.asarray(self.pool.flat_block_table(bts)),
-            jnp.asarray(tokens), jnp.asarray(positions), self._rng)
-        self.pool.adopt(new_layers)
-        # iteration-level scheduling: every tick's tokens come back to the
-        # host so finished sequences free their slot/pages for the SAME-
-        # tick refill — the one sync per tick is the scheduling primitive,
-        # not an accident (Orca's design point)
-        # distlint: disable=DL002 -- the per-tick sync is the scheduler's eviction/refill decision point
-        nxt = np.asarray(jax.device_get(nxt))
-        now = self._now()
-        for i, s in active:
-            tok = int(nxt[i])
-            s.buf[s.prompt_len + s.generated] = tok
-            s.generated += 1
-            s.position += 1
-            if (s.generated >= s.req.max_new_tokens
-                    or tok == self.cfg.eos_id):
-                s.done = True
-                s.finish_ts = now
-            self._note_decode(s, now, tokens=1)
+        attrs = {"rids": [s.req.rid for _, s in active]}
+        if self.tracer is not None:
+            attrs["trace_ids"] = [s.trace_id for _, s in active]
+        with self._span("serve.tick", **attrs):
+            if self.cfg.spec_k > 0:
+                self._tick_spec(active)
+            else:
+                self._tick_plain(active)
         self.ticks += 1
         self._occupancy_sum += len(active) / max(len(self.slots), 1)
 
-    def _tick_spec(self, active) -> None:
-        """One speculative iteration: k draft proposals + one base verify
-        per active slot, all in one dispatch (_spec_tick_program), then
-        host-side emission with per-slot budget/eos truncation — the same
-        sync point the plain tick already pays, now worth up to k tokens."""
+    def _tick_inputs(self, active, with_caps: bool = False):
+        """The tick's host-side inputs: each slot's last token, position,
+        block table (and, for the speculative tick, its write-mask cap)."""
         n = len(self.slots)
-        k = self.cfg.spec_k
         tokens = np.zeros((n,), np.int32)
         positions = np.zeros((n,), np.int32)
         caps = np.zeros((n,), np.int32)
@@ -1332,37 +1368,84 @@ class ServeEngine:
             # (a draft window can overrun a nearly-done request)
             caps[i] = s.prompt_len + s.req.max_new_tokens
             bts[i] = s.block_table
-        program = _spec_tick_program(self.model, self.draft_model, k)
-        # same occupancy-invariance as the plain tick: allowed=1
-        register_audit_program("serve_spec_tick", program)
-        emitted, emit_n, new_layers, new_dlayers = program(
-            self.params, self.draft_params, self.pool.layers(),
-            self.draft_pool.layers(),
-            jnp.asarray(self.pool.flat_block_table(bts)),
-            jnp.asarray(tokens), jnp.asarray(positions), jnp.asarray(caps))
-        self.pool.adopt(new_layers)
-        self.draft_pool.adopt(new_dlayers)
-        # distlint: disable=DL002 -- the per-tick sync is the scheduler's eviction/refill decision point
-        emitted, emit_n = map(np.asarray, jax.device_get((emitted, emit_n)))
-        now = self._now()
-        for i, s in active:
-            took = 0
-            for j in range(int(emit_n[i])):
-                tok = int(emitted[i, j])
-                s.buf[s.prompt_len + s.generated] = tok
-                s.generated += 1
-                s.position += 1
-                self.spec_emitted += 1
-                took += 1
-                if (s.generated >= s.req.max_new_tokens
-                        or tok == self.cfg.eos_id):
-                    s.done = True
-                    s.finish_ts = now
-                    break
-            self.spec_slot_ticks += 1
-            self._note_decode(s, now, tokens=took, drafted=k)
-        self.ticks += 1
-        self._occupancy_sum += len(active) / max(len(self.slots), 1)
+        arrays = [jnp.asarray(self.pool.flat_block_table(bts)),
+                  jnp.asarray(tokens), jnp.asarray(positions)]
+        if with_caps:
+            arrays.append(jnp.asarray(caps))
+        return arrays
+
+    def _emit_token(self, s: _Slot, tok: int, now: float) -> None:
+        s.buf[s.prompt_len + s.generated] = tok
+        s.token_ts[s.generated] = now
+        s.generated += 1
+        s.position += 1
+        if s.generated >= s.req.max_new_tokens or tok == self.cfg.eos_id:
+            s.done = True
+            s.finish_ts = now
+
+    def _tick_plain(self, active) -> None:
+        with self._span("tick.build"):
+            self._resolve_cow(active)
+            inputs = self._tick_inputs(active)
+        with self._span("tick.dispatch",
+                        first_call=self._first_call("tick")):
+            program = _tick_program(self.model, self.cfg.temperature,
+                                    self.cfg.top_k, self.cfg.top_p,
+                                    self.sp_mesh)
+            # tick shapes are occupancy-invariant (inactive slots ride the
+            # trash page), so ANY cache growth is a retrace hazard: allowed=1
+            register_audit_program("serve_tick", program)
+            nxt, new_layers, self._rng = program(
+                self.params, self.pool.layers(), *inputs, self._rng)
+            self.pool.adopt(new_layers)
+        with self._span("tick.wait"):
+            # iteration-level scheduling: every tick's tokens come back to
+            # the host so finished sequences free their slot/pages for the
+            # SAME-tick refill — the one sync per tick is the scheduling
+            # primitive, not an accident (Orca's design point)
+            # distlint: disable=DL002 -- the per-tick sync is the scheduler's eviction/refill decision point
+            nxt = np.asarray(jax.device_get(nxt))
+        with self._span("tick.emit"):
+            now = self._now()
+            for i, s in active:
+                self._emit_token(s, int(nxt[i]), now)
+                self._note_decode(s, now, tokens=1)
+
+    def _tick_spec(self, active) -> None:
+        """One speculative iteration: k draft proposals + one base verify
+        per active slot, all in one dispatch (_spec_tick_program), then
+        host-side emission with per-slot budget/eos truncation — the same
+        sync point the plain tick already pays, now worth up to k tokens."""
+        k = self.cfg.spec_k
+        with self._span("tick.build"):
+            self._resolve_cow(active)
+            inputs = self._tick_inputs(active, with_caps=True)
+        with self._span("tick.dispatch",
+                        first_call=self._first_call("spec_tick")):
+            program = _spec_tick_program(self.model, self.draft_model, k)
+            # same occupancy-invariance as the plain tick: allowed=1
+            register_audit_program("serve_spec_tick", program)
+            emitted, emit_n, new_layers, new_dlayers = program(
+                self.params, self.draft_params, self.pool.layers(),
+                self.draft_pool.layers(), *inputs)
+            self.pool.adopt(new_layers)
+            self.draft_pool.adopt(new_dlayers)
+        with self._span("tick.wait"):
+            # distlint: disable=DL002 -- the per-tick sync is the scheduler's eviction/refill decision point
+            emitted, emit_n = map(np.asarray,
+                                  jax.device_get((emitted, emit_n)))
+        with self._span("tick.emit"):
+            now = self._now()
+            for i, s in active:
+                took = 0
+                for j in range(int(emit_n[i])):
+                    self._emit_token(s, int(emitted[i, j]), now)
+                    self.spec_emitted += 1
+                    took += 1
+                    if s.done:
+                        break
+                self.spec_slot_ticks += 1
+                self._note_decode(s, now, tokens=took, drafted=k)
 
     def _note_decode(self, s: _Slot, now: float, tokens: int,
                      drafted: int = 0) -> None:

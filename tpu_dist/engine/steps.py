@@ -96,7 +96,8 @@ def _loss_and_metrics(model, transform, params, batch_stats, images_u8, labels,
         logits = model.apply(variables, x, train=False)
         new_stats = batch_stats
     n = jnp.float32(labels.shape[0])
-    loss_sum = cross_entropy_sum(logits, labels)
+    with jax.named_scope("loss"):
+        loss_sum = cross_entropy_sum(logits, labels)
     mean_loss = loss_sum / n
     metrics = _metric_sums(logits, labels, loss_sum)
     return prec.scale_loss(mean_loss, loss_scale), (new_stats, metrics)
@@ -129,12 +130,16 @@ def _apply_update(tx, state: TrainState, grads, new_stats, metrics,
     from tpu_dist.obs.health import probe_update_metrics, probes_ok
 
     grads, new_scale, finite = prec.unscale_and_update(grads, state.loss_scale)
-    if hasattr(tx, "apply"):  # FusedSGD protocol: fused params+momentum update
-        new_params, new_opt = tx.apply(state.params, grads, state.opt_state,
-                                       state.step)
-    else:  # optax GradientTransformation
-        updates, new_opt = tx.update(grads, state.opt_state, state.params)
-        new_params = jax.tree.map(lambda p, u: p + u, state.params, updates)
+    # named in the compiled program, for a trace's readers (metadata only)
+    with jax.named_scope("optimizer"):
+        if hasattr(tx, "apply"):  # FusedSGD protocol: fused params+momentum
+            new_params, new_opt = tx.apply(state.params, grads,
+                                           state.opt_state, state.step)
+        else:  # optax GradientTransformation
+            updates, new_opt = tx.update(grads, state.opt_state,
+                                         state.params)
+            new_params = jax.tree.map(lambda p, u: p + u, state.params,
+                                      updates)
     # loss-scale skip: on non-finite grads keep old params/opt (apex behavior)
     if state.loss_scale is not None:
         new_params = jax.tree.map(

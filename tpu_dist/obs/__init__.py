@@ -4,8 +4,9 @@ Four pieces, one handle:
 
 * :mod:`~tpu_dist.obs.ledger` — append-only JSONL of typed events (the
   source of truth; the epoch CSV and progress line render FROM it);
-* :mod:`~tpu_dist.obs.trace` — host-side step-phase spans that also emit
-  ``jax.profiler`` annotations when a trace is active;
+* :mod:`~tpu_dist.obs.trace` — the program's spans: one always-on bounded
+  ring on the engine's clock, each span also a ``jax.profiler`` annotation
+  (so any profiler session holds them beside the device's operations);
 * :mod:`~tpu_dist.obs.skew` — cross-host step-time allgather every K steps
   (straggler index, p50/p99/spread);
 * :mod:`~tpu_dist.obs.watchdog` — trailing-median hang detector that dumps
@@ -34,7 +35,7 @@ import time
 import traceback
 from typing import Optional
 
-from tpu_dist.obs import faults
+from tpu_dist.obs import faults, trace
 from tpu_dist.obs.attr import bucket_totals, cost_buckets, emit_cost_model
 from tpu_dist.obs.flightrec import FlightRecorder
 from tpu_dist.obs.goodput import (GoodputAccumulator, GoodputMonitor,
@@ -60,7 +61,12 @@ __all__ = ["EVENT_SCHEMA", "EpochCsvSink", "FlightRecorder",
            "job_accounting",
            "metrics_ledger_sink", "next_attempt_index", "per_process_path",
            "phase_totals", "profile_session", "read_ledger",
-           "serve_metrics", "split_attempts", "step_annotation"]
+           "serve_metrics", "split_attempts", "step_annotation", "trace"]
+
+
+# RunObs.run_end writes the span ring (JSONL) to <ledger_path> + this: a
+# name no glob over ledgers (run*.jsonl, run.a*.jsonl, run.p*.jsonl) matches
+SPANS_SUFFIX = ".spans"
 
 
 def effective_peak_tflops() -> tuple:
@@ -123,9 +129,11 @@ class RunObs:
             # the legacy per-epoch CSV becomes a VIEW of the epoch event
             self.ledger.add_sink(EpochCsvSink(cfg.log_csv))
         profile_dir = getattr(cfg, "profile_dir", "") or ""
+        # this run owns a profile_dir session (the program's spans are in
+        # ANY session's trace; this only says whose session it is)
         self.profiling = bool(profile_dir) and self.is_main
         self.profile_dir = profile_dir
-        self.tracer = StepTracer(annotate=self.profiling)
+        self.tracer = StepTracer(prefix="train.")
         skew_every = getattr(cfg, "skew_every", 0) or 0
         self.skew = (SkewMonitor(skew_every, ledger=self.ledger)
                      if skew_every > 0 else None)
@@ -334,6 +342,12 @@ class RunObs:
         if self.metrics_server is not None:
             self.metrics_server.close()
             self.metrics_server = None
+        if self.ledger.path:
+            # the program's spans, beside the ledger (obs.trace)
+            try:
+                trace.ring().dump(self.ledger.path + SPANS_SUFFIX)
+            except OSError:
+                pass  # the crash paths reach here too
         self.ledger.close()
 
     # -- crash-safe shutdown -------------------------------------------

@@ -16,6 +16,9 @@ bundle directory:
   (pprof; per-buffer attribution for OOM forensics);
 * ``events_tail.jsonl``— the ring: the last N ledger records leading up
   to the trigger (what the run was doing);
+* ``spans_tail.jsonl`` — the tail of the program's span ring (obs.trace):
+  the last spans closed before the trigger, then every thread's open ones
+  (``"open": true``): which phase the run was inside;
 * ``trace/``           — a ``jax.profiler`` trace of the next K step
   records after the trigger (armed at trigger time, started/stopped on
   the loop thread at drain boundaries — profiler state is global, so a
@@ -43,7 +46,10 @@ import time
 from collections import deque
 from typing import Callable, List, Optional
 
+from tpu_dist.obs import trace
 from tpu_dist.obs.ledger import Ledger
+
+SPANS_TAIL = 256    # closed spans a bundle carries
 
 # a skew sample is a straggler SPIKE (not routine jitter) when the
 # cross-host spread exceeds both bounds
@@ -185,7 +191,10 @@ class FlightRecorder:
         files["stacks.txt"] = self._write_stacks(bundle)
         files["hbm.json"] = self._write_hbm(bundle)
         files["memory.prof"] = self._write_memory_profile(bundle)
-        files["events_tail.jsonl"] = self._write_tail(bundle, tail)
+        files["events_tail.jsonl"] = self._write_jsonl(
+            bundle, "events_tail.jsonl", tail)
+        files["spans_tail.jsonl"] = self._write_jsonl(
+            bundle, "spans_tail.jsonl", self._spans_tail())
         trace_status = self._arm_trace(bundle)
         manifest = {
             "reason": reason,
@@ -256,14 +265,24 @@ class FlightRecorder:
         except Exception:
             return None
 
-    def _write_tail(self, bundle: str, tail: list) -> Optional[str]:
+    def _write_jsonl(self, bundle: str, name: str,
+                     rows: list) -> Optional[str]:
         try:
-            with open(os.path.join(bundle, "events_tail.jsonl"), "w") as f:
-                for rec in tail:
+            with open(os.path.join(bundle, name), "w") as f:
+                for rec in rows:
                     f.write(json.dumps(rec, default=str) + "\n")
-            return "events_tail.jsonl"
+            return name
         except OSError:
             return None
+
+    def _spans_tail(self) -> list:
+        """The span ring's last closed spans, then every thread's open ones."""
+        ring = trace.ring()
+        rows = [trace.span_record(sp) for sp in ring.tail(SPANS_TAIL)]
+        for ident, spans in ring.open_stacks().items():
+            rows += [{**trace.span_record(sp), "open": True, "thread": ident}
+                     for sp in spans]
+        return rows
 
     # -- the profiler window ---------------------------------------------
     def _arm_trace(self, bundle: str) -> dict:
@@ -295,7 +314,12 @@ class FlightRecorder:
                 with self._lock:
                     tr["state"] = "active"
             except Exception as e:
-                self._finish_trace(tr, "failed", why=repr(e))
+                # the profiler is global: a session somebody else started
+                # (a benchmark's, a user's) is not this recorder's failure.
+                # The program's spans are in that session's trace anyway
+                busy = "already" in str(e).lower()
+                self._finish_trace(tr, "trace_skipped" if busy else "failed",
+                                   why=repr(e))
             return
         with self._lock:
             tr["remaining"] -= 1

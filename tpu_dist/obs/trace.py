@@ -1,88 +1,210 @@
-"""Step-phase tracer: host-side spans that line up with XLA traces.
+"""Program spans: one bounded ring, on the engine's clock and the profiler's.
 
-The loops need to know where a step's wall time went — data wait vs dispatch
-vs device block — every step and with ~zero overhead, not only when a
-profiler is attached. :class:`StepTracer` accumulates named host-side spans
-(``with tracer.span("data"): ...``) into a per-step dict the ledger's
-``step`` record carries; when a ``jax.profiler`` trace is active
-(``profile_dir`` set), the same spans also emit
-``jax.profiler.TraceAnnotation`` so the host phases appear as named regions
-on the XLA timeline, and :func:`step_annotation` wraps
-``StepTraceAnnotation`` so XLA's per-step grouping matches the ledger's
-step numbering.
+A span has a name, a start, an end, the span that caused it (``parent``:
+the span open on the same thread when it began) and attributes; the spans
+of one request carry its identifier. Every span the program opens
 
-:func:`profile_session` replaces the two copy-pasted start/stop_trace
-blocks the engines grew in round 2: one context manager that starts the
-trace on entry and flushes it even on OOM/interrupt — a failing run is
-exactly the one worth profiling.
+* is appended, when it closes, to ONE process-wide bounded ring
+  (:func:`ring`: a ``collections.deque`` of :data:`RING_SIZE` entries; a
+  traced 8 s serving window makes about 2,000). It is always on: no config
+  field, no environment variable, no flag. A reader takes
+  ``ring().snapshot()``, an operator ``ring().dump(path)`` (JSONL), the
+  watchdog and the flight recorder ``open_stack()`` and ``tail()``;
+* enters a ``jax.profiler.TraceAnnotation("tpu_dist:<name>", sid=<id>)``,
+  which is free while no profiler session runs and, while one runs (a
+  benchmark's, the flight recorder's, a ``profile_dir``'s), puts the same
+  span on the profiler's clock beside the device's operations. ``sid`` is
+  the ring entry's, so a reader joins the two clocks span by span.
+
+Timestamps are ``time.monotonic`` in the trainers and the engine's own
+``now_fn`` in ``ServeEngine`` (virtual under a test's virtual clock).
+
+:class:`StepTracer` is the trainers' view: the same spans, plus the
+per-step sums of seconds (``pop()``) that the ledger's ``step`` record
+carries as ``data_s``/``dispatch_s``/``device_s``. :func:`step_annotation`
+wraps ``StepTraceAnnotation`` so XLA's per-step grouping matches the
+ledger's step numbering, and :func:`profile_session` starts a
+``profile_dir`` trace and stops it on every exit path.
 """
 
 from __future__ import annotations
 
+import itertools
+import json
+import threading
 import time
+from collections import deque
 from contextlib import contextmanager
-from typing import Dict, Optional
+from typing import Callable, Dict, Iterable, List, NamedTuple, Optional
+
+RING_SIZE = 65_536
+ANNOTATION_PREFIX = "tpu_dist:"
+
+_TraceAnnotation = None
+
+
+def _annotation(name: str, sid: int):
+    # imported on first use: the supervisor imports tpu_dist.obs jax-free
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(ANNOTATION_PREFIX + name, sid=sid)
+
+
+class Span(NamedTuple):
+    sid: int
+    name: str
+    start: float
+    end: float
+    parent: Optional[int]        # sid of the span that caused this one
+    attrs: dict
+
+
+class _OpenSpan:
+    """One span while it is open; ``attrs`` may be added to until it
+    closes (what a phase found out, e.g. how many it evicted)."""
+
+    __slots__ = ("ring", "sid", "name", "now", "attrs", "start", "parent",
+                 "seconds", "_ann", "_stack")
+
+    def __init__(self, ring: "SpanRing", name: str, now, attrs: dict):
+        self.ring, self.name, self.now, self.attrs = ring, name, now, attrs
+        self.sid = next(ring._ids)
+        self.seconds = 0.0
+
+    def __enter__(self) -> "_OpenSpan":
+        stacks, ident = self.ring._stacks, threading.get_ident()
+        stack = self._stack = stacks.get(ident)
+        if stack is None:
+            stack = self._stack = stacks[ident] = []
+        self.parent = stack[-1].sid if stack else None
+        stack.append(self)
+        self.start = self.now()
+        self._ann = _annotation(self.name, self.sid)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._ann.__exit__(*exc)
+        end = self.now()
+        self.seconds = end - self.start
+        self._stack.pop()
+        self.ring._spans.append(Span(self.sid, self.name, self.start, end,
+                                     self.parent, self.attrs))
+
+
+def span_record(span: Span) -> dict:
+    """A span as one flat JSON object (the dump's line)."""
+    return {"sid": span.sid, "name": span.name, "start": span.start,
+            "end": span.end, "parent": span.parent, **span.attrs}
+
+
+class SpanRing:
+    """The bounded ring of closed spans plus each thread's open stack."""
+
+    def __init__(self, size: int = RING_SIZE):
+        self._spans: deque = deque(maxlen=size)
+        self._ids = itertools.count(1)
+        self._stacks: Dict[int, List[_OpenSpan]] = {}
+
+    def span(self, name: str, now: Callable[[], float] = time.monotonic,
+             **attrs) -> _OpenSpan:
+        """``with ring().span("serve.evict", now=self._now) as sp: ...``"""
+        return _OpenSpan(self, name, now, attrs)
+
+    def snapshot(self) -> List[Span]:
+        """The closed spans, oldest first (a child closes, and so comes,
+        before its parent)."""
+        return list(self._spans)
+
+    def tail(self, n: int = 32) -> List[Span]:
+        """The last ``n`` closed spans, oldest first."""
+        return list(itertools.islice(reversed(self._spans), n))[::-1]
+
+    def open_stack(self, thread_ident: Optional[int] = None) -> List[Span]:
+        """The spans open right now on one thread (default: the caller's),
+        outermost first; ``end`` is the start again, nothing has ended."""
+        ident = threading.get_ident() if thread_ident is None else thread_ident
+        return [Span(s.sid, s.name, s.start, s.start, s.parent, s.attrs)
+                for s in list(self._stacks.get(ident, ()))]
+
+    def open_stacks(self) -> Dict[int, List[Span]]:
+        """thread ident -> its open spans, for the threads that have any."""
+        stacks = {ident: self.open_stack(ident)
+                  for ident in list(self._stacks)}
+        return {ident: spans for ident, spans in stacks.items() if spans}
+
+    def dump(self, path: str) -> int:
+        """Write the ring as JSONL, one span a line; returns the count."""
+        spans = self.snapshot()
+        with open(path, "w") as f:
+            for span in spans:
+                f.write(json.dumps(span_record(span), default=str) + "\n")
+        return len(spans)
+
+
+def format_spans(spans: Iterable[Span]) -> str:
+    """One line a span, for the watchdog's dump."""
+    return "\n".join(
+        f"  {s.name} [{s.start:.6f} .. {s.end:.6f}] sid={s.sid} "
+        f"parent={s.parent} {s.attrs or ''}".rstrip() for s in spans)
+
+
+_RING = SpanRing()
+
+
+def ring() -> SpanRing:
+    """THE process-wide ring."""
+    return _RING
 
 
 class StepTracer:
-    """Accumulating named spans for one step (or window) of host work.
+    """The trainers' spans: each goes to the ring as ``<prefix><name>`` and
+    also accumulates its seconds under its path (``data`` ->
+    ``data/decode``; a parent's total includes its children's) until
+    :meth:`pop` collects {path: seconds} at a step boundary."""
 
-    Spans nest: a span opened inside another accumulates under the joined
-    path (``data`` -> ``data/decode``), and the parent's total includes the
-    child's time (wall-clock truth; the report subtracts if it wants
-    self-time). ``annotate=True`` additionally wraps each span in
-    ``jax.profiler.TraceAnnotation`` so host phases land on the XLA trace.
-
-    One tracer per loop; call :meth:`pop` at each step boundary to collect
-    {phase: seconds} and reset. :meth:`add` folds in externally measured
-    seconds (the boundary device_get block, timed where it happens).
-    """
-
-    def __init__(self, annotate: bool = False):
-        self.annotate = annotate
+    def __init__(self, prefix: str = ""):
+        self.prefix = prefix
         self._acc: Dict[str, float] = {}
-        self._stack = []
+        self._stack: List[str] = []
 
     @contextmanager
-    def span(self, name: str):
+    def span(self, name: str, **attrs):
         path = "/".join(self._stack + [name])
         self._stack.append(name)
-        ann = None
-        if self.annotate:
-            import jax.profiler
-            ann = jax.profiler.TraceAnnotation(path)
-            ann.__enter__()
-        t0 = time.perf_counter()
+        sp = _RING.span(self.prefix + name, **attrs)
         try:
-            yield
+            with sp:
+                yield sp
         finally:
-            dt = time.perf_counter() - t0
-            if ann is not None:
-                ann.__exit__(None, None, None)
             self._stack.pop()
-            self._acc[path] = self._acc.get(path, 0.0) + dt
+            self._acc[path] = self._acc.get(path, 0.0) + sp.seconds
 
-    def add(self, name: str, seconds: float) -> None:
-        """Fold externally measured seconds into a phase."""
-        self._acc[name] = self._acc.get(name, 0.0) + float(seconds)
-
-    def phases(self) -> Dict[str, float]:
-        return dict(self._acc)
+    def timed_iter(self, name: str, iterable):
+        """Yield the iterable's items with every wait for the next one
+        under a ``name`` span (the loops' wait for their input)."""
+        it = iter(iterable)
+        while True:
+            with self.span(name):
+                try:
+                    item = next(it)
+                except StopIteration:
+                    return
+            yield item
 
     def pop(self) -> Dict[str, float]:
-        """Collect the accumulated {phase: seconds} and reset for the next
+        """Collect the accumulated {path: seconds} and reset for the next
         step."""
         out, self._acc = self._acc, {}
         return out
 
 
 @contextmanager
-def step_annotation(step_num: int, enabled: bool = True):
-    """``jax.profiler.StepTraceAnnotation`` wrapper (no-op when disabled)
-    so XLA's per-step trace grouping carries the ledger's step number."""
-    if not enabled:
-        yield
-        return
+def step_annotation(step_num: int):
+    """``jax.profiler.StepTraceAnnotation`` so XLA's per-step trace
+    grouping carries the ledger's step number (free with no session)."""
     import jax.profiler
     with jax.profiler.StepTraceAnnotation("step", step_num=step_num):
         yield

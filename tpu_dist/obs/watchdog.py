@@ -17,6 +17,12 @@ never false-trigger), it dumps — ONCE per stall — to stderr and the ledger:
 * live HBM counters (``utils.telemetry.device_memory_stats``);
 * the last ledger event (what the run was doing when it stopped).
 
+A threshold that passes while the driving thread's innermost open span
+(obs.trace) is a ``*.dispatch`` with ``first_call`` set is a COMPILATION,
+not a stall: it is counted (``compile_waits``) and noted in one line, with
+no ``stall`` event and so no flight-recorder window. Every real dump leads
+with that thread's open span stack and the ring's last 32 spans.
+
 It never kills the run: a stall that resolves (a slow eval, a network blip)
 re-arms on the next ``step_done`` and the run continues with the dump as a
 breadcrumb. Loops call :meth:`pause` around phases where step completions
@@ -33,6 +39,7 @@ import traceback
 from collections import deque
 from typing import Optional
 
+from tpu_dist.obs import trace
 from tpu_dist.obs.ledger import Ledger
 
 
@@ -44,6 +51,17 @@ def thread_stacks() -> str:
         parts.append(f"--- thread {names.get(ident, '?')} ({ident}) ---\n"
                      + "".join(traceback.format_stack(frame)))
     return "\n".join(parts)
+
+
+def _is_first_dispatch(open_spans) -> bool:
+    """The innermost open span is a program's first dispatch: the call
+    that compiles it (engine/loop.py, lm_loop.py and serve.py set
+    ``first_call`` on their ``*.dispatch`` spans)."""
+    if not open_spans:
+        return False
+    inner = open_spans[-1]
+    return (inner.name.endswith(".dispatch")
+            and bool(inner.attrs.get("first_call")))
 
 
 class Watchdog:
@@ -68,6 +86,9 @@ class Watchdog:
         self._last_done: Optional[float] = None
         self._fired_this_stall = False
         self.stall_count = 0
+        self.compile_waits = 0
+        self._compile_noted = False
+        self._driver: Optional[int] = None   # the stepping thread's ident
         self._paused = False
         self._stop = threading.Event()
         self._lock = threading.Lock()
@@ -80,7 +101,9 @@ class Watchdog:
             self._durations.append(float(seconds))
             self._last_done = time.monotonic()
             self._fired_this_stall = False  # stall over; re-arm
+            self._compile_noted = False
             self._paused = False
+            self._driver = threading.get_ident()
         if self._thread is None:
             self._thread = threading.Thread(
                 target=self._run, name="tpu-dist-watchdog", daemon=True)
@@ -106,6 +129,7 @@ class Watchdog:
             with self._lock:
                 self._last_done = now
                 self._paused = False
+                self._driver = threading.get_ident()
             if self._thread is None:
                 self._thread = threading.Thread(
                     target=self._run, name="tpu-dist-watchdog", daemon=True)
@@ -146,14 +170,35 @@ class Watchdog:
                 idle = time.monotonic() - self._last_done
                 if thr is None or idle < thr:
                     continue
-                self._fired_this_stall = True  # once per stall
-                self.stall_count += 1
-            self._dump(idle, thr)
+                open_spans = trace.ring().open_stack(self._driver)
+                compiling = _is_first_dispatch(open_spans)
+                if compiling:
+                    # not a stall, and not latched as one: a hang AFTER
+                    # the compile (another open span) still dumps
+                    noted, self._compile_noted = self._compile_noted, True
+                    if noted:
+                        continue
+                    self.compile_waits += 1
+                else:
+                    self._fired_this_stall = True  # once per stall
+                    self.stall_count += 1
+            if compiling:
+                print(f"tpu_dist watchdog: no step for {idle:.1f}s inside "
+                      f"{open_spans[-1].name} (first call of its program): "
+                      "a compilation, not a stall",
+                      file=self._stream or sys.stderr, flush=True)
+            else:
+                self._dump(idle, thr, open_spans)
 
-    def _dump(self, idle_s: float, threshold_s: float) -> None:
+    def _dump(self, idle_s: float, threshold_s: float,
+              open_spans=()) -> None:
         from tpu_dist.utils.telemetry import device_memory_stats
 
         stacks = thread_stacks()
+        spans = (f"open spans (outermost first):\n"
+                 f"{trace.format_spans(open_spans) or '  none'}\n"
+                 f"last spans:\n"
+                 f"{trace.format_spans(trace.ring().tail(32)) or '  none'}")
         try:
             hbm = device_memory_stats()
         except Exception:
@@ -164,7 +209,7 @@ class Watchdog:
               f"{idle_s:.1f}s (threshold {threshold_s:.1f}s = "
               f"{self.factor:g} x trailing-median step) ===\n"
               f"last ledger event: {last}\n"
-              f"hbm: {hbm or 'n/a'}\n{stacks}\n"
+              f"hbm: {hbm or 'n/a'}\n{spans}\n{stacks}\n"
               f"=== end watchdog dump (run NOT killed) ===",
               file=stream, flush=True)
         if self.ledger is not None:
@@ -172,6 +217,7 @@ class Watchdog:
                 self.ledger.emit(
                     "stall", idle_s=round(idle_s, 3),
                     threshold_s=round(threshold_s, 3), stacks=stacks,
+                    open_span=open_spans[-1].name if open_spans else None,
                     hbm=hbm or None, last_event=last)
             except Exception:
                 pass  # the dump must never take the run down

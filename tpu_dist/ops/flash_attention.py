@@ -327,7 +327,7 @@ def _fa_forward(q, k, v, causal, q_offset, kv_offset, block_q, block_k,
     scale = 1.0 / math.sqrt(d)
     grid = (b * h, lq // bq, lk // bk)          # kv INNERMOST: scratch carries
 
-    out, lse = pl.pallas_call(
+    forward = pl.pallas_call(
         functools.partial(_fa_fwd_kernel, bq=bq, bk=bk, nk=lk // bk,
                           scale=scale, causal=causal,
                           q_offset=q_offset, kv_offset=kv_offset),
@@ -352,7 +352,10 @@ def _fa_forward(q, k, v, causal, q_offset, kv_offset, block_q, block_k,
             pltpu.VMEM((bq, _LANES), jnp.float32),   # running sum
         ],
         interpret=interpret,
-    )(qf, kf, vf)
+    )
+    # the kernels' name in a trace, whatever the backend calls them
+    with jax.named_scope("flash_attention"):
+        out, lse = forward(qf, kf, vf)
     return jnp.swapaxes(out.reshape(b, h, lq, d), 1, 2), lse
 
 
@@ -379,7 +382,7 @@ def _fa_backward(q, k, v, out, lse, g, causal, q_offset, kv_offset,
     row_spec = pl.BlockSpec((1, bq, _STAT_LANES),
                             lambda bh, iq, ik: (bh, iq, 0))
 
-    dq = pl.pallas_call(
+    backward_dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, bq=bq, bk=bk, nk=lk // bk,
                           scale=scale, causal=causal,
                           q_offset=q_offset, kv_offset=kv_offset),
@@ -389,14 +392,14 @@ def _fa_backward(q, k, v, out, lse, g, causal, q_offset, kv_offset,
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-    )(qf, kf, vf, gf, lse, delta)
+    )
 
     # second pass: kv block fixed, q blocks innermost (dk/dv carry)
     q_spec2 = pl.BlockSpec((1, bq, d), lambda bh, ik, iq: (bh, iq, 0))
     k_spec2 = pl.BlockSpec((1, bk, d), lambda bh, ik, iq: (bh, ik, 0))
     row_spec2 = pl.BlockSpec((1, bq, _STAT_LANES),
                              lambda bh, ik, iq: (bh, iq, 0))
-    dk, dv = pl.pallas_call(
+    backward_dkv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, bq=bq, bk=bk, nq=lq // bq,
                           scale=scale, causal=causal,
                           q_offset=q_offset, kv_offset=kv_offset),
@@ -408,7 +411,10 @@ def _fa_backward(q, k, v, out, lse, g, causal, q_offset, kv_offset,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
-    )(qf, kf, vf, gf, lse, delta)
+    )
+    with jax.named_scope("flash_attention"):
+        dq = backward_dq(qf, kf, vf, gf, lse, delta)
+        dk, dv = backward_dkv(qf, kf, vf, gf, lse, delta)
 
     unfold = lambda x, l: jnp.swapaxes(x.reshape(b, h, l, d), 1, 2)
     return unfold(dq, lq), unfold(dk, lk), unfold(dv, lk)

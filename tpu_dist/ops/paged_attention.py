@@ -496,24 +496,28 @@ def paged_attend(q, k, v, paged: dict, *, prefill: bool, attn_fn, dtype):
         # training contraction, so flash/blockwise plug-ins keep working
         return attn_fn(q, k, v), new_layer
 
-    if layer.quant == "int8" and layer.read == "flash" and lq == 1:
-        # the Pallas kernel is one-query-per-row (the decode tick); the
-        # Lq > 1 verify window reads through the exact dequant path below
-        # — same math, and verify dispatches are 1-in-k ticks by design.
-        # Under an sp-sharded pool the gathered view is replicated by the
-        # psum, so the kernel composes UNCHANGED — sharding lives entirely
-        # in the gather.
-        out = int8kv_paged_flash_attention_fn()(
-            q, read(new_layer.k), read(new_layer.k_scale),
-            read(new_layer.v), read(new_layer.v_scale),
-            positions + 1)
-        return out.astype(q.dtype), new_layer
+    # the paged read: the gather of every slot's pages and everything that
+    # consumes the gathered rows (cast or dequant, scores, weighted sum),
+    # named so that a trace finds it whatever shapes or kernel it has
+    with jax.named_scope("paged_read"):
+        if layer.quant == "int8" and layer.read == "flash" and lq == 1:
+            # the Pallas kernel is one-query-per-row (the decode tick); the
+            # Lq > 1 verify window reads through the exact dequant path
+            # below — same math, and verify dispatches are 1-in-k ticks by
+            # design. Under an sp-sharded pool the gathered view is
+            # replicated by the psum, so the kernel composes UNCHANGED —
+            # sharding lives entirely in the gather.
+            out = int8kv_paged_flash_attention_fn()(
+                q, read(new_layer.k), read(new_layer.k_scale),
+                read(new_layer.v), read(new_layer.v_scale),
+                positions + 1)
+            return out.astype(q.dtype), new_layer
 
-    gk = read(new_layer.k)
-    gv = read(new_layer.v)
-    if layer.quant == "int8":
-        gk = (gk.astype(jnp.float32)
-              * read(new_layer.k_scale)[..., None]).astype(dtype)
-        gv = (gv.astype(jnp.float32)
-              * read(new_layer.v_scale)[..., None]).astype(dtype)
-    return masked_attention(q, gk, gv, positions), new_layer
+        gk = read(new_layer.k)
+        gv = read(new_layer.v)
+        if layer.quant == "int8":
+            gk = (gk.astype(jnp.float32)
+                  * read(new_layer.k_scale)[..., None]).astype(dtype)
+            gv = (gv.astype(jnp.float32)
+                  * read(new_layer.v_scale)[..., None]).astype(dtype)
+        return masked_attention(q, gk, gv, positions), new_layer

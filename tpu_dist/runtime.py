@@ -45,4 +45,12 @@ def enable_compile_cache() -> str:
     # the default 1 s floor would leave the small serving programs (one
     # per prefill bucket) to recompile on every cold start
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
+    # an executable read back from the cache carries the op_names of the
+    # program that WROTE it, and JAX's default leaves names out of the key:
+    # a commit that only renames a jax.named_scope would run, and be traced,
+    # under its parent's names (found on the chip in PR 24: the decode tick
+    # came back without its paged_read scope). With the names in the key a
+    # trace's readers join on this checkout's own scopes; the price is that
+    # entries are per checkout path and per edit of a traced file
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return path
