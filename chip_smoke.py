@@ -455,6 +455,7 @@ def _serve(model, params, sizes: Sizes, seed: int, **cfg_over):
             "prompt_lens": list(sizes.prompt_lens),
             "new_tokens": sizes.new_tokens, "prefills": st["prefills"],
             "ticks": st["ticks"], "occupancy": st["occupancy"],
+            "read": st["read"], "live_pages": st["live_pages"],
             "tokens_per_s_cold": round(
                 len(comps) * sizes.new_tokens / max(secs, 1e-9), 1)}
     return eng, {c.rid: c.tokens for c in comps}, reqs, line

@@ -6,8 +6,9 @@ what Mosaic would refuse on the chip — a block not aligned to the tiling,
 more fast memory than a kernel may use — it refuses here, on the CPU, at no
 chip time. Each case is a kernel the trainers or the server really call, at
 the widths they call it with (bench.py's LM default: 8 layers, d1024, 8 heads
-of 128, L2048, V32000, batch 8; also 16 heads of 64 at L16384, and the
-serving pool's pages). Interpret-mode tests cannot see any of this.
+of 128, L2048, V32000, batch 8; also 16 heads of 64 at L16384, the
+serving pool's pages, and the serving cell's decode read). Interpret-mode
+tests cannot see any of this.
 
 A compile that passes is not a chip run: it says nothing about results or
 times (``python chip_smoke.py`` is that proof).
@@ -77,6 +78,18 @@ def _paged_int8(b, l):
                   ((b,), jnp.int32)]
 
 
+def _paged_decode(b, num_pages, max_pages, h, d):
+    """The decode tick's in-place read: arenas as the pool lays them out
+    (16-token bf16 pages), one query a slot, the block table walked."""
+    from tpu_dist.ops.paged_attention import paged_decode_attention
+
+    arena = ((num_pages + 1, 16, h, d), jnp.bfloat16)
+    return (lambda q, k, v, bt, ln: paged_decode_attention(
+        q, k, v, bt, ln, interpret=False)), \
+        [((b, 1, h, d), jnp.bfloat16), arena, arena,
+         ((b, max_pages), jnp.int32), ((b,), jnp.int32)]
+
+
 def _quant_matmul(m, k, n):
     from tpu_dist.ops.pallas_quant import fused_quant_matmul
 
@@ -107,6 +120,8 @@ CASES = {
     "flash_prefill_l1024": lambda: _flash_prefill(1024),
     "paged_int8_b8_l2048": lambda: _paged_int8(8, 2048),
     "paged_int8_b32_l4096": lambda: _paged_int8(32, 4096),
+    "paged_decode_b16_p128_h16_d128":
+        lambda: _paged_decode(16, 2048, 128, 16, 128),
     "quant_matmul_mlp_16384x1024x4096": lambda: _quant_matmul(16384, 1024, 4096),
     "quant_matmul_decode_8x1024x4096": lambda: _quant_matmul(8, 1024, 4096),
     "quant_matmul_head_16384x1024x32000":
@@ -172,8 +187,10 @@ def _cellbench():
     return mod
 
 
-def _serving_tick(aot, devices):
-    """The serving cell's decode tick, lowered for shapes on one chip."""
+def _serving_tick(aot, devices, monkeypatch):
+    """The serving cell's decode tick, lowered for shapes on one chip. The
+    tick picks its Pallas call's mode by the process's backend (the CPU's
+    here): steered to the chip's branch while the program is traced."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from tpu_dist.engine.kv_cache import PagedKVPool
@@ -197,30 +214,38 @@ def _serving_tick(aot, devices):
     n = srv["max_slots"]
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
     rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip)
-    return _tick_program(model, 0.0, 0, 0.0, None).lower(
-        params, layers, i32(n, srv["max_len"] // srv["page_size"]), i32(n),
-        i32(n), rng).compile()
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        lowered = _tick_program(model, 0.0, 0, 0.0, None).lower(
+            params, layers, i32(n, srv["max_len"] // srv["page_size"]),
+            i32(n), i32(n), rng)
+    return lowered.compile()
 
 
-def test_timed_programs_carry_the_programs_scopes_for_v5e(v5e):
+def test_timed_programs_carry_the_programs_scopes_for_v5e(v5e, monkeypatch):
     """The names the traced runs' readers look for survive the TPU
-    compiler's fusion: the serving tick's optimized HLO holds
-    ``paged_read`` in its ``op_name``s, the LM step's ``flash_attention``
-    (on the Mosaic custom-calls, forward and backward), ``loss`` and
-    ``optimizer``. Metadata only: the instructions are the parent's."""
+    compiler's fusion: the serving tick's 24 Mosaic custom-calls (the
+    in-place paged read, one a layer) carry ``paged_read`` in their
+    ``op_name``s, the LM step's ``flash_attention`` (forward and backward),
+    and its instructions ``loss`` and ``optimizer``."""
     import re
 
     aot = _cellbench()
     op_names = lambda compiled: re.findall(r'op_name="([^"]*)"',
                                            compiled.as_text())
-    tick = op_names(_serving_tick(aot, v5e))
-    assert sum("/paged_read/" in n for n in tick) >= 24 * 4   # every layer
+    tick = _serving_tick(aot, v5e, monkeypatch).as_text()
+    mosaic = (r'custom-call\(.*custom_call_target="tpu_custom_call"'
+              r'.*op_name="([^"]*)"')
+    reads = re.findall(mosaic, tick)
+    assert len(reads) == 24                     # one in-place read a layer
+    assert all("/paged_read/" in n for n in reads)
+    # and nothing of the gathered path's: no copy of the table's 2048 pages
+    assert "[2048,16,16,128]" not in tick
     cell = aot._cell("cerebras-gpt-1.3b-depthcut.train")
     e = cell.workload["engine"]
     compiled = aot._lm_train_step(cell.config, e, v5e[:1], e["batch_size"],
                                   False)
-    kernels = re.findall(r'custom-call\(.*custom_call_target="tpu_custom_call"'
-                         r'.*op_name="([^"]*)"', compiled.as_text())
+    kernels = re.findall(mosaic, compiled.as_text())
     assert len(kernels) == 3 * cell.config["num_layers"]
     assert all("/flash_attention/" in n for n in kernels)
     assert sum("transpose(jvp(" in n for n in kernels) == 2 * len(kernels) // 3

@@ -85,7 +85,7 @@ from tpu_dist.engine.generate import (_quantize_for_decode, _refuse_wo_tree,
 from tpu_dist.engine.kv_cache import PagedKVPool, PrefixMatch
 from tpu_dist.obs import trace
 from tpu_dist.obs.reqtrace import RequestTracer
-from tpu_dist.ops.paged_attention import cow_fork_pages
+from tpu_dist.ops.paged_attention import cow_fork_pages, decode_read
 from tpu_dist.parallel.mesh import SP_AXIS
 from tpu_dist.parallel.ring_attention import ring_attention_fn
 from tpu_dist.plan.compile import check_audit_sentry, register_audit_program
@@ -274,7 +274,10 @@ def _tick_program(model, temperature, top_k, top_p, sp_mesh=None):
         # one token per slot at its OWN position; inactive slots carry
         # all-trash block tables and position 0, so their writes land on
         # the trash page and their (ignored) logits cost one lane of the
-        # same program — occupancy changes never retrace
+        # same program — occupancy changes never retrace. The read follows
+        # the block table in place where the shapes allow it
+        # (ops.paged_attention.decode_read): its cost then follows the
+        # slots' lengths, an inactive slot's is one page
         paged = {"layers": layers, "block_tables": block_tables,
                  "positions": positions, "lengths": positions + 1,
                  "sp_mesh": sp_mesh}
@@ -636,6 +639,14 @@ class ServeEngine:
         self.prompt_pages = 0
         self.shared_prompt_pages = 0
         self._occupancy_sum = 0.0
+        # how the tick's program reads its pages ("pages": the in-place
+        # kernel; "gathered": the gathered copy): the rule
+        # ops.paged_attention applies when the program is traced, asked
+        # once here for the one program this engine dispatches (the
+        # speculative tick is named by its verify window, Lq = k + 1)
+        self.tick_read = decode_read(self.pool.layers()[0],
+                                     1 + cfg.spec_k, self.sp_mesh)
+        self._live_pages_sum = 0
         self._wait_ema: Optional[float] = None
         self._wait_samples = 0
         self._in_breach = False
@@ -1341,7 +1352,12 @@ class ServeEngine:
                   if s is not None and not s.done and s.chunk_next < 0]
         if not active:
             return
-        attrs = {"rids": [s.req.rid for _, s in active]}
+        # pages holding a row this tick attends to: what a read that
+        # follows the block table touches, of the table's slots x max_pages
+        live_pages = sum(self.pool.pages_needed(s.position + 1)
+                         for _, s in active)
+        attrs = {"rids": [s.req.rid for _, s in active],
+                 "read": self.tick_read, "live_pages": live_pages}
         if self.tracer is not None:
             attrs["trace_ids"] = [s.trace_id for _, s in active]
         with self._span("serve.tick", **attrs):
@@ -1351,6 +1367,7 @@ class ServeEngine:
                 self._tick_plain(active)
         self.ticks += 1
         self._occupancy_sum += len(active) / max(len(self.slots), 1)
+        self._live_pages_sum += live_pages
 
     def _tick_inputs(self, active, with_caps: bool = False):
         """The tick's host-side inputs: each slot's last token, position,
@@ -1533,6 +1550,13 @@ class ServeEngine:
         apt = self.accepted_per_tick
         phr = self.prefix_hit_rate
         return {"ticks": self.ticks, "completed": self.completed,
+                "read": self.tick_read,
+                "ticks_by_read": {self.tick_read: self.ticks},
+                # mean share of the block table's entries (slots x
+                # max_pages) that held a live page, over the ticks
+                "live_pages": (round(self._live_pages_sum / (
+                    self.ticks * len(self.slots) * self.max_pages_per_seq), 6)
+                    if self.ticks else None),
                 "rejected": self.rejected, "prefills": self.prefills,
                 "sp_prefills": self.sp_prefills,
                 "chunk_ticks": self.chunk_ticks,

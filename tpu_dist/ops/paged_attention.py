@@ -16,15 +16,23 @@ in ``engine.kv_cache``, the scheduler in ``engine.serve``):
   one token per sequence). Masked rows route to the arena's *trash page*
   (index ``num_pages``, the reason arenas carry one extra page): the scatter
   stays branch-free and fully static under jit.
-* :func:`gather_pages` — the read half: block table -> contiguous
-  ``(B, max_pages * page_size, ...)`` view of each sequence's cache.
+* :func:`gather_pages` — the gathered read: block table -> contiguous
+  ``(B, max_pages * page_size, ...)`` copy of each sequence's cache, which
+  :func:`masked_attention` then scores with a per-row causal horizon.
+* :func:`paged_decode_attention` — the in-place read of the decode tick: a
+  Pallas kernel that walks the block table itself and fetches only the
+  pages below each row's length out of the arena as it lies in HBM (no
+  gathered copy, no float32 copy, no work past a row's length).
 * :func:`paged_attend` — the attention entry ``models.transformer.
   attend_maybe_cached`` delegates to: prefill attends within the prompt via
   the model's own ``attn_fn`` (+ page writes); the decode tick writes one
-  row and attends over the gathered pages with PER-ROW positions — the
+  row and attends over its pages with PER-ROW positions — the
   continuous-batching difference from the flax cache, whose scalar
-  ``cache_index`` forces every batch row to the same position. The same
-  non-prefill path generalizes to Lq > 1 as the speculative-decoding
+  ``cache_index`` forces every batch row to the same position. Which read
+  it takes is :func:`decode_read`'s rule, made from the inputs alone: one
+  query a row over unsharded bf16/fp32 arenas of lane-wide heads reads in
+  place, everything else gathers. The same non-prefill path generalizes
+  to Lq > 1 (gathered) as the speculative-decoding
   VERIFY read: row ``b`` carries ``Lq`` queries at positions
   ``pos[b]..pos[b]+Lq-1`` (the last real token plus the draft proposals),
   writes all their K/V rows through the block table, and attends each
@@ -44,10 +52,17 @@ in ``engine.kv_cache``, the scheduler in ``engine.serve``):
   kernels' causal offsets — the decode-tick geometry where every batch row
   sits at a different position.
 
-Exactness contract: the exact read path mirrors ``full_attention``'s math
-op-for-op (fp32 scores/softmax, same einsum contractions), and masked slots
-contribute *exactly zero* weight — so greedy decode through pages is
-bit-identical to the contiguous-cache path (tests/test_serve.py pins it).
+Exactness contract: both reads score in float32 (bf16 products
+accumulated in float32), keep the softmax and its statistics in float32,
+and give masked positions *exactly zero* weight. The gathered read mirrors
+``full_attention`` op-for-op (same einsum contractions, softmax weights
+rounded to the value dtype before the weighted sum), so greedy decode
+through it is bit-identical to the contiguous-cache path
+(tests/test_serve.py pins the tokens). The in-place read is an online
+softmax over chunks with float32 weights: at least as precise, not
+bit-equal in its logits; what is pinned for it is EQUAL GREEDY TOKENS
+against ``engine.generate`` (tests/test_paged_attention.py) and one bf16
+step against the gathered read.
 """
 
 from __future__ import annotations
@@ -401,6 +416,196 @@ def int8kv_paged_flash_attention_fn(block_k: int = 512,
 
 
 # ---------------------------------------------------------------------------
+# in-place decode read (block table -> live pages, no gathered copy)
+# ---------------------------------------------------------------------------
+#
+# The gathered read above costs what the TABLE holds: every block-table
+# entry of every slot is copied out of the arena, cast, scored and summed
+# whatever the slots' lengths are. The decode tick needs one query a row
+# over the rows below its length, so this kernel walks the block table
+# itself: grid over the slots, block table and lengths prefetched to SMEM,
+# the arenas left in HBM in the layout they have, and whole pages (one
+# contiguous (page_size, H, D) block each) fetched by async copies into a
+# two-slot VMEM buffer, ``_DECODE_CHUNK_PAGES`` a chunk, the next chunk's
+# copies (the next SLOT's first chunk after a slot's last) in flight while
+# the current one is scored. Only chunks below a row's length are walked and
+# only pages below it fetched; an inactive slot (length 1, all-trash table)
+# costs one page.
+#
+# Math per chunk, on the VPU with H on the sublanes throughout (one query
+# a row and no grouped heads leave the MXU nothing to win; the v5e has no
+# bf16 VPU, so the tile is cast to float32 in VMEM): s = sum_d k * q,
+# mask kpos < length, online max/sum, acc = acc * alpha + sum_t p * v.
+
+_DECODE_CHUNK_PAGES = 8      # pages a chunk (a constant of the kernel)
+
+
+def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         kbuf, vbuf, sem, parity_ref, *,
+                         page_size, chunk_pages, scale):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    tokens = chunk_pages * page_size             # rows a chunk
+    h, d = q_ref.shape[1], q_ref.shape[2]
+
+    def copies(row, chunk, slot, op):
+        """Start or wait for chunk ``chunk`` of slot ``row``: its pages
+        below the row's length, K and V, into buffer ``slot``. Pages past
+        the length are not fetched."""
+        first = chunk * chunk_pages
+        live = jnp.minimum(pl.cdiv(len_ref[row], page_size) - first,
+                           chunk_pages)
+
+        def page_copy(j, _):
+            page = bt_ref[row, first + j]
+            dst = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for i, (arena, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(
+                    arena.at[page], buf.at[slot, dst], sem.at[i, slot])
+                if op == "start":
+                    cp.start()
+                else:
+                    cp.wait()
+            return 0
+
+        jax.lax.fori_loop(0, live, page_copy, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        parity_ref[0] = 0
+        copies(0, 0, 0, "start")
+
+    length = len_ref[b]
+    n_chunks = pl.cdiv(length, tokens)           # >= 1: lengths are >= 1
+    parity = parity_ref[0]                       # buffer of this row's chunk 0
+    q = q_ref[0].astype(jnp.float32) * scale     # (H, D)
+
+    def chunk_step(c, carry):
+        slot = (parity + c) % 2
+        last = c == n_chunks - 1
+
+        @pl.when(jnp.logical_not(last))
+        def _():
+            copies(b, c + 1, 1 - slot, "start")
+
+        @pl.when(jnp.logical_and(last, b + 1 < nb))
+        def _():
+            copies(b + 1, 0, 1 - slot, "start")
+
+        copies(b, c, slot, "wait")
+
+        def attend(carry, first, rows):
+            """``rows`` (static) rows of the buffer from ``first`` on."""
+            m, l, acc = carry
+            at = pl.ds(first, rows)
+            k = kbuf[slot, at].astype(jnp.float32)                # (T, H, D)
+            v = vbuf[slot, at].astype(jnp.float32)
+            s = jnp.sum(k * q[None], axis=-1, keepdims=True)      # (T, H, 1)
+            kpos = c * tokens + first + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, h, 1), 0)
+            s = jnp.where(kpos < length, s, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(s, axis=0))            # (H, 1)
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[None])         # masked rows: exactly 0
+            l_new = l * alpha + jnp.sum(p, axis=0)
+            acc_new = acc * alpha + jnp.sum(p * v, axis=0)        # (H, D)
+            return m_new, l_new, acc_new
+
+        # a chunk wholly below the length is one block of arithmetic (the
+        # least VPU time a row, which a full table needs to stay behind its
+        # DMA); a row's last chunk, and an inactive slot's only one, walks
+        # its fetched pages one by one and touches nothing past them
+        live = length - c * tokens
+        return jax.lax.cond(
+            live >= tokens,
+            lambda: attend(carry, 0, tokens),
+            lambda: jax.lax.fori_loop(
+                0, pl.cdiv(live, page_size),
+                lambda j, carry: attend(
+                    carry, pl.multiple_of(j * page_size, page_size),
+                    page_size), carry))
+
+    m0 = jnp.full((h, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((h, 1), jnp.float32)
+    acc0 = jnp.zeros((h, d), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_chunks, chunk_step, (m0, l0, acc0))
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    parity_ref[0] = (parity + n_chunks) % 2
+
+
+def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths, *,
+                           interpret: bool | None = None):
+    """One query a row over its LIVE pages, read in place: ``q``
+    (B, 1, H, D), arenas (num_pages + 1, page_size, H, D) as the pool lays
+    them out (bf16 or fp32, never copied or cast in HBM), ``block_tables``
+    (B, max_pages) i32 whose entries past a row's pages hold the trash row,
+    ``lengths`` (B,) i32 >= 1 (``position + 1``: keys at ``kpos >= length``
+    are masked, which IS the causal mask of a decode tick). Float32 scores,
+    softmax statistics and accumulator; forward-only.
+    ``interpret=None`` auto-selects interpreter mode off-TPU."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, lq, h, d = q.shape
+    if lq != 1:
+        raise ValueError(f"paged decode kernel is one query per row "
+                         f"(got Lq={lq})")
+    page_size = k_arena.shape[1]
+    chunk_pages = min(_DECODE_CHUNK_PAGES, block_tables.shape[1])
+    buf = (2, chunk_pages * page_size, h, d)
+    kernel = functools.partial(
+        _paged_decode_kernel, page_size=page_size, chunk_pages=chunk_pages,
+        scale=1.0 / math.sqrt(d))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, h, d), lambda i, bt, ln: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, h, d), lambda i, bt, ln: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM(buf, k_arena.dtype),
+                pltpu.VMEM(buf, v_arena.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        interpret=pallas_interpret(interpret),
+    )(block_tables.astype(jnp.int32),
+      # a length the table cannot hold (a draft window overrunning max_len)
+      # reads what the gathered mask would let it read: every table entry
+      jnp.clip(lengths.astype(jnp.int32), 1,
+               block_tables.shape[1] * page_size),
+      q[:, 0], k_arena, v_arena)
+    return out[:, None]
+
+
+def decode_read(layer: PagedLayer, lq: int, sp_mesh=None) -> str:
+    """Which way a non-prefill read of ``layer`` goes, from what the inputs
+    show and nothing else: ``"pages"`` (:func:`paged_decode_attention`) for
+    one query a row over unsharded bf16 or fp32 arenas whose head_dim fills
+    the lanes, ``"gathered"`` for everything else — the Lq > 1 verify and
+    chunk windows, int8 pages, sp-sharded arenas, and heads narrower than
+    a lane row (every toy model of tests/test_serve.py)."""
+    if lq != 1 or layer.quant != "none" or sp_mesh is not None:
+        return "gathered"
+    # what Mosaic's tiling of the arena's (H, D) minor dims takes: D whole
+    # lane rows, and a page slice's H rows whole sublane tiles ((8, 128)
+    # rows for 4-byte arenas of any H; 2-byte arenas tile H by 8, or by
+    # 4 or 2 when H is that small)
+    _, _, h, d = layer.k.shape
+    whole_tiles = (layer.k.dtype.itemsize == 4 or h % 8 == 0
+                   or h in (2, 4))
+    return "pages" if d % 128 == 0 and whole_tiles else "gathered"
+
+
+# ---------------------------------------------------------------------------
 # the attend_maybe_cached delegate
 # ---------------------------------------------------------------------------
 
@@ -496,10 +701,18 @@ def paged_attend(q, k, v, paged: dict, *, prefill: bool, attn_fn, dtype):
         # training contraction, so flash/blockwise plug-ins keep working
         return attn_fn(q, k, v), new_layer
 
-    # the paged read: the gather of every slot's pages and everything that
-    # consumes the gathered rows (cast or dequant, scores, weighted sum),
-    # named so that a trace finds it whatever shapes or kernel it has
+    # the paged read: the in-place kernel, or the gather of every slot's
+    # pages and everything that consumes the gathered rows (cast or dequant,
+    # scores, weighted sum), named so that a trace finds it whatever shapes
+    # or kernel it has
     with jax.named_scope("paged_read"):
+        if decode_read(layer, lq, sp_mesh) == "pages":
+            # positions + 1, not ``lengths``: the tick's own causal horizon
+            # (the same the gathered mask below is built from)
+            out = paged_decode_attention(q, new_layer.k, new_layer.v, bt,
+                                         positions + 1)
+            return out, new_layer
+
         if layer.quant == "int8" and layer.read == "flash" and lq == 1:
             # the Pallas kernel is one-query-per-row (the decode tick); the
             # Lq > 1 verify window reads through the exact dequant path
