@@ -7,7 +7,8 @@ more fast memory than a kernel may use — it refuses here, on the CPU, at no
 chip time. Each case is a kernel the trainers or the server really call, at
 the widths they call it with (bench.py's LM default: 8 layers, d1024, 8 heads
 of 128, L2048, V32000, batch 8; also 16 heads of 64 at L16384, the
-serving pool's pages, and the serving cell's decode read). Interpret-mode
+serving pool's pages, the serving cell's decode read, and the hybrid LM's
+selective scan at 5120 channels). Interpret-mode
 tests cannot see any of this.
 
 A compile that passes is not a chip run: it says nothing about results or
@@ -90,6 +91,20 @@ def _paged_decode(b, num_pages, max_pages, h, d):
          ((b, max_pages), jnp.int32), ((b,), jnp.int32)]
 
 
+def _selective_scan(l):
+    """The hybrid LM's prefill scan at the published Mamba widths: one
+    prompt, 5120 channels, 16 states, bf16 activations."""
+    from tpu_dist.ops.selective_scan import selective_scan
+
+    ch, n = 5120, 16
+    f32 = jnp.float32
+    return (lambda u, dt, a, b, c, d, s0, ln: selective_scan(
+        u, dt, a, b, c, d, s0, ln, interpret=False)), \
+        [((1, l, ch), jnp.bfloat16), ((1, l, ch), f32), ((ch, n), f32),
+         ((1, l, n), f32), ((1, l, n), f32), ((ch,), f32),
+         ((1, n, ch), f32), ((1,), jnp.int32)]
+
+
 def _quant_matmul(m, k, n):
     from tpu_dist.ops.pallas_quant import fused_quant_matmul
 
@@ -122,6 +137,8 @@ CASES = {
     "paged_int8_b32_l4096": lambda: _paged_int8(32, 4096),
     "paged_decode_b16_p128_h16_d128":
         lambda: _paged_decode(16, 2048, 128, 16, 128),
+    "selective_scan_l1024_c5120": lambda: _selective_scan(1024),
+    "selective_scan_l256_c5120": lambda: _selective_scan(256),
     "quant_matmul_mlp_16384x1024x4096": lambda: _quant_matmul(16384, 1024, 4096),
     "quant_matmul_decode_8x1024x4096": lambda: _quant_matmul(8, 1024, 4096),
     "quant_matmul_head_16384x1024x32000":

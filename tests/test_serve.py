@@ -49,7 +49,7 @@ def _lm_and_params(seed=0, **kw):
 
 # ---------------------------------------------------------------- pool
 def test_pool_alloc_free_and_high_water():
-    pool = PagedKVPool(num_layers=1, num_pages=8, page_size=4,
+    pool = PagedKVPool(1, num_pages=8, page_size=4,
                        num_heads=2, head_dim=8)
     a = pool.alloc(3)
     b = pool.alloc(4)
@@ -499,7 +499,7 @@ def test_pool_refcount_double_free_and_leak_pins():
     survives its first holder's release, and a full share/release cycle
     leaks nothing — high_water_used stays at the unshared peak because
     sharing never inflates the physical footprint."""
-    pool = PagedKVPool(num_layers=1, num_pages=8, page_size=4,
+    pool = PagedKVPool(1, num_pages=8, page_size=4,
                        num_heads=2, head_dim=8)
     a = pool.alloc(2)
     pool.free(a)
@@ -525,7 +525,7 @@ def test_pool_heap_grants_lowest_index_first():
     """Round 16 swapped the free list's O(n log n) full-sort-per-free
     for a heap; the observable grant order is pinned unchanged —
     lowest index first, whatever order pages came back in."""
-    pool = PagedKVPool(num_layers=1, num_pages=8, page_size=4,
+    pool = PagedKVPool(1, num_pages=8, page_size=4,
                        num_heads=2, head_dim=8)
     assert pool.alloc(6) == [0, 1, 2, 3, 4, 5]
     pool.free([4, 1, 3])

@@ -424,6 +424,11 @@ def decode_section(records, out=print):
             srv["chunks_pending_max"] = max(depths) if depths else None
             srv["chunks_pending_last"] = depths[-1] if depths else None
             srv["sharded_devices"] = last.get("sharded_devices")
+            # per-slot recurrent state beside the pages (models whose
+            # cache_layout has slot-state layers): bytes allocated, and
+            # prefills that wrote a slot's state
+            srv["state_bytes"] = last.get("state_bytes")
+            srv["state_writes_last"] = last.get("state_writes")
         d["serving"] = srv
         out(f"\nserving: {srv['completed']} completed, {rejected} rejected"
             + (f", occupancy {srv['occupancy'] * 100:.0f}%"
@@ -456,6 +461,10 @@ def decode_section(records, out=print):
                 + (f"; queue depth max {srv['chunks_pending_max']}, "
                    f"last {srv['chunks_pending_last']}"
                    if srv.get("chunks_pending_max") is not None else ""))
+        if srv.get("state_bytes"):
+            out(f"  slot state: {_si(srv['state_bytes'], 'B')} allocated, "
+                f"{srv['state_writes_last'] or 0} prefills wrote a slot's "
+                "state")
         if (srv.get("sharded_devices") or 0) > 1:
             out(f"  sp-sharded KV pool: {srv['sharded_devices']} devices")
     return d

@@ -34,6 +34,21 @@ int8 mode stores pages as int8 with per-(slot, head) fp32 scales (the
 bandwidth-bound by; ``read='flash'`` additionally routes the tick's reads
 through the int8-KV Pallas kernel.
 
+A second kind of sequence state lives beside the pages (PR 26): a layer
+whose entry in the model's ``cache_layout()`` is ``("slot_state", {name:
+(shape a slot, dtype)})`` keeps no K and V rows but arrays
+``[max_slots, ...]`` made once (a Mamba layer's float32 recurrent state
+and its convolution tail), addressed by the scheduler's SLOT index:
+written by prefill at the prompt's true length, updated in place by every
+tick, started from zeros by the call that feeds position 0, never shared
+and never allocated or freed (eviction frees pages only). The pool holds
+one entry a model layer, a ``PagedLayer`` or such a dict, in the model's
+order; ``layers()`` / ``adopt()`` carry both through the programs' one
+donated argument. Prefix sharing, copy-on-write forks and the sp-sharded
+layout are about pages and have no meaning for slot state: the serving
+engine refuses them for such a model, and a sharded pool refuses a
+slot-state layer here.
+
 The allocator is HOST-side state (plain Python ints): page grants happen
 at admission time on the scheduler thread, never inside a jitted program —
 the device programs only ever see block tables as arrays.
@@ -111,9 +126,17 @@ class PagedKVPool:
     contiguous layout provably cannot fit.
     """
 
-    def __init__(self, num_layers: int, num_pages: int, page_size: int,
-                 num_heads: int, head_dim: int, dtype=jnp.float32,
-                 kv_quant: str = "none", read: str = "exact", mesh=None):
+    def __init__(self, layers, num_pages: int, page_size: int,
+                 num_heads: int = None, head_dim: int = None,
+                 dtype=jnp.float32, kv_quant: str = "none",
+                 read: str = "exact", mesh=None, max_slots: int = 0):
+        # ``layers``: a model's ``cache_layout()``, which says what EACH
+        # layer keeps (pages of so many KV heads, or ``max_slots`` rows of
+        # state), or a COUNT of layers that all keep pages of ``num_heads``
+        # heads of ``head_dim``, as the pool was made before PR 26
+        layout = layers
+        if isinstance(layers, int):
+            layout = (("pages", num_heads, head_dim, 1),) * layers
         if kv_quant not in ("none", "int8"):
             raise ValueError(f"kv_quant must be 'none' or 'int8', "
                              f"got {kv_quant!r}")
@@ -145,7 +168,7 @@ class PagedKVPool:
                     f"num_pages {num_pages} must divide by the {SP_AXIS!r} "
                     f"axis size {n} (whole pages per device)")
         self.sharded_devices = n
-        self.num_layers = num_layers
+        self.num_layers = len(layout)
         self.num_pages = num_pages
         self.page_size = page_size
         self.kv_quant = kv_quant
@@ -153,8 +176,6 @@ class PagedKVPool:
         self.pages_per_device = num_pages // n
         self._rows_local = self.pages_per_device + 1   # + local trash row
         rows = n * self._rows_local
-        shape = (rows, page_size, num_heads, head_dim)
-        sshape = (rows, page_size, num_heads)
 
         def zeros(shp, dt):
             z = jnp.zeros(shp, dt)
@@ -162,8 +183,27 @@ class PagedKVPool:
                 z = jax.device_put(z, NamedSharding(mesh, P(SP_AXIS)))
             return z
 
-        self._layers: List[PagedLayer] = []
-        for _ in range(num_layers):
+        # one entry a model layer, in the model's order: a PagedLayer of
+        # page arenas, or (slot state) a dict of [max_slots, ...] arrays
+        # made once and addressed by SLOT: written by prefill, updated in
+        # place by every tick, never shared between sequences
+        self._layers: List = []
+        self.state_bytes = 0
+        for kind, *spec in layout:
+            if kind == "slot_state":
+                if mesh is not None:
+                    raise NotImplementedError(
+                        "slot state in an sp-sharded pool: the arenas "
+                        "shard by page, and per-slot recurrent state has "
+                        "no page to shard by")
+                state = {name: jnp.zeros((max_slots, *shp), dt)
+                         for name, (shp, dt) in spec[0].items()}
+                self.state_bytes += sum(x.nbytes for x in state.values())
+                self._layers.append(state)
+                continue
+            heads, hdim = spec[0], spec[1]
+            shape = (rows, page_size, heads, hdim)
+            sshape = (rows, page_size, heads)
             if kv_quant == "int8":
                 self._layers.append(PagedLayer(
                     zeros(shape, jnp.int8), zeros(shape, jnp.int8),
@@ -472,8 +512,14 @@ class PagedKVPool:
 
     # -- arena plumbing ---------------------------------------------------
     def layers(self) -> tuple:
-        """The per-layer ``PagedLayer`` packs, as jit arguments."""
+        """What each layer holds (a ``PagedLayer`` pack or a slot-state
+        dict), as one jit argument: the programs donate it and ``adopt``
+        takes it back, so neither kind is copied a token."""
         return tuple(self._layers)
+
+    def page_layers(self) -> tuple:
+        """The layers that hold pages."""
+        return tuple(l for l in self._layers if isinstance(l, PagedLayer))
 
     def adopt(self, new_layers) -> None:
         """Store the functionally-updated arenas a jitted program returned
@@ -494,4 +540,5 @@ class PagedKVPool:
                 "prefix_lookups": self.prefix_lookups,
                 "cow_copies": self.cow_copies,
                 "alloc_total": self.alloc_total,
-                "kv_quant": self.kv_quant}
+                "kv_quant": self.kv_quant,
+                "state_bytes": self.state_bytes}

@@ -14,7 +14,10 @@ Composition (each piece usable alone):
 
 * :class:`tpu_dist.engine.kv_cache.PagedKVPool` backs every sequence with
   block-table pages (bf16/fp32, or int8+scales via the PR 9 ``quantize_kv``
-  convention) — mixed-length sequences share HBM without fragmentation;
+  convention) — mixed-length sequences share HBM without fragmentation —
+  and, for the layers whose ``model.cache_layout()`` says ``slot_state``
+  (a state-space layer's recurrent state and convolution tail), with
+  per-slot arrays beside the pages (the class docstring has the rules);
 * two jitted programs serve all traffic: ``prefill`` (one admit's prompt,
   padded to a length bucket, writing its pages and sampling the first
   token) and ``decode_tick`` (the packed slot set, one token per active
@@ -211,6 +214,11 @@ class _Slot:
     win_drafted: int = 0
 
 
+def _slot_state_layers(layout) -> int:
+    """How many layers of a model's ``cache_layout()`` keep slot state."""
+    return sum(kind == "slot_state" for kind, *_ in layout)
+
+
 def _default_buckets(max_len: int) -> Tuple[int, ...]:
     """Powers of two up to max_len (plus max_len itself): each bucket is
     one compiled prefill geometry, so a handful covers every prompt."""
@@ -236,7 +244,7 @@ def _prefill_program(model, temperature, top_k, top_p, sp_mesh=None):
     # prompt, in the feature that exists to keep KV HBM tight
     @partial(jax.jit, donate_argnums=(1,))
     def prefill(params, layers, block_table, length, shared_len, prompt,
-                rng):
+                rng, slot=0):
         # block_table (1, max_pages) i32, length () i32, prompt (1, bucket):
         # causal self-attention over the padded prompt (positions >= length
         # influence nothing earlier), pages written for the live prefix,
@@ -248,10 +256,14 @@ def _prefill_program(model, temperature, top_k, top_p, sp_mesh=None):
         # (0 when nothing is shared), so sharing never re-specializes.
         valid = (jnp.arange(prompt.shape[1], dtype=jnp.int32)[None, :]
                  >= jnp.asarray(shared_len, jnp.int32))
+        # Slot state (a layer whose cache_layout is "slot_state") is taken
+        # at the prompt's true length, ``live``, not at the bucket's end,
+        # and written to row ``slot`` of its arrays.
+        lengths = jnp.asarray(length, jnp.int32)[None]
         paged = {"layers": layers, "block_tables": block_table,
                  "positions": jnp.zeros((1,), jnp.int32),
-                 "lengths": jnp.asarray(length, jnp.int32)[None],
-                 "valid": valid, "sp_mesh": sp_mesh}
+                 "lengths": lengths, "valid": valid, "sp_mesh": sp_mesh,
+                 "live": lengths, "slots": jnp.asarray(slot, jnp.int32)[None]}
         logits, new_layers = model.apply(
             {"params": params}, prompt, train=False,
             paged=paged, paged_prefill=True)
@@ -278,9 +290,14 @@ def _tick_program(model, temperature, top_k, top_p, sp_mesh=None):
         # the block table in place where the shapes allow it
         # (ops.paged_attention.decode_read): its cost then follows the
         # slots' lengths, an inactive slot's is one page
+        # Slot state: row b of a slot-state array IS slot b, updated in
+        # place; a slot the tick carries but does not decode keeps its row
+        # (``live`` 0). A decoding slot's position is its prompt's length
+        # or more, so position 0 names exactly the slots that sit out.
         paged = {"layers": layers, "block_tables": block_tables,
                  "positions": positions, "lengths": positions + 1,
-                 "sp_mesh": sp_mesh}
+                 "sp_mesh": sp_mesh,
+                 "live": (positions > 0).astype(jnp.int32)}
         logits, new_layers = model.apply(
             {"params": params}, tokens[:, None], train=False,
             pos_offset=positions, paged=paged)
@@ -306,14 +323,20 @@ def _chunk_prefill_program(model, chunk, sp_mesh=None):
     # admit, same as monolithic.
     @partial(jax.jit, donate_argnums=(1,))
     def chunk_step(params, layers, block_table, start, length, shared_len,
-                   tokens):
+                   tokens, slot=0):
         pos = jnp.asarray(start, jnp.int32)[None]               # (1,)
         rows = pos[:, None] + jnp.arange(chunk, dtype=jnp.int32)[None]
         valid = ((rows < jnp.asarray(length, jnp.int32))
                  & (rows >= jnp.asarray(shared_len, jnp.int32)))
+        # slot state rides from chunk to chunk in the slot's own row: a
+        # chunk starts from it (from zeros at start 0) and leaves the state
+        # of its last live row
         paged = {"layers": layers, "block_tables": block_table,
                  "positions": pos, "lengths": pos + chunk,
-                 "valid": valid, "sp_mesh": sp_mesh}
+                 "valid": valid, "sp_mesh": sp_mesh,
+                 "live": jnp.clip(jnp.asarray(length, jnp.int32) - pos, 0,
+                                  chunk),
+                 "slots": jnp.asarray(slot, jnp.int32)[None]}
         logits, new_layers = model.apply(
             {"params": params}, tokens, train=False,
             pos_offset=pos, paged=paged)
@@ -499,6 +522,22 @@ class ServeEngine:
     and bit-identity shape) or manually: ``submit()`` as requests arrive,
     ``step()`` once per scheduler iteration (evict -> admit+prefill ->
     decode tick), each returning the requests that finished.
+
+    Which models: the dense ``TransformerLM`` and the hybrid state-space /
+    attention ``HybridLM``. The engine asks a model one question, once:
+    ``model.cache_layout()``, one entry a layer, ``("pages", kv_heads,
+    head_dim, query heads a KV head)`` or ``("slot_state", {name: (shape a
+    slot, dtype)})``, and builds its pool from the answer. Slot state is
+    addressed by slot, not by block table: prefill writes a slot's rows at
+    the prompt's true length (the programs hand the model ``live`` and
+    ``slots`` beside the block tables), every tick updates every decoding
+    slot's row in place and holds the others, a call that feeds position 0
+    starts from zeros (no reset at admission; eviction frees pages only),
+    and chunked prefill carries the state from chunk to chunk in the
+    slot's own row. Refused by name for a model with slot state:
+    ``prefix_cache`` (shared pages bring no recurrent state), ``spec_k > 0``
+    (a rejected draft would need the state rolled back) and ``mesh=`` (the
+    sp-sharded pool shards by page); refused for every model: MoE blocks.
     """
 
     def __init__(self, model, params, config: Optional[ServeConfig] = None,
@@ -537,11 +576,14 @@ class ServeEngine:
                     "speculative decoding over an sp-sharded pool is the "
                     "named residue: the draft scan's per-step sharded "
                     "writes need their own collective story")
-        head_dim = model.d_model // model.num_heads
-        self.pool = PagedKVPool(
-            model.num_layers, cfg.num_pages, cfg.page_size,
-            model.num_heads, head_dim, dtype=model.dtype,
-            kv_quant=cfg.kv_quant, read=cfg.attn_read, mesh=mesh)
+        # the one question to the model: what does each layer keep for a
+        # sequence? Pages (K and V rows behind block tables) or slot state
+        # (arrays a slot, written by prefill and updated by every tick)
+        layout = model.cache_layout()
+        self.state_layers = _slot_state_layers(layout)
+        if self.state_layers:
+            self._refuse_over_slot_state(cfg, mesh)
+        self.pool = self._pool_for(layout, model.dtype, cfg.attn_read, mesh)
         self.max_pages_per_seq = self.pool.pages_needed(self.max_len)
         # speculative decoding: a draft proposes cfg.spec_k tokens per tick
         # over its OWN arenas (a second pool, same page geometry + indices,
@@ -564,16 +606,14 @@ class ServeEngine:
             else:
                 self.draft_model, self.draft_params = prepare_draft(
                     self.model, draft_model, draft_params, cfg.quant)
-            d_head = (self.draft_model.d_model
-                      // self.draft_model.num_heads)
             # draft reads stay on the exact path: the flash kernel is a
             # bandwidth optimization for the big base arenas; the draft's
             # are small by construction
-            self.draft_pool = PagedKVPool(
-                self.draft_model.num_layers, cfg.num_pages, cfg.page_size,
-                self.draft_model.num_heads, d_head,
-                dtype=self.draft_model.dtype, kv_quant=cfg.kv_quant,
-                read="exact")
+            draft_layout = self.draft_model.cache_layout()
+            if _slot_state_layers(draft_layout):
+                self._refuse_over_slot_state(cfg, None)
+            self.draft_pool = self._pool_for(
+                draft_layout, self.draft_model.dtype, "exact", None)
         elif draft_model is not None:
             raise ValueError("draft_model given but cfg.spec_k == 0: set "
                              "spec_k to the draft window size")
@@ -644,8 +684,11 @@ class ServeEngine:
         # ops.paged_attention applies when the program is traced, asked
         # once here for the one program this engine dispatches (the
         # speculative tick is named by its verify window, Lq = k + 1)
-        self.tick_read = decode_read(self.pool.layers()[0],
-                                     1 + cfg.spec_k, self.sp_mesh)
+        groups = [group for kind, *_, group in layout if kind == "pages"]
+        self.tick_read = decode_read(
+            self.pool.page_layers()[0], 1 + cfg.spec_k, self.sp_mesh,
+            groups[0]) if groups else "none"
+        self.state_writes = 0        # prefills that wrote a slot's state
         self._live_pages_sum = 0
         self._wait_ema: Optional[float] = None
         self._wait_samples = 0
@@ -659,6 +702,32 @@ class ServeEngine:
         self._drained = False
         self._preempt_event = threading.Event()
         self._prev_sigterm = None
+
+    def _pool_for(self, layout, dtype, read, mesh) -> PagedKVPool:
+        cfg = self.cfg
+        return PagedKVPool(
+            layout, cfg.num_pages, cfg.page_size, dtype=dtype,
+            kv_quant=cfg.kv_quant, read=read, mesh=mesh,
+            max_slots=cfg.max_slots)
+
+    @staticmethod
+    def _refuse_over_slot_state(cfg: ServeConfig, mesh) -> None:
+        """What has no meaning yet for a model that keeps slot state, each
+        refused by name rather than served wrong."""
+        if cfg.prefix_cache:
+            raise NotImplementedError(
+                "prefix_cache over slot state: a hit on shared pages brings "
+                "K and V but not the recurrent state at the end of the "
+                "shared prefix")
+        if cfg.spec_k > 0:
+            raise NotImplementedError(
+                "speculative decoding (spec_k > 0) over slot state: a "
+                "rejected draft would need the recurrent state rolled back "
+                "to the accepted token")
+        if mesh is not None:
+            raise NotImplementedError(
+                "an sp-sharded pool (mesh=) over slot state: the arenas "
+                "shard by page, and per-slot state has no page")
 
     # -- admission --------------------------------------------------------
     def submit(self, req: DecodeRequest) -> bool:
@@ -1039,7 +1108,8 @@ class ServeEngine:
         padded[0, :p] = prompt
         with self._span("serve.prefill", rid=req.rid,
                         trace_id=self._trace_id(req.rid), prompt_len=p,
-                        bucket=bucket, shared_len=shared_len):
+                        bucket=bucket, shared_len=shared_len,
+                        state_layers=self.state_layers):
             with self._span("prefill.dispatch", first_call=self._first_call(
                     ("prefill", bucket))):
                 program = _prefill_program(self.model, self.cfg.temperature,
@@ -1055,9 +1125,10 @@ class ServeEngine:
                     self.params, self.pool.layers(),
                     jnp.asarray(self.pool.flat_block_table(bt[None])),
                     jnp.int32(p), jnp.int32(shared_len), jnp.asarray(padded),
-                    self._rng)
+                    self._rng, jnp.int32(slot_idx))
                 self.pool.adopt(new_layers)
                 self.prefill_token_work += bucket
+                self.state_writes += bool(self.state_layers)
                 if self.draft_pool is not None:
                     # the draft's prompt rows, through the same block table
                     # (the pools share page indices); shared rows were written
@@ -1157,10 +1228,11 @@ class ServeEngine:
         p = s.prompt_len
         with self._span("serve.prefill", rid=s.req.rid, trace_id=s.trace_id,
                         prompt_len=p, bucket=chunk, shared_len=s.shared_len,
-                        chunk_start=s.chunk_next):
+                        chunk_start=s.chunk_next,
+                        state_layers=self.state_layers):
             with self._span("prefill.dispatch",
                             first_call=self._first_call("chunk_prefill")):
-                tok = self._dispatch_chunk(s)
+                tok = self._dispatch_chunk(slot_idx, s)
             if tok is None:
                 return      # not the last chunk: nothing to wait for yet
             with self._span("prefill.wait"):
@@ -1189,7 +1261,7 @@ class ServeEngine:
                                cow=s.cow_pending is not None,
                                tenant=s.req.tenant, **tr.attrs())
 
-    def _dispatch_chunk(self, s: _Slot):
+    def _dispatch_chunk(self, slot_idx: int, s: _Slot):
         """Dispatch the slot's next prefill chunk; on the final chunk also
         the first token's sampling, whose device value is returned (None
         before that)."""
@@ -1207,7 +1279,7 @@ class ServeEngine:
             self.params, self.pool.layers(),
             jnp.asarray(self.pool.flat_block_table(s.block_table[None])),
             jnp.int32(start), jnp.int32(p), jnp.int32(s.shared_len),
-            jnp.asarray(tokens))
+            jnp.asarray(tokens), jnp.int32(slot_idx))
         self.pool.adopt(new_layers)
         self.chunk_ticks += 1
         self.prefill_token_work += chunk
@@ -1244,6 +1316,7 @@ class ServeEngine:
             self.prompt_pages += self.pool.pages_needed(p)
             self.shared_prompt_pages += s.n_shared
         self.prefills += 1
+        self.state_writes += bool(self.state_layers)
         return tok
 
     # -- sequence-parallel prefill -----------------------------------------
@@ -1357,7 +1430,8 @@ class ServeEngine:
         live_pages = sum(self.pool.pages_needed(s.position + 1)
                          for _, s in active)
         attrs = {"rids": [s.req.rid for _, s in active],
-                 "read": self.tick_read, "live_pages": live_pages}
+                 "read": self.tick_read, "live_pages": live_pages,
+                 "state_slots": len(active) if self.state_layers else 0}
         if self.tracer is not None:
             attrs["trace_ids"] = [s.trace_id for _, s in active]
         with self._span("serve.tick", **attrs):
@@ -1508,6 +1582,8 @@ class ServeEngine:
                          sharded_devices=st["sharded_devices"],
                          chunks_pending=self.chunks_pending,
                          chunk_ticks=self.chunk_ticks,
+                         state_bytes=st["state_bytes"],
+                         state_writes=self.state_writes,
                          slots=len(self.slots), tick=self.ticks)
 
     # -- introspection ----------------------------------------------------
@@ -1558,6 +1634,7 @@ class ServeEngine:
                     self.ticks * len(self.slots) * self.max_pages_per_seq), 6)
                     if self.ticks else None),
                 "rejected": self.rejected, "prefills": self.prefills,
+                "state_writes": self.state_writes,
                 "sp_prefills": self.sp_prefills,
                 "chunk_ticks": self.chunk_ticks,
                 "chunks_pending": self.chunks_pending,

@@ -69,7 +69,21 @@ _REGISTRY: Dict[str, Tuple[Callable, str]] = {
     "transformer_lm": (transformer.TransformerLM, "lm"),
     "tiny_lm": (transformer.tiny_lm, "lm"),
     "moe_lm": (moe.MoETransformerLM, "lm"),
+    # the hybrid state-space / attention block (models.hybrid: Mamba-1
+    # mixers beside grouped-head attention, RMSNorm, gated MLP, tied head).
+    # SERVING ONLY so far: ServeEngine serves it (pages + per-slot state)
+    # and the plain full-sequence forward runs; LMTrainer, the scan's
+    # backward pass and tp/fsdp specs for it are not built. Imported on
+    # first use, so that nothing that never asks for it pays for it.
+    "hybrid_lm": (lambda **kw: _hybrid_lm(**kw), "lm"),
 }
+
+
+def _hybrid_lm(**kw):
+    from tpu_dist.models.hybrid import HybridLM
+
+    return HybridLM(**kw)
+
 
 model_names = sorted(_REGISTRY)  # reference 1.dataparallel.py:23-24 equivalent
 

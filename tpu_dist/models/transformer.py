@@ -189,6 +189,13 @@ class TransformerLM(nn.Module):
                             # projections; outputs are this device's
                             # (B, L/n, ...) sequence chunk.
 
+    def cache_layout(self) -> tuple:
+        """What each layer keeps for a sequence being served, asked once by
+        ``ServeEngine``: in every layer ``("pages", kv_heads, head_dim, query
+        heads a KV head)``, here K and V pages of every head."""
+        return (("pages", self.num_heads, self.d_model // self.num_heads,
+                 1),) * self.num_layers
+
     @nn.compact
     def __call__(self, tokens, train: bool = True, pos_offset=0,
                  decode: bool = False, return_features: bool = False,

@@ -285,12 +285,30 @@ def masked_attention(q, k, v, q_positions):
     identical contractions) so the scalar-offset case is bit-identical —
     the serving tick's degenerate-to-generate contract rides on this."""
     d = q.shape[-1]
-    scores = jnp.einsum(
-        "bqhd,bkhd->bhqk", q, k,
-        preferred_element_type=jnp.float32) / jnp.sqrt(d).astype(jnp.float32)
     qpos = q_positions[:, None] + jnp.arange(q.shape[1])[None, :]  # (B, Lq)
     kpos = jnp.arange(k.shape[1])                                  # (Lk,)
     mask = kpos[None, None, :] <= qpos[:, :, None]                 # (B,Lq,Lk)
+    if q.shape[2] != k.shape[2]:
+        # grouped heads: query heads j*g .. (j+1)*g - 1 read KV head j, as
+        # one contraction over the group; K and V are never repeated.
+        # A branch of its own, not the one below at g = 1: the five-index
+        # contraction lowers to another dot and differs from the equal-
+        # heads one in the last float32 bit (3.6e-7 on the CPU, PR 26),
+        # and the contract above pins that one to ``generate``'s bits
+        b, lq, h, _ = q.shape
+        kv = k.shape[2]
+        qg = q.reshape(b, lq, kv, h // kv, d)
+        scores = jnp.einsum(
+            "bqjgd,bkjd->bjgqk", qg, k,
+            preferred_element_type=jnp.float32) / jnp.sqrt(d).astype(
+                jnp.float32)
+        scores = jnp.where(mask[:, None, None, :, :], scores, -jnp.inf)
+        weights = jax.nn.softmax(scores, axis=-1)
+        return jnp.einsum("bjgqk,bkjd->bqjgd", weights.astype(v.dtype),
+                          v).reshape(b, lq, h, d)
+    scores = jnp.einsum(
+        "bqhd,bkhd->bhqk", q, k,
+        preferred_element_type=jnp.float32) / jnp.sqrt(d).astype(jnp.float32)
     scores = jnp.where(mask[:, None, :, :], scores, -jnp.inf)
     weights = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", weights.astype(v.dtype), v)
@@ -586,14 +604,17 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths, *,
     return out[:, None]
 
 
-def decode_read(layer: PagedLayer, lq: int, sp_mesh=None) -> str:
+def decode_read(layer: PagedLayer, lq: int, sp_mesh=None,
+                group: int = 1) -> str:
     """Which way a non-prefill read of ``layer`` goes, from what the inputs
     show and nothing else: ``"pages"`` (:func:`paged_decode_attention`) for
     one query a row over unsharded bf16 or fp32 arenas whose head_dim fills
     the lanes, ``"gathered"`` for everything else — the Lq > 1 verify and
-    chunk windows, int8 pages, sp-sharded arenas, and heads narrower than
-    a lane row (every toy model of tests/test_serve.py)."""
-    if lq != 1 or layer.quant != "none" or sp_mesh is not None:
+    chunk windows, int8 pages, sp-sharded arenas, heads narrower than a
+    lane row (every toy model of tests/test_serve.py), and grouped heads
+    (``group`` query heads a KV head: the kernel reads one head for one)."""
+    if (lq != 1 or layer.quant != "none" or sp_mesh is not None
+            or group != 1):
         return "gathered"
     # what Mosaic's tiling of the arena's (H, D) minor dims takes: D whole
     # lane rows, and a page slice's H rows whole sublane tiles ((8, 128)
@@ -696,9 +717,14 @@ def paged_attend(q, k, v, paged: dict, *, prefill: bool, attn_fn, dtype):
         new_layer = layer.replace(
             k=write(layer.k, k), v=write(layer.v, v))
 
+    group = q.shape[2] // k.shape[2]
     if prefill:
         # causal self-attention over the prompt itself — exactly the
         # training contraction, so flash/blockwise plug-ins keep working
+        # (grouped heads: the pages hold the KV heads, the contraction
+        # gets them broadcast to the query heads)
+        if group > 1:
+            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
         return attn_fn(q, k, v), new_layer
 
     # the paged read: the in-place kernel, or the gather of every slot's
@@ -706,14 +732,15 @@ def paged_attend(q, k, v, paged: dict, *, prefill: bool, attn_fn, dtype):
     # scores, weighted sum), named so that a trace finds it whatever shapes
     # or kernel it has
     with jax.named_scope("paged_read"):
-        if decode_read(layer, lq, sp_mesh) == "pages":
+        if decode_read(layer, lq, sp_mesh, group) == "pages":
             # positions + 1, not ``lengths``: the tick's own causal horizon
             # (the same the gathered mask below is built from)
             out = paged_decode_attention(q, new_layer.k, new_layer.v, bt,
                                          positions + 1)
             return out, new_layer
 
-        if layer.quant == "int8" and layer.read == "flash" and lq == 1:
+        if (layer.quant == "int8" and layer.read == "flash" and lq == 1
+                and group == 1):
             # the Pallas kernel is one-query-per-row (the decode tick); the
             # Lq > 1 verify window reads through the exact dequant path
             # below — same math, and verify dispatches are 1-in-k ticks by
