@@ -23,6 +23,7 @@ The pins that matter:
 
 import itertools
 import os
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -758,6 +759,7 @@ def test_kv_cache_event_carries_serving_plane_fields(tmp_path):
     for r in kv:
         assert r["sharded_devices"] == 1
         assert "chunks_pending" in r and "chunk_ticks" in r
+        assert r["ticks_ahead"] <= r["tick"] and r["overrun_tokens"] == 0
     assert max(depths) > 0               # backlog was visible mid-flight
     assert kv[-1]["chunks_pending"] == 0
     assert kv[-1]["chunk_ticks"] == 5    # ceil(17/4)
@@ -868,3 +870,181 @@ def test_step_spans_nest_and_every_token_has_a_time(span_lm, mode):
         if mode != "speculative":           # one token a tick
             assert len(mine) == c.n_generated - 1
             assert len(set(c.token_ts)) == c.n_generated
+
+
+# ------------------------------------ the tick one ahead of the host (PR 27)
+def plain_greedy(fwd, params, prompt, steps, width, eos=None):
+    """A plain step-by-step decode: one full forward over everything so far
+    for every token (no cache, no pages, no engine), greedy; stops on
+    ``eos`` as the engine does, the end token kept."""
+    toks = [int(t) for t in prompt]
+    for _ in range(steps):
+        x = np.zeros((1, width), np.int32)
+        x[0, :len(toks)] = toks
+        toks.append(int(np.argmax(np.asarray(
+            fwd(params, jnp.asarray(x)))[0, len(toks) - 1])))
+        if toks[-1] == eos:
+            break
+    return np.asarray(toks, np.int32)
+
+
+def _mixed_requests(seed, n=5):
+    r = np.random.default_rng(seed)
+    return [DecodeRequest(i, r.integers(0, V, (int(r.integers(2, 12)),))
+                          .astype(np.int32), int(r.integers(3, 14)))
+            for i in range(n)]
+
+
+def _hybrid_case():
+    from test_hybrid_lm import TOY, engine_params, toy_model
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from benchmarks.reference import jamba as ref
+
+    model = toy_model()
+    params = engine_params(model, ref.make_weights(TOY,
+                                                   jax.random.PRNGKey(3)))
+    r = np.random.default_rng(3)
+    reqs = [DecodeRequest(i, r.integers(0, 256, (n,)).astype(np.int32), new)
+            for i, (n, new) in enumerate([(9, 4), (14, 6), (6, 3)])]
+    return (model, params, jax.jit(lambda p, x: model.apply({"params": p}, x)),
+            64, dict(max_slots=2, page_size=4, num_pages=64, max_len=64), reqs)
+
+
+def _gpt2_case(seed, cfg, reqs=None):
+    lm, params = _lm_and_params(seed=seed)
+    return (lm, params,
+            jax.jit(lambda p, x: lm.apply({"params": p}, x, train=False)), L,
+            {**dict(max_slots=2, page_size=4, num_pages=32), **cfg},
+            reqs if reqs is not None else _mixed_requests(seed))
+
+
+_HOT = ((np.arange(18, dtype=np.int32) * 5 + 3) % V).astype(np.int32)
+_AHEAD_CASES = {
+    # five requests of mixed lengths through two slots: slots are left and
+    # refilled while the tick runs ahead
+    "gpt2": lambda: _gpt2_case(40, {}),
+    # per-slot recurrent state beside the pages
+    "hybrid": _hybrid_case,
+    # the same prompt three times: the second and third read a shared
+    # frontier page whose fork is pending at their first decode write
+    "prefix_cow": lambda: _gpt2_case(20, dict(prefix_cache=True, max_slots=3),
+                                     [DecodeRequest(i, _HOT, 6)
+                                      for i in range(3)]),
+    # a 17-token prompt chunks in over five steps while the other slot
+    # decodes: the parked slot sits those ticks out
+    "chunked": lambda: _gpt2_case(24, dict(prefill_chunk=4), [
+        DecodeRequest(0, np.array([1, 2, 3], np.int32), 12),
+        DecodeRequest(1, ((np.arange(17, dtype=np.int32) * 5 + 3) % V), 4),
+        DecodeRequest(2, np.array([7, 8], np.int32), 5)]),
+    # int8 pages move a logit by a few 1e-2: on these weights no greedy
+    # choice is that close, so the tokens are the plain decode's
+    "int8_kv": lambda: _gpt2_case(42, dict(kv_quant="int8")),
+    "drain": lambda: _gpt2_case(40, dict(refill="drain")),
+    # ends the host cannot foresee: see the test
+    "eos": lambda: _gpt2_case(40, {}),
+}
+
+
+@pytest.mark.parametrize("case", list(_AHEAD_CASES))
+def test_engine_one_tick_ahead_serves_the_plain_decodes_tokens(case):
+    """The engine dispatches tick n + 1 before it has read tick n; whatever
+    rides the tick, every completion is, token for token, a plain
+    step-by-step greedy decode of its own prompt. Under ``eos_id`` (a token
+    the model emits mid-answer) the tick in flight has computed one more
+    token for the slot that ended: it is absent from the completion, counted
+    in ``overrun_tokens``, and the slot's pages are freed once."""
+    model, params, fwd, width, cfg, reqs = _AHEAD_CASES[case]()
+    eos = None
+    if case == "eos":
+        free = plain_greedy(fwd, params, reqs[0].prompt,
+                            reqs[0].max_new_tokens, width)
+        eos = int(free[reqs[0].prompt.size + 2])     # its third token
+    refs = {r.rid: plain_greedy(fwd, params, r.prompt, r.max_new_tokens,
+                                width, eos) for r in reqs}
+    eng = ServeEngine(model, params, ServeConfig(**cfg, eos_id=eos))
+    pages0 = eng.pool.pages_free
+    comps = eng.run(reqs)
+    assert sorted(c.rid for c in comps) == [r.rid for r in reqs]
+    for c in comps:
+        np.testing.assert_array_equal(refs[c.rid], c.tokens, str(c.rid))
+        assert c.n_generated == len(c.token_ts) == len(c.tokens) - c.prompt_len
+    st = eng.stats()
+    assert not eng._flights                      # nothing left unread
+    assert eng.pool.pages_free == pages0         # a double free raises
+    assert st["ticks_ahead"] > 0
+    # one dropped token for every request that ended on eos in a tick, short
+    # of its budget (an end on the prefill's own token is seen before any
+    # tick, an end at the budget was foreseen)
+    cut = [r for r in reqs if refs[r.rid][-1] == eos
+           and 1 < len(refs[r.rid]) - r.prompt.size < r.max_new_tokens]
+    assert st["overrun_tokens"] == len(cut) and bool(cut) == (case == "eos")
+    if case == "prefix_cow":
+        assert eng.pool.cow_copies == 2
+    if case == "chunked":
+        assert eng.chunk_ticks == 5 and eng.ticks > 5
+
+
+def test_tick_spans_say_which_ticks_ran_ahead_and_tokens_are_stamped_held():
+    """On the span ring, with an injected clock: the first tick after an
+    idle spell is dispatched with nothing unread (``ahead`` 0), every other
+    one while the tick before it is still in flight (``ahead`` 1, also
+    through an admission into the busy engine); tick n + 1 is dispatched
+    before tick n's ``tick.wait`` ends; a token's time is taken after the
+    ``tick.wait`` that fetched it; ``drain()`` leaves no tick unread."""
+    from tpu_dist.obs import trace
+
+    lm, params = _lm_and_params(seed=31)
+    clock = itertools.count()
+    now = lambda: float(next(clock))
+    eng = ServeEngine(lm, params, ServeConfig(
+        max_slots=2, page_size=8, num_pages=16), now_fn=now)
+    dispatched = []
+    real = eng._dispatch_tick
+
+    def recording(active):
+        dispatched.append(now())
+        return real(active)
+
+    eng._dispatch_tick = recording
+    with trace.ring().span("mark") as mark:
+        pass
+    comps = []
+    assert eng.submit(DecodeRequest(0, np.array([1, 9, 17], np.int32), 9))
+    for _ in range(4):
+        comps += eng.step()
+    assert len(eng._flights) == 1                # one tick ahead, no more
+    assert eng.submit(DecodeRequest(1, np.array([5, 6], np.int32), 4))
+    comps += eng.run()                           # both to their end: idle
+    assert not eng._flights
+    assert eng.submit(DecodeRequest(2, np.array([3], np.int32), 6))
+    comps += eng.step()
+    comps += eng.drain()
+    assert not eng._flights and all(s is None for s in eng.slots)
+    assert sorted(c.rid for c in comps) == [0, 1, 2]
+
+    spans = [sp for sp in trace.ring().snapshot() if sp.sid > mark.sid]
+    ticks = [sp for sp in spans if sp.name == "serve.tick"]
+    waits = {sp.parent: sp for sp in spans if sp.name == "tick.wait"}
+    sends = {sp.parent: sp for sp in spans if sp.name == "tick.dispatch"}
+    assert len(ticks) == eng.ticks == len(dispatched)
+    # two idle spells: request 0's first tick and request 2's
+    first_of_2 = next(k for k, tk in enumerate(ticks)
+                      if tk.attrs["rids"] == [2])
+    assert [tk.attrs["ahead"] for tk in ticks] == [
+        int(k not in (0, first_of_2)) for k in range(len(ticks))]
+    assert eng.stats()["ticks_ahead"] == len(ticks) - 2
+    assert eng.stats()["overrun_tokens"] == 0
+    # tick n + 1 goes out before tick n has been read
+    for n, tk in enumerate(ticks[:-1]):
+        if ticks[n + 1].attrs["ahead"]:
+            assert dispatched[n + 1] < waits[tk.sid].end
+            assert sends[tk.sid].start < dispatched[n + 1] < sends[tk.sid].end
+    # a token is stamped once the host holds it
+    for c in comps:
+        mine = [tk for tk in ticks if c.rid in tk.attrs["rids"]]
+        assert len(mine) == c.n_generated - 1
+        for t, tk in zip(c.token_ts[1:], mine):
+            assert waits[tk.sid].end <= t <= tk.end
+        assert np.all(np.diff(c.token_ts) > 0)

@@ -5,8 +5,10 @@ give, token by token, the LOGITS of the plain reference's full forward
 
 How the logits are read: the engine's sampler (``serve._sample``) is
 wrapped to hand every logits row it is given to the host, and the engine's
-prefill, chunk and tick methods to say whose rows those are; the engine is
-synchronous (one ``device_get`` a dispatch), so the two interleave in order.
+prefill, chunk and tick DISPATCHES to say whose rows those are. The device
+runs the programs in the order they were dispatched (the tick runs one
+ahead of the host's read), so the k-th sampling dispatch owns the k-th
+logged batch of rows.
 
 Tolerance. float32 on both sides at toy size: a decode step recomputes
 nothing the full forward does not, but in another order (one-step scan
@@ -86,20 +88,26 @@ def serve_recorded(eng, log, arrivals):
     that step; returns ({rid: Completion}, {rid: [logits row a generated
     token]})."""
     rows = {}
-    marks = []
+    marks = []          # one entry a dispatch that samples: [(row, rid)]
 
     def wrap(name, mark):
         real = getattr(eng, name)
 
         def wrapped(*a, **kw):
-            marks.append((len(log), mark(*a, **kw)))
-            return real(*a, **kw)
+            out = real(*a, **kw)
+            owners = mark(out, *a, **kw)
+            if owners is not None:
+                marks.append(owners)
+            return out
 
         setattr(eng, name, wrapped)
 
-    wrap("_prefill", lambda i, req, *a, **kw: [(0, req.rid)])
-    wrap("_run_chunk", lambda i, s: [(0, s.req.rid)])
-    wrap("_tick_plain", lambda active: [(i, s.req.rid) for i, s in active])
+    wrap("_prefill", lambda out, i, req, *a, **kw: [(0, req.rid)])
+    # a chunk that is not a prompt's last samples nothing (returns None)
+    wrap("_dispatch_chunk",
+         lambda tok, i, s: None if tok is None else [(0, s.req.rid)])
+    wrap("_dispatch_tick",
+         lambda _, active: [(i, s.req.rid) for i, s in active])
     done, step = {}, 0
     while arrivals or eng.queue or any(s is not None for s in eng.slots):
         for req in arrivals.pop(step, ()):
@@ -108,15 +116,12 @@ def serve_recorded(eng, log, arrivals):
             done[c.rid] = c
         step += 1
         assert step < 500
+    assert not eng._flights
     jax.effects_barrier()
-    # a marker owns the logits logged between it and the next marker (a
-    # chunk that is not a prompt's last logs none)
-    bounds = [at for at, _ in marks[1:]] + [len(log)]
-    for (at, owners), end in zip(marks, bounds):
-        assert end - at in (0, 1)
-        if end > at:
-            for row, rid in owners:
-                rows.setdefault(rid, []).append(log[at][row])
+    assert len(marks) == len(log)
+    for owners, logged in zip(marks, log):
+        for row, rid in owners:
+            rows.setdefault(rid, []).append(logged[row])
     return done, rows
 
 
@@ -330,3 +335,35 @@ def test_a_transformer_engine_builds_the_pool_it_had(dtype, d_model, heads,
     tick = [sp for sp in serve.trace.ring().snapshot()
             if sp.name == "serve.tick"][-1]
     assert tick.attrs["state_slots"] == 0
+
+
+@pytest.mark.parametrize("slots", [1, 2])
+def test_a_slot_that_ended_on_eos_leaves_nothing_to_its_next_occupant(slots):
+    """The tick runs one ahead of the host, so a slot that ends on
+    ``eos_id`` has been stepped once more when the host sees it: that token
+    is dropped, the state row it moved is overwritten by the next prefill
+    into the slot, and every completion is still the plain decode's."""
+    from test_serve import plain_greedy
+
+    # a tied head over full-size embeddings echoes the last token for ever;
+    # smaller embeddings let the layers move the answer mid-way
+    w = {k: 0.1 * v if k.endswith("tok_emb") else v
+         for k, v in weights(seed=13).items()}
+    model = toy_model()
+    params = engine_params(model, w)
+    fwd = jax.jit(lambda p, x: model.apply({"params": p}, x))
+    reqs = requests([9, 12, 7, 10], new=6, seed=14)
+    free = plain_greedy(fwd, params, reqs[0].prompt, 6, 64)
+    eos = int(free[reqs[0].prompt.size + 2])
+    refs = {r.rid: plain_greedy(fwd, params, r.prompt, r.max_new_tokens, 64,
+                                eos) for r in reqs}
+    eng = ServeEngine(model, params, ServeConfig(
+        max_slots=slots, page_size=4, num_pages=64, max_len=64, eos_id=eos))
+    done = {c.rid: c for c in eng.run(reqs)}
+    assert sorted(done) == [0, 1, 2, 3]
+    for rid, c in done.items():
+        np.testing.assert_array_equal(refs[rid], c.tokens, str(rid))
+    ended = [r for r in reqs if refs[r.rid][-1] == eos
+             and 1 < len(refs[r.rid]) - r.prompt.size < r.max_new_tokens]
+    assert ended and eng.stats()["overrun_tokens"] == len(ended)
+    assert eng.pool.pages_free == eng.pool.num_pages and not eng._flights

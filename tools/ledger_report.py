@@ -429,6 +429,12 @@ def decode_section(records, out=print):
             # prefills that wrote a slot's state
             srv["state_bytes"] = last.get("state_bytes")
             srv["state_writes_last"] = last.get("state_writes")
+            # the decode tick one ahead of the host: ticks dispatched
+            # while the tick before them was unread, and tokens computed
+            # for a slot that had already ended on eos_id (dropped)
+            srv["ticks_last"] = last.get("tick")
+            srv["ticks_ahead_last"] = last.get("ticks_ahead")
+            srv["overrun_tokens_last"] = last.get("overrun_tokens")
         d["serving"] = srv
         out(f"\nserving: {srv['completed']} completed, {rejected} rejected"
             + (f", occupancy {srv['occupancy'] * 100:.0f}%"
@@ -465,6 +471,11 @@ def decode_section(records, out=print):
             out(f"  slot state: {_si(srv['state_bytes'], 'B')} allocated, "
                 f"{srv['state_writes_last'] or 0} prefills wrote a slot's "
                 "state")
+        if srv.get("ticks_ahead_last"):
+            out(f"  decode tick: {srv['ticks_ahead_last']} of "
+                f"{srv['ticks_last']} ticks dispatched ahead of the host's "
+                f"read, {srv['overrun_tokens_last'] or 0} overrun tokens "
+                "dropped")
         if (srv.get("sharded_devices") or 0) > 1:
             out(f"  sp-sharded KV pool: {srv['sharded_devices']} devices")
     return d

@@ -6,8 +6,8 @@ prompt/output lengths, and the offline pattern — admit a fixed batch, run
 it to the longest sequence's completion, repeat — leaves most slots idle
 most of the time. This module implements Orca-style iteration-level
 scheduling [OSDI '22]: admission decisions happen at every decode tick, a
-finished sequence's slot and pages are reclaimed and refilled the same
-tick, and the headline metric becomes throughput-under-load (completed
+finished sequence's slot and pages are reclaimed and refilled at the next
+iteration, and the headline metric becomes throughput-under-load (completed
 requests/s at a latency SLO), not offline tok/s.
 
 Composition (each piece usable alone):
@@ -23,6 +23,13 @@ Composition (each piece usable alone):
   token) and ``decode_tick`` (the packed slot set, one token per active
   sequence, per-slot positions — inactive slots ride along masked to the
   pool's trash page, so the program never re-specializes on occupancy);
+* the plain decode tick runs **one tick ahead of the host**: its small
+  inputs (block tables, each slot's last token, its position) stay on the
+  device, and an iteration dispatches tick n + 1 before it reads tick n's
+  tokens, so the device computes while the host emits, evicts and admits
+  (``ServeEngine._tick_plain`` has the rules: who ends by budget is known
+  before the dispatch, an ``eos_id`` end is seen one tick late and its
+  extra token dropped);
 * **speculative decoding** (``spec_k > 0``): a small draft model over the
   shared base proposes k greedy tokens per slot and ONE jitted program per
   tick both drafts and verifies — the draft scan rides its own page arenas
@@ -80,7 +87,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpu_dist._compat import shard_map
 from tpu_dist.engine.generate import (_quantize_for_decode, _refuse_wo_tree,
@@ -214,6 +221,16 @@ class _Slot:
     win_drafted: int = 0
 
 
+@dataclass
+class _Flight:
+    """A plain decode tick the device has been given and the host has not
+    read yet."""
+
+    nxt: jax.Array                       # (slots,) its sampled tokens
+    slots: List[Tuple[int, _Slot]]       # who decodes in it
+    ahead: int   # 1: dispatched while the tick before it was still unread
+
+
 def _slot_state_layers(layout) -> int:
     """How many layers of a model's ``cache_layout()`` keep slot state."""
     return sum(kind == "slot_state" for kind, *_ in layout)
@@ -294,6 +311,12 @@ def _tick_program(model, temperature, top_k, top_p, sp_mesh=None):
         # place; a slot the tick carries but does not decode keeps its row
         # (``live`` 0). A decoding slot's position is its prompt's length
         # or more, so position 0 names exactly the slots that sit out.
+        # The three small inputs are the engine's device-resident decode
+        # state (ServeEngine._tick_plain): ``block_tables`` is handed back
+        # in as it is tick after tick, the sampled tokens returned here ARE
+        # the next tick's ``tokens``, and ``positions`` comes back through
+        # _advance_positions; the host uploads none of them a tick. The
+        # random key rides through as before: one split a tick.
         paged = {"layers": layers, "block_tables": block_tables,
                  "positions": positions, "lengths": positions + 1,
                  "sp_mesh": sp_mesh,
@@ -305,6 +328,29 @@ def _tick_program(model, temperature, top_k, top_p, sp_mesh=None):
         return nxt.astype(jnp.int32), new_layers, rng
 
     return tick
+
+
+# The decode state that lives on the device between ticks (block tables,
+# each slot's last token, its position) is moved by two small programs;
+# jit specializes each on the engine's (slots, max_pages) once.
+
+@jax.jit
+def _advance_positions(positions):
+    # dispatched right behind every plain tick: a decoding slot's position
+    # moves on by one, a slot that sits out (position 0) stays there
+    return jnp.where(positions > 0, positions + 1, 0)
+
+
+@jax.jit
+def _patch_slot_row(block_tables, tokens, positions, patch):
+    # one slot's row of the decode state, when it changes: a slot joins the
+    # tick (its block-table row, its last token, its position) or leaves it
+    # (the trash row, position 0). ``patch`` is one int32 vector, one
+    # upload: [slot, token, position, *block-table row]
+    slot = patch[0]
+    return (block_tables.at[slot].set(patch[3:]),
+            tokens.at[slot].set(patch[1]),
+            positions.at[slot].set(patch[2]))
 
 
 @lru_cache(maxsize=32)
@@ -689,6 +735,25 @@ class ServeEngine:
             self.pool.page_layers()[0], 1 + cfg.spec_k, self.sp_mesh,
             groups[0]) if groups else "none"
         self.state_writes = 0        # prefills that wrote a slot's state
+        # the plain tick's decode state, resident on the device: (flat block
+        # tables, each slot's last token, its position), every row on the
+        # trash page at position 0 to begin with; which slot each of its
+        # rows decodes for (None: that trash row); and the ticks dispatched
+        # but not read yet. Beside an sp-sharded pool the state is
+        # replicated over the mesh from the start, as the tick returns it:
+        # one placement, so one compiled tick
+        put = (jnp.asarray if mesh is None else partial(
+            jax.device_put, device=NamedSharding(mesh, P())))
+        self._dev = (
+            put(self.pool.flat_block_table(np.full(
+                (cfg.max_slots, self.max_pages_per_seq), self.pool.num_pages,
+                np.int32))),
+            put(np.zeros((cfg.max_slots,), np.int32)),
+            put(np.zeros((cfg.max_slots,), np.int32)))
+        self._dev_rows: List[Optional[_Slot]] = [None] * cfg.max_slots
+        self._flights: Deque[_Flight] = deque()
+        self.ticks_ahead = 0         # ticks dispatched one ahead of a read
+        self.overrun_tokens = 0      # computed for a slot already ended
         self._live_pages_sum = 0
         self._wait_ema: Optional[float] = None
         self._wait_samples = 0
@@ -840,8 +905,21 @@ class ServeEngine:
     def step(self) -> List[Completion]:
         """One iteration: evict finished sequences (freeing their slots
         and pages), admit + prefill from the queue into the free slots,
-        then run one decode tick over the packed active set. Returns the
-        completions evicted this iteration."""
+        then one pass of the decode tick over the packed active set.
+        Returns the completions evicted this iteration.
+
+        The plain tick runs ONE TICK AHEAD of the host (:meth:`_tick_plain`):
+        a pass dispatches the next tick and only then reads the tokens of
+        the tick dispatched a pass earlier, so the device computes tick
+        n + 1 while the host emits, evicts and admits on tick n's tokens
+        (after an idle spell a pass dispatches two ticks and reads the
+        first). Every pass still hands each decoding request one token, but
+        a finished sequence is evicted, and its slot refilled, one tick
+        later than a synchronous loop would, and a prefill queues behind
+        the tick in flight. Timestamps are taken when the host HOLDS a
+        token's value. ``run()``, ``drain()`` and any loop that steps while
+        a slot is occupied leave no tick unread:
+        a tick is in flight only while some slot is still occupied."""
         with self._span("serve.step", tick=self.ticks,
                         n_active=sum(s is not None for s in self.slots),
                         queue_depth=len(self.queue)):
@@ -1419,33 +1497,47 @@ class ServeEngine:
             s.cow_pending = None
 
     def _tick(self) -> None:
+        if self.cfg.spec_k > 0:
+            self._tick_spec_pass()
+        else:
+            self._tick_plain()
+
+    def _tick_attrs(self, slots, ahead: int) -> dict:
+        """What a ``serve.tick`` span says of the tick whose tokens
+        ``slots`` receive at its end."""
+        # pages holding a row that tick attends to: what a read that
+        # follows the block table touches, of the table's slots x max_pages
+        attrs = {"rids": [s.req.rid for _, s in slots],
+                 "read": self.tick_read,
+                 "live_pages": sum(self.pool.pages_needed(s.position + 1)
+                                   for _, s in slots),
+                 "state_slots": len(slots) if self.state_layers else 0,
+                 "ahead": ahead}
+        if self.tracer is not None:
+            attrs["trace_ids"] = [s.trace_id for _, s in slots]
+        return attrs
+
+    def _count_tick(self, attrs: dict) -> None:
+        self.ticks += 1
+        self._occupancy_sum += len(attrs["rids"]) / max(len(self.slots), 1)
+        self._live_pages_sum += attrs["live_pages"]
+
+    def _tick_spec_pass(self) -> None:
         # a slot mid-chunked-prefill (chunk_next >= 0) has no token to
         # decode yet — it keeps its pages but sits out the tick
         active = [(i, s) for i, s in enumerate(self.slots)
                   if s is not None and not s.done and s.chunk_next < 0]
         if not active:
             return
-        # pages holding a row this tick attends to: what a read that
-        # follows the block table touches, of the table's slots x max_pages
-        live_pages = sum(self.pool.pages_needed(s.position + 1)
-                         for _, s in active)
-        attrs = {"rids": [s.req.rid for _, s in active],
-                 "read": self.tick_read, "live_pages": live_pages,
-                 "state_slots": len(active) if self.state_layers else 0}
-        if self.tracer is not None:
-            attrs["trace_ids"] = [s.trace_id for _, s in active]
+        attrs = self._tick_attrs(active, ahead=0)    # read as dispatched
         with self._span("serve.tick", **attrs):
-            if self.cfg.spec_k > 0:
-                self._tick_spec(active)
-            else:
-                self._tick_plain(active)
-        self.ticks += 1
-        self._occupancy_sum += len(active) / max(len(self.slots), 1)
-        self._live_pages_sum += live_pages
+            self._tick_spec(active)
+        self._count_tick(attrs)
 
-    def _tick_inputs(self, active, with_caps: bool = False):
-        """The tick's host-side inputs: each slot's last token, position,
-        block table (and, for the speculative tick, its write-mask cap)."""
+    def _tick_inputs(self, active):
+        """The speculative tick's host-side inputs, built and uploaded
+        every tick (the plain tick keeps its own on the device): each
+        slot's block table, last token, position and write-mask cap."""
         n = len(self.slots)
         tokens = np.zeros((n,), np.int32)
         positions = np.zeros((n,), np.int32)
@@ -1459,11 +1551,9 @@ class ServeEngine:
             # (a draft window can overrun a nearly-done request)
             caps[i] = s.prompt_len + s.req.max_new_tokens
             bts[i] = s.block_table
-        arrays = [jnp.asarray(self.pool.flat_block_table(bts)),
-                  jnp.asarray(tokens), jnp.asarray(positions)]
-        if with_caps:
-            arrays.append(jnp.asarray(caps))
-        return arrays
+        return [jnp.asarray(self.pool.flat_block_table(bts)),
+                jnp.asarray(tokens), jnp.asarray(positions),
+                jnp.asarray(caps)]
 
     def _emit_token(self, s: _Slot, tok: int, now: float) -> None:
         s.buf[s.prompt_len + s.generated] = tok
@@ -1474,33 +1564,138 @@ class ServeEngine:
             s.done = True
             s.finish_ts = now
 
-    def _tick_plain(self, active) -> None:
-        with self._span("tick.build"):
-            self._resolve_cow(active)
-            inputs = self._tick_inputs(active)
-        with self._span("tick.dispatch",
-                        first_call=self._first_call("tick")):
-            program = _tick_program(self.model, self.cfg.temperature,
-                                    self.cfg.top_k, self.cfg.top_p,
-                                    self.sp_mesh)
-            # tick shapes are occupancy-invariant (inactive slots ride the
-            # trash page), so ANY cache growth is a retrace hazard: allowed=1
-            register_audit_program("serve_tick", program)
-            nxt, new_layers, self._rng = program(
-                self.params, self.pool.layers(), *inputs, self._rng)
-            self.pool.adopt(new_layers)
-        with self._span("tick.wait"):
-            # iteration-level scheduling: every tick's tokens come back to
-            # the host so finished sequences free their slot/pages for the
-            # SAME-tick refill — the one sync per tick is the scheduling
-            # primitive, not an accident (Orca's design point)
-            # distlint: disable=DL002 -- the per-tick sync is the scheduler's eviction/refill decision point
-            nxt = np.asarray(jax.device_get(nxt))
-        with self._span("tick.emit"):
-            now = self._now()
-            for i, s in active:
-                self._emit_token(s, int(nxt[i]), now)
-                self._note_decode(s, now, tokens=1)
+    def _decoding_next(self) -> List[Tuple[int, _Slot]]:
+        """Who decodes in the next tick to dispatch. A slot mid-chunked-
+        prefill (chunk_next >= 0) has no token to decode yet: it keeps its
+        pages but sits out. A slot whose budget the tokens already in
+        flight fill is known to end before they are read, and sits out
+        too."""
+        flying = {}
+        for flight in self._flights:
+            for _, s in flight.slots:
+                flying[id(s)] = flying.get(id(s), 0) + 1
+        return [(i, s) for i, s in enumerate(self.slots)
+                if s is not None and not s.done and s.chunk_next < 0
+                and s.generated + flying.get(id(s), 0)
+                < s.req.max_new_tokens]
+
+    def _tick_plain(self) -> None:
+        """One pass of the plain decode tick, one tick ahead of the host:
+        dispatch tick n + 1, THEN read tick n's tokens and emit them.
+
+        Tick n + 1 needs nothing of the host that tick n's tokens decide,
+        because its inputs stay on the device (``self._dev``: the flat
+        block tables, each slot's last token, its position; the tick's
+        sampled tokens are the next tick's input, ``_advance_positions``
+        moves the positions, and :meth:`_sync_rows` patches a row only when
+        a slot joins or leaves the tick: a block-table row is fixed at
+        admission, pages being reserved for prompt + ``max_new_tokens``),
+        and because who is done is known beforehand: a slot whose budget
+        the token in flight fills (``generated + 1 >= max_new_tokens``) is
+        taken out of tick n + 1 before the dispatch. After an idle spell
+        nothing is in flight, so that pass dispatches two ticks and reads
+        the first. The one end the host cannot foresee is ``eos_id``: that
+        slot is seen done when tick n is read, tick n + 1 has computed one
+        more token for it, and that token is dropped here (never emitted,
+        counted or stamped: ``overrun_tokens``); its write fell inside the
+        slot's own reservation, and slot and pages are freed once, by the
+        next ``_evict``. Greedy tokens are those of a synchronous loop
+        always. Sampled tokens (``temperature > 0``) are too wherever the
+        engine dispatches the same sequence of prefills and ticks as a
+        synchronous loop would, since the random key is carried through the
+        programs in dispatch order; a request admitted into a slot that
+        another just left joins one tick later, and a dropped tick has
+        advanced the key once, so later samples are then another draw of
+        the same distribution.
+
+        A pass is one ``serve.tick`` span: ``rids`` are the requests that
+        receive a token at its end, ``ahead`` is 1 when those tokens' tick
+        was dispatched while the tick before it was still unread (every
+        tick but the first after an idle spell). ``token_ts`` and
+        ``finish_ts`` are read after the ``device_get`` returned."""
+        flights = self._flights
+        active = self._decoding_next()
+        if not flights and not active:
+            return
+        with self._span("serve.tick") as tick:
+            with self._span("tick.build"):
+                if active:
+                    self._resolve_cow(active)
+                    self._sync_rows(active)
+            with self._span("tick.dispatch", first_call=bool(active)
+                            and self._first_call("tick")):
+                if active:
+                    self._dispatch_tick(active)
+                    if len(flights) == 1:
+                        # nothing was in flight: the tick after this one
+                        # goes out too, so that the read below has a tick
+                        # behind it
+                        active = self._decoding_next()
+                        if active:
+                            self._sync_rows(active)
+                            self._dispatch_tick(active)
+            flight = flights.popleft()
+            # a slot that ended on eos_id while this tick was already
+            # dispatched has a token in it: dropped
+            landing = [(i, s) for i, s in flight.slots if not s.done]
+            tick.attrs.update(self._tick_attrs(landing, ahead=flight.ahead))
+            with self._span("tick.wait"):
+                # this tick was dispatched a pass ago (or, after an idle
+                # spell, a moment ago with the next one behind it): the
+                # host blocks only for what is left of a tick the device
+                # has been working on while the host emitted, evicted and
+                # admitted. Scheduling no longer waits on this sync: who
+                # ends by budget was known before the dispatch, and an
+                # eos_id is acted on one tick late
+                # distlint: disable=DL002 -- reads the tick dispatched a pass earlier; the next tick is already queued behind it
+                nxt = np.asarray(jax.device_get(flight.nxt))
+            with self._span("tick.emit"):
+                now = self._now()
+                for i, s in landing:
+                    self._emit_token(s, int(nxt[i]), now)
+                    self._note_decode(s, now, tokens=1)
+        self._count_tick(tick.attrs)
+        self.ticks_ahead += flight.ahead
+        self.overrun_tokens += len(flight.slots) - len(landing)
+
+    def _sync_rows(self, active) -> None:
+        """Patch the device's decode state where it differs from who
+        decodes next: a row whose slot left the tick (ended, evicted) goes
+        back to the trash page at position 0, a slot that joins (admitted,
+        or its chunked prefill's last chunk done) gets its block-table row,
+        last token and position. Nothing changes on most ticks."""
+        want: List[Optional[_Slot]] = [None] * len(self.slots)
+        for i, s in active:
+            want[i] = s
+        for i, s in enumerate(want):
+            if self._dev_rows[i] is s:
+                continue
+            patch = np.empty((3 + self.max_pages_per_seq,), np.int32)
+            if s is None:
+                patch[:3] = i, 0, 0
+                patch[3:] = self.pool.flat_block_table(self.pool.num_pages)
+            else:
+                patch[:3] = (i, s.buf[s.prompt_len + s.generated - 1],
+                             s.position)
+                patch[3:] = self.pool.flat_block_table(s.block_table)
+            self._dev = _patch_slot_row(*self._dev, jnp.asarray(patch))
+            self._dev_rows[i] = s
+
+    def _dispatch_tick(self, active) -> None:
+        program = _tick_program(self.model, self.cfg.temperature,
+                                self.cfg.top_k, self.cfg.top_p,
+                                self.sp_mesh)
+        # tick shapes are occupancy-invariant (inactive slots ride the
+        # trash page), so ANY cache growth is a retrace hazard: allowed=1
+        register_audit_program("serve_tick", program)
+        block_tables, tokens, positions = self._dev
+        nxt, new_layers, self._rng = program(
+            self.params, self.pool.layers(), block_tables, tokens,
+            positions, self._rng)
+        self.pool.adopt(new_layers)
+        self._dev = (block_tables, nxt, _advance_positions(positions))
+        self._flights.append(
+            _Flight(nxt, active, ahead=int(bool(self._flights))))
 
     def _tick_spec(self, active) -> None:
         """One speculative iteration: k draft proposals + one base verify
@@ -1510,7 +1705,7 @@ class ServeEngine:
         k = self.cfg.spec_k
         with self._span("tick.build"):
             self._resolve_cow(active)
-            inputs = self._tick_inputs(active, with_caps=True)
+            inputs = self._tick_inputs(active)
         with self._span("tick.dispatch",
                         first_call=self._first_call("spec_tick")):
             program = _spec_tick_program(self.model, self.draft_model, k)
@@ -1584,6 +1779,8 @@ class ServeEngine:
                          chunk_ticks=self.chunk_ticks,
                          state_bytes=st["state_bytes"],
                          state_writes=self.state_writes,
+                         ticks_ahead=self.ticks_ahead,
+                         overrun_tokens=self.overrun_tokens,
                          slots=len(self.slots), tick=self.ticks)
 
     # -- introspection ----------------------------------------------------
@@ -1635,6 +1832,11 @@ class ServeEngine:
                     if self.ticks else None),
                 "rejected": self.rejected, "prefills": self.prefills,
                 "state_writes": self.state_writes,
+                # plain ticks dispatched while the tick before them was
+                # still unread, and tokens such a tick computed for a slot
+                # that had ended on eos_id (dropped, never emitted)
+                "ticks_ahead": self.ticks_ahead,
+                "overrun_tokens": self.overrun_tokens,
                 "sp_prefills": self.sp_prefills,
                 "chunk_ticks": self.chunk_ticks,
                 "chunks_pending": self.chunks_pending,
