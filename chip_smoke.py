@@ -8,8 +8,8 @@ cookbook scripts use, at the full width the repo supports:
   scripts/7.jax_tpu.py: ResNet-50, CIFAR10 shapes, global batch 1024, bf16,
   16 steps per dispatch): two windows, the distributed eval, a checkpoint
   save and a resume;
-* the LM trainer (``tpu_dist.engine.lm_loop.LMTrainer`` at bench.py's LM
-  default: 8 layers, d1024, 8 heads, L2048, V32000, batch 8, bf16, flash
+* the LM trainer (``tpu_dist.engine.lm_loop.LMTrainer`` at the repo's
+  long-standing LM geometry: 8 layers, d1024, 8 heads, L2048, V32000, batch 8, bf16, flash
   attention): a few steps, then one step each with the fused Pallas AdamW
   and with int8 matmuls;
 * the server (``tpu_dist.engine.serve.ServeEngine``) on that LM's
@@ -66,7 +66,7 @@ class Sizes:
     # run, PR 21), a regime that amplifies rounding instead of exposing a
     # sharding fault
     multichip_image_lr: float = 0.01
-    # LM trainer — bench.py's LM default
+    # LM trainer — the geometry tests/test_chip_compile.py compiles for
     num_layers: int = 8
     d_model: int = 1024
     num_heads: int = 8

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Variant 7 — the flagship TPU-native path (BASELINE.json north star).
+"""Variant 7 — the flagship TPU-native path.
 
 The "sixth backend" the reference never had: ResNet-50 / CIFAR-10 on a TPU
 pod. jit+mesh data parallelism, bf16 compute with fp32 master weights and BN

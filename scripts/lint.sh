@@ -1,6 +1,6 @@
 #!/bin/bash
 # Static-analysis gate: distlint over the FULL acceptance surface —
-# tpu_dist, tools (the linter lints itself), tests, scripts, bench.py.
+# tpu_dist, tools (the linter lints itself), tests, scripts.
 # Stdlib-only (no jax, no devices), so this runs anywhere — pre-commit,
 # CI, a laptop. The run also writes distlint.sarif (SARIF 2.1.0) as a CI
 # code-scanning artifact. Exit code gates on ERROR-tier findings only:

@@ -42,13 +42,13 @@ def main() -> None:
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from tpu_dist.engine.checkpoint import gather_to_host
-    from tpu_dist.engine.lm_steps import (make_lm_batches,
-                                          make_lm_sp_train_step,
-                                          make_lm_train_step)
+    from tpu_dist.engine.lm_steps import make_lm_batches
     from tpu_dist.engine.state import TrainState
     from tpu_dist.models.transformer import tiny_lm
     from tpu_dist.ops import make_optimizer
     from tpu_dist.parallel.mesh import make_mesh, replicated
+    from tpu_dist.plan.compile import Bindings, compile_train_step
+    from tpu_dist.plan.ir import Plan
 
     V, L, B, STEPS = 64, 32, 4, 3
     axis = {"tp": "model", "sp": "seq", "pp": "stage", "ep": "expert"}[mode]
@@ -67,7 +67,9 @@ def main() -> None:
                             jnp.zeros((1, L), jnp.int32),
                             train=False)["params"]
         state = shard_state_ep(mesh, TrainState.create(params, {}, tx))
-        step = make_lm_train_step(model, tx, mesh, donate=False)
+        step = compile_train_step(
+            Plan(engine="lm", donate=False),
+            Bindings(mesh=mesh, model=model, tx=tx))
         data_spec = P("data")
     else:
         model = tiny_lm(**lm_kw)
@@ -84,15 +86,20 @@ def main() -> None:
                 opt_state=jax.device_put(st.opt_state,
                                          NamedSharding(mesh, P())),
                 loss_scale=None)
-            step = make_lm_train_step(model, tx, mesh, donate=False)
+            step = compile_train_step(
+                Plan(engine="lm", layout="tp", donate=False),
+                Bindings(mesh=mesh, model=model, tx=tx))
             data_spec = P("data")
         elif mode == "sp":
             from functools import partial
 
             state = jax.device_put(TrainState.create(params, {}, tx),
                                    replicated(mesh))
-            step = make_lm_sp_train_step(partial(tiny_lm, **lm_kw), tx,
-                                         mesh, donate=False)
+            step = compile_train_step(
+                Plan(engine="lm", layout="sp", sync="explicit",
+                     donate=False),
+                Bindings(mesh=mesh, model_ctor=partial(tiny_lm, **lm_kw),
+                         tx=tx))
             data_spec = P("data", "seq")
         else:  # pp
             from tpu_dist.parallel.pp import (make_lm_pp_train_step,

@@ -18,7 +18,7 @@ HEADLINE = "cifar10_resnet50_images_per_sec_per_chip"
 
 
 def _write_round(dirpath, n, value, metric=HEADLINE, **parsed_extra):
-    doc = {"n": n, "cmd": "python bench.py", "rc": 0,
+    doc = {"n": n, "cmd": "python headline.py", "rc": 0,
            "parsed": {"metric": metric, "value": value,
                       "unit": "images/sec/chip", **parsed_extra}}
     path = os.path.join(dirpath, f"BENCH_r{n:02d}.json")

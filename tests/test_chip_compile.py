@@ -5,7 +5,7 @@ described, not attached (``jax.experimental.topologies``): nothing runs, but
 what Mosaic would refuse on the chip — a block not aligned to the tiling,
 more fast memory than a kernel may use — it refuses here, on the CPU, at no
 chip time. Each case is a kernel the trainers or the server really call, at
-the widths they call it with (bench.py's LM default: 8 layers, d1024, 8 heads
+the widths they call it with (chip_smoke.py's LM: 8 layers, d1024, 8 heads
 of 128, L2048, V32000, batch 8; also 16 heads of 64 at L16384, the
 serving pool's pages, the serving cell's decode read, and the hybrid LM's
 selective scan at 5120 channels). Interpret-mode

@@ -162,6 +162,37 @@ def test_stream_prefetch_passes_none_and_exception_items():
     assert raised and got == [1]
 
 
+def test_stream_prefetch_waits_for_its_producer_when_abandoned():
+    """A consumer that leaves early (a preemption snapshot leaves the
+    epoch by SystemExit) must not leave the producer running: a daemon
+    thread still inside device_put when the interpreter exits aborts the
+    process (SIGABRT in place of the snapshot's exit code). Closing the
+    generator stops the producer AND joins it."""
+    import threading
+    import time
+
+    from tpu_dist.data.loader import stream_prefetch
+
+    in_item = threading.Event()
+    finished = threading.Event()
+
+    def slow():
+        for i in range(100):
+            if i == 2:              # staged behind item 1: nobody waits
+                in_item.set()
+                time.sleep(0.3)     # "inside device_put"
+                finished.set()
+            yield i
+
+    before = set(threading.enumerate())
+    gen = stream_prefetch(slow(), depth=1)
+    assert next(gen) == 0
+    assert in_item.wait(timeout=10.0)
+    gen.close()                     # what unwinding the loop does
+    assert finished.is_set(), "close() returned with the producer mid-item"
+    assert set(threading.enumerate()) <= before, "a producer outlived it"
+
+
 def test_token_bin_size_alignment_checked(tmp_path):
     """A .bin whose byte size is not a whole number of tokens for the
     configured dtype fails loudly instead of yielding garbage ids."""

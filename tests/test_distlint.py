@@ -28,7 +28,7 @@ from tools.distlint.__main__ import main as distlint_main
 FIXTURES = os.path.join(os.path.dirname(__file__), "fixtures", "distlint")
 RULE_IDS = [r.id for r in RULES]
 
-SURFACE = ["tpu_dist", "tools", "tests", "scripts", "bench.py"]
+SURFACE = ["tpu_dist", "tools", "tests", "scripts"]
 _FULL: list = []   # memoized full-surface lint (the most expensive call
 #                    here — the pin test and the debt test share one run)
 
@@ -388,15 +388,21 @@ def test_callgraph_typed_attribute_resolution():
 
 
 def test_callgraph_jit_factory_fixpoint():
-    """Step builders returning jax.jit(...) products are factories, so
-    self.train_step = make_train_step(...) resolves to a traced handle
-    and the engines' loops derive as hot without any hard-coded list."""
+    """The plan compiler's entries return jax.jit(...) products through a
+    chain of plain returns (compile_train_step -> _lower_train -> a
+    lowering -> _jit_gspmd), so they are factories, the trainers'
+    self.train_step = compile_train_step(plan, binds) resolves to a
+    traced handle, and the engines' loops derive as hot without any
+    hard-coded list."""
     g = load_callgraph()
-    assert "tpu_dist/engine/steps.py::make_train_step" in g._jit_factories()
+    fac = g._jit_factories()
+    for entry in ("compile_train_step", "compile_eval_step"):
+        assert f"tpu_dist/plan/compile.py::{entry}" in fac, entry
     rt = g.reaches_traced()
     for fn in ("train_epoch", "_train_epoch_windowed", "_fit_epochs",
                "validate"):
         assert f"tpu_dist/engine/loop.py::Trainer.{fn}" in rt, fn
+        assert f"tpu_dist/engine/lm_loop.py::LMTrainer.{fn}" in rt, fn
 
 
 def test_callgraph_alias_and_import_resolution(tmp_path):
@@ -585,7 +591,7 @@ def test_mesh_axes_authority_loaded():
 def test_tree_is_clean():
     """THE tier-1 pin: zero unsuppressed findings across the FULL
     acceptance surface — tpu_dist, tools (the linter lints itself),
-    tests, scripts, bench.py — with ALL rules (old + DL007 + DL1xx), and
+    tests, scripts — with ALL rules (old + DL007 + DL1xx), and
     every suppression carries a reason."""
     res = _full_lint()
     assert res.findings == [], "\n".join(f.render() for f in res.findings)

@@ -12,11 +12,12 @@ import pytest
 
 from tpu_dist.data import make_transform
 from tpu_dist.engine.state import TrainState, init_model
-from tpu_dist.engine.steps import (make_eval_step, make_shard_map_train_step,
-                                   make_train_step)
 from tpu_dist.models import create_model
 from tpu_dist.ops import make_optimizer
 from tpu_dist.parallel.mesh import batch_sharding, make_mesh, replicated
+from tpu_dist.plan.compile import (Bindings, compile_eval_step,
+                                   compile_train_step)
+from tpu_dist.plan.ir import Plan
 
 
 def _setup(mesh, arch="lenet", lr=0.1, shape=(28, 28, 1)):
@@ -40,7 +41,9 @@ def _batch(n=64, shape=(28, 28, 1), seed=0):
 def test_loss_decreases_on_learnable_batch():
     mesh = make_mesh()
     model, tx, state, transform = _setup(mesh)
-    step = make_train_step(model, tx, transform, mesh)
+    step = compile_train_step(
+        Plan(engine="image"),
+        Bindings(mesh=mesh, model=model, tx=tx, transform=transform))
     imgs, labels = _batch(64)
     sh = batch_sharding(mesh)
     imgs, labels = jax.device_put(imgs, sh), jax.device_put(labels, sh)
@@ -86,8 +89,10 @@ def test_jit_and_shard_map_flavors_agree_exactly():
                            replicated(mesh))
     transform = make_transform(np.full((1,), 0.5, np.float32),
                                np.full((1,), 0.25, np.float32))
-    step_a = make_train_step(model, tx, transform, mesh, donate=False)
-    step_b = make_shard_map_train_step(model, tx, transform, mesh, donate=False)
+    binds = Bindings(mesh=mesh, model=model, tx=tx, transform=transform)
+    step_a = compile_train_step(Plan(engine="image", donate=False), binds)
+    step_b = compile_train_step(
+        Plan(engine="image", sync="explicit", donate=False), binds)
     imgs, labels = _batch(64)
     sh = batch_sharding(mesh)
     imgs, labels = jax.device_put(imgs, sh), jax.device_put(labels, sh)
@@ -113,8 +118,11 @@ def test_single_vs_multi_device_same_update():
     model, tx, state8, transform = _setup(mesh8, arch="resnet18",
                                           shape=(32, 32, 3))
     _, _, state1, _ = _setup(mesh1, arch="resnet18", shape=(32, 32, 3))
-    step8 = make_train_step(model, tx, transform, mesh8, donate=False)
-    step1 = make_train_step(model, tx, transform, mesh1, donate=False)
+    plan = Plan(engine="image", donate=False)
+    step8 = compile_train_step(plan, Bindings(
+        mesh=mesh8, model=model, tx=tx, transform=transform))
+    step1 = compile_train_step(plan, Bindings(
+        mesh=mesh1, model=model, tx=tx, transform=transform))
     imgs, labels = _batch(64, (32, 32, 3))
     rng = jax.random.PRNGKey(1)
     s8, _ = step8(state8, jax.device_put(imgs, batch_sharding(mesh8)),
@@ -129,7 +137,9 @@ def test_single_vs_multi_device_same_update():
 def test_eval_step_counts_mask_padding():
     mesh = make_mesh()
     model, tx, state, transform = _setup(mesh)
-    estep = make_eval_step(model, transform, mesh)
+    estep = compile_eval_step(
+        Plan(engine="image"),
+        Bindings(mesh=mesh, model=model, eval_transform=transform))
     imgs, labels = _batch(32)
     sh = batch_sharding(mesh)
     # last 8 samples marked as sampler padding -> excluded from every metric
@@ -146,8 +156,9 @@ def test_eval_step_counts_mask_padding():
 def test_grad_compression_still_converges():
     mesh = make_mesh()
     model, tx, state, transform = _setup(mesh)
-    step = make_shard_map_train_step(model, tx, transform, mesh,
-                                     grad_compression="bf16")
+    step = compile_train_step(
+        Plan(engine="image", sync="explicit", grad_compression="bf16"),
+        Bindings(mesh=mesh, model=model, tx=tx, transform=transform))
     imgs, labels = _batch(64)
     sh = batch_sharding(mesh)
     imgs, labels = jax.device_put(imgs, sh), jax.device_put(labels, sh)
@@ -170,7 +181,6 @@ def test_grad_compression_still_converges():
 # test_indexed_multi_step_equals_host_batches below (the indexed twin)
 def test_multi_step_equals_sequential_steps():
     """K steps in one scan dispatch == K sequential jit dispatches."""
-    from tpu_dist.engine.steps import make_multi_train_step
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = make_mesh()
@@ -181,8 +191,10 @@ def test_multi_step_equals_sequential_steps():
                             replicated(mesh))
     transform = make_transform(np.full((1,), 0.5, np.float32),
                                np.full((1,), 0.25, np.float32))
-    single = make_train_step(model, tx, transform, mesh, donate=False)
-    multi = make_multi_train_step(model, tx, transform, mesh, donate=False)
+    binds = Bindings(mesh=mesh, model=model, tx=tx, transform=transform)
+    single = compile_train_step(Plan(engine="image", donate=False), binds)
+    multi = compile_train_step(
+        Plan(engine="image", window="stacked", donate=False), binds)
 
     k, b = 3, 32
     rng_np = np.random.default_rng(0)
@@ -213,7 +225,6 @@ def test_multi_step_equals_sequential_steps():
 def test_grad_accum_equals_big_batch():
     """K microbatches accumulated == one step over the concatenated batch
     (exact for batch-decoupled models)."""
-    from tpu_dist.engine.steps import make_grad_accum_train_step
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = make_mesh()
@@ -224,9 +235,10 @@ def test_grad_accum_equals_big_batch():
                             replicated(mesh))
     transform = make_transform(np.full((1,), 0.5, np.float32),
                                np.full((1,), 0.25, np.float32))
-    big = make_train_step(model, tx, transform, mesh, donate=False)
-    accum = make_grad_accum_train_step(model, tx, transform, mesh,
-                                       donate=False)
+    binds = Bindings(mesh=mesh, model=model, tx=tx, transform=transform)
+    big = compile_train_step(Plan(engine="image", donate=False), binds)
+    accum = compile_train_step(
+        Plan(engine="image", grad_accum_steps=4, donate=False), binds)
 
     k, b = 4, 16
     imgs, labels = _batch(k * b)
@@ -249,8 +261,7 @@ def test_grad_accum_equals_big_batch():
 
 def test_indexed_multi_step_equals_host_batches():
     """Device-resident dataset + (K,B) index window == host-fed batches."""
-    from tpu_dist.engine.steps import (make_indexed_multi_train_step,
-                                       pack_images_for_device)
+    from tpu_dist.engine.steps import pack_images_for_device
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     mesh = make_mesh()
@@ -261,9 +272,11 @@ def test_indexed_multi_step_equals_host_batches():
                             replicated(mesh))
     transform = make_transform(np.full((1,), 0.5, np.float32),
                                np.full((1,), 0.25, np.float32))
-    single = make_train_step(model, tx, transform, mesh, donate=False)
-    indexed = make_indexed_multi_train_step(model, tx, transform, mesh,
-                                            (28, 28, 1), donate=False)
+    binds = Bindings(mesh=mesh, model=model, tx=tx, transform=transform,
+                     image_shape=(28, 28, 1))
+    single = compile_train_step(Plan(engine="image", donate=False), binds)
+    indexed = compile_train_step(
+        Plan(engine="image", window="indexed", donate=False), binds)
 
     n, k, b = 256, 3, 32
     rng_np = np.random.default_rng(1)

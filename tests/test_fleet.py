@@ -396,6 +396,53 @@ def test_supervisor_request_stop_tears_down_and_reports_stopped(tmp_path):
     assert seen[0].failure_class == res.attempts[0].failure_class
 
 
+def test_fleet_hold_gates_hosts_on_scheduled_membership(tmp_path):
+    """The fleet clock gates both ways: ``hold.tick`` sits one publish
+    window past the next scheduled action, stays there until the
+    consensus supervisor has RESOLVED the change (hosts pace by the wall
+    clock, the control plane gets the CPU that is left: without the hold
+    a starved box lets the consensus host run its trace out before the
+    rescale reaches it), and goes when nothing is scheduled. A capacity
+    decision's change holds nobody."""
+    from types import SimpleNamespace
+
+    from tpu_dist.sim.runner import FleetSim
+    from tpu_dist.sim.worker import WINDOW_TICKS, _read_hold
+
+    sim = FleetSim(CI_SCENARIO, str(tmp_path))
+    hold = os.path.join(str(tmp_path), "hold.tick")
+    peers = {h: SimpleNamespace(register=lambda: None, leave=lambda: None)
+             for h in range(3)}
+    sim._members = {0, 1, 2}
+    csup = SimpleNamespace(mesh_view=SimpleNamespace(hosts=(0, 1, 2)))
+    sim._sups[sim.sc.consensus_host] = csup
+    pending = list(sim.actions)
+    assert [(a.tick, a.action) for a in pending] == \
+        [(56, "leave"), (120, "register")]
+
+    sim._publish_hold(pending, True)
+    assert _read_hold(hold) == 56 + WINDOW_TICKS
+    # the leave fires; the supervisor has not polled the new view yet
+    act = pending.pop(0)
+    sim._set_member(peers, act.host, False, act.tick)
+    sim._publish_hold(pending, True)
+    assert _read_hold(hold) == 56 + WINDOW_TICKS
+    csup.mesh_view = SimpleNamespace(hosts=(0, 2))      # resolved
+    sim._publish_hold(pending, True)
+    assert _read_hold(hold) == 120 + WINDOW_TICKS
+    act = pending.pop(0)
+    sim._set_member(peers, act.host, True, act.tick)
+    sim._publish_hold(pending, True)
+    assert _read_hold(hold) == 120 + WINDOW_TICKS       # unresolved
+    # a consensus host that is gone can resolve nothing: never wait on it
+    sim._publish_hold(pending, False)
+    assert _read_hold(hold) is None and not os.path.exists(hold)
+    # an autoscale decision's membership change is no scheduled action
+    sim._set_member(peers, 2, False)
+    sim._publish_hold(pending, True)
+    assert _read_hold(hold) is None
+
+
 # ---------------------------------------------------------------------------
 # ACCEPTANCE: the checked-in CI scenario end to end (CPU, real workers)
 

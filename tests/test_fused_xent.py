@@ -137,12 +137,13 @@ def test_loss_chunk_under_tensor_parallel_matches_dp():
     one tp+chunk step equals the dp full-logits step per-leaf."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from tpu_dist.engine.lm_steps import (make_lm_batches,
-                                          make_lm_train_step)
+    from tpu_dist.engine.lm_steps import make_lm_batches
     from tpu_dist.engine.state import TrainState
     from tpu_dist.models.transformer import tiny_lm
     from tpu_dist.ops import make_optimizer
     from tpu_dist.parallel.mesh import make_mesh, replicated
+    from tpu_dist.plan.compile import Bindings, compile_train_step
+    from tpu_dist.plan.ir import Plan
     from tpu_dist.parallel.tp import shard_lm_params
 
     V, L, B = 64, 32, 8
@@ -158,7 +159,9 @@ def test_loss_chunk_under_tensor_parallel_matches_dp():
     mesh_dp = make_mesh((8,), ("data",))
     st = jax.device_put(TrainState.create(params, {}, tx),
                         replicated(mesh_dp))
-    dp_step = make_lm_train_step(model, tx, mesh_dp, donate=False)
+    dp_step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh_dp, model=model, tx=tx))
     sh = NamedSharding(mesh_dp, P("data"))
     st_dp, _ = dp_step(st, jax.device_put(inputs, sh),
                        jax.device_put(targets, sh), key)
@@ -171,8 +174,9 @@ def test_loss_chunk_under_tensor_parallel_matches_dp():
         opt_state=jax.device_put(st2.opt_state,
                                  NamedSharding(mesh_tp, P())),
         loss_scale=None)
-    tp_step = make_lm_train_step(model, tx, mesh_tp, donate=False,
-                                 loss_chunk=16)
+    tp_step = compile_train_step(
+        Plan(engine="lm", donate=False, loss_chunk=16),
+        Bindings(mesh=mesh_tp, model=model, tx=tx))
     sh_tp = NamedSharding(mesh_tp, P("data"))
     st_tp, _ = tp_step(st2, jax.device_put(inputs, sh_tp),
                        jax.device_put(targets, sh_tp), key)
@@ -218,13 +222,14 @@ def test_loss_chunk_under_fsdp_matches_dp():
     fsdp+chunk step equals the replicated dp full-logits step per-leaf."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from tpu_dist.engine.lm_steps import (make_lm_batches,
-                                          make_lm_train_step)
+    from tpu_dist.engine.lm_steps import make_lm_batches
     from tpu_dist.engine.state import TrainState
     from tpu_dist.models.transformer import tiny_lm
     from tpu_dist.ops import make_optimizer
     from tpu_dist.parallel.fsdp import shard_state_fsdp
     from tpu_dist.parallel.mesh import make_mesh, replicated
+    from tpu_dist.plan.compile import Bindings, compile_train_step
+    from tpu_dist.plan.ir import Plan
 
     V, L, B = 64, 32, 8
     rng_np = np.random.RandomState(2)
@@ -239,14 +244,17 @@ def test_loss_chunk_under_fsdp_matches_dp():
     sh = NamedSharding(mesh, P("data"))
 
     st = jax.device_put(TrainState.create(params, {}, tx), replicated(mesh))
-    dp_step = make_lm_train_step(model, tx, mesh, donate=False)
+    dp_step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))
     st_dp, _ = dp_step(st, jax.device_put(inputs, sh),
                        jax.device_put(targets, sh), key)
 
     st_f = shard_state_fsdp(mesh, TrainState.create(params, {}, tx),
                             min_size=256)
-    f_step = make_lm_train_step(model, tx, mesh, donate=False,
-                                loss_chunk=16)
+    f_step = compile_train_step(
+        Plan(engine="lm", donate=False, loss_chunk=16),
+        Bindings(mesh=mesh, model=model, tx=tx))
     st_fs, _ = f_step(st_f, jax.device_put(inputs, sh),
                       jax.device_put(targets, sh), key)
 

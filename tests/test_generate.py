@@ -6,11 +6,13 @@ import numpy as np
 import pytest
 
 from tpu_dist.engine.generate import generate
-from tpu_dist.engine.lm_steps import make_lm_batches, make_lm_train_step
+from tpu_dist.engine.lm_steps import make_lm_batches
 from tpu_dist.engine.state import TrainState
 from tpu_dist.models.transformer import tiny_lm
 from tpu_dist.ops import make_optimizer
 from tpu_dist.parallel.mesh import make_mesh, replicated
+from tpu_dist.plan.compile import Bindings, compile_train_step
+from tpu_dist.plan.ir import Plan
 
 V, L = 64, 32
 
@@ -53,7 +55,9 @@ def test_trained_lm_generates_the_learned_rule():
     mesh = make_mesh((8,), ("data",))
     state = jax.device_put(TrainState.create(params, {}, tx),
                            replicated(mesh))
-    step = make_lm_train_step(lm, tx, mesh, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=lm, tx=tx))
 
     rng = np.random.default_rng(0)
     start = rng.integers(0, V, (16, 1))

@@ -13,12 +13,13 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tpu_dist.engine.lm_steps import (make_lm_batches, make_lm_sp_train_step,
-                                      make_lm_train_step)
+from tpu_dist.engine.lm_steps import make_lm_batches
 from tpu_dist.engine.state import TrainState
 from tpu_dist.models.transformer import tiny_lm
 from tpu_dist.ops import make_optimizer
 from tpu_dist.parallel.mesh import make_mesh, replicated
+from tpu_dist.plan.compile import Bindings, compile_train_step
+from tpu_dist.plan.ir import Plan
 from tpu_dist.parallel.tp import lm_param_specs, shard_lm_params
 
 B, L, V = 8, 64, 256
@@ -45,7 +46,9 @@ def _loss(m):
 def _run_dp(setup_data, mesh):
     model, params, tx, inputs, targets = setup_data
     st = jax.device_put(TrainState.create(params, {}, tx), replicated(mesh))
-    step = make_lm_train_step(model, tx, mesh, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))
     sh = NamedSharding(mesh, P("data"))
     s, m = step(st, jax.device_put(inputs, sh), jax.device_put(targets, sh),
                 jax.random.PRNGKey(1))
@@ -72,7 +75,9 @@ def test_tp_matches_dp(setup):
                     opt_state=jax.device_put(st.opt_state,
                                              NamedSharding(mesh, P())),
                     loss_scale=None)
-    step = make_lm_train_step(model, tx, mesh, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))
     sh = NamedSharding(mesh, P("data"))
     _, m = step(st, jax.device_put(inputs, sh), jax.device_put(targets, sh),
                 jax.random.PRNGKey(1))
@@ -86,8 +91,10 @@ def test_sp_ring_matches_dp(setup):
 
     mesh = make_mesh((2, 4), ("data", "seq"))
     st = jax.device_put(TrainState.create(params, {}, tx), replicated(mesh))
-    step = make_lm_sp_train_step(partial(tiny_lm, vocab_size=V, max_len=L),
-                                 tx, mesh, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", layout="sp", sync="explicit", donate=False),
+        Bindings(mesh=mesh, tx=tx,
+                 model_ctor=partial(tiny_lm, vocab_size=V, max_len=L)))
     sh = NamedSharding(mesh, P("data", "seq"))
     s, m = step(st, jax.device_put(inputs, sh), jax.device_put(targets, sh),
                 jax.random.PRNGKey(1))
@@ -108,7 +115,9 @@ def test_lm_learns_structured_sequence():
                         jnp.zeros((1, 32), jnp.int32), train=False)["params"]
     tx = make_optimizer(0.05, 0.9, 0.0, steps_per_epoch=1000)
     st = jax.device_put(TrainState.create(params, {}, tx), replicated(mesh))
-    step = make_lm_train_step(model, tx, mesh, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))
     sh = NamedSharding(mesh, P("data"))
 
     rng_np = np.random.default_rng(1)
@@ -139,7 +148,9 @@ def test_fsdp_matches_dp_and_stays_sharded(setup):
     st = shard_state_fsdp(mesh, TrainState.create(params, {}, tx))
     emb_spec = st.params["tok_emb"]["embedding"].sharding.spec
     assert emb_spec[0] == "data"  # actually sharded
-    step = make_lm_train_step(model, tx, mesh, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))
     sh = NamedSharding(mesh, P("data"))
     s_f, m = step(st, jax.device_put(inputs, sh), jax.device_put(targets, sh),
                   jax.random.PRNGKey(1))
@@ -161,7 +172,8 @@ def test_lm_eval_step_exact_metrics():
     """Eval metric sums equal a hand-computed forward (counts, not means)."""
     import numpy as np
     from tpu_dist.engine.lm_steps import (lm_loss_and_metrics,
-                                          make_lm_batches, make_lm_eval_step)
+                                          make_lm_batches)
+    from tpu_dist.plan.compile import compile_eval_step
     from tpu_dist.models.transformer import tiny_lm
     from tpu_dist.parallel.mesh import make_mesh
 
@@ -172,7 +184,7 @@ def test_lm_eval_step_exact_metrics():
     tokens = np.random.default_rng(0).integers(0, 32, (8, 17)).astype(np.int32)
     inputs, targets = make_lm_batches(tokens)
     mesh = make_mesh((8,), ("data",))
-    step = make_lm_eval_step(lm, mesh)
+    step = compile_eval_step(Plan(engine="lm"), Bindings(mesh=mesh, model=lm))
     m = jax.device_get(step(params, jnp.asarray(inputs), jnp.asarray(targets),
                             jnp.ones((inputs.shape[0],), jnp.float32)))
 

@@ -6,12 +6,14 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tpu_dist.engine.lm_steps import make_lm_batches, make_lm_train_step
+from tpu_dist.engine.lm_steps import make_lm_batches
 from tpu_dist.engine.state import TrainState
 from tpu_dist.models.moe import MoEMLP, MoETransformerLM
 from tpu_dist.ops import make_optimizer
 from tpu_dist.parallel.ep import ep_param_specs
 from tpu_dist.parallel.mesh import make_mesh, replicated
+from tpu_dist.plan.compile import Bindings, compile_train_step
+from tpu_dist.plan.ir import Plan
 
 V, L, B, E = 64, 32, 16, 4
 
@@ -58,7 +60,9 @@ def test_moe_lm_trains(moe_setup):
     model, params, tx, inputs, targets = moe_setup
     mesh = make_mesh((8,), ("data",))
     st = jax.device_put(TrainState.create(params, {}, tx), replicated(mesh))
-    step = make_lm_train_step(model, tx, mesh, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))
     sh = NamedSharding(mesh, P("data"))
     inputs_d, targets_d = jax.device_put(inputs, sh), jax.device_put(targets, sh)
     losses = []
@@ -79,7 +83,9 @@ def test_expert_parallel_matches_dp(moe_setup):
 
     mesh_dp = make_mesh((8,), ("data",))
     st = jax.device_put(TrainState.create(params, {}, tx), replicated(mesh_dp))
-    step = make_lm_train_step(model, tx, mesh_dp, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh_dp, model=model, tx=tx))
     sh = NamedSharding(mesh_dp, P("data"))
     _, m_dp = step(st, jax.device_put(inputs, sh), jax.device_put(targets, sh),
                    jax.random.PRNGKey(1))
@@ -92,7 +98,9 @@ def test_expert_parallel_matches_dp(moe_setup):
     mom_specs = [l.sharding.spec for l in jax.tree.leaves(st_ep.opt_state)
                  if hasattr(l, "ndim") and l.ndim == 3]
     assert mom_specs and all(s[0] == "expert" for s in mom_specs)
-    step_ep = make_lm_train_step(model, tx, mesh_ep, donate=False)
+    step_ep = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh_ep, model=model, tx=tx))
     sh_ep = NamedSharding(mesh_ep, P("data"))
     _, m_ep = step_ep(st_ep, jax.device_put(inputs, sh_ep),
                       jax.device_put(targets, sh_ep), jax.random.PRNGKey(1))
@@ -135,7 +143,9 @@ def test_top2_moe_lm_trains(moe_setup):
     mesh = make_mesh((8,), ("data",))
     state = jax.device_put(TrainState.create(params, {}, tx),
                            replicated(mesh))
-    step = make_lm_train_step(model, tx, mesh, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))
     sh = NamedSharding(mesh, P("data"))
     di, dt = jax.device_put(inputs, sh), jax.device_put(targets, sh)
     key = jax.random.PRNGKey(1)
@@ -219,7 +229,9 @@ def test_ep_actually_shards_expert_compute():
 
     def compiled(sharder):
         st = sharder(mesh, TrainState.create(params, {}, tx))
-        step = make_lm_train_step(moe, tx, mesh, donate=False)
+        step = compile_train_step(
+            Plan(engine="lm", donate=False),
+            Bindings(mesh=mesh, model=moe, tx=tx))
         return step.lower(st, jax.device_put(i, sh), jax.device_put(t, sh),
                           jax.random.PRNGKey(1)).compile()
 
@@ -258,7 +270,9 @@ def test_moe_remat_matches_no_remat(moe_setup):
                             train=False)["params"]
         st = jax.device_put(TrainState.create(params, {}, tx),
                             replicated(mesh))
-        step = make_lm_train_step(model, tx, mesh, donate=False)
+        step = compile_train_step(
+            Plan(engine="lm", donate=False),
+            Bindings(mesh=mesh, model=model, tx=tx))
         lowered = step.lower(st, di, dt, jax.random.PRNGKey(1)).compile()
         st, m = step(st, di, dt, jax.random.PRNGKey(1))
         return (jax.device_get(st.params), jax.device_get(m),
@@ -293,7 +307,9 @@ def test_moe_tp_composition_matches_dp(moe_setup):
     mesh_dp = make_mesh((8,), ("data",))
     st = jax.device_put(TrainState.create(params, {}, tx),
                         replicated(mesh_dp))
-    step = make_lm_train_step(model, tx, mesh_dp, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh_dp, model=model, tx=tx))
     sh = NamedSharding(mesh_dp, P("data"))
     st_dp, m_dp = step(st, jax.device_put(inputs, sh),
                        jax.device_put(targets, sh), jax.random.PRNGKey(1))
@@ -306,7 +322,9 @@ def test_moe_tp_composition_matches_dp(moe_setup):
     assert local[0] == w_in.shape[0] // 2 and local[2] == w_in.shape[2] // 2
     qkv = st_tp.params["block0"]["qkv"]["kernel"]
     assert qkv.sharding.spec == P(None, "model")
-    step_tp = make_lm_train_step(model, tx, mesh, donate=False)
+    step_tp = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))
     sh_tp = NamedSharding(mesh, P("data"))
     st_tp, m_tp = step_tp(st_tp, jax.device_put(inputs, sh_tp),
                           jax.device_put(targets, sh_tp),
@@ -383,8 +401,6 @@ def test_moe_sp_composition_matches_dp():
     equals one dp step parameter-for-parameter."""
     from functools import partial
 
-    from tpu_dist.engine.lm_steps import make_lm_sp_train_step
-
     rng_np = np.random.default_rng(3)
     tokens = rng_np.integers(0, V, (8, L + 1)).astype(np.int32)
     inputs, targets = make_lm_batches(tokens)
@@ -402,8 +418,9 @@ def test_moe_sp_composition_matches_dp():
     mesh_dp = make_mesh((8,), ("data",))
     st = jax.device_put(TrainState.create(params, {}, tx),
                         replicated(mesh_dp))
-    dp_step = make_lm_train_step(model, tx, mesh_dp, aux_weight=0.0,
-                                 donate=False)
+    dp_step = compile_train_step(
+        Plan(engine="lm", aux_weight=0.0, donate=False),
+        Bindings(mesh=mesh_dp, model=model, tx=tx))
     sh = NamedSharding(mesh_dp, P("data"))
     st_dp, _ = dp_step(st, jax.device_put(inputs, sh),
                        jax.device_put(targets, sh), key)
@@ -411,8 +428,10 @@ def test_moe_sp_composition_matches_dp():
     mesh_sp = make_mesh((2, 4), ("data", "seq"))
     st2 = jax.device_put(TrainState.create(params, {}, tx),
                          replicated(mesh_sp))
-    sp_step = make_lm_sp_train_step(ctor, tx, mesh_sp, aux_weight=0.0,
-                                    donate=False)
+    sp_step = compile_train_step(
+        Plan(engine="lm", layout="sp", sync="explicit", aux_weight=0.0,
+             donate=False),
+        Bindings(mesh=mesh_sp, model_ctor=ctor, tx=tx))
     sh_sp = NamedSharding(mesh_sp, P("data", "seq"))
     st_sp, _ = sp_step(st2, jax.device_put(inputs, sh_sp),
                        jax.device_put(targets, sh_sp), key)
@@ -470,8 +489,9 @@ def test_moe_pp_gpipe_matches_dp():
     mesh_dp = make_mesh((8,), ("data",))
     st = jax.device_put(TrainState.create(params, {}, tx),
                         replicated(mesh_dp))
-    dp_step = make_lm_train_step(model, tx, mesh_dp, aux_weight=0.0,
-                                 donate=False)
+    dp_step = compile_train_step(
+        Plan(engine="lm", aux_weight=0.0, donate=False),
+        Bindings(mesh=mesh_dp, model=model, tx=tx))
     sh = NamedSharding(mesh_dp, P("data"))
     st_dp, m_dp = dp_step(st, jax.device_put(inputs, sh),
                           jax.device_put(targets, sh), key)

@@ -201,8 +201,8 @@ def test_vit_ring_forward_parity():
 def test_lm_bucketed_step_matches_jit_dp():
     """One optimizer step through the explicit bucketed-sync dp step ==
     the jit/GSPMD dp step (loss equal, updated params allclose)."""
-    from tpu_dist.engine.lm_steps import (make_lm_shard_map_train_step,
-                                          make_lm_train_step)
+    from tpu_dist.plan.compile import Bindings, compile_train_step
+    from tpu_dist.plan.ir import Plan
     from tpu_dist.engine.state import TrainState
     from tpu_dist.ops import make_optimizer
     from tpu_dist.parallel.mesh import replicated
@@ -218,10 +218,14 @@ def test_lm_bucketed_step_matches_jit_dp():
     state = jax.device_put(TrainState.create(params, {}, tx),
                            replicated(mesh))
     key = jax.random.PRNGKey(1)
-    st_jit, m_jit = make_lm_train_step(model, tx, mesh, donate=False)(
+    st_jit, m_jit = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))(
         state, inputs, targets, key)
-    st_b, m_b = make_lm_shard_map_train_step(
-        model, tx, mesh, grad_bucket_mb=0.0005, donate=False)(
+    st_b, m_b = compile_train_step(
+        Plan(engine="lm", sync="explicit", grad_bucket_mb=0.0005,
+             donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))(
         state, inputs, targets, key)
     assert float(m_jit["loss_sum"]) == pytest.approx(
         float(m_b["loss_sum"]), rel=1e-6)
@@ -248,8 +252,13 @@ def test_overlap_knob_validation():
         LMTrainer(LMConfig(fsdp=True, grad_bucket_mb=25.0, **lm))
     img = dict(dataset="synthetic-mnist", arch="lenet", epochs=1,
                batch_size=16, synth_train_size=32, synth_val_size=16)
-    with pytest.raises(ValueError, match="shard_map"):
+    # the mode exclusions are the plan's (plan.ir.Plan.validate), raised
+    # when the trainer derives its plan; PlanError is a ValueError
+    with pytest.raises(ValueError, match="sync='explicit'"):
         Trainer(TrainConfig(grad_bucket_mb=25.0, **img))
+    with pytest.raises(ValueError, match="lm tp/sp layouts"):
+        LMTrainer(LMConfig(mesh_shape=(2, 4), mesh_axes=("data", "model"),
+                           grad_bucket_mb=25.0, **lm))
     with pytest.raises(ValueError, match="vit"):
         Trainer(TrainConfig(variant="shard_map", tp_impl="ring", **img))
     with pytest.raises(ValueError, match="num_heads"):
@@ -307,8 +316,8 @@ def test_ring_int8_quant_composition():
     the collective matmul chunks. Scales are per-shard (finer than GSPMD's
     global per-row amax), so parity with the GSPMD int8 path is loss-level,
     and both track the fp loss closely at init."""
-    from tpu_dist.engine.lm_steps import (make_lm_train_step,
-                                          make_lm_tp_ring_train_step)
+    from tpu_dist.plan.compile import Bindings, compile_train_step
+    from tpu_dist.plan.ir import Plan
     from tpu_dist.engine.state import TrainState
     from tpu_dist.ops import make_optimizer
     from tpu_dist.parallel.mesh import replicated
@@ -333,11 +342,15 @@ def test_ring_int8_quant_composition():
                   opt_state=jax.device_put(tp_state.opt_state,
                                            NamedSharding(mesh, P())),
                   loss_scale=None)
-    gspmd_step = make_lm_train_step(model, tx, mesh, donate=False)
+    gspmd_step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))
     ring_state = jax.device_put(TrainState.create(params, {}, tx),
                                 replicated(mesh))
-    ring_step = make_lm_tp_ring_train_step(
-        model.clone(tp_impl="ring"), tx, mesh, donate=False)
+    ring_step = compile_train_step(
+        Plan(engine="lm", layout="tp", sync="explicit", tp_impl="ring",
+             donate=False),
+        Bindings(mesh=mesh, model=model, tx=tx))
     losses = {"gspmd": [], "ring": []}
     for _ in range(3):
         tp_state, m1 = gspmd_step(tp_state, inputs, targets, key)

@@ -7,10 +7,12 @@ Three layers, cheapest first:
   winner over the checked-in canned measurement file, byte-determinism,
   trial-specificity) exercise modules that must import under the
   scripts/lint.sh jax blocker;
-* **CPU parity** — ``compile_train_step(plan)`` built DIRECTLY from a
-  Plan matches every legacy ``make_*`` builder's loss/param trajectory
-  bit-for-bit (the builders are shims over the compiler now; these pin
-  the plan-field -> builder-argument mapping);
+* **config -> plan** — ``plan_from_config`` of each trainer mode's config
+  gives exactly the expected Plan (the one decision point the trainers
+  compile), refuses what ``Plan.validate`` refuses, and inverts
+  ``apply_plan_to_config`` over the tuner's whole candidate space;
+* **CPU parity** — every mode lowers through ``compile_train_step(plan)``
+  and the flavors agree on the loss/param trajectory;
 * **engine acceptance** — both engines accept an emitted plan file via
   the new ``plan`` config knob, stamp it into run_start + a ``plan``
   ledger event, and ledger_report renders it.
@@ -30,7 +32,8 @@ import pytest
 from tpu_dist.plan.ir import (DEFAULT_OPT_BLOCK_ROWS, DEFAULT_QUANT_BLOCK,
                               KNOWN_AXES, Plan, PlanError,
                               apply_plan_to_config, load_plan_file,
-                              plan_for_device, plan_hash, plan_knob_summary)
+                              plan_for_device, plan_from_config, plan_hash,
+                              plan_knob_summary)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TUNE_CI = os.path.join(REPO, "scripts", "tune_ci.json")
@@ -275,100 +278,169 @@ def test_tools_tune_cli_deterministic_and_ledger(tmp_path):
     assert tunes[0]["candidates"] > 10 and tunes[0]["measured"] is True
 
 
-# ---- every maker's plan mapping, pinned exactly (no compiles) -------------
+# ---- config -> plan: every trainer mode, pinned exactly (no compiles) ------
 
-def test_all_makers_construct_expected_plans(monkeypatch):
-    """Intercept the compiler entry and pin the EXACT Plan every legacy
-    ``make_*`` builder constructs — complete shim coverage in
-    milliseconds; the runtime parity tests below then prove the lowering
-    itself on one representative per mode."""
-    import tpu_dist.plan.compile as pc
-    from tpu_dist.engine import lm_steps, steps
+DP4 = {"data": 4}
+DP_TP = {"data": 2, "model": 2}
+DP_SP = {"data": 2, "seq": 2}
+IMG = dict(engine="image")
+LM = dict(engine="lm")
+SP = dict(engine="lm", layout="sp", sync="explicit")
+RING = dict(layout="tp", sync="explicit", tp_impl="ring")
 
-    captured = {}
+# (id, config kind, config fields, mesh axis sizes, observed window, Plan)
+CONFIG_MODES = [
+    ("img-jit", "image", dict(health="skip"), DP4, "none",
+     Plan(**IMG, health="skip")),
+    ("img-jit-drops-mean-path-knobs", "image",
+     dict(grad_compression="bf16", gradient_predivide_factor=2.0), DP4,
+     "none", Plan(**IMG)),
+    ("img-stacked", "image", dict(steps_per_dispatch=4), DP4, "stacked",
+     Plan(**IMG, window="stacked", steps_per_dispatch=4)),
+    ("img-indexed", "image",
+     dict(steps_per_dispatch=16, precision="bf16"), {"data": 1}, "indexed",
+     Plan(**IMG, window="indexed", steps_per_dispatch=16,
+          precision="bf16")),
+    ("img-indexed-k1", "image", dict(data_placement="device"), DP4,
+     "indexed", Plan(**IMG, window="indexed")),
+    ("img-accum", "image", dict(grad_accum_steps=4), DP4, "none",
+     Plan(**IMG, grad_accum_steps=4)),
+    ("img-shard_map-compression-predivide", "image",
+     dict(variant="shard_map", grad_compression="bf16",
+          gradient_predivide_factor=2.0), DP4, "none",
+     Plan(**IMG, sync="explicit", grad_compression="bf16",
+          predivide_factor=2.0)),
+    ("img-shard_map-bucket", "image",
+     dict(variant="shard_map", grad_bucket_mb=25.0), DP4, "none",
+     Plan(**IMG, sync="explicit", grad_bucket_mb=25.0)),
+    ("img-shard_map-adasum", "image",
+     dict(variant="shard_map", adasum=True), DP4, "none",
+     Plan(**IMG, sync="explicit", adasum=True)),
+    ("img-ring", "image",
+     dict(variant="shard_map", tp_impl="ring", quant="int8"), DP_TP, "none",
+     Plan(**IMG, **RING, quant="int8")),
+    ("lm-gspmd", "lm", dict(loss_chunk=64, moe_aux_weight=0.5), DP4, "none",
+     Plan(**LM, loss_chunk=64, aux_weight=0.5)),
+    ("lm-gspmd-fsdp-ep-stage-are-not-plan-layouts", "lm", dict(fsdp=True),
+     {"data": 2, "expert": 2, "stage": 1}, "none", Plan(**LM)),
+    ("lm-tp-gspmd", "lm", dict(quant="int8", precision="bf16"), DP_TP,
+     "none", Plan(**LM, layout="tp", quant="int8", precision="bf16")),
+    ("lm-accum", "lm", dict(grad_accum_steps=2, loss_chunk=32), DP4, "none",
+     Plan(**LM, grad_accum_steps=2, loss_chunk=32)),
+    ("lm-bucket", "lm", dict(grad_bucket_mb=25.0), DP4, "none",
+     Plan(**LM, sync="explicit", grad_bucket_mb=25.0)),
+    ("lm-bucket-indexed", "lm",
+     dict(grad_bucket_mb=25.0, steps_per_dispatch=2), DP4, "indexed",
+     Plan(**LM, sync="explicit", grad_bucket_mb=25.0, window="indexed",
+          steps_per_dispatch=2)),
+    ("lm-ring", "lm", dict(tp_impl="ring", moe_aux_weight=0.0), DP_TP,
+     "none", Plan(**LM, **RING, aux_weight=0.0)),
+    ("lm-ring-needs-a-model-axis", "lm", dict(tp_impl="ring"), DP4, "none",
+     Plan(**LM)),
+    ("lm-sp", "lm", dict(loss_chunk=8), DP_SP, "none",
+     Plan(**SP, loss_chunk=8)),
+    ("lm-sp-indexed", "lm", dict(steps_per_dispatch=2), DP_SP, "indexed",
+     Plan(**SP, window="indexed", steps_per_dispatch=2)),
+    ("lm-indexed", "lm", dict(steps_per_dispatch=2, health="halt"), DP4,
+     "indexed",
+     Plan(**LM, window="indexed", steps_per_dispatch=2, health="halt")),
+]
 
-    def fake_train(plan, binds):
-        captured["plan"], captured["binds"] = plan, binds
-        return "train-stub"
 
-    def fake_eval(plan, binds):
-        captured["plan"], captured["binds"] = plan, binds
-        return "eval-stub"
+def _config(kind, fields):
+    from tpu_dist.configs import LMConfig, TrainConfig
 
-    monkeypatch.setattr(pc, "compile_train_step", fake_train)
-    monkeypatch.setattr(pc, "compile_eval_step", fake_eval)
-    MESH, MODEL, TX, TR = object(), object(), object(), object()
+    return (TrainConfig if kind == "image" else LMConfig)(**fields)
 
-    def check(fn, args, kwargs, expect, want="train-stub"):
-        captured.clear()
-        assert fn(*args, **kwargs) == want
-        assert captured["plan"] == expect, fn.__name__
-        assert captured["binds"].mesh is MESH
 
-    img = dict(engine="image")
-    check(steps.make_train_step, (MODEL, TX, TR, MESH),
-          dict(health="skip"), Plan(**img, health="skip"))
-    check(steps.make_multi_train_step, (MODEL, TX, TR, MESH), {},
-          Plan(**img, window="stacked"))
-    check(steps.make_indexed_multi_train_step,
-          (MODEL, TX, TR, MESH, (8, 8, 1)), dict(donate=False),
-          Plan(**img, window="indexed", donate=False))
-    check(steps.make_grad_accum_train_step, (MODEL, TX, TR, MESH), {},
-          Plan(**img, grad_accum_steps=2))
-    check(steps.make_shard_map_train_step, (MODEL, TX, TR, MESH),
-          dict(grad_compression="bf16", predivide_factor=2.0,
-               grad_bucket_mb=25.0),
-          Plan(**img, sync="explicit", grad_compression="bf16",
-               predivide_factor=2.0, grad_bucket_mb=25.0))
-    check(steps.make_shard_map_train_step, (MODEL, TX, TR, MESH),
-          dict(model_axis="model"),
-          Plan(**img, sync="explicit", layout="tp", tp_impl="ring"))
-    check(steps.make_eval_step, (MODEL, TR, MESH), {}, Plan(**img),
-          want="eval-stub")
-    check(steps.make_indexed_eval_step, (MODEL, TR, MESH, (8, 8, 1)), {},
-          Plan(**img, window="indexed"), want="eval-stub")
+@pytest.mark.parametrize("kind,fields,mesh_shape,window,expect",
+                         [c[1:] for c in CONFIG_MODES],
+                         ids=[c[0] for c in CONFIG_MODES])
+def test_plan_from_config_modes(kind, fields, mesh_shape, window, expect):
+    """The ONE decision from a config to a step program: each trainer
+    mode's config, on its mesh, with the window the trainer observed,
+    gives exactly this Plan (what the trainers hand the compiler)."""
+    assert plan_from_config(_config(kind, fields), mesh_shape,
+                            window) == expect
 
-    lm = dict(engine="lm")
-    check(lm_steps.make_lm_train_step, (MODEL, TX, MESH),
-          dict(aux_weight=0.5, loss_chunk=64),
-          Plan(**lm, aux_weight=0.5, loss_chunk=64))
-    check(lm_steps.make_lm_grad_accum_train_step, (MODEL, TX, MESH), {},
-          Plan(**lm, grad_accum_steps=2))
-    check(lm_steps.make_lm_shard_map_train_step, (MODEL, TX, MESH), {},
-          Plan(**lm, sync="explicit", grad_bucket_mb=25.0))
-    check(lm_steps.make_lm_tp_ring_train_step, (MODEL, TX, MESH), {},
-          Plan(**lm, sync="explicit", layout="tp", tp_impl="ring"))
-    check(lm_steps.make_lm_explicit_indexed_multi_train_step,
-          (MODEL, MESH), {},
-          Plan(**lm, sync="explicit", window="indexed",
-               steps_per_dispatch=2))
-    check(lm_steps.make_lm_indexed_multi_train_step, (MODEL, TX, MESH),
-          dict(health="halt"),
-          Plan(**lm, window="indexed", steps_per_dispatch=2,
-               health="halt"))
-    check(lm_steps.make_lm_eval_step, (MODEL, MESH), dict(loss_chunk=32),
-          Plan(**lm, loss_chunk=32), want="eval-stub")
-    check(lm_steps.make_lm_indexed_eval_step, (MODEL, MESH), {},
-          Plan(**lm, window="indexed", steps_per_dispatch=2),
-          want="eval-stub")
-    sp = dict(engine="lm", layout="sp", sync="explicit")
-    check(lm_steps.make_lm_sp_train_step, (MODEL, TX, MESH), {},
-          Plan(**sp))
-    check(lm_steps.make_lm_sp_indexed_multi_train_step,
-          (MODEL, TX, MESH), {},
-          Plan(**sp, window="indexed", steps_per_dispatch=2))
-    check(lm_steps.make_lm_sp_eval_step, (MODEL, MESH), {}, Plan(**sp),
-          want="eval-stub")
-    check(lm_steps.make_lm_sp_indexed_eval_step, (MODEL, MESH), {},
-          Plan(**sp, window="indexed", steps_per_dispatch=2),
-          want="eval-stub")
+
+# (id, config kind, config fields, mesh axis sizes, window, message)
+CONFIG_REFUSALS = [
+    ("img-unknown-variant", "image", dict(variant="pmap"), DP4, "none",
+     "variant"),
+    ("img-unknown-health", "image", dict(health="panic"), DP4, "none",
+     "health"),
+    ("img-jit-bucket", "image", dict(grad_bucket_mb=25.0), DP4, "none",
+     "sync='explicit'"),
+    ("img-jit-adasum", "image", dict(adasum=True), DP4, "none", "adasum"),
+    ("img-jit-ring", "image", dict(tp_impl="ring"), DP_TP, "none",
+     "tp_impl='ring'"),
+    ("img-ring-without-model-axis", "image",
+     dict(variant="shard_map", tp_impl="ring"), DP4, "none", "mesh axis"),
+    ("img-accum-with-window", "image",
+     dict(grad_accum_steps=2, steps_per_dispatch=4), DP4, "none",
+     "mutually exclusive"),
+    ("img-accum-shard_map", "image",
+     dict(grad_accum_steps=2, variant="shard_map"), DP4, "none", "jit"),
+    ("img-shard_map-window", "image",
+     dict(variant="shard_map", steps_per_dispatch=4), DP4, "stacked",
+     "compiler-partitioned"),
+    ("img-shard_map-device-data", "image",
+     dict(variant="shard_map", data_placement="device"), DP4, "indexed",
+     "compiler-partitioned"),
+    ("lm-unknown-tp-impl", "lm", dict(tp_impl="nccl"), DP_TP, "none",
+     "tp_impl"),
+    ("lm-unknown-health", "lm", dict(health="panic"), DP4, "none", "health"),
+    ("lm-tp-bucket", "lm", dict(grad_bucket_mb=25.0), DP_TP, "none",
+     "lm tp/sp layouts"),
+    ("lm-sp-bucket", "lm", dict(grad_bucket_mb=25.0), DP_SP, "none",
+     "lm tp/sp layouts"),
+    ("lm-sp-accum", "lm", dict(grad_accum_steps=2), DP_SP, "none", "jit"),
+    ("lm-ring-accum", "lm", dict(tp_impl="ring", grad_accum_steps=2),
+     DP_TP, "none", "jit"),
+    ("lm-accum-indexed", "lm", dict(grad_accum_steps=2), DP4, "indexed",
+     "mutually exclusive"),
+]
+
+
+@pytest.mark.parametrize("kind,fields,mesh_shape,window,message",
+                         [c[1:] for c in CONFIG_REFUSALS],
+                         ids=[c[0] for c in CONFIG_REFUSALS])
+def test_plan_from_config_refuses(kind, fields, mesh_shape, window,
+                                  message):
+    """The mode exclusions have ONE home: the trainers raise none of
+    these themselves, they surface Plan.validate's (a ValueError)."""
+    with pytest.raises(PlanError, match=message):
+        plan_from_config(_config(kind, fields), mesh_shape, window)
+
+
+@pytest.mark.parametrize("engine,devices", [("image", 1), ("image", 8),
+                                            ("lm", 1), ("lm", 8)])
+def test_plan_config_roundtrip_over_tune_space(engine, devices):
+    """plan_from_config inverts apply_plan_to_config over everything the
+    tuner can emit: write a candidate into a config, read the plan the
+    config runs back out. The three switches activate_plan owns have no
+    config field (they are process-level trace-time switches), so they
+    are compared as carried."""
+    import dataclasses
+
+    from tpu_dist.plan.tune import default_space
+
+    base = _config(engine, {})
+    space = default_space(engine, devices)
+    assert len(space) > 10
+    for p in space:
+        cfg = apply_plan_to_config(base, p)
+        got = plan_from_config(cfg, {"data": devices}, p.window)
+        assert dataclasses.replace(
+            got, fused_quant=p.fused_quant, quant_block=p.quant_block,
+            opt_block_rows=p.opt_block_rows) == p, plan_hash(p)
 
 
 # ---- CPU loss parity: every mode through the ONE compiler -----------------
-# The capture test above pins bit-for-bit equivalence with the legacy
-# builders structurally (a maker IS compile_train_step of its pinned plan
-# — there is no other code path); the tests below prove the LOWERINGS
-# themselves: every mode (jit, shard_map/bucketed, windowed, ring, sp,
+# The table above pins which plan each trainer mode compiles; the tests
+# below prove the LOWERINGS themselves: every mode (jit,
+# shard_map/bucketed, windowed, ring, sp,
 # × quant) trains through compile(plan) and the flavors agree on the
 # loss trajectory. Sub-meshes (4 of the 8 virtual devices) keep the SPMD
 # compiles cheap — tier-1 budget.
@@ -472,7 +544,7 @@ def test_lm_plan_loss_parity_across_modes(clean_plan_globals):
     mesh_ring = make_mesh((2, 2), ("data", "model"), devices=devs)
     ring_step = _plan_step(
         Plan(engine="lm", sync="explicit", layout="tp", tp_impl="ring"),
-        mesh=mesh_ring, model=model.clone(tp_impl="ring"), tx=tx)
+        mesh=mesh_ring, model=model, tx=tx)
     s = fresh()
     s, rm = ring_step(s, inp, tgt, rng)
     assert float(rm["loss_sum"]) == pytest.approx(base_losses[0], rel=2e-4)
@@ -551,7 +623,7 @@ def test_image_plan_loss_parity_across_modes():
     _leaves_close(t.params, s.params, rtol=1e-4)
 
     # stacked window: one 2-step dispatch == the 2 sequential jit steps
-    # (identical rng folds — the make_multi_train_step contract)
+    # (identical rng folds — the stacked lowering's contract)
     w_step = _plan_step(Plan(engine="image", window="stacked",
                              steps_per_dispatch=2), **binds)
     w = fresh()
@@ -561,15 +633,13 @@ def test_image_plan_loss_parity_across_modes():
         float(m1["loss_sum"]) + float(m2["loss_sum"]), rel=1e-6)
     _leaves_close(w.params, s.params, rtol=1e-6)
 
-    # eval lowering via the public lazy pair (compile_plan/CompiledPlan —
-    # same lowering as compile_eval_step, built on first access, cached)
-    from tpu_dist.plan.compile import compile_plan
+    # the eval lowering: forward-only, needs neither tx nor a train
+    # transform binding
+    from tpu_dist.plan.compile import compile_eval_step
 
-    cp = compile_plan(Plan(engine="image"),
-                      Bindings(mesh=mesh, model=model,
-                               eval_transform=transform))
-    ev = cp.eval_step
-    assert cp.eval_step is ev          # lazy + cached
+    ev = compile_eval_step(Plan(engine="image"),
+                           Bindings(mesh=mesh, model=model,
+                                    eval_transform=transform))
     out = ev(params, bs, imgs, lbls, np.ones(8, np.float32))
     logits = model.apply({"params": params, "batch_stats": bs},
                          transform(imgs, None), train=False)

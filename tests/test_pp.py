@@ -10,7 +10,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpu_dist.engine.lm_steps import make_lm_batches, make_lm_train_step
+from tpu_dist.engine.lm_steps import make_lm_batches
 from tpu_dist.engine.state import TrainState
 from tpu_dist.models.transformer import tiny_lm
 from tpu_dist.ops import make_optimizer
@@ -18,6 +18,8 @@ from tpu_dist.parallel.mesh import make_mesh, replicated
 from tpu_dist.parallel.pp import (make_lm_pp_train_step,
                                   shard_state_pp, stack_pipeline_params,
                                   unstack_pipeline_params)
+from tpu_dist.plan.compile import Bindings, compile_train_step
+from tpu_dist.plan.ir import Plan
 
 V, L, B, D = 64, 32, 8, 64
 
@@ -89,7 +91,9 @@ def test_pp_step_matches_dp(mesh_shape, axes, microbatches, schedule):
     mesh_dp = make_mesh((1,), ("data",), devices=jax.devices()[:1])
     st_dp = jax.device_put(TrainState.create(params, {}, tx),
                            replicated(mesh_dp))
-    dp_step = make_lm_train_step(lm, tx, mesh_dp, donate=False)
+    dp_step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh_dp, model=lm, tx=tx))
     sh = jax.sharding.NamedSharding(mesh_dp, jax.sharding.PartitionSpec("data"))
     st_dp, m_dp = dp_step(st_dp, jax.device_put(inputs, sh),
                           jax.device_put(targets, sh), key)
@@ -137,7 +141,9 @@ def test_pp_1f1b_loss_chunk_matches_dp():
     mesh_dp = make_mesh((1,), ("data",), devices=jax.devices()[:1])
     st_dp = jax.device_put(TrainState.create(params, {}, tx),
                            replicated(mesh_dp))
-    dp_step = make_lm_train_step(lm, tx, mesh_dp, donate=False)
+    dp_step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh_dp, model=lm, tx=tx))
     sh = jax.sharding.NamedSharding(mesh_dp, jax.sharding.PartitionSpec("data"))
     st_dp, m_dp = dp_step(st_dp, jax.device_put(inputs, sh),
                           jax.device_put(targets, sh), key)
@@ -272,7 +278,9 @@ def test_pp_tp_composition_matches_dp(schedule):
     mesh_dp = make_mesh((1,), ("data",), devices=jax.devices()[:1])
     st_dp = jax.device_put(TrainState.create(params, {}, tx),
                            replicated(mesh_dp))
-    dp_step = make_lm_train_step(lm, tx, mesh_dp, donate=False)
+    dp_step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh_dp, model=lm, tx=tx))
     sh = jax.sharding.NamedSharding(mesh_dp, jax.sharding.PartitionSpec("data"))
     st_dp, m_dp = dp_step(st_dp, jax.device_put(inputs, sh),
                           jax.device_put(targets, sh), key)

@@ -15,7 +15,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from tpu_dist.engine.generate import generate
-from tpu_dist.engine.lm_steps import make_lm_batches, make_lm_train_step
+from tpu_dist.engine.lm_steps import make_lm_batches
 from tpu_dist.engine.state import TrainState
 from tpu_dist.models.transformer import tiny_lm
 from tpu_dist.ops import make_optimizer
@@ -23,6 +23,8 @@ from tpu_dist.ops.quant import (QUANT_MODES, dequantize, quant_einsum,
                                 quantize_int8, validate_quant,
                                 wo_fake_quant, wo_quantize_params)
 from tpu_dist.parallel.mesh import make_mesh, replicated
+from tpu_dist.plan.compile import Bindings, compile_train_step
+from tpu_dist.plan.ir import Plan
 from tpu_dist.parallel.tp import shard_lm_params
 
 V, L = 64, 32
@@ -152,7 +154,9 @@ def _train(lm, params, mesh, steps=60, lr=0.05):
     tx = make_optimizer(lr, 0.9, 0.0, steps_per_epoch=1000)
     state = jax.device_put(TrainState.create(params, {}, tx),
                            replicated(mesh))
-    step = make_lm_train_step(lm, tx, mesh, donate=False)
+    step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh, model=lm, tx=tx))
     inputs, targets = make_lm_batches(_affine_rows())
     sh = NamedSharding(mesh, P("data"))
     di, dt = jax.device_put(inputs, sh), jax.device_put(targets, sh)
@@ -272,7 +276,9 @@ def test_int8_train_step_under_dp_tp_mesh():
     def run(mesh, place):
         st = TrainState.create(params, {}, tx)
         st = place(mesh, st)
-        step = make_lm_train_step(lm, tx, mesh, donate=False)
+        step = compile_train_step(
+            Plan(engine="lm", donate=False),
+            Bindings(mesh=mesh, model=lm, tx=tx))
         sh = NamedSharding(mesh, P("data"))
         _, m = step(st, jax.device_put(inputs, sh),
                     jax.device_put(targets, sh), key)
@@ -337,7 +343,9 @@ def test_quant_pp_step_matches_dp(quant, schedule):
     mesh_dp = make_mesh((1,), ("data",), devices=jax.devices()[:1])
     st_dp = jax.device_put(TrainState.create(params, {}, tx),
                            replicated(mesh_dp))
-    dp_step = make_lm_train_step(lm, tx, mesh_dp, donate=False)
+    dp_step = compile_train_step(
+        Plan(engine="lm", donate=False),
+        Bindings(mesh=mesh_dp, model=lm, tx=tx))
     sh = NamedSharding(mesh_dp, P("data"))
     _, m_dp = dp_step(st_dp, jax.device_put(inputs, sh),
                       jax.device_put(targets, sh), key)

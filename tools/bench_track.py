@@ -18,9 +18,9 @@ caught at review time.
 
 Accepted inputs per file (positional args override the default glob):
 the wrapper format above, or a raw headline JSON object (``{"metric",
-"value", ...}`` — what ``bench.py`` prints) via ``--headline`` for the
+"value", ...}``) via ``--headline`` for the
 run-under-test. Different metric names track independently (quant/tp_impl
-variants publish their own names by design — bench.py), so a variant run
+variants publish their own names by design), so a variant run
 never gates the bf16 headline. Stdlib only: runs in CI, on a login host,
 anywhere.
 """
@@ -445,7 +445,7 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="directory holding BENCH_r*.json (default: repo "
                     "root)")
     ap.add_argument("--headline", default="",
-                    help="a raw bench.py headline JSON for the run under "
+                    help="a raw headline JSON for the run under "
                     "test, appended as the newest point")
     ap.add_argument("--threshold-pct", type=float, default=5.0,
                     help="fail when the newest point drops more than this "

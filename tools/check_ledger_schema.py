@@ -9,7 +9,7 @@ callers/CI muscle memory and keeps the original API surface:
 * :func:`load_schema` — EVENT_SCHEMA extracted from ledger.py by AST;
 * :func:`check_file` — one file's violations as ``rel:line: msg`` strings;
 * :func:`check_tree` — the historical sweep (tpu_dist, tools, tests,
-  scripts, bench.py), same string format;
+  scripts), same string format;
 * CLI: ``python tools/check_ledger_schema.py [root]`` — prints violations,
   exits non-zero if any.
 
@@ -33,7 +33,6 @@ from tools.distlint.rules import check_emit_calls  # noqa: E402
 
 SCHEMA_FILE = os.path.join("tpu_dist", "obs", "ledger.py")
 CHECKED = ("tpu_dist", "tools", "tests", "scripts")
-CHECKED_FILES = ("bench.py",)
 FORWARD_MARK = "ledger-schema: forward"
 
 
@@ -63,8 +62,6 @@ def check_tree(root: str = ROOT) -> list:
     distlint's walker skips fixture dirs, where deliberately bad emit
     calls live as linter test data)."""
     paths = [d for d in CHECKED if os.path.isdir(os.path.join(root, d))]
-    paths += [f for f in CHECKED_FILES
-              if os.path.exists(os.path.join(root, f))]
     result = lint_files(paths, root=root, select=["DL006"])
     return [f"{f.path}:{f.line}: {f.message}" for f in result.findings]
 

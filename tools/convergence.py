@@ -2,7 +2,7 @@
 """Steps/seconds to a val top-1 threshold (the convergence north star).
 
 The reference's only QA signal was convergence watched by hand
-(reference README_EN.md:10 "Tested..."); BASELINE.json's north star is
+(reference README_EN.md:10 "Tested..."); this repo's first north star was
 time-to-90% top-1. This tool measures it on the learnable synthetic CIFAR
 set (fixed seed, deterministic sampler): it trains epoch by epoch with the
 SAME Trainer the cookbook scripts use and reports the first optimizer step

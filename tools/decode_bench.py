@@ -761,7 +761,6 @@ def main():
     print(json.dumps({
         # long-context replays publish their own metric name so the
         # virtual-clock numbers never gate the wall-clock tok/s line
-        # (the same convention as quant/tp_impl variants in bench.py)
         "metric": ("lm_longcontext_serving" if long_mode
                    else "lm_decode_tokens_per_sec"),
         "kv_cache": round(cache_rate, 1) if cache_rate is not None else None,

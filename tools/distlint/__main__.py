@@ -3,8 +3,8 @@
 Exit code 1 when any unsuppressed ERROR-tier finding exists (warn-tier
 findings print but never gate — scripts/lint.sh relies on this), 2 on
 usage errors, 0 otherwise. The default path set is the full acceptance
-surface — tpu_dist, tools (the linter lints itself), tests, scripts,
-bench.py — and the tree stays pinned at zero findings.
+surface — tpu_dist, tools (the linter lints itself), tests, scripts —
+and the tree stays pinned at zero findings.
 
 Formats: ``--format human|json|sarif`` (``--json`` is a legacy alias);
 ``--sarif-out FILE`` additionally writes the SARIF artifact beside any
@@ -24,7 +24,7 @@ from tools.distlint.report import (collect_debt, render_debt,
                                    split_by_severity, to_sarif)
 from tools.distlint.rules import RULES
 
-DEFAULT_PATHS = ["tpu_dist", "tools", "tests", "scripts", "bench.py"]
+DEFAULT_PATHS = ["tpu_dist", "tools", "tests", "scripts"]
 
 
 def main(argv=None) -> int:

@@ -383,7 +383,7 @@ def _statement_span(tree: ast.AST, line: int) -> range:
 
 # the project surface the base graph indexes (missing entries skipped —
 # tests build graphs against tmp roots too)
-GRAPH_SURFACE = ("tpu_dist", "tools", "scripts", "tests", "bench.py")
+GRAPH_SURFACE = ("tpu_dist", "tools", "scripts", "tests")
 
 # terminal method names excluded from the by-name fallback: they are
 # overwhelmingly stdlib container/IO calls, and an edge from every
@@ -951,8 +951,8 @@ class CallGraph:
             return ((), True)
         ah = self.attr_assign_calls.get((cur, m))
         if ah is not None:
-            # self.train_step = make_train_step(...): traced handle when the
-            # maker is (transitively) a jit factory
+            # self.train_step = compile_train_step(...): traced handle when
+            # the callee is (transitively) a jit factory
             owner_rel = cur[0]
             mod_node = self.funcs.get(f"{owner_rel}::<module>")
             base = mod_node if mod_node is not None else None
@@ -1011,11 +1011,10 @@ class CallGraph:
                         if tq is None:
                             # cross-module factory chain through an import
                             # alias (`from plan.compile import
-                            # compile_train_step` inside the shim body):
-                            # the make_* builders return the plan
-                            # compiler's product since round 15, so the
-                            # chain must survive the module boundary —
-                            # a plain table lookup, no resolve() recursion
+                            # compile_train_step` in a function that
+                            # returns its product): the chain must survive
+                            # the module boundary — a plain table lookup,
+                            # no resolve() recursion
                             target = n.aliases.get(rc)
                             if target and "." in target:
                                 mod, _, fname = target.rpartition(".")

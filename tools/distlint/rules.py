@@ -234,8 +234,8 @@ class HotLoopHostSync(Rule):
     # What counts as hot is DERIVED, not listed: a loop is a step loop
     # when its body (transitively, through the call graph) dispatches a
     # jit/shard_map-traced computation — either a resolved traced handle
-    # (self.train_step = make_train_step(...) where the maker returns
-    # jax.jit(...)) or, as a syntactic backstop, a callee whose name says
+    # (self.train_step = compile_train_step(...) whose chain of returns
+    # ends in jax.jit(...)) or, as a syntactic backstop, a callee whose name says
     # it dispatches steps. Everything REACHABLE from a step-loop body is
     # hot too, which closes the old closure seam: a .item() inside a
     # helper or nested def that the loop calls no longer escapes because

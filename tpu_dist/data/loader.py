@@ -73,7 +73,11 @@ def stream_prefetch(iterable, depth: int = 2):
     with at most ``depth`` items staged. The generic engine behind the
     trainers' streamed host->device window paths (datasets too large for
     HBM residency); exceptions propagate to the consumer, and abandoning
-    the generator stops the producer."""
+    the generator stops the producer AND waits for it: a producer that
+    outlives its consumer can be inside ``device_put`` when the interpreter
+    exits (a preemption snapshot leaves mid-epoch by SystemExit), and a
+    daemon thread killed inside XLA aborts the process — SIGABRT in place
+    of the exit code the supervisor reads."""
     q: "queue.Queue" = queue.Queue(maxsize=depth)
     stop = threading.Event()
 
@@ -97,7 +101,8 @@ def stream_prefetch(iterable, depth: int = 2):
         except BaseException as e:  # surface assembly/upload errors
             _put(("err", e))
 
-    threading.Thread(target=producer, daemon=True).start()
+    thread = threading.Thread(target=producer, daemon=True)
+    thread.start()
     try:
         while True:
             tag, payload = q.get()
@@ -108,6 +113,9 @@ def stream_prefetch(iterable, depth: int = 2):
             yield payload
     finally:
         stop.set()
+        # bounded: a producer wedged inside the iterable must not turn an
+        # exit under a preemption deadline into a hang
+        thread.join(timeout=10.0)
 
 
 def assemble_global(sharding, batch):

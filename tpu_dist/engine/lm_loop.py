@@ -20,6 +20,7 @@ Mode selection is by mesh axes, exactly like scripts/8:
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
 from functools import partial
@@ -33,25 +34,28 @@ from tpu_dist.configs import LMConfig
 from tpu_dist.data import DistributedSampler, assemble_global
 from tpu_dist.data.tokens import load_token_dataset
 from tpu_dist.engine import checkpoint as ckpt
-from tpu_dist.engine.lm_steps import (LM_METRIC_KEYS, make_lm_batches,
-                                      make_lm_eval_step,
-                                      make_lm_indexed_eval_step,
-                                      make_lm_indexed_multi_train_step,
-                                      make_lm_sp_eval_step,
-                                      make_lm_sp_train_step,
-                                      make_lm_train_step)
+from tpu_dist.engine.lm_steps import LM_METRIC_KEYS, make_lm_batches
 from tpu_dist.engine.state import TrainState
 from tpu_dist.obs import (HealthError, RunObs, faults, profile_session,
                           step_annotation, trace)
 from tpu_dist.ops import lm_lr_schedule, make_optimizer, make_policy
 from tpu_dist.parallel.mesh import make_mesh, replicated
 from tpu_dist.parallel.supervisor import PREEMPT_SNAPSHOT_RC
+from tpu_dist.plan.compile import (Bindings, compile_eval_step,
+                                   compile_train_step)
+from tpu_dist.plan.ir import plan_from_config
 from tpu_dist.runtime import pallas_interpret
 from tpu_dist.utils.meters import MeterBank
 
 
 class LMTrainer:
-    """One engine for every LM parallelism flavor; mode picked by the mesh."""
+    """One engine for every LM parallelism flavor; mode picked by the mesh.
+
+    Which step programs a run gets is ONE decision, ``self.plan``
+    (``plan.ir.plan_from_config`` of the config, the mesh's axis sizes and
+    where the rows live), compiled in :meth:`_build_steps`. Pipeline
+    parallelism is the one mode a Plan cannot name yet: a ``stage`` axis
+    takes ``parallel/pp.py``'s own builders there."""
 
     def __init__(self, cfg: LMConfig, mesh=None):
         # step plan (tpu_dist.plan): the `plan` knob rewrites the
@@ -72,8 +76,6 @@ class LMTrainer:
             # the SGD update form)
             raise ValueError(f"unknown optimizer {cfg.optimizer!r} "
                              "(sgd|adamw|fused_adamw)")
-        from tpu_dist.obs.health import validate_health
-        validate_health(cfg.health)  # record | skip | halt, before any build
         mesh_shape = cfg.mesh_shape or (jax.device_count(),)
         self.mesh = mesh if mesh is not None else make_mesh(
             tuple(mesh_shape), tuple(cfg.mesh_axes))
@@ -93,11 +95,14 @@ class LMTrainer:
         self.use_tp = "model" in names and shape["model"] > 1
         self.use_ep = "expert" in names and shape["expert"] > 1
         self.use_pp = "stage" in names and shape["stage"] > 1
-        from tpu_dist.parallel.overlap import validate_tp_impl
-        validate_tp_impl(cfg.tp_impl)
-        self.use_ring = self.use_tp and cfg.tp_impl == "ring"
-        self.use_bucket = cfg.grad_bucket_mb > 0
         self._validate_mode()
+        # the step plan of this config on this mesh, BEFORE corpus and
+        # model: health/tp_impl spellings and every mode exclusion
+        # (Plan.validate) fail here; the window kind joins it once the
+        # corpus has been measured
+        self.plan = plan_from_config(cfg, dict(shape))
+        self.use_ring = self.plan.tp_impl == "ring"
+        self.use_bucket = self.plan.grad_bucket_mb > 0
         self.mode = (f"pp-{cfg.pp_schedule}"
                      + ("+tp" if self.use_pp and self.use_tp else "")
                      if self.use_pp else
@@ -127,7 +132,7 @@ class LMTrainer:
         self.local_batch = cfg.batch_size // nprocs
 
         # ---- model ----
-        self.model, self._model_ctor_kw, self.decode_model = \
+        self.model, self._model_ctor, self.decode_model = \
             self._build_model()
         # init through the single-device twin (a one-row dummy batch does
         # not divide over a mesh-bound attention kernel's data axis), as ONE
@@ -210,42 +215,23 @@ class LMTrainer:
             params = stack_pipeline_params(params, shape["stage"])
         state = TrainState.create(params, {}, self.tx)
 
-        # ---- steps ----
-        self.rng = jax.random.PRNGKey(seed + 1)
-        self._build_steps()
-
         # ---- windows / device-resident rows ----
+        self.rng = jax.random.PRNGKey(seed + 1)
         self.k = cfg.steps_per_dispatch
-        if self.k < 1:
-            raise ValueError("steps_per_dispatch must be >= 1")
         if cfg.data_placement not in ("auto", "host", "device"):
             raise ValueError(f"unknown data_placement {cfg.data_placement!r}")
         # gradient accumulation (jit modes): N sequential microbatches per
-        # optimizer step — the same mutual exclusions as the image Trainer
+        # optimizer step
         self.accum = cfg.grad_accum_steps
-        if self.accum < 1:
-            raise ValueError("grad_accum_steps must be >= 1")
         if self.accum > 1:
-            if self.use_sp or self.use_pp:
+            if self.use_pp:
                 raise ValueError("grad_accum_steps > 1 supports the jit "
                                  "modes (dp/fsdp/tp/ep); pp already "
                                  "microbatches via --pp-microbatches")
-            if self.k > 1:
-                raise ValueError("grad_accum_steps and steps_per_dispatch "
-                                 "> 1 are mutually exclusive")
-            if cfg.data_placement == "device":
-                raise ValueError("grad_accum_steps > 1 requires "
-                                 "data_placement='host'/'auto' (the indexed "
-                                 "window step has no microbatch loop)")
             if cfg.batch_size % (self.accum * d_size):
                 raise ValueError(
                     f"global batch {cfg.batch_size} not divisible by "
                     f"grad_accum_steps x data axis ({self.accum} x {d_size})")
-            from tpu_dist.engine.lm_steps import (
-                make_lm_grad_accum_train_step)
-            self.train_step = make_lm_grad_accum_train_step(
-                self.model, self.tx, self.mesh, loss_chunk=cfg.loss_chunk,
-                aux_weight=cfg.moe_aux_weight, health=cfg.health)
         rows_bytes = (len(self.train_ds) + len(self.val_ds)) * \
             (cfg.seq_len + 1) * 4
         fits = rows_bytes <= int(os.environ.get("TPU_DIST_DEVICE_DATA_MAX",
@@ -253,6 +239,12 @@ class LMTrainer:
         self.device_data = (cfg.data_placement == "device" or
                             (cfg.data_placement == "auto" and fits
                              and self.k > 1))
+        if self.k > 1 and not self.device_data:
+            raise ValueError(
+                "steps_per_dispatch > 1 needs the device-resident row path "
+                "(corpus too large for TPU_DIST_DEVICE_DATA_MAX, or "
+                "data_placement='host')")
+        self._build_steps()
         self._train_rows_dev = None
         self._val_rows_dev = None
         self._prefetched_windows = None
@@ -264,53 +256,6 @@ class LMTrainer:
             # distlint: disable=DL008 -- one-time whole-dataset HBM residency at init (see _train_rows_dev)
             self._val_rows_dev = jax.device_put(
                 self.val_ds.rows_array(), replicated(self.mesh))
-            # every mode gets the K-steps-per-dispatch window path: the jit
-            # modes via the GSPMD step, sp/pp via a lax.scan over index
-            # windows INSIDE their shard_map programs
-            if self.use_pp:
-                from tpu_dist.parallel.pp import (
-                    make_lm_pp_indexed_eval_step,
-                    make_lm_pp_indexed_multi_train_step)
-                self.window_step = make_lm_pp_indexed_multi_train_step(
-                    self.model, self.tx, self.mesh, cfg.pp_microbatches,
-                    schedule=cfg.pp_schedule, loss_chunk=cfg.loss_chunk,
-                    aux_weight=cfg.moe_aux_weight,
-                    grad_clip=cfg.grad_clip, health=cfg.health)
-                self.window_eval_step = make_lm_pp_indexed_eval_step(
-                    self.model, self.mesh, cfg.pp_microbatches,
-                    loss_chunk=cfg.loss_chunk)
-            elif self.use_sp:
-                from tpu_dist.engine.lm_steps import (
-                    make_lm_sp_indexed_eval_step,
-                    make_lm_sp_indexed_multi_train_step)
-                self.window_step = make_lm_sp_indexed_multi_train_step(
-                    self._sp_ctor, self.tx, self.mesh,
-                    loss_chunk=cfg.loss_chunk,
-                    aux_weight=cfg.moe_aux_weight, health=cfg.health)
-                self.window_eval_step = make_lm_sp_indexed_eval_step(
-                    self._sp_ctor, self.mesh, loss_chunk=cfg.loss_chunk)
-            elif self.use_ring or self.use_bucket:
-                # the explicit-collective modes scan index windows inside
-                # their own shard_map program; eval (forward-only, no grad
-                # sync, replicated params) rides the GSPMD indexed step
-                from tpu_dist.engine.lm_steps import (
-                    make_lm_explicit_indexed_multi_train_step)
-                self.window_step = make_lm_explicit_indexed_multi_train_step(
-                    self._explicit_step_fn, self.mesh)
-                self.window_eval_step = make_lm_indexed_eval_step(
-                    self.model, self.mesh, loss_chunk=cfg.loss_chunk)
-            else:
-                self.window_step = make_lm_indexed_multi_train_step(
-                    self.model, self.tx, self.mesh,
-                    loss_chunk=cfg.loss_chunk,
-                    aux_weight=cfg.moe_aux_weight, health=cfg.health)
-                self.window_eval_step = make_lm_indexed_eval_step(
-                    self.model, self.mesh, loss_chunk=cfg.loss_chunk)
-        elif self.k > 1:
-            raise ValueError(
-                "steps_per_dispatch > 1 needs the device-resident row path "
-                "(corpus too large for TPU_DIST_DEVICE_DATA_MAX, or "
-                "data_placement='host')")
 
         # ---- geometry meta / resume ----
         self._run_meta = {
@@ -447,7 +392,7 @@ class LMTrainer:
         if cfg.fsdp and (self.use_sp or self.use_tp or self.use_ep):
             self.log("warning: fsdp applies to the pure data-parallel "
                      "layout; ignored with a seq/model/expert mesh axis")
-        if self.use_ring:
+        if self.use_tp and cfg.tp_impl == "ring":
             tp = self.mesh.shape["model"]
             if self.use_pp or self.use_ep:
                 raise ValueError("tp_impl='ring' drives the pure "
@@ -461,20 +406,13 @@ class LMTrainer:
                 raise ValueError(f"tp_impl='ring' shards heads: num_heads "
                                  f"{cfg.num_heads} must divide by the model "
                                  f"axis ({tp})")
-            if cfg.grad_accum_steps > 1:
-                raise ValueError("tp_impl='ring' does not compose with "
-                                 "grad_accum_steps > 1 yet (the accum step "
-                                 "is GSPMD-partitioned)")
-        if self.use_bucket:
-            if self.use_tp or self.use_sp or self.use_pp or self.use_ep \
-                    or cfg.fsdp:
-                raise ValueError(
-                    "grad_bucket_mb > 0 decomposes the pure-dp gradient "
-                    "allreduce (replicated params); fsdp/tp/sp/pp/ep keep "
-                    "their GSPMD-scheduled sync")
-            if cfg.grad_accum_steps > 1:
-                raise ValueError("grad_bucket_mb does not compose with "
-                                 "grad_accum_steps > 1 yet")
+        if cfg.grad_bucket_mb > 0 and (self.use_pp or self.use_ep
+                                       or cfg.fsdp):
+            # (a model or seq axis beside it is the plan's to refuse)
+            raise ValueError(
+                "grad_bucket_mb > 0 decomposes the pure-dp gradient "
+                "allreduce (replicated params); fsdp/pp/ep keep their "
+                "GSPMD-scheduled sync")
 
     def _build_model(self):
         cfg = self.cfg
@@ -521,101 +459,70 @@ class LMTrainer:
                          router_top_k=cfg.router_top_k,
                          group_size=cfg.moe_group_size,
                          capacity_factor=cfg.moe_capacity_factor)
-            model = MoETransformerLM(**lm_kw)
+            model_cls = MoETransformerLM
         else:
             from tpu_dist.models.transformer import tiny_lm
-            model = tiny_lm(**lm_kw)
+            model_cls = tiny_lm
+        model = model_cls(**lm_kw)
         # generate/serve apply these weights on ONE device: same attention
         # math, no training mesh bound into the kernel call
         decode_model = (model if train_attn_fn is None
                         else model.clone(attn_fn=attn_fn))
-        return model, lm_kw, decode_model
+        # the sp lowerings bind ring attention per seq axis themselves:
+        # they take the constructor (``ctor(attn_fn=...)``), not the module
+        ctor = partial(model_cls, **{k: v for k, v in lm_kw.items()
+                                     if k != "attn_fn"})
+        return model, ctor, decode_model
 
     def _build_steps(self):
+        """THE place the step programs come from: the config's plan with
+        the window kind the corpus allows, compiled against this run's
+        objects. ``train_step``/``eval_step`` take one host-fed batch;
+        ``window_step``/``window_eval_step`` scan (K, B) index windows
+        over the HBM-resident row matrix, in every mode."""
         cfg = self.cfg
+        self.valid_spec = P("data")
         if self.use_pp:
-            from tpu_dist.parallel.pp import (make_lm_pp_1f1b_train_step,
-                                              make_lm_pp_eval_step,
-                                              make_lm_pp_train_step)
+            from tpu_dist.parallel import pp
             if cfg.pp_schedule not in ("gpipe", "1f1b"):
                 raise ValueError(f"unknown pp_schedule {cfg.pp_schedule!r} "
                                  "(gpipe|1f1b)")
-            maker = (make_lm_pp_1f1b_train_step
-                     if cfg.pp_schedule == "1f1b" else make_lm_pp_train_step)
-            self.train_step = maker(
-                self.model, self.tx, self.mesh, cfg.pp_microbatches,
-                loss_chunk=cfg.loss_chunk, aux_weight=cfg.moe_aux_weight,
-                grad_clip=cfg.grad_clip, health=cfg.health)
-            self.eval_step = make_lm_pp_eval_step(
+            train_kw = dict(loss_chunk=cfg.loss_chunk,
+                            aux_weight=cfg.moe_aux_weight,
+                            grad_clip=cfg.grad_clip, health=cfg.health)
+            maker = (pp.make_lm_pp_1f1b_train_step
+                     if cfg.pp_schedule == "1f1b"
+                     else pp.make_lm_pp_train_step)
+            self.train_step = maker(self.model, self.tx, self.mesh,
+                                    cfg.pp_microbatches, **train_kw)
+            self.eval_step = pp.make_lm_pp_eval_step(
                 self.model, self.mesh, cfg.pp_microbatches,
                 loss_chunk=cfg.loss_chunk)
+            if self.device_data:
+                self.window_step = pp.make_lm_pp_indexed_multi_train_step(
+                    self.model, self.tx, self.mesh, cfg.pp_microbatches,
+                    schedule=cfg.pp_schedule, **train_kw)
+                self.window_eval_step = pp.make_lm_pp_indexed_eval_step(
+                    self.model, self.mesh, cfg.pp_microbatches,
+                    loss_chunk=cfg.loss_chunk)
             self.data_spec = P("data", None)
-            self.valid_spec = P("data")
-        elif self.use_sp:
-            from tpu_dist.models.moe import MoETransformerLM
-            from tpu_dist.models.transformer import tiny_lm
-            kw = {k: v for k, v in self._model_ctor_kw.items()
-                  if k != "attn_fn"}
-            ctor = partial(MoETransformerLM if cfg.num_experts else tiny_lm,
-                           **kw)
-            self._sp_ctor = ctor  # the windowed sp steps rebind it per-axis
+            return
+        sp = self.plan.layout == "sp"
+        if sp:
             # sp's training model closes over mesh axis names (ring
             # attention); decode with the full-attention equivalent
-            self.decode_model = ctor()
-            self.train_step = make_lm_sp_train_step(
-                ctor, self.tx, self.mesh, loss_chunk=cfg.loss_chunk,
-                aux_weight=cfg.moe_aux_weight, health=cfg.health)
-            self.eval_step = make_lm_sp_eval_step(
-                ctor, self.mesh, loss_chunk=cfg.loss_chunk)
-            self.data_spec = P("data", "seq")
-            self.valid_spec = P("data")
-        elif self.use_ring:
-            # ring collective-matmul TP (parallel.overlap): the train step
-            # runs a tp_impl='ring' CLONE of the model (identical params)
-            # inside shard_map over (data, model); eval and checkpoints keep
-            # the plain model — params are replicated, so the GSPMD eval
-            # step applies unchanged
-            from tpu_dist.engine.lm_steps import (_lm_tp_ring_step_fn,
-                                                  make_lm_tp_ring_train_step)
-            self._ring_model = self.model.clone(tp_impl="ring")
-            self._explicit_step_fn = _lm_tp_ring_step_fn(
-                self._ring_model, self.tx, cfg.moe_aux_weight, "data",
-                "model", self.mesh.shape["model"],
-                loss_chunk=cfg.loss_chunk, health=cfg.health)
-            self.train_step = make_lm_tp_ring_train_step(
-                self._ring_model, self.tx, self.mesh,
-                loss_chunk=cfg.loss_chunk, aux_weight=cfg.moe_aux_weight,
-                health=cfg.health)
-            self.eval_step = make_lm_eval_step(
-                self.model, self.mesh, loss_chunk=cfg.loss_chunk)
-            self.data_spec = P("data")
-            self.valid_spec = P("data")
-        elif self.use_bucket:
-            # explicit bucketed dp grad sync (parallel.overlap): DDP's
-            # fusion-buffer decomposition behind --grad-bucket-mb
-            from tpu_dist.engine.lm_steps import (_lm_explicit_dp_step_fn,
-                                                  make_lm_shard_map_train_step)
-            self._explicit_step_fn = _lm_explicit_dp_step_fn(
-                self.model, self.tx, cfg.moe_aux_weight, "data",
-                self.mesh.shape["data"], cfg.grad_bucket_mb,
-                loss_chunk=cfg.loss_chunk, health=cfg.health)
-            self.train_step = make_lm_shard_map_train_step(
-                self.model, self.tx, self.mesh,
-                grad_bucket_mb=cfg.grad_bucket_mb,
-                loss_chunk=cfg.loss_chunk, aux_weight=cfg.moe_aux_weight,
-                health=cfg.health)
-            self.eval_step = make_lm_eval_step(
-                self.model, self.mesh, loss_chunk=cfg.loss_chunk)
-            self.data_spec = P("data")
-            self.valid_spec = P("data")
-        else:
-            self.train_step = make_lm_train_step(
-                self.model, self.tx, self.mesh, loss_chunk=cfg.loss_chunk,
-                aux_weight=cfg.moe_aux_weight, health=cfg.health)
-            self.eval_step = make_lm_eval_step(
-                self.model, self.mesh, loss_chunk=cfg.loss_chunk)
-            self.data_spec = P("data")
-            self.valid_spec = P("data")
+            self.decode_model = self._model_ctor()
+        self.data_spec = P("data", "seq") if sp else P("data")
+        self.plan = dataclasses.replace(
+            self.plan, window="indexed" if self.device_data else "none")
+        binds = Bindings(mesh=self.mesh, model=self.model,
+                         model_ctor=self._model_ctor, tx=self.tx)
+        per_batch = dataclasses.replace(self.plan, window="none")
+        self.train_step = compile_train_step(per_batch, binds)
+        self.eval_step = compile_eval_step(per_batch, binds)
+        if self.device_data:
+            self.window_step = compile_train_step(self.plan, binds)
+            self.window_eval_step = compile_eval_step(self.plan, binds)
 
     def _place(self, st):
         """Apply the mode's parameter sharding (also re-places resumes)."""

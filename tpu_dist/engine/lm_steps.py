@@ -3,26 +3,23 @@
 Extends the image engine (tpu_dist.engine.steps) to token sequences — the
 long-context, model-parallel half of the framework the reference never had.
 
-Since round 15 this module holds the LM engine's step TEMPLATES — the ONE
-shared objective (:func:`_lm_grads_and_metrics`) wrapped as the gspmd
-template (:func:`_lm_step_fn`) and its explicit/ring/sp per-device flavors
+This module holds the LM engine's step TEMPLATES — the ONE shared
+objective (:func:`_lm_grads_and_metrics`) wrapped as the gspmd template
+(:func:`_lm_step_fn`) and its explicit/ring/sp per-device flavors
 (:func:`_lm_explicit_dp_step_fn` / :func:`_lm_tp_ring_step_fn` /
-:func:`_lm_sp_step_fn`) — plus the eval kernel. Every public ``make_lm_*``
-builder below is a THIN SHIM over the plan compiler
-(``tpu_dist.plan.compile``): it names its variant as a declarative
-:class:`tpu_dist.plan.ir.Plan` and the compiler's validate/template/
-window/partition passes produce the callable (the jit/shard_map/scan
-wrapper bodies live once, in the compiler). Signatures and math are
-unchanged; loss/param parity with the pre-plan builders is pinned
-bit-for-bit in tests/test_plan.py.
+:func:`_lm_sp_step_fn`) — plus the eval kernel. The plan compiler
+(``tpu_dist.plan.compile``) wraps them: its lowerings hold the jit /
+shard_map / scan bodies and document each program's signature, and
+``LMTrainer`` reaches them through ``compile_train_step(plan, bindings)``.
 
-Builder map (mode selection is by mesh axes, exactly like scripts/8):
+Mode map (``plan.ir.plan_from_config`` picks by mesh axes, like scripts/8):
 
-* :func:`make_lm_train_step` — jit over a (data[, model]) mesh. Batch sharded
-  on 'data'; with TP param shardings (tpu_dist.parallel.tp) GSPMD emits the
-  Megatron collectives. Works for pure DP (no 'model' axis) unchanged.
-* :func:`make_lm_sp_train_step` — shard_map over (data, seq): each device
-  holds a sequence shard, attention runs as a ring over 'seq'
+* ``layout='dp'|'tp'``, ``sync='gspmd'`` — jit over a (data[, model]) mesh.
+  Batch sharded on 'data'; with TP param shardings (tpu_dist.parallel.tp)
+  GSPMD emits the Megatron collectives. Works for pure DP (no 'model' axis)
+  unchanged.
+* ``layout='sp'`` — shard_map over (data, seq): each device holds a sequence
+  shard, attention runs as a ring over 'seq'
   (tpu_dist.parallel.ring_attention), grads/metrics psum over both axes.
   This is the blockwise/ring long-context regime: per-device activation
   memory scales with L/n_seq.
@@ -48,7 +45,7 @@ from jax.sharding import Mesh
 from tpu_dist.engine.state import TrainState
 from tpu_dist.engine.steps import _apply_update
 from tpu_dist.ops.fused_xent import chunked_softmax_xent
-from tpu_dist.parallel.mesh import DATA_AXIS, MODEL_AXIS, SEQ_AXIS
+from tpu_dist.parallel.mesh import DATA_AXIS
 from tpu_dist.plan.ir import Plan
 
 
@@ -345,230 +342,20 @@ def _sp_window_slices(rows, seq_idx, shard_len):
     return inputs, targets
 
 
-# ---- the make_lm_* builders: thin shims over the plan compiler -------------
-# (plain `return f(...)` chains on purpose: distlint's jit-factory
-# fixpoint follows them, so the engines' loops still derive as hot)
-
-def _train(plan: Plan, **binds):
-    from tpu_dist.plan.compile import Bindings, compile_train_step
-    return compile_train_step(plan, Bindings(**binds))
-
-
-def _eval(plan: Plan, **binds):
-    from tpu_dist.plan.compile import Bindings, compile_eval_step
-    return compile_eval_step(plan, Bindings(**binds))
-
+# ---- the last builder shim ----------------------------------------------------
+# tests/benchmarks/test_cellbench_aot_compile.py and test_cellbench_spans.py
+# import this name, and only a `benchmark` PR may edit those files (ROADMAP
+# S9 moves them to compile_train_step, and this goes with them). Everything
+# else calls the plan compiler.
 
 def make_lm_train_step(model, tx, mesh: Mesh, data_axis: str = DATA_AXIS,
                        aux_weight: float = 0.01,
                        donate: bool = True, loss_chunk: int = 0,
                        health: str = "record") -> Callable:
-    """jit step for DP — and for DP x TP / FSDP / EP when the TrainState was
-    placed with the matching sharding helper (GSPMD propagates the param
-    layout and emits the collectives; the step code is identical).
-    ``aux_weight`` scales any sown MoE load-balancing losses."""
+    """The gspmd LM train step of ``Plan(engine='lm')``: see
+    ``plan.compile._lm_train``."""
+    from tpu_dist.plan.compile import Bindings, compile_train_step
+
     plan = Plan(engine="lm", data_axis=data_axis, aux_weight=aux_weight,
                 donate=donate, loss_chunk=loss_chunk, health=health)
-    return _train(plan, mesh=mesh, model=model, tx=tx)
-
-
-def make_lm_grad_accum_train_step(model, tx, mesh: Mesh,
-                                  data_axis: str = DATA_AXIS,
-                                  aux_weight: float = 0.01,
-                                  donate: bool = True,
-                                  loss_chunk: int = 0,
-                                  health: str = "record") -> Callable:
-    """ONE optimizer step from K microbatches (gradient accumulation), the
-    LM twin of steps.py make_grad_accum_train_step.
-
-    signature: (state, inputs (K, B, L), targets (K, B, L), rng) -> (state,
-    metric sums over microbatches). Grads average over the K microbatches
-    inside a lax.scan, then apply once — for global token batches beyond
-    device memory. Equal microbatch sizes make the average of per-micro
-    means equal the full-batch mean; dropout folds a per-microbatch index
-    on top of the usual state.step fold.
-    """
-    # grad_accum_steps > 1 selects the accum template (K itself is read
-    # from the stacked batch's leading dim at trace time)
-    plan = Plan(engine="lm", grad_accum_steps=2, data_axis=data_axis,
-                aux_weight=aux_weight, donate=donate, loss_chunk=loss_chunk,
-                health=health)
-    return _train(plan, mesh=mesh, model=model, tx=tx)
-
-
-def make_lm_shard_map_train_step(model, tx, mesh: Mesh,
-                                 data_axis: str = DATA_AXIS,
-                                 aux_weight: float = 0.01,
-                                 grad_bucket_mb: float = 25.0,
-                                 donate: bool = True,
-                                 loss_chunk: int = 0,
-                                 health: str = "record") -> Callable:
-    """Explicit-collective dp LM step — the LM twin of steps.py
-    make_shard_map_train_step, carrying the ``grad_bucket_mb`` knob:
-    gradient sync as independent ~25MB bucket reduce-scatters (DDP's
-    overlap decomposition) instead of whatever single fused all-reduce
-    GSPMD would emit. bucket_mb <= 0 keeps one monolithic pmean."""
-    plan = Plan(engine="lm", sync="explicit", data_axis=data_axis,
-                aux_weight=aux_weight, grad_bucket_mb=grad_bucket_mb,
-                donate=donate, loss_chunk=loss_chunk, health=health)
-    return _train(plan, mesh=mesh, model=model, tx=tx)
-
-
-def make_lm_tp_ring_train_step(model, tx, mesh: Mesh,
-                               data_axis: str = DATA_AXIS,
-                               model_axis: str = MODEL_AXIS,
-                               aux_weight: float = 0.01,
-                               donate: bool = True,
-                               loss_chunk: int = 0,
-                               health: str = "record") -> Callable:
-    """dp x TP step over the ring collective matmul (tp_impl='ring'):
-    shard_map over (data, model), batch sharded on 'data', the model's
-    ppermute rings running over 'model'. ``model`` must be built with
-    tp_impl='ring'. Loss parity with the GSPMD TP step is exact for fp
-    (tests/test_overlap.py); int8 quantizes per feature shard (finer
-    granularity than GSPMD's global per-row amax), so quant parity is
-    loss-level, not bitwise."""
-    plan = Plan(engine="lm", sync="explicit", layout="tp", tp_impl="ring",
-                data_axis=data_axis, model_axis=model_axis,
-                aux_weight=aux_weight, donate=donate, loss_chunk=loss_chunk,
-                health=health)
-    return _train(plan, mesh=mesh, model=model, tx=tx)
-
-
-def make_lm_explicit_indexed_multi_train_step(step_fn, mesh: Mesh,
-                                              data_axis: str = DATA_AXIS,
-                                              donate: bool = True) -> Callable:
-    """K steps per dispatch for the explicit-collective LM steps
-    (_lm_explicit_dp_step_fn / _lm_tp_ring_step_fn): a lax.scan over
-    (K, B) index windows INSIDE the shard_map program, gathering rows from
-    the HBM-resident (N, L+1) matrix and shifting on device — the explicit
-    twin of make_lm_indexed_multi_train_step, same signature:
-    (state, rows_all REPLICATED, idx (K, B) sharded (None, data), rng)."""
-    plan = Plan(engine="lm", sync="explicit", window="indexed",
-                steps_per_dispatch=2,  # K is read from the index window
-                data_axis=data_axis, donate=donate)
-    return _train(plan, mesh=mesh, explicit_step_fn=step_fn)
-
-
-def make_lm_eval_step(model, mesh: Mesh, data_axis: str = DATA_AXIS,
-                      loss_chunk: int = 0) -> Callable:
-    """Forward-only metric sums on a held-out shard: (params, inputs,
-    targets, valid) -> {loss_sum, correct1, count}. ``valid`` (B,) 0/1
-    excludes sampler wrap-padding rows so perplexity is exact (the same
-    masking contract as the image eval, steps.py make_eval_step). Works for
-    any GSPMD placement the params carry (dp / fsdp / tp / ep)."""
-    plan = Plan(engine="lm", data_axis=data_axis, loss_chunk=loss_chunk)
-    return _eval(plan, mesh=mesh, model=model)
-
-
-def make_lm_indexed_multi_train_step(model, tx, mesh: Mesh,
-                                     data_axis: str = DATA_AXIS,
-                                     aux_weight: float = 0.01,
-                                     donate: bool = True,
-                                     loss_chunk: int = 0,
-                                     health: str = "record") -> Callable:
-    """K optimizer steps per dispatch from an HBM-RESIDENT token corpus.
-
-    signature: (state, rows_all (N, L+1) i32 REPLICATED, idx (K, B) i32
-    sharded (None, data), rng) -> (state, metrics summed over K steps).
-
-    The LM twin of steps.py make_indexed_multi_train_step: the whole row
-    matrix lives on device once, each scan iteration gathers its (B, L+1)
-    batch at HBM bandwidth and shifts inputs/targets ON DEVICE, and the host
-    sends only the index window — so LM training throughput tracks the
-    device step rate, not the host link. Identical math to K sequential
-    make_lm_train_step calls (same per-step rng fold). Works under any
-    GSPMD param placement (dp / fsdp / tp / ep) like the single step.
-    """
-    plan = Plan(engine="lm", window="indexed", steps_per_dispatch=2,
-                data_axis=data_axis, aux_weight=aux_weight, donate=donate,
-                loss_chunk=loss_chunk, health=health)
-    return _train(plan, mesh=mesh, model=model, tx=tx)
-
-
-def make_lm_indexed_eval_step(model, mesh: Mesh,
-                              data_axis: str = DATA_AXIS,
-                              loss_chunk: int = 0) -> Callable:
-    """Whole-val-set perplexity in ONE dispatch from HBM-resident rows.
-
-    signature: (params, rows_all (N, L+1) REPLICATED, idx (K, B) i32 sharded
-    (None, data), valid (K, B) f32 same sharding) -> summed metrics over all
-    K batches, sampler padding masked per row."""
-    plan = Plan(engine="lm", window="indexed", steps_per_dispatch=2,
-                data_axis=data_axis, loss_chunk=loss_chunk)
-    return _eval(plan, mesh=mesh, model=model)
-
-
-def make_lm_sp_eval_step(model_ctor: Callable, mesh: Mesh,
-                         data_axis: str = DATA_AXIS,
-                         seq_axis: str = SEQ_AXIS,
-                         loss_chunk: int = 0) -> Callable:
-    """Held-out eval under sequence parallelism: (params, inputs, targets,
-    valid) with (data, seq)-sharded tokens, ring attention, metric sums
-    psum'd over BOTH axes — closing the round-2 gap where sp had no eval."""
-    plan = Plan(engine="lm", layout="sp", sync="explicit",
-                data_axis=data_axis, seq_axis=seq_axis,
-                loss_chunk=loss_chunk)
-    return _eval(plan, mesh=mesh, model_ctor=model_ctor)
-
-
-def make_lm_sp_train_step(model_ctor: Callable, tx, mesh: Mesh,
-                          data_axis: str = DATA_AXIS,
-                          seq_axis: str = SEQ_AXIS,
-                          aux_weight: float = 0.01,
-                          donate: bool = True,
-                          loss_chunk: int = 0,
-                          health: str = "record") -> Callable:
-    """shard_map step: batch on 'data', sequence on 'seq', ring attention.
-
-    ``model_ctor(attn_fn)`` builds the model with the given attention fn so
-    the ring can be bound per-axis (tpu_dist.models.transformer.tiny_lm or a
-    partial of TransformerLM).
-    """
-    plan = Plan(engine="lm", layout="sp", sync="explicit",
-                data_axis=data_axis, seq_axis=seq_axis,
-                aux_weight=aux_weight, donate=donate, loss_chunk=loss_chunk,
-                health=health)
-    return _train(plan, mesh=mesh, model_ctor=model_ctor, tx=tx)
-
-
-def make_lm_sp_indexed_multi_train_step(model_ctor: Callable, tx, mesh: Mesh,
-                                        data_axis: str = DATA_AXIS,
-                                        seq_axis: str = SEQ_AXIS,
-                                        aux_weight: float = 0.01,
-                                        donate: bool = True,
-                                        loss_chunk: int = 0,
-                                        health: str = "record") -> Callable:
-    """K sp optimizer steps per dispatch from HBM-resident rows (VERDICT r3
-    #3 — the long-context mode was locked out of dispatch amortization,
-    paying a host round-trip plus full token upload per step on exactly the
-    workloads with the biggest per-step payload).
-
-    signature: (state, rows_all (N, L+1) i32 REPLICATED, idx (K, B) i32
-    sharded (None, data), rng) -> (state, metric sums over K steps).
-
-    The lax.scan over index windows runs INSIDE the existing shard_map
-    program: each iteration gathers its (B/data, L+1) rows at HBM bandwidth
-    and takes this device's sequence shard with a device-side shift —
-    identical math to K sequential make_lm_sp_train_step calls (same
-    per-step rng fold; parameter equality asserted to rtol 1e-5 in
-    tests/test_lm_loop.py)."""
-    plan = Plan(engine="lm", layout="sp", sync="explicit", window="indexed",
-                steps_per_dispatch=2, data_axis=data_axis,
-                seq_axis=seq_axis, aux_weight=aux_weight, donate=donate,
-                loss_chunk=loss_chunk, health=health)
-    return _train(plan, mesh=mesh, model_ctor=model_ctor, tx=tx)
-
-
-def make_lm_sp_indexed_eval_step(model_ctor: Callable, mesh: Mesh,
-                                 data_axis: str = DATA_AXIS,
-                                 seq_axis: str = SEQ_AXIS,
-                                 loss_chunk: int = 0) -> Callable:
-    """Whole-val-set perplexity in ONE dispatch under sequence parallelism:
-    (params, rows_all (N, L+1) REPLICATED, idx (K, B) sharded (None, data),
-    valid (K, B) f32 same sharding) -> metric sums over all K batches,
-    sampler wrap-padding masked per row, psum'd over both axes."""
-    plan = Plan(engine="lm", layout="sp", sync="explicit", window="indexed",
-                steps_per_dispatch=2, data_axis=data_axis,
-                seq_axis=seq_axis, loss_chunk=loss_chunk)
-    return _eval(plan, mesh=mesh, model_ctor=model_ctor)
+    return compile_train_step(plan, Bindings(mesh=mesh, model=model, tx=tx))

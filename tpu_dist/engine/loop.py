@@ -16,6 +16,7 @@ design:
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
 from functools import partial
@@ -31,15 +32,16 @@ from tpu_dist.data import (DataLoader, DistributedSampler, assemble_global,
                            load_dataset, make_transform, prefetch_to_device)
 from tpu_dist.engine import checkpoint as ckpt
 from tpu_dist.engine.state import TrainState, init_model
-from tpu_dist.engine.steps import (make_eval_step, make_indexed_multi_train_step,
-                                   make_multi_train_step,
-                                   make_shard_map_train_step, make_train_step)
+from tpu_dist.engine.steps import pack_images_for_device
 from tpu_dist.models import create_model
 from tpu_dist.obs import (HealthError, RunObs, faults, profile_session,
                           step_annotation, trace)
 from tpu_dist.ops import LossScaleState, make_optimizer, make_policy, step_decay_schedule
 from tpu_dist.parallel.mesh import batch_sharding, make_mesh, replicated
 from tpu_dist.parallel.supervisor import PREEMPT_SNAPSHOT_RC
+from tpu_dist.plan.compile import (Bindings, compile_eval_step,
+                                   compile_train_step)
+from tpu_dist.plan.ir import plan_from_config
 from tpu_dist.runtime import pallas_interpret
 from tpu_dist.utils.meters import MeterBank
 
@@ -48,8 +50,11 @@ class Trainer:
     """One engine for all cookbook variants; flavor picked by config.
 
     ``cfg.variant``: 'jit' (compiler-partitioned, DDP-equiv) or 'shard_map'
-    (explicit psum, horovod-equiv). Multi-host vs single-host is decided by
-    how the process was launched (tpu_dist.parallel.launch), not here.
+    (explicit psum, horovod-equiv). Which step programs that means is ONE
+    decision, ``self.plan`` (``plan.ir.plan_from_config`` of the config,
+    the mesh and where the data lives), compiled in :meth:`_build_steps`.
+    Multi-host vs single-host is decided by how the process was launched
+    (tpu_dist.parallel.launch), not here.
     """
 
     def __init__(self, cfg: configs.TrainConfig, mesh=None):
@@ -73,11 +78,19 @@ class Trainer:
             raise ValueError(
                 f"--arch {cfg.arch} is a language model; this trainer drives "
                 "image classifiers — use scripts/8.lm_longcontext.py")
-        if cfg.variant not in ("jit", "shard_map"):
-            raise ValueError(f"unknown variant {cfg.variant!r} (jit|shard_map)")
-        from tpu_dist.obs.health import validate_health
-        validate_health(cfg.health)  # record | skip | halt, before any build
+        if cfg.tp_impl == "ring" and not cfg.arch.startswith("vit"):
+            # ring collective-matmul TP (parallel.overlap) is for the
+            # transformer-family image archs
+            raise ValueError(
+                f"--tp-impl ring applies to the transformer-family "
+                f"image archs (vit_*); arch {cfg.arch!r} has no "
+                "column/row-parallel projections")
         self.mesh = mesh if mesh is not None else make_mesh(cfg.mesh_shape, cfg.mesh_axes)
+        # the step plan of this config on this mesh, BEFORE any build:
+        # variant/health/tp_impl spellings and every mode exclusion
+        # (Plan.validate) fail here; the window kind joins it once the
+        # data set has been measured (_build_steps)
+        self.plan = plan_from_config(cfg, dict(self.mesh.shape))
         self.policy = make_policy(cfg.precision)
         self.train_ds, self.val_ds = load_dataset(
             cfg.dataset, cfg.data, cfg.synth_train_size, cfg.synth_val_size,
@@ -123,32 +136,6 @@ class Trainer:
                     f"--quant {cfg.quant} applies to the transformer-family "
                     f"image archs (vit_*); arch {cfg.arch!r} does not take it")
             model_kw["quant"] = cfg.quant
-        from tpu_dist.parallel.overlap import validate_tp_impl
-        validate_tp_impl(cfg.tp_impl)
-        if cfg.tp_impl == "ring":
-            # ring collective-matmul TP (parallel.overlap) for the
-            # transformer-family image archs: needs the explicit-collective
-            # engine (the ppermute rings run inside its shard_map) and a
-            # 'model' mesh axis for them to ride
-            if not cfg.arch.startswith("vit"):
-                raise ValueError(
-                    f"--tp-impl ring applies to the transformer-family "
-                    f"image archs (vit_*); arch {cfg.arch!r} has no "
-                    "column/row-parallel projections")
-            if cfg.variant != "shard_map":
-                raise ValueError("--tp-impl ring requires "
-                                 "variant='shard_map' (the ring collectives "
-                                 "are explicit)")
-            if "model" not in self.mesh.axis_names \
-                    or self.mesh.shape["model"] < 2:
-                raise ValueError("--tp-impl ring needs a 'model' mesh axis "
-                                 "of size >= 2 (e.g. --mesh-shape=-1,2 "
-                                 "--mesh-axes=data,model)")
-        if cfg.grad_bucket_mb > 0 and cfg.variant != "shard_map":
-            raise ValueError("--grad-bucket-mb decomposes the explicit "
-                             "gradient allreduce; it requires "
-                             "variant='shard_map' (the jit flavor's sync "
-                             "is GSPMD-scheduled)")
         self.model = create_model(
             cfg.arch, num_classes=self.num_classes,
             dtype=self.policy.compute_dtype, pretrained=cfg.pretrained,
@@ -217,77 +204,23 @@ class Trainer:
             dtype=self.policy.compute_dtype)
 
         # gradient accumulation: split each global batch into N sequential
-        # microbatches whose grads average into ONE optimizer step (steps.py
-        # make_grad_accum_train_step) — for global batches beyond HBM
+        # microbatches whose grads average into ONE optimizer step — for
+        # global batches beyond HBM
         self.accum = cfg.grad_accum_steps
-        if self.accum < 1:
-            raise ValueError("grad_accum_steps must be >= 1")
-        if self.accum > 1 and cfg.variant != "jit":
-            raise ValueError("grad_accum_steps > 1 requires variant='jit'")
-        if cfg.adasum and cfg.variant != "shard_map":
-            # the Adasum operator lives in the explicit-collective engine;
-            # silently averaging instead would misreport the run's math
-            raise ValueError("adasum requires variant='shard_map'")
-        if cfg.adasum and cfg.grad_compression != "none":
-            raise ValueError("adasum replaces the compressed-mean allreduce; "
-                             "use grad_compression='none' with it")
-        if self.accum > 1 and cfg.steps_per_dispatch > 1:
-            raise ValueError("grad_accum_steps and steps_per_dispatch > 1 "
-                             "are mutually exclusive")
         if self.accum > 1 and cfg.batch_size % (self.accum * ndev):
             raise ValueError(
                 f"global batch {cfg.batch_size} not divisible by "
                 f"grad_accum_steps x device count ({self.accum} x {ndev})")
 
-        if self.accum > 1:
-            from tpu_dist.engine.steps import make_grad_accum_train_step
-            self.train_step = make_grad_accum_train_step(
-                self.model, self.tx, self.transform, self.mesh,
-                health=cfg.health)
-        elif cfg.variant == "shard_map":
-            # ring TP trains through a tp_impl='ring' CLONE (identical
-            # params — parallel.overlap); init/eval/checkpoints keep the
-            # plain model, which the replicated params drive unchanged
-            train_model = (self.model.clone(tp_impl=cfg.tp_impl)
-                           if cfg.tp_impl != "gspmd" else self.model)
-            self.train_step = make_shard_map_train_step(
-                train_model, self.tx, self.transform, self.mesh,
-                grad_compression=cfg.grad_compression,
-                predivide_factor=cfg.gradient_predivide_factor,
-                adasum=cfg.adasum,
-                grad_bucket_mb=cfg.grad_bucket_mb,
-                model_axis="model" if cfg.tp_impl == "ring" else None,
-                health=cfg.health)
-        else:
-            self.train_step = make_train_step(
-                self.model, self.tx, self.transform, self.mesh,
-                health=cfg.health)
-        self.eval_step = make_eval_step(self.model, eval_transform, self.mesh)
-
-        # K-steps-per-dispatch window (the bench's multi-step
-        # machinery wired into real training). Math is identical to K
-        # sequential dispatches; only the host round-trip count changes.
+        # K-steps-per-dispatch window. Math is identical to K sequential
+        # dispatches; only the host round-trip count changes.
         self.k = cfg.steps_per_dispatch
-        if self.k < 1:
-            raise ValueError("steps_per_dispatch must be >= 1")
-        if self.k > 1 and cfg.variant != "jit":
-            raise ValueError("steps_per_dispatch > 1 requires variant='jit'")
         if cfg.data_placement not in ("auto", "host", "device"):
             raise ValueError(f"unknown data_placement {cfg.data_placement!r}")
         in_memory = isinstance(getattr(self.train_ds, "images", None), np.ndarray)
         if cfg.data_placement == "device" and not in_memory:
             raise ValueError("data_placement='device' needs an in-memory "
                              "(ArrayDataset) training set")
-        if cfg.data_placement == "device" and self.accum > 1:
-            # the indexed window step has no microbatch loop; accumulation
-            # rides the host-fed per-batch path
-            raise ValueError("grad_accum_steps > 1 requires "
-                             "data_placement='host' or 'auto'")
-        if cfg.data_placement == "device" and cfg.variant != "jit":
-            # the indexed window step is compiler-partitioned; routing a
-            # shard_map config through it would silently drop grad
-            # compression/predivide and per-replica BN semantics
-            raise ValueError("data_placement='device' requires variant='jit'")
         # budget covers BOTH splits when the val set can ride along into HBM
         # (in-memory, same image shape — the upload gate below)
         val_rides = (in_memory and
@@ -303,6 +236,7 @@ class Trainer:
         self.device_data = (cfg.data_placement == "device" or
                             (cfg.data_placement == "auto" and fits_hbm
                              and self.k > 1))
+        self._build_steps(eval_transform, val_rides)
         self._train_data_dev = None
         self._val_data_dev = None
         self._prefetched_windows = None  # (epoch, [(n, device idx window)])
@@ -311,16 +245,11 @@ class Trainer:
             # whole training set resident in HBM (rows packed into i32 words
             # for native 32-bit gathers), replicated per chip; per-step
             # batches are gathered on device from an index window
-            from tpu_dist.engine.steps import (make_indexed_eval_step,
-                                               pack_images_for_device)
             self._train_data_dev = (
                 jax.device_put(pack_images_for_device(self.train_ds.images),
                                replicated(self.mesh)),
                 jax.device_put(self.train_ds.labels.astype(np.int32),
                                replicated(self.mesh)))
-            self.window_step = make_indexed_multi_train_step(
-                self.model, self.tx, self.transform, self.mesh,
-                self.train_ds.image_shape, health=cfg.health)
             # the val set rides along in HBM too (same placement rules):
             # the whole distributed eval becomes ONE dispatch per epoch
             if val_rides:
@@ -329,13 +258,6 @@ class Trainer:
                                    replicated(self.mesh)),
                     jax.device_put(self.val_ds.labels.astype(np.int32),
                                    replicated(self.mesh)))
-                self.window_eval_step = make_indexed_eval_step(
-                    self.model, eval_transform, self.mesh,
-                    self.val_ds.image_shape)
-        elif self.k > 1:
-            self.window_step = make_multi_train_step(
-                self.model, self.tx, self.transform, self.mesh,
-                health=cfg.health)
 
         self.batch_sharding = batch_sharding(self.mesh)
         self.best_acc1 = 0.0
@@ -450,6 +372,28 @@ class Trainer:
         self._fused_quant = cfg.quant == "int8" and fused_quant_active()
 
     # ------------------------------------------------------------------
+    def _build_steps(self, eval_transform, val_rides: bool) -> None:
+        """THE place the step programs come from: the config's plan with
+        the window kind the data allows, compiled against this run's
+        objects. ``train_step``/``eval_step`` take one host-fed batch;
+        ``window_step`` takes K steps a dispatch (device-resident rows by
+        index, or host-fed stacked batches), ``window_eval_step`` the whole
+        resident validation set."""
+        window = ("indexed" if self.device_data
+                  else "stacked" if self.k > 1 else "none")
+        self.plan = dataclasses.replace(self.plan, window=window)
+        binds = Bindings(mesh=self.mesh, model=self.model, tx=self.tx,
+                         transform=self.transform,
+                         eval_transform=eval_transform,
+                         image_shape=self.train_ds.image_shape)
+        per_batch = dataclasses.replace(self.plan, window="none")
+        self.train_step = compile_train_step(per_batch, binds)
+        self.eval_step = compile_eval_step(per_batch, binds)
+        if window != "none":
+            self.window_step = compile_train_step(self.plan, binds)
+        if window == "indexed" and val_rides:
+            self.window_eval_step = compile_eval_step(self.plan, binds)
+
     def log(self, *a, **k):
         # getattr: log is callable from __init__ before is_main is set
         if getattr(self, "is_main", jax.process_index() == 0):

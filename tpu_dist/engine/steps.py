@@ -7,33 +7,29 @@ XLA program: normalize/augment, forward, loss, grads, cross-replica reduction,
 optimizer update, and metric counts all fuse; there is no per-batch host
 round-trip and no barrier (XLA orders the collectives).
 
-Since round 15 this module holds the image engine's ONE step template
+This module holds the image engine's ONE step template
 (:func:`_train_step_fn` around the shared :func:`_apply_update` funnel) and
-the metric/loss helpers; every public ``make_*`` builder below is a THIN
-SHIM over the plan compiler (``tpu_dist.plan.compile``) — it names its
-variant as a declarative :class:`tpu_dist.plan.ir.Plan` and the compiler's
-validate/template/window/partition passes produce the callable. The
-builders' signatures and math are unchanged (loss/param parity is pinned
-bit-for-bit in tests/test_plan.py); what changed is that the jit/
-shard_map/windowed/bucketed/ring wrapper bodies now live once, in the
-compiler, instead of once per builder.
+the metric/loss helpers. The plan compiler (``tpu_dist.plan.compile``)
+wraps the template: its lowerings hold the jit / shard_map / windowed /
+bucketed / ring bodies and document each program's signature, and
+``Trainer`` reaches them through ``compile_train_step(plan, bindings)``.
 
 Two interchangeable distribution flavors produce bit-comparable updates for
 BatchNorm-free models (for BN models the gradient math still agrees, but the
 running statistics differ by design — global-batch SyncBN vs per-replica +
 pmean, see below):
 
-* :func:`make_train_step` — *compiler-partitioned* (DDP-equivalent,
+* ``Plan(sync='gspmd')`` — *compiler-partitioned* (DDP-equivalent,
   reference variants 2/3/6): ``jit`` over a Mesh with the batch sharded on
   the ``data`` axis and params replicated; XLA inserts the gradient
   all-reduce exactly where DDP's bucketed NCCL allreduce fired. BatchNorm
   statistics are computed over the GLOBAL batch (SyncBN semantics — a
   documented improvement over per-replica torch BN).
-* :func:`make_shard_map_train_step` — *explicit-collective*
-  (horovod-equivalent, reference variant 5): ``shard_map`` gives one program
-  per device; gradients are explicitly ``psum``'d with optional bf16
-  compression (hvd.Compression.fp16-equiv) and predivide factor. BatchNorm
-  stats stay per-replica then get pmean'd — mirroring horovod's
+* ``Plan(sync='explicit')`` — *explicit-collective* (horovod-equivalent,
+  reference variant 5): ``shard_map`` gives one program per device;
+  gradients are explicitly ``psum``'d with optional bf16 compression
+  (hvd.Compression.fp16-equiv) and predivide factor. BatchNorm stats stay
+  per-replica then get pmean'd — mirroring horovod's
   local-BN-plus-broadcast behavior.
 
 Metrics are returned as SUMS (loss*n, correct counts, sample count) so the
@@ -44,16 +40,13 @@ the reference's equal-weight averaging of per-rank fractions
 
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh
 
 from tpu_dist.engine.state import TrainState
 from tpu_dist.ops import precision as prec
-from tpu_dist.parallel.mesh import DATA_AXIS
-from tpu_dist.plan.ir import Plan
 
 
 def cross_entropy_sum(logits: jax.Array, labels: jax.Array,
@@ -205,158 +198,3 @@ def pack_images_for_device(images_u8):
     if flat.shape[1] % 4 or not flat.flags.c_contiguous:
         return images_u8
     return flat.view(np.int32)
-
-
-# ---- the make_* builders: thin shims over the plan compiler ----------------
-# (the two hops below are plain `return f(...)` chains on purpose: distlint's
-# jit-factory fixpoint follows them, so `self.train_step = make_*(...)`
-# still derives the engine loops as hot)
-
-def _train(plan: Plan, **binds):
-    from tpu_dist.plan.compile import Bindings, compile_train_step
-    return compile_train_step(plan, Bindings(**binds))
-
-
-def _eval(plan: Plan, **binds):
-    from tpu_dist.plan.compile import Bindings, compile_eval_step
-    return compile_eval_step(plan, Bindings(**binds))
-
-
-def make_train_step(model, tx, transform, mesh: Mesh,
-                    data_axis: str = DATA_AXIS, donate: bool = True,
-                    health: str = "record") -> Callable:
-    """Compiler-partitioned step: jit over mesh, batch sharded, params replicated."""
-    plan = Plan(engine="image", data_axis=data_axis, donate=donate,
-                health=health)
-    return _train(plan, mesh=mesh, model=model, tx=tx,
-                     transform=transform)
-
-
-def make_multi_train_step(model, tx, transform, mesh: Mesh,
-                          data_axis: str = DATA_AXIS,
-                          donate: bool = True,
-                          health: str = "record") -> Callable:
-    """K optimizer steps in ONE dispatch: lax.scan over stacked batches.
-
-    signature: (state, images_u8 (K,B,...), labels (K,B), rng) -> (state,
-    metrics summed over the K steps). The TPU-idiomatic answer to per-
-    dispatch host latency (the reference's analog concern was CUDA-stream
-    overlap, C13): the whole window executes on-device with zero host
-    round-trips. K is a trace-time constant (leading dim).
-    """
-    plan = Plan(engine="image", window="stacked", data_axis=data_axis,
-                donate=donate, health=health)
-    return _train(plan, mesh=mesh, model=model, tx=tx,
-                     transform=transform)
-
-
-def make_indexed_multi_train_step(model, tx, transform, mesh: Mesh,
-                                  image_shape, data_axis: str = DATA_AXIS,
-                                  donate: bool = True,
-                                  health: str = "record") -> Callable:
-    """K steps per dispatch reading a DEVICE-RESIDENT dataset by index.
-
-    signature: (state, images_all REPLICATED (packed via
-    :func:`pack_images_for_device` — (N,HWC/4) i32, or (N,H,W,C) u8
-    fallback), labels_all (N,) REPLICATED, idx (K,B) i32 sharded
-    (None, data), rng) -> (state, metrics summed over the K steps).
-
-    TPU-first data path for datasets that fit in HBM (CIFAR-scale): the
-    arrays live on device once, each scan iteration gathers its batch at HBM
-    bandwidth, and the host sends only the (K,B) int32 index window per
-    dispatch — a few KB instead of ~3 KB/image. End-to-end training
-    throughput then tracks the device step rate instead of the host->device
-    link (the reference's prefetcher fought the same battle on CUDA streams
-    and lost, reference 4.apex_distributed2.py:80). Identical math to K
-    sequential :func:`make_train_step` calls (same per-step rng fold).
-    """
-    plan = Plan(engine="image", window="indexed", data_axis=data_axis,
-                donate=donate, health=health)
-    return _train(plan, mesh=mesh, model=model, tx=tx,
-                     transform=transform, image_shape=image_shape)
-
-
-def make_indexed_eval_step(model, transform, mesh: Mesh, image_shape,
-                           data_axis: str = DATA_AXIS) -> Callable:
-    """Whole-validation-set eval in ONE dispatch from HBM-resident data.
-
-    signature: (params, batch_stats, images_all (packed, REPLICATED),
-    labels_all, idx (K,B) i32 sharded (None, data), valid (K,B) f32 same
-    sharding) -> summed metrics over all K batches. The companion of
-    :func:`make_indexed_multi_train_step` for the eval loop: sampler padding
-    is masked per sample via ``valid`` exactly like the host-fed
-    :func:`make_eval_step`.
-    """
-    plan = Plan(engine="image", window="indexed", data_axis=data_axis)
-    return _eval(plan, mesh=mesh, model=model,
-                     eval_transform=transform,
-                     image_shape=image_shape)
-
-
-def make_eval_step(model, transform, mesh: Mesh,
-                   data_axis: str = DATA_AXIS) -> Callable:
-    """Distributed eval step (C15): metric sums on the global sharded batch."""
-    plan = Plan(engine="image", data_axis=data_axis)
-    return _eval(plan, mesh=mesh, model=model,
-                     eval_transform=transform)
-
-
-def make_grad_accum_train_step(model, tx, transform, mesh: Mesh,
-                               data_axis: str = DATA_AXIS,
-                               donate: bool = True,
-                               health: str = "record") -> Callable:
-    """ONE optimizer step from K microbatches (gradient accumulation).
-
-    signature: (state, images_u8 (K,B,...), labels (K,B), rng) -> (state,
-    metrics summed over microbatches). Grads are averaged over the K
-    microbatches inside a lax.scan, then applied once — the standard recipe
-    for global batches that exceed device memory (absent from the reference,
-    whose answer to batch 3200 was requiring 4x V100s). BN statistics advance
-    per microbatch (same semantics as torch accumulation loops).
-    """
-    # the accum template reads K from the batch's leading dim at trace
-    # time; any grad_accum_steps > 1 selects it (2 = the mode marker)
-    plan = Plan(engine="image", grad_accum_steps=2, data_axis=data_axis,
-                donate=donate, health=health)
-    return _train(plan, mesh=mesh, model=model, tx=tx,
-                     transform=transform)
-
-
-def make_shard_map_train_step(model, tx, transform, mesh: Mesh,
-                              data_axis: str = DATA_AXIS,
-                              grad_compression: str = "none",
-                              predivide_factor: float = 1.0,
-                              adasum: bool = False,
-                              donate: bool = True,
-                              grad_bucket_mb: float = 0.0,
-                              model_axis: Optional[str] = None,
-                              health: str = "record") -> Callable:
-    """Explicit-collective step (horovod-equivalent, reference variant 5).
-
-    Per-device program via shard_map; gradient averaging is an explicit psum
-    with optional bf16 payload compression (reference 5.horovod_distributed.py:
-    123-125) and horovod's gradient_predivide_factor placement (pre-scale
-    before summation, post-scale after; reference 5.2...py:185). With
-    ``adasum=True`` the mean is replaced by the Adasum recursive-halving
-    operator (hvd.Adasum, reference 5.2...py:184 —
-    tpu_dist.parallel.collectives.adasum_reduce); predivide/compression are
-    mean-path knobs and do not apply.
-
-    ``grad_bucket_mb > 0`` replaces the tree-wide psum with DDP-style
-    size-targeted bucket collectives (parallel.overlap.bucketed_grad_sync:
-    independent reduce-scatter+all-gather per ~bucket_mb of grads), the
-    decomposition XLA's scheduler can overlap. ``model_axis`` names a ring-TP
-    mesh axis (models built with tp_impl='ring'/'ring_ar'): the model's
-    collectives run over it inside this same program, compute is replicated
-    across it per data shard, and the grads of the (replicated) params are
-    additionally pmean'd over it.
-    """
-    plan = Plan(engine="image", sync="explicit",
-                layout="tp" if model_axis is not None else "dp",
-                tp_impl="ring" if model_axis is not None else "gspmd",
-                model_axis=model_axis or "model",
-                data_axis=data_axis, grad_compression=grad_compression,
-                predivide_factor=predivide_factor, adasum=adasum,
-                grad_bucket_mb=grad_bucket_mb, donate=donate, health=health)
-    return _train(plan, mesh=mesh, model=model, tx=tx,
-                     transform=transform)

@@ -119,8 +119,8 @@ class ResNet(nn.Module):
     # the BN->relu->residual chain fp32). jnp.bfloat16 emits bf16 normalized
     # activations while BN/GN STATISTICS still accumulate in fp32 (flax
     # computes mean/var in f32 internally, and running stats/affine params
-    # stay f32 param_dtype) — the MLPerf-TPU ResNet practice. The round-5
-    # profile (tools/profile_image.py, BASELINE.md) showed the training
+    # stay f32 param_dtype) — the MLPerf-TPU ResNet practice. A round-5
+    # per-op profile showed the training
     # step HBM-bandwidth-bound with fp32 activation/cotangent tensors
     # between every bf16 conv; bf16 norm outputs halve that traffic.
 

@@ -14,8 +14,8 @@ Four pieces, one handle:
 
 :class:`RunObs` wires them from a config (``ledger_path`` /
 ``watchdog_factor`` / ``skew_every`` / ``log_csv`` / ``profile_dir``) so the
-image Trainer, the LMTrainer, ``engine.generate`` and ``bench.py`` all feed
-the SAME records instead of five bespoke logging stacks. MFU per step is
+image Trainer, the LMTrainer, ``engine.generate`` and the serving tools all
+feed the SAME records instead of bespoke logging stacks. MFU per step is
 computed here against the device's bf16 peak; on backends with no published
 peak (CPU, virtual) the field stays non-null by normalizing against a
 nominal ``TPU_DIST_NOMINAL_PEAK_TFLOPS`` (default 1.0 — i.e. the value

@@ -253,9 +253,9 @@ def split_attempts(records: List[dict]) -> List[List[dict]]:
 class GoodputAccumulator:
     """Feed ledger records in order; :meth:`finalize` yields the partition.
 
-    Also usable directly as a ledger sink (``ledger.add_sink(acc.add)``) —
-    bench.py does exactly that to put a ``goodput`` block in its headline
-    JSON. All fields tolerate schema-legal ``None`` values.
+    Also usable directly as a ledger sink (``ledger.add_sink(acc.add)``),
+    which gives a live run its partition without a second read of the
+    file. All fields tolerate schema-legal ``None`` values.
     """
 
     def __init__(self):

@@ -91,7 +91,7 @@ def make_optimizer(lr: float, momentum: float = 0.9, weight_decay: float = 1e-4,
     0.95, the large-LM convention, not torch's 0.999.
 
     Horovod's gradient_predivide_factor lives in the explicit-psum step
-    (tpu_dist.engine.steps.make_shard_map_train_step), matching horovod's
+    (tpu_dist.plan.compile._image_explicit_train), matching horovod's
     placement around the allreduce — NOT here, so it cannot double-apply.
     """
     sched = schedule or step_decay_schedule(lr, steps_per_epoch, lr_step_epochs)

@@ -440,6 +440,12 @@ class Supervisor:
             except Exception as e:
                 self._log(f"warning: scale event dropped ({e})")
 
+    @property
+    def mesh_view(self) -> Optional[MeshView]:
+        """The membership this supervisor last resolved (None without a
+        consensus dir, or before the first round)."""
+        return self._view
+
     def _resolve_view(self) -> Optional[MeshView]:
         """One consensus round + the env/flag fallout: dense renumbering,
         degraded marking, shrink/expand scale events, and the one-shot

@@ -16,12 +16,12 @@ import importlib
 _SUBMODULES = ("ir", "tune", "compile")
 
 _IR = ("Plan", "PlanError", "plan_hash", "load_plan_file",
-       "plan_for_device", "apply_plan_to_config", "plan_knob_summary",
-       "KNOWN_AXES")
+       "plan_for_device", "apply_plan_to_config", "plan_from_config",
+       "plan_knob_summary", "KNOWN_AXES")
 _TUNE = ("search", "default_space", "device_peaks",
          "estimate_step_seconds", "emit_plan_file")
-_COMPILE = ("compile_plan", "Bindings", "CompiledPlan", "activate_plan",
-            "resolve_config_plan")
+_COMPILE = ("compile_train_step", "compile_eval_step", "Bindings",
+            "activate_plan", "resolve_config_plan")
 
 __all__ = list(_IR + _TUNE + _COMPILE)
 

@@ -1,9 +1,11 @@
 """Plan compiler: lower a :class:`tpu_dist.plan.ir.Plan` to step callables.
 
-ONE pass pipeline replaces the hand-built step-builder matrix (PR 15):
+The trainers derive a Plan (``plan.ir.plan_from_config``) and hand it here
+with their :class:`Bindings`; :func:`compile_train_step` and
+:func:`compile_eval_step` are the one way from a plan to a step program:
 
-1. **validate** — :meth:`Plan.validate` + the mesh-axis check (the same
-   exclusion rules the engines enforced ad hoc);
+1. **validate** — :meth:`Plan.validate` + the mesh-axis check (the mode
+   exclusion rules' one home);
 2. **template** — pick the engine's pure step function (the ONE step
    template per engine: ``engine/steps.py:_train_step_fn`` for images,
    ``engine/lm_steps.py:_lm_step_fn`` and its explicit/ring/sp per-device
@@ -16,10 +18,7 @@ ONE pass pipeline replaces the hand-built step-builder matrix (PR 15):
    ``shard_map`` + ``jit`` with explicit specs (``sync='explicit'`` /
    ``layout='sp'``).
 
-The legacy ``make_*`` builders in ``engine/steps.py`` and
-``engine/lm_steps.py`` are now thin shims over :func:`compile_plan`
-(loss/param parity pinned bit-for-bit in tests/test_plan.py): every
-wrapper body that used to live in a ``make_*`` lives HERE, once.
+Each lowering's docstring states the signature of the program it returns.
 
 ``activate_plan`` applies a plan's global trace-time switches (fused
 int8 kernel, Pallas block sizes) and ``resolve_config_plan`` implements
@@ -30,24 +29,28 @@ from __future__ import annotations
 
 import dataclasses
 from dataclasses import dataclass
-from typing import Any, Callable, Optional, Tuple
+from typing import TYPE_CHECKING, Any, Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpu_dist._compat import shard_map
-from tpu_dist.engine.state import TrainState
 from tpu_dist.plan.ir import (Plan, PlanError, apply_plan_to_config,
                               plan_hash, plan_knob_summary)
+
+if TYPE_CHECKING:   # annotations only: the engine package imports this module
+    from tpu_dist.engine.state import TrainState
 
 
 @dataclass
 class Bindings:
     """What a plan lowers AGAINST: the run's concrete objects. The model
-    binding must already embody the plan's quant/tp_impl (flax modules
-    bake those in at construction — the engines build them from the same
-    config the plan was applied to)."""
+    binding must already embody the plan's quant (flax modules bake it in
+    at construction — the engines build them from the same config the
+    plan was derived from). It is the PLAIN model under ``tp_impl='ring'``
+    too: init, eval and checkpoints use it as it is, and the train
+    lowerings take their ring twin from it (:func:`_train_model`)."""
 
     mesh: Mesh
     model: Any = None                 # flax module (non-sp paths)
@@ -56,52 +59,20 @@ class Bindings:
     transform: Optional[Callable] = None       # image train transform
     eval_transform: Optional[Callable] = None  # image eval transform
     image_shape: Optional[Tuple[int, int, int]] = None  # indexed image paths
-    explicit_step_fn: Optional[Callable] = None  # pre-built per-device step
-    #                                    (the lm explicit window wrapper)
-
-
-class CompiledPlan:
-    """Lazy pair of compiled callables for one (plan, bindings):
-    ``train_step`` and ``eval_step`` lower on first access (a maker shim
-    that only needs one never builds the other)."""
-
-    def __init__(self, plan: Plan, binds: Bindings):
-        _pass_validate(plan, binds)
-        self.plan = plan
-        self.binds = binds
-        self._train = None
-        self._eval = None
-
-    @property
-    def train_step(self) -> Callable:
-        if self._train is None:
-            self._train = _lower_train(self.plan, self.binds)
-        return self._train
-
-    @property
-    def eval_step(self) -> Callable:
-        if self._eval is None:
-            self._eval = _lower_eval(self.plan, self.binds)
-        return self._eval
-
-
-def compile_plan(plan: Plan, binds: Bindings) -> CompiledPlan:
-    """THE entry point: validate + return the lazy compiled pair."""
-    return CompiledPlan(plan, binds)
 
 
 def compile_train_step(plan: Plan, binds: Bindings) -> Callable:
-    """Validate + lower the train step directly (the make_* shim entry:
-    a plain `return compile_train_step(...)` chain keeps the builders
-    inside distlint's jit-factory fixpoint, so the engines' loops still
-    derive as hot — an attribute hop through CompiledPlan would not)."""
+    """Validate the plan against the bindings' mesh and lower its train
+    step: ``(state, <batch or window>, rng) -> (state, metric sums)``, the
+    batch arguments as the lowering that :func:`_lower_train` picks
+    documents them."""
     _pass_validate(plan, binds)
     return _lower_train(plan, binds)
 
 
 def compile_eval_step(plan: Plan, binds: Bindings) -> Callable:
-    """Validate + lower the eval step directly (compile_train_step's
-    forward-only twin)."""
+    """Validate + lower the plan's eval step (forward only, metric sums);
+    a windowed plan gives the whole-set scan."""
     _pass_validate(plan, binds)
     return _lower_eval(plan, binds)
 
@@ -124,9 +95,9 @@ def _pass_validate(plan: Plan, binds: Bindings) -> None:
 # ---- program audit (tpu_dist.analysis.proglint) ---------------------------
 # A module-level switch in the activate_plan mold: the engines arm it from
 # cfg.audit before their first dispatch, the partition helpers below
-# REGISTER every program they mint as a side effect (never a wrapper — an
-# attribute hop would take the builders out of distlint's jit-factory
-# fixpoint and DL002's hot-loop derivation with it), and the engines run
+# REGISTER every program they mint as a side effect (never a wrapper: the
+# callers hold the jitted callable itself and ``.lower()`` it), and the
+# engines run
 # the compile-time pass at the same first-dispatch probe that already
 # lowers the program for telemetry. The runtime half (the recompile
 # sentry) is a host-only counter read at the drain boundaries.
@@ -245,9 +216,25 @@ def _shard_map_jit(fn, mesh, in_specs, out_specs, donate: bool):
 
 # ---- image lowerings ------------------------------------------------------
 
+def _train_model(plan: Plan, b: Bindings):
+    """The model the train templates apply: under ``tp_impl='ring'`` the
+    ring collective-matmul CLONE of the bound model (identical params —
+    parallel.overlap), made here and nowhere else."""
+    return b.model.clone(tp_impl="ring") if plan.tp_impl == "ring" \
+        else b.model
+
+
 def _image_accum_train(plan: Plan, b: Bindings) -> Callable:
-    """ONE optimizer step from K microbatches (the grad-accum template;
-    the steps.py make_grad_accum_train_step body, verbatim)."""
+    """ONE optimizer step from K microbatches (gradient accumulation).
+
+    signature: (state, images_u8 (K,B,...), labels (K,B), rng) -> (state,
+    metrics summed over microbatches), the microbatches sharded
+    (None, data). Grads are averaged over the K microbatches inside a
+    lax.scan, then applied once — the standard recipe for global batches
+    that exceed device memory. K is read from the leading dim at trace
+    time (any ``grad_accum_steps > 1`` selects this template). BN
+    statistics advance per microbatch (torch accumulation-loop semantics).
+    """
     from tpu_dist.engine.steps import _apply_update, _loss_and_metrics
 
     mesh, model, tx, transform = b.mesh, b.model, b.tx, b.transform
@@ -288,13 +275,30 @@ def _image_accum_train(plan: Plan, b: Bindings) -> Callable:
 
 
 def _image_explicit_train(plan: Plan, b: Bindings) -> Callable:
-    """Explicit-collective image step (the make_shard_map_train_step
-    per-device body, verbatim): horovod allreduce with predivide /
-    compression / Adasum / DDP bucket decomposition / ring-TP pmean."""
+    """Explicit-collective image step (horovod-equivalent, reference
+    variant 5): one program per device via shard_map, same signature as
+    the plain jit step — (state, images_u8 (B,...), labels (B,), rng),
+    batch sharded on ``data``.
+
+    Gradient averaging is an explicit psum with optional bf16 payload
+    compression (reference 5.horovod_distributed.py:123-125) and horovod's
+    gradient_predivide_factor placement (pre-scale before summation,
+    post-scale after; reference 5.2...py:185). ``adasum`` replaces the mean
+    by the Adasum recursive-halving operator (hvd.Adasum —
+    parallel.collectives.adasum_reduce); predivide/compression are
+    mean-path knobs and do not apply to it. ``grad_bucket_mb > 0`` replaces
+    the tree-wide psum with DDP-style size-targeted bucket collectives
+    (parallel.overlap.bucketed_grad_sync), the decomposition XLA's
+    scheduler can overlap. Under ``tp_impl='ring'`` the model's collectives
+    run over ``model_axis`` inside this same program, compute is replicated
+    across it per data shard, and the grads of the (replicated) params are
+    additionally pmean'd over it. BatchNorm stats stay per replica and are
+    pmean'd (horovod's local BN), where the jit step's are global-batch."""
     from tpu_dist.engine.steps import _apply_update, _loss_and_metrics
     from tpu_dist.parallel.collectives import compress_grads
 
-    mesh, model, tx, transform = b.mesh, b.model, b.tx, b.transform
+    mesh, tx, transform = b.mesh, b.tx, b.transform
+    model = _train_model(plan, b)
     data_axis = plan.data_axis
     health = plan.health
     grad_compression = plan.grad_compression
@@ -352,9 +356,27 @@ def _image_explicit_train(plan: Plan, b: Bindings) -> Callable:
 
 
 def _image_train(plan: Plan, b: Bindings) -> Callable:
-    """The gspmd image train lowerings: plain jit, stacked K-step window,
-    or HBM-resident indexed window around ONE template
-    (engine.steps._train_step_fn)."""
+    """The gspmd image train lowerings around ONE template
+    (engine.steps._train_step_fn), batch sharded on ``data``, params
+    replicated; XLA inserts the gradient all-reduce (DDP-equivalent) and
+    BatchNorm statistics are over the GLOBAL batch.
+
+    * ``window='none'``: (state, images_u8 (B,...), labels (B,), rng).
+    * ``window='stacked'``: K optimizer steps in ONE dispatch, a lax.scan
+      over host-fed stacked batches — (state, images_u8 (K,B,...), labels
+      (K,B), rng) -> (state, metrics summed over the K steps). K is a
+      trace-time constant (leading dim); the window runs on the device
+      with zero host round-trips.
+    * ``window='indexed'``: K steps reading a DEVICE-RESIDENT data set by
+      index — (state, images_all REPLICATED (packed by
+      steps.pack_images_for_device: (N,HWC/4) i32, or (N,H,W,C) u8),
+      labels_all (N,) REPLICATED, idx (K,B) i32 sharded (None, data),
+      rng). The arrays live in HBM once, each scan iteration gathers its
+      batch at HBM bandwidth, and the host sends only the index window: a
+      few KB a dispatch, not ~3 KB an image.
+
+    Both windows are identical math to K sequential single steps (same
+    per-step rng fold)."""
     from tpu_dist.engine.steps import _train_step_fn
 
     mesh = b.mesh
@@ -406,9 +428,13 @@ def _image_train(plan: Plan, b: Bindings) -> Callable:
 
 
 def _image_eval(plan: Plan, b: Bindings) -> Callable:
-    """Image eval lowerings: per-batch metric sums, or the whole-val-set
-    indexed scan (engine.steps make_eval_step / make_indexed_eval_step
-    bodies, verbatim)."""
+    """Image eval lowerings (C15): metric sums on the global sharded
+    batch — (params, batch_stats, images_u8, labels, valid (B,) f32) — or,
+    for ``window='indexed'``, the whole validation set in ONE dispatch from
+    HBM-resident data: (params, batch_stats, images_all (packed,
+    REPLICATED), labels_all, idx (K,B) i32 sharded (None, data), valid
+    (K,B) f32 same sharding) -> sums over all K batches. ``valid`` masks
+    sampler padding per sample in both."""
     from tpu_dist.engine.steps import _metric_sums, cross_entropy_sum
 
     mesh = b.mesh
@@ -468,7 +494,11 @@ def _image_eval(plan: Plan, b: Bindings) -> Callable:
 # ---- lm lowerings ---------------------------------------------------------
 
 def _lm_accum_train(plan: Plan, b: Bindings) -> Callable:
-    """LM grad-accum step (make_lm_grad_accum_train_step body)."""
+    """ONE LM optimizer step from K microbatches: (state, inputs
+    (K,B,L), targets (K,B,L), rng) -> (state, metric sums over
+    microbatches). Equal microbatch sizes make the average of per-micro
+    means the full-batch mean; dropout folds a per-microbatch index on top
+    of the usual state.step fold."""
     from tpu_dist.engine.lm_steps import _lm_grads_and_metrics
     from tpu_dist.engine.steps import _apply_update
 
@@ -504,18 +534,16 @@ def _lm_accum_train(plan: Plan, b: Bindings) -> Callable:
 
 
 def _lm_explicit_template(plan: Plan, b: Bindings) -> Callable:
-    """The explicit per-device LM step the plan names: a pre-built
-    ``explicit_step_fn`` binding wins (the engines build ring/bucketed
-    flavors once and window them); otherwise ring or bucketed-dp from the
-    engine templates."""
-    if b.explicit_step_fn is not None:
-        return b.explicit_step_fn
+    """The explicit per-device LM step the plan names, from the engine
+    templates: ring-TP (shard_map over (data, model), the ring clone's
+    ppermute rings riding ``model``; params replicated) or explicit dp
+    with ``grad_bucket_mb`` bucket reduce-scatters (<= 0: one pmean)."""
     from tpu_dist.engine.lm_steps import (_lm_explicit_dp_step_fn,
                                           _lm_tp_ring_step_fn)
 
     if plan.tp_impl == "ring":
         return _lm_tp_ring_step_fn(
-            b.model, b.tx, plan.aux_weight, plan.data_axis,
+            _train_model(plan, b), b.tx, plan.aux_weight, plan.data_axis,
             plan.model_axis, b.mesh.shape[plan.model_axis],
             plan.loss_chunk, plan.health)
     return _lm_explicit_dp_step_fn(
@@ -525,9 +553,10 @@ def _lm_explicit_template(plan: Plan, b: Bindings) -> Callable:
 
 
 def _lm_explicit_train(plan: Plan, b: Bindings) -> Callable:
-    """Partition an explicit per-device LM step: single-batch shard_map
-    (the _wrap_explicit_step body) or the indexed scan-inside-shard_map
-    window (make_lm_explicit_indexed_multi_train_step body)."""
+    """Partition an explicit per-device LM step: single-batch shard_map,
+    or for ``window='indexed'`` a lax.scan over (K, B) index windows INSIDE
+    the shard_map program, gathering rows from the HBM-resident (N, L+1)
+    matrix and shifting on device — :func:`_lm_train`'s signatures."""
     step_fn = _lm_explicit_template(plan, b)
     mesh = b.mesh
     data_axis = plan.data_axis
@@ -550,9 +579,13 @@ def _lm_explicit_train(plan: Plan, b: Bindings) -> Callable:
 
 
 def _lm_sp_train(plan: Plan, b: Bindings) -> Callable:
-    """Sequence-parallel LM lowerings (ring attention inside shard_map):
-    single-batch or the indexed device-side-shift window
-    (make_lm_sp_train_step / make_lm_sp_indexed_multi_train_step bodies)."""
+    """Sequence-parallel LM lowerings: batch on ``data``, sequence on
+    ``seq``, ring attention inside shard_map. ``model_ctor(attn_fn=...)``
+    builds the model so the ring binds per axis. Single-batch — tokens
+    sharded (data, seq) — or the indexed window, :func:`_lm_train`'s
+    signature: each scan iteration gathers its (B/data, L+1) rows and takes
+    this device's sequence shard with a device-side shift, so the
+    long-context mode too pays one dispatch per K steps."""
     from tpu_dist.engine.lm_steps import _lm_sp_step_fn, _sp_window_slices
     from tpu_dist.parallel.ring_attention import ring_attention_fn
 
@@ -588,9 +621,20 @@ def _lm_sp_train(plan: Plan, b: Bindings) -> Callable:
 
 
 def _lm_train(plan: Plan, b: Bindings) -> Callable:
-    """The gspmd LM train lowerings: plain jit (dp and every GSPMD-placed
-    layout) or the HBM-resident indexed window, around the ONE template
-    (engine.lm_steps._lm_step_fn)."""
+    """The gspmd LM train lowerings around the ONE template
+    (engine.lm_steps._lm_step_fn): dp, and dp x tp / fsdp / ep when the
+    TrainState was placed with the matching sharding helper (GSPMD
+    propagates the param layout and emits the collectives).
+
+    * ``window='none'``: (state, inputs (B,L), targets (B,L), rng), batch
+      sharded on ``data``.
+    * ``window='indexed'``: K optimizer steps per dispatch from an
+      HBM-RESIDENT token corpus — (state, rows_all (N, L+1) i32
+      REPLICATED, idx (K, B) i32 sharded (None, data), rng) -> (state,
+      metrics summed over K steps). Each scan iteration gathers its
+      (B, L+1) rows and shifts inputs/targets ON DEVICE; the host sends
+      only the index window. Identical math to K sequential single steps
+      (same per-step rng fold)."""
     from tpu_dist.engine.lm_steps import _lm_step_fn
 
     mesh = b.mesh
@@ -624,8 +668,9 @@ def _lm_train(plan: Plan, b: Bindings) -> Callable:
 
 
 def _lm_sp_eval(plan: Plan, b: Bindings) -> Callable:
-    """SP eval lowerings (make_lm_sp_eval_step /
-    make_lm_sp_indexed_eval_step bodies)."""
+    """Held-out eval under sequence parallelism, :func:`_lm_eval`'s two
+    signatures with (data, seq)-sharded tokens, ring attention, and metric
+    sums psum'd over BOTH axes."""
     from tpu_dist.engine.lm_steps import (_lm_eval_metrics,
                                           _sp_window_slices,
                                           zeros_lm_metrics)
@@ -685,8 +730,12 @@ def _lm_sp_eval(plan: Plan, b: Bindings) -> Callable:
 
 
 def _lm_eval(plan: Plan, b: Bindings) -> Callable:
-    """GSPMD LM eval lowerings (make_lm_eval_step /
-    make_lm_indexed_eval_step bodies)."""
+    """GSPMD LM eval lowerings, for any placement the params carry (dp /
+    fsdp / tp / ep): (params, inputs, targets, valid (B,)) -> {loss_sum,
+    correct1, count}, or for ``window='indexed'`` whole-val-set perplexity
+    in ONE dispatch — (params, rows_all (N, L+1) REPLICATED, idx (K, B) i32
+    sharded (None, data), valid (K, B) f32 same sharding). ``valid`` 0/1
+    excludes sampler wrap-padding rows, so perplexity is exact."""
     from tpu_dist.engine.lm_steps import _lm_eval_metrics, zeros_lm_metrics
 
     mesh = b.mesh

@@ -2,11 +2,10 @@
 
 The reference repo's whole value proposition is "pick the right
 launcher/backend variant for your hardware" (PAPER.md: 5-6 hand-tuned
-script variants); rounds 1-14 reproduced that as a combinatorial matrix of
-hand-built step builders (``engine/steps.py`` x ``engine/lm_steps.py``:
-jit / shard_map / windowed / bucketed / ring / sp, x quant x health x
-fused), every new feature touching all of them. :class:`Plan` collapses
-the matrix into one declarative record:
+script variants). Here that choice is one declarative record: the
+trainers derive a :class:`Plan` from their config, mesh and data
+(:func:`plan_from_config`) and ``plan/compile.py`` lowers it; no other
+code decides which step program a run gets. A Plan names:
 
 * **parallelism layout** — ``layout`` (dp | tp | sp) + ``sync`` (gspmd |
   explicit: compiler-inserted vs hand-written collectives);
@@ -24,7 +23,9 @@ A Plan is frozen (hashable), JSON-round-trippable, and content-addressed:
 :func:`plan_hash` is a sha256 over the canonical JSON, so tuner outputs,
 ledger stamps, and bench tags can all name a plan by one stable id.
 ``plan/compile.py`` lowers a Plan to the actual train/eval step callables;
-``plan/tune.py`` searches the plan space against measured artifacts.
+``plan/tune.py`` searches the plan space against measured artifacts;
+:func:`apply_plan_to_config` writes a tuned plan's knobs into a config and
+:func:`plan_from_config` reads the plan a config runs back out.
 
 THIS MODULE IMPORTS NO JAX (the parallel.supervisor convention): the
 ``scripts/lint.sh`` plan gate imports it under a jax-import blocker, and
@@ -97,8 +98,9 @@ def validate_opt_block_rows(rows: int) -> None:
 @dataclass(frozen=True)
 class Plan:
     """One declarative step plan. Every field is a trace-time-static knob
-    of the step compiler; cross-field legality lives in :meth:`validate`
-    (the same exclusion rules the engines enforced by hand, in one place).
+    of the step compiler; cross-field legality lives in :meth:`validate`,
+    its one home (the trainers check only what a plan cannot know: batch
+    divisibility, data placement against data size, model geometry).
     """
 
     engine: str = "lm"                  # image | lm
@@ -135,9 +137,9 @@ class Plan:
     # ------------------------------------------------------------------
     def validate(self) -> "Plan":
         """Raise :class:`PlanError` on any invalid field or combination;
-        returns self so call sites can chain. These are exactly the
-        exclusion rules engine/loop.py + engine/lm_loop.py enforce (one
-        home now, so a new mode cannot drift between them)."""
+        returns self so call sites can chain. The one home of the mode
+        exclusion rules: neither trainer repeats them, so a new mode
+        cannot drift between the two."""
         def _enum(name, value, allowed):
             if value not in allowed:
                 raise PlanError(f"plan.{name}={value!r} "
@@ -175,20 +177,6 @@ class Plan:
                 raise PlanError("window='stacked' is the image engine's "
                                 "host-fed K-step window; the LM windowed "
                                 "path is 'indexed' (HBM-resident rows)")
-        if self.tp_impl == "ring" and not (self.layout == "tp"
-                                           and self.sync == "explicit"):
-            raise PlanError("tp_impl='ring' is the explicit collective "
-                            "matmul: it needs layout='tp' + "
-                            "sync='explicit' (a 'model' axis for the "
-                            "ppermute rings to ride)")
-        if self.layout == "tp" and self.sync == "explicit" \
-                and self.tp_impl != "ring":
-            raise PlanError("layout='tp' + sync='explicit' IS the ring "
-                            "path (tp_impl='ring'); GSPMD TP lowers "
-                            "through sync='gspmd'")
-        if self.layout == "sp" and self.sync != "explicit":
-            raise PlanError("layout='sp' runs ring attention inside "
-                            "shard_map; it requires sync='explicit'")
         if self.grad_bucket_mb < 0:
             raise PlanError("grad_bucket_mb must be >= 0")
         if self.grad_bucket_mb > 0:
@@ -204,6 +192,24 @@ class Plan:
                                 "lm tp/sp layouts keep their own sync "
                                 "(the image explicit step may bucket over "
                                 "'data' while ring-pmean'ing over 'model')")
+        if self.tp_impl == "ring" and not (self.layout == "tp"
+                                           and self.sync == "explicit"):
+            raise PlanError("tp_impl='ring' is the explicit collective "
+                            "matmul: it needs layout='tp' + "
+                            "sync='explicit' (a 'model' axis for the "
+                            "ppermute rings to ride)")
+        if self.layout == "tp" and self.sync == "explicit" \
+                and self.tp_impl != "ring":
+            raise PlanError("layout='tp' + sync='explicit' IS the ring "
+                            "path (tp_impl='ring'); GSPMD TP lowers "
+                            "through sync='gspmd'")
+        if self.layout == "sp" and self.sync != "explicit":
+            raise PlanError("layout='sp' runs ring attention inside "
+                            "shard_map; it requires sync='explicit'")
+        if self.adasum and self.sync != "explicit":
+            raise PlanError("adasum is a reduction of the explicit step; "
+                            "it requires sync='explicit' (averaging "
+                            "instead would misreport the run's math)")
         if self.adasum and self.grad_bucket_mb > 0:
             raise PlanError("grad_bucket_mb decomposes the mean allreduce; "
                             "adasum replaces it — the two are exclusive")
@@ -376,6 +382,58 @@ def apply_plan_to_config(cfg, plan: Plan):
     if plan.window == "indexed":
         updates["data_placement"] = "device"
     return dataclasses.replace(cfg, **updates)
+
+
+# ---- config -> plan -------------------------------------------------------
+
+def plan_from_config(cfg, mesh_shape: dict, window: str = "none") -> Plan:
+    """The :class:`Plan` a TrainConfig/LMConfig runs on a mesh of these
+    axis sizes: THE decision from a config to a step program, and the
+    inverse of :func:`apply_plan_to_config`. ``window`` is what the trainer
+    observed of its data (``none`` | ``stacked`` | ``indexed``): whether
+    the data set fits on the device is a measurement, not a config field.
+
+    The image engine names its sync by ``variant``; the LM engine picks
+    layout and sync from the mesh (a ``seq`` axis is ring attention, a
+    ``model`` axis tensor parallelism, ``tp_impl``/``grad_bucket_mb`` the
+    explicit flavors). The three switches :func:`~tpu_dist.plan.compile.
+    activate_plan` owns (``fused_quant``, ``quant_block``,
+    ``opt_block_rows``) have no config field and stay at their defaults.
+    Returns the plan validated against the mesh."""
+    knobs = dict(window=window, quant=cfg.quant, precision=cfg.precision,
+                 health=cfg.health, grad_bucket_mb=cfg.grad_bucket_mb,
+                 steps_per_dispatch=cfg.steps_per_dispatch,
+                 grad_accum_steps=cfg.grad_accum_steps)
+    if any(f.name == "variant" for f in dataclasses.fields(type(cfg))):
+        if cfg.variant not in ("jit", "shard_map"):
+            raise PlanError(f"unknown variant {cfg.variant!r} "
+                            "(jit|shard_map)")
+        explicit = cfg.variant == "shard_map"
+        plan = Plan(engine="image",
+                    sync="explicit" if explicit else "gspmd",
+                    layout="tp" if cfg.tp_impl == "ring" else "dp",
+                    tp_impl=cfg.tp_impl, adasum=cfg.adasum,
+                    # mean-path knobs of the explicit allreduce: the
+                    # compiler-partitioned step has no place for them
+                    grad_compression=(cfg.grad_compression if explicit
+                                      else "none"),
+                    predivide_factor=(cfg.gradient_predivide_factor
+                                      if explicit else 1.0),
+                    **knobs)
+    else:
+        sp = mesh_shape.get("seq", 1) > 1
+        tp = mesh_shape.get("model", 1) > 1
+        # no model axis, no ring: the knob names how a model axis is run
+        tp_impl = ("gspmd" if cfg.tp_impl == "ring" and not tp
+                   else cfg.tp_impl)
+        plan = Plan(engine="lm",
+                    layout="sp" if sp else "tp" if tp else "dp",
+                    sync=("explicit" if sp or tp_impl == "ring"
+                          or cfg.grad_bucket_mb > 0 else "gspmd"),
+                    tp_impl=tp_impl,
+                    loss_chunk=cfg.loss_chunk,
+                    aux_weight=cfg.moe_aux_weight, **knobs)
+    return plan.validate_against_mesh(mesh_shape)
 
 
 def plan_knob_summary(plan: Plan) -> dict:

@@ -104,8 +104,8 @@ def device_peaks(device_kind: str) -> dict:
 # ---- workload -------------------------------------------------------------
 
 _WORKLOAD_DEFAULTS = {
-    # the r06 LM bench geometry (bench.py BENCH_* defaults): 8 layers,
-    # d1024, seq 2048, vocab 32k — flops/bytes derived below
+    # chip_smoke.py's LM geometry: 8 layers, d1024, seq 2048, vocab 32k,
+    # batch 8 — flops/bytes derived below
     "engine": "lm", "n_params": 113_000_000, "tokens_per_step": 16_384,
     "devices": 8, "seq_len": 2048,
 }

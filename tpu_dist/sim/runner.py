@@ -16,7 +16,14 @@ live (not scheduled-down, not finished) host's published tick
 (``<ledger>.tick`` sidecar) has reached the action's tick — tick gating
 both orders the actions deterministically w.r.t. the traffic and proves
 the gated hosts are actually serving (a host mid-restart holds the clock
-until it resumes). A wall deadline backstops a wedged fleet.
+until it resumes). The gate holds the other way too: hosts pace their
+ticks by the wall clock, this loop and the consensus supervisor's poll run
+on whatever CPU is left, so ``hold.tick`` names the tick no host may pass
+until the next scheduled action has fired and the consensus supervisor has
+resolved every scheduled membership change
+(:meth:`FleetSim._publish_hold`) — on a
+starved box a host would otherwise run its trace out before the rescale
+scheduled inside it arrives. A wall deadline backstops a wedged fleet.
 
 Outputs under ``out_dir``::
 
@@ -49,6 +56,7 @@ from tpu_dist.parallel.consensus import ConsensusDir
 from tpu_dist.parallel.supervisor import RestartPolicy, Supervisor
 from tpu_dist.sim.scenario import (Scenario, compile_host_plans,
                                    load_scenario)
+from tpu_dist.sim.worker import WINDOW_TICKS
 
 
 _REPO_ROOT = os.path.dirname(os.path.dirname(
@@ -105,6 +113,12 @@ class FleetSim:
             else AutoscalePolicy.load(pol))
         self.standby = set(self.sc.standby_hosts())
         self.decisions: List[dict] = []
+        # the hold (module docstring): the membership this runner has
+        # asked for, and the tick of a change the consensus supervisor
+        # has not resolved yet
+        self._members: set = set()
+        self._unresolved_tick: Optional[int] = None
+        self._hold: Optional[int] = None    # what hold.tick says now
 
     # -- wiring -----------------------------------------------------------
     def _host_dir(self, h: int) -> str:
@@ -164,6 +178,51 @@ class FleetSim:
         except (OSError, ValueError):
             return 0
 
+    def _set_member(self, peers, h: int, present: bool,
+                    hold_tick: Optional[int] = None) -> None:
+        """One membership change through the consensus dir. A SCHEDULED
+        change (``hold_tick``, its tick) goes on the books for the hold:
+        its place in the trace is part of the schedule. A capacity
+        decision's change does not: it lands when the monitor says, and
+        the hosts it rescales should meet it under the load that caused
+        it, not after a pause that let their queues drain."""
+        if present:
+            peers[h].register()
+            self._members.add(h)
+        else:
+            peers[h].leave()
+            self._members.discard(h)
+        if hold_tick is not None:
+            self._unresolved_tick = hold_tick
+
+    def _publish_hold(self, pending: list, consensus_alive: bool) -> None:
+        """Write (or clear) ``hold.tick``: one publish window past the
+        earlier of the next scheduled action and a membership change the
+        consensus supervisor has not resolved — a host reaches the action's
+        tick, publishes it, and waits there (sim.worker)."""
+        csup = self._sups.get(self.sc.consensus_host)
+        view = csup.mesh_view if csup is not None else None
+        if not consensus_alive or (
+                view is not None and set(view.hosts) == self._members):
+            self._unresolved_tick = None
+        ticks = [t for t in (self._unresolved_tick,
+                             pending[0].tick if pending else None)
+                 if t is not None]
+        hold = min(ticks) + WINDOW_TICKS if ticks else None
+        if hold == self._hold:
+            return
+        path = os.path.join(self.out, "hold.tick")
+        try:
+            if hold is None:
+                os.remove(path)
+            else:
+                with open(path + ".tmp", "w") as f:
+                    f.write(f"{hold}\n")
+                os.replace(path + ".tmp", path)
+            self._hold = hold
+        except OSError:
+            pass    # retried next poll
+
     # -- the autoscaling loop (round 20, obs.autoscale) -------------------
     def _autoscale_step(self, monitor: CapacityMonitor,
                         tailer: LedgerTailer, clock: int, live: list,
@@ -205,7 +264,7 @@ class FleetSim:
                     f.write(f"{clock}\n")
                 if csup is not None:
                     csup.autoscale_decision = dec["decision"]
-                peers[h].register()
+                self._set_member(peers, h, True)
                 parked.discard(h)
                 elastic.add(h)
                 start_host(h)
@@ -216,7 +275,7 @@ class FleetSim:
             for h in cands[:max(-n, 0)]:
                 if csup is not None:
                     csup.autoscale_decision = dec["decision"]
-                peers[h].leave()
+                self._set_member(peers, h, False)
                 down.add(h)      # the clock must not wait on it
                 gone.add(h)      # permanently out: sheds hand off
                 elastic.discard(h)
@@ -285,6 +344,7 @@ class FleetSim:
         for h, c in peers.items():
             if h not in parked:
                 c.register()
+                self._members.add(h)
 
         threads: Dict[int, threading.Thread] = {}
 
@@ -331,16 +391,17 @@ class FleetSim:
                                or now > force_after or not live):
                 act = pending.pop(0)
                 if act.action == "leave":
-                    peers[act.host].leave()
+                    self._set_member(peers, act.host, False, act.tick)
                     down.add(act.host)
                 elif act.action == "register":
-                    peers[act.host].register()
+                    self._set_member(peers, act.host, True, act.tick)
                     down.discard(act.host)
             if monitor is not None and clock is not None:
                 self._autoscale_step(monitor, tailer, clock, live, peers,
                                      parked, elastic, gone, down,
                                      fleet_ledger, _start_host)
                 self._handoff_step(gone, handoff_done, live)
+            self._publish_hold(pending, sc.consensus_host in live)
             if now - last_fleet_emit >= 1.0:
                 last_fleet_emit = now
                 fleet_ledger.emit("fleet", hosts_live=len(live),

@@ -20,7 +20,12 @@ The global tick survives restarts through a cursor sidecar
 (``<ledger>.cursor.json``: resume tick + completed rids), so a preempted
 host resumes where the fleet clock left it instead of replaying from
 zero; a ``<ledger>.tick`` sidecar publishes the current tick for the
-runner's fleet-clock gate.
+runner's fleet-clock gate. The gate works both ways: a host does not run
+past the tick in the runner's ``hold.tick`` (beside the scenario file) — it
+keeps serving what it has admitted, with its schedule frozen, until the
+runner moves the hold on. Ticks are wall-paced but the control plane is
+not, so without the hold a starved box lets a host run its trace out
+before a membership change scheduled inside it can reach it.
 
 Faults ride the standard machinery: the supervisor exports
 ``TPU_DIST_FAULTS`` from the scenario compile, and the tick loop checks
@@ -123,6 +128,25 @@ def _write_cursor(base: str, tick: int, done, shed=None) -> None:
         os.replace(tmp, _cursor_path(base))
     except OSError:
         pass  # progress bookkeeping must never kill the host
+
+
+def _read_hold(path: str):
+    """The runner's hold tick, or None (no runner, or nothing held)."""
+    try:
+        with open(path) as f:
+            return int(f.read().strip())
+    except (OSError, ValueError):
+        return None
+
+
+def _held(path: str, tick: int) -> bool:
+    """Whether the fleet holds this host at ``tick``. Asked only where the
+    tick has just been published (every WINDOW_TICKS), so that the runner
+    can see the tick it waits for."""
+    if tick % WINDOW_TICKS:
+        return False
+    hold = _read_hold(path)
+    return hold is not None and tick >= hold
 
 
 def _write_tick(base: str, tick: int) -> None:
@@ -232,6 +256,8 @@ def main(argv=None) -> int:
     from tpu_dist.obs.autoscale import LedgerTailer
 
     handoff_tail = LedgerTailer()
+    hold_path = os.path.join(
+        os.path.dirname(os.path.abspath(args.scenario)), "hold.tick")
     handoff_path = base + ".handoff.jsonl" if base else ""
     pending_handoff: list = []
 
@@ -241,6 +267,7 @@ def main(argv=None) -> int:
     window_device_s = 0.0
     window_dispatch_s = 0.0
     window_tokens = 0
+    window_held_s = 0.0
     window_start_tick = tick
     emitted_compile = False
     t_run0 = time.perf_counter()
@@ -250,6 +277,16 @@ def main(argv=None) -> int:
                or eng.queue or any(s is not None for s in eng.slots)):
             if tick > sc.ticks * 10 + 100_000:
                 raise RuntimeError(f"worker did not drain by tick {tick}")
+            # the fleet's hold (module docstring): serve on, admit nothing
+            # new, until the runner moves it or a SIGTERM ends the wait
+            while _held(hold_path, tick) and not obs.preempt_pending():
+                t_hold = time.perf_counter()
+                for c in eng.step():
+                    done.add(c.rid)
+                    window_tokens += c.n_generated
+                obs.heartbeat()
+                time.sleep(sc.tick_s)
+                window_held_s += time.perf_counter() - t_hold
             # coordinated preemption (SIGTERM via RunObs, or an injected
             # preempt_deadline advance notice below)
             if obs.preempt_pending():
@@ -321,8 +358,8 @@ def main(argv=None) -> int:
             tick += 1
             # pacing: the global tick maps to wall time at tick_s x skew;
             # a slow machine just runs late (schedules never change)
-            target = window_t0 + (tick - window_start_tick) \
-                * sc.tick_s * plan.skew
+            target = window_t0 + window_held_s \
+                + (tick - window_start_tick) * sc.tick_s * plan.skew
             sleep = target - time.perf_counter()
             if sleep > 0:
                 time.sleep(sleep)
@@ -349,7 +386,7 @@ def main(argv=None) -> int:
                     _write_tick(base, tick)
                 window_t0 = now
                 window_start_tick = tick
-                window_device_s = window_dispatch_s = 0.0
+                window_device_s = window_dispatch_s = window_held_s = 0.0
                 window_tokens = 0
         eng._emit_kv_cache()  # final pool-pressure snapshot
         if base:

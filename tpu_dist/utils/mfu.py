@@ -1,7 +1,8 @@
 """MFU accounting: model FLOPs vs device peak.
 
-Shared by bench.py and the LM/image trainers so every throughput number can
-carry a model-FLOPs-utilization figure. Peaks are public bf16 spec-sheet
+Shared by the trainers' step records (obs.RunObs), the tuner and the cell
+benchmark so every throughput number can carry a
+model-FLOPs-utilization figure. Peaks are public bf16 spec-sheet
 numbers per chip, keyed by ``device_kind``; a TPU that is not in the table
 is an error (:func:`lookup_peak`), never a default.
 """
@@ -46,7 +47,7 @@ def lm_flops_per_token(params, num_layers: int, seq_len: int,
                        d_model: int) -> float:
     """Analytical model FLOPs per trained token for a dense causal LM:
     6 * N_non-embedding + 6 * layers * L * d (fwd+bwd, causal-halved
-    attention). THE shared accounting for bench.py and LMTrainer — XLA's
+    attention). THE shared accounting for LMTrainer and the benchmark — XLA's
     cost model counts scan bodies once and cannot cost Pallas custom calls,
     so it understates flash-attention runs."""
     import jax
@@ -105,6 +106,5 @@ def moe_lm_flops_per_token(params, num_layers: int, seq_len: int,
     return dense + experts + routing
 
 
-# (the former step_flops() XLA-cost-model probe lives in
-# utils.telemetry.program_stats now — one AOT lower for flops/hbm/HLO
-# together; its last caller, bench.py, moved there in round 10)
+# (the XLA-cost-model probe is utils.telemetry.program_stats — one AOT
+# lower for flops/hbm/HLO together)
