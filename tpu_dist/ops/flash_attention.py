@@ -11,12 +11,15 @@ into the SAME weights as full attention:
   backend; this is the long-context workhorse and the ground truth for the
   kernel below.
 * :func:`flash_attention_fn` — Pallas TPU FlashAttention-2: forward grid
-  (batch*head, q_blocks, kv_blocks) with VMEM scratch accumulators carried
+  (batch*head, q tiles, kv tiles) with VMEM scratch accumulators carried
   across the innermost KV dimension (scores never touch HBM; O(bq*bk)
-  working set at ANY sequence length), causal above-diagonal blocks skipped,
-  fp32 online math, per-row logsumexp written out. Backward is two Pallas
-  kernels (dq; dk+dv) that re-derive probabilities from the stashed
-  logsumexp — score recompute only, not a second full forward.
+  working set at ANY sequence length), fp32 online math, per-row logsumexp
+  written out. Backward is ONE Pallas kernel that re-derives probabilities
+  from the stashed logsumexp once a block pair and feeds dq, dk and dv from
+  them: five products and one exp pass, score recompute only, not a second
+  full forward. Inside a grid tile both walk 512- or 256-wide sub-blocks, so
+  that work above the causal diagonal is not executed and only sub-blocks
+  the diagonal crosses pay for a mask (:func:`flash_work` counts it).
 
 Both are numerically validated against full attention (tests/test_flash.py)
 and compose with the causal offsets ring attention uses.
@@ -24,6 +27,7 @@ and compose with the causal offsets ring attention uses.
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 
@@ -109,23 +113,65 @@ def blockwise_attention_fn(block_size: int = 512):
 # Pallas flash attention (FlashAttention-2 schedule, forward + backward)
 # ---------------------------------------------------------------------------
 #
-# Forward: grid (B*H, q_blocks, kv_blocks) with the KV dimension INNERMOST,
-# so the VMEM scratch accumulators (acc, running max m, running sum l) carry
-# across KV steps of one q block — peak memory is O(bq * bk) regardless of
-# sequence length (no whole-K/V fetch, unlike the round-2 kernel). Causal
-# blocks strictly above the diagonal are skipped (pl.when), saving ~half the
-# FLOPs. The (bq,) logsumexp per row is written out for the backward.
+# Both kernels tile the (lq, lk) score square twice. The GRID's tile is
+# (bq, bk) (``block_q``/``block_k`` clamped to the lengths by ``_blocks``):
+# what one grid step holds in VMEM. Inside a tile the program walks BANDS of
+# sq query rows against the tile's keys in chunks of sk (``_SUB``: 512
+# forward, 256 backward, where the tile allows), and places each band
+# against the causal diagonal from scalars alone:
 #
-# Backward: two Pallas kernels re-derive p = exp(s - lse) from the stashed
-# statistics (FLASH-style recompute of SCORES only, never a second full
-# forward): dq accumulates over KV blocks; dk/dv accumulate over q blocks.
-# delta = rowsum(o * dout) is a cheap fused elementwise pass outside Pallas.
+# * chunks strictly above the diagonal are not computed: a band multiplies
+#   against its first n live chunks only, and a band with none runs nothing;
+# * chunks strictly below it take NO iota, compare or select;
+# * only the last m chunks, the ones the diagonal crosses, are masked.
+#
+# Shapes are static, so every grid tile that the call's shapes and offsets
+# can produce (``_schedule``: the kinds (n, m) of its bands, top to bottom)
+# is a straight-line body of its own, picked by scalars; the aligned
+# square has two, the diagonal tile and the interior one. A band is ONE
+# product of width n * sk and one softmax update, because the lane
+# reductions and the accumulator's rescaling cost a row, not an element:
+# chunk-by-chunk updates at 512 ran the forward 1.4 times slower than one
+# update a band (v5e, PR 29). No branch stands between a tile's bands, so
+# the scheduler may run one band's products under another's vector passes.
+# A tile with no live band runs nothing, and its index maps name the last
+# (forward) / first (backward) live tile, so no DMA is issued for it.
+#
+# Forward: grid (B*H, q tiles, kv tiles), kv INNERMOST: the VMEM scratch
+# accumulators (acc, running max m, running sum l) carry across the kv steps
+# of one q tile. Scores stay unscaled; the softmax scale enters inside the
+# exponent ((s - m) * scale), so the running max is of raw scores. The
+# per-row logsumexp is written out for the backward.
+#
+# Backward: ONE kernel, grid (B*H, kv tiles, q tiles), q INNERMOST. Scores,
+# p = exp(s * scale - lse), dP = dO V^T and dS = p * (dP - delta) are made
+# once a band and feed dV += P^T dO, dK += dS^T Q and dQ += dS K: five
+# products and one exp pass. dK/dV accumulate in VMEM over the inner q
+# steps; dQ accumulates in a float32 (lq, D) scratch that stays in VMEM for
+# the whole head, and each q tile's rows are written out during the last kv
+# tile's steps. The scale is applied once to the dQ/dK accumulators when
+# they are written. delta = rowsum(o * dout) is a cheap fused elementwise
+# pass outside Pallas.
 
 _LANES = 128      # TPU vector lane count: scratch row-stats are (bq, _LANES)
 _STAT_LANES = 8   # lse/delta HBM layout: (B*H, L, 8) — Mosaic block tiling
                   # wants the last dim either 128-divisible or equal to the
                   # array's, so an 8-wide stat lane keeps blocks legal while
                   # costing 8 (not 128) floats per row
+# band height and key-chunk width inside a grid tile (_sub_block). One v5e,
+# B4 L2048 H16 D128, us a call (PR 29): forward 799 at 512, 864 at 256 (each
+# band pays a softmax update a row: a lane reduction and the accumulator's
+# rescaling); the fused backward, which keeps no such state, 1550 at 1024,
+# 1290 at 512, 1185 at 256, 1284 at 128 (forward 1041). The two-kernel
+# program at 1024: 977 and 2701.
+_SUB = {"forward": 512, "backward": 256}
+# the backward's float32 dQ accumulator is resident for a whole head: lq rows
+# of max(D, 128) lanes. 8 MiB holds L 16384 at D <= 128; a longer lq is cut
+# into q chunks outside the kernel (_fa_backward). The kernel's other VMEM
+# (double-buffered tiles, dK/dV accumulators, a band's float32
+# intermediates) is about 14 MiB at 1024 x 1024 x D 128.
+_DQ_RESIDENT_BYTES = 8 * 2**20
+_BWD_TILE_BYTES = 24 * 2**20
 
 
 def _causal_bounds(causal, q_start, k_start, bq, bk):
@@ -137,14 +183,146 @@ def _causal_bounds(causal, q_start, k_start, bq, bk):
     return skip, needs_mask
 
 
+def _sub_block(block, sub):
+    """The band height (or key-chunk width) of a grid tile ``block`` wide:
+    ``sub`` where it divides the tile, else their gcd; a tile whose gcd is
+    not a whole number of lanes is one band."""
+    sub = math.gcd(block, sub)
+    return sub if sub % _LANES == 0 else block
+
+
+def _whole(x, n, most):
+    """How many whole ``n`` fit in ``x``, kept within [0, most]. ``x`` is an
+    int (the static schedule) or a traced scalar (the kernel, an index map);
+    clipping first keeps the traced division, which truncates, a floor."""
+    if isinstance(x, int):
+        return max(0, min(x // n, most))
+    return jax.lax.div(jax.lax.max(jax.lax.min(x, most * n), 0), n)
+
+
+def _first_gap(i, j, bq, bk, q_offset, kv_offset):
+    """First query position of q tile ``i`` minus first key position of kv
+    tile ``j``."""
+    return i * bq - j * bk + (q_offset - kv_offset)
+
+
+def _last_live_kv(iq, bq, bk, nk, q_offset, kv_offset):
+    """The last kv tile with a key that q tile ``iq`` may see."""
+    return _whole(_first_gap(iq, 0, bq, bk, q_offset, kv_offset) + (bq - 1),
+                  bk, nk - 1)
+
+
+def _first_live_q(ik, bq, bk, nq, q_offset, kv_offset):
+    """The first q tile with a row that may see kv tile ``ik``."""
+    return _whole(-_first_gap(0, ik, bq, bk, q_offset, kv_offset), bq, nq - 1)
+
+
+def _band_kind(gap, sq, sk, nc):
+    """(n, m) of the band whose first row sits ``gap`` positions after the
+    tile's first key: it sees the tile's first n key chunks of sk (chunk c
+    is live when c * sk <= gap + sq - 1, its first key no later than the
+    band's last row), the last m of them through the diagonal (chunk c is
+    clear of it when c * sk + sk - 1 <= gap). n = 0: it sees none."""
+    n = _whole(gap + (sq - 1 + sk), sk, nc)
+    return n, n - _whole(gap + 1, sk, nc)
+
+
+def _schedule(lq, lk, bq, bk, sq, sk, causal, q_offset, kv_offset):
+    """The kernels' static schedule: {tile: count} over every grid tile of
+    one (batch, head), a tile being the kinds (n, m) of its bands of sq query
+    rows, top to bottom. The kernels hold one straight-line body a tile that
+    occurs here, and no other."""
+    nc, bands = bk // sk, bq // sq
+    if not causal:
+        return {((nc, 0),) * bands: (lq // bq) * (lk // bk)}
+    tiles = collections.Counter()
+    for iq in range(lq // bq):
+        for ik in range(lk // bk):
+            rel = _first_gap(iq, ik, bq, bk, q_offset, kv_offset)
+            tiles[tuple(_band_kind(rel + a * sq, sq, sk, nc)
+                        for a in range(bands))] += 1
+    return dict(tiles)
+
+
+def _rows(i, n):
+    """Rows [i * n, (i + 1) * n) of a ref, ``i`` static or traced."""
+    import jax.experimental.pallas as pl
+
+    if isinstance(i, int):
+        return pl.ds(i * n, n)
+    return pl.ds(pl.multiple_of(i * n, n), n)
+
+
+def _walk(band, tiles, rel, bq, bk, sq, sk, causal):
+    """``band(a, n, m, gap)`` on every live band of one grid tile. ``rel`` is
+    the tile's first query position minus its first key position; ``gap`` is
+    the band's own (key j of row i is live iff j - i <= gap). Shapes are
+    static, so every tile of ``tiles`` is a body of its own, its bands one
+    after the other with no branch between them (the scheduler may then run
+    one band's products under another's vector passes), and the bands' kinds,
+    from scalars, pick the body."""
+    import jax.experimental.pallas as pl
+
+    nc, bands = bk // sk, range(bq // sq)
+    if not causal:
+        for a in bands:
+            band(a, nc, 0, None)
+        return
+    gaps = [rel + a * sq for a in bands]
+    now = [_band_kind(gap, sq, sk, nc) for gap in gaps]
+
+    def run(tile):
+        for a, (n, m) in enumerate(tile):
+            if n:
+                band(a, n, m, gaps[a])
+
+    for tile in sorted(t for t in tiles if any(n for n, _ in t)):
+        hit = functools.reduce(jnp.logical_and, [
+            jnp.logical_and(now[a][0] == n, now[a][1] == m)
+            for a, (n, m) in enumerate(tile)])
+        pl.when(hit)(functools.partial(run, tile))
+
+
+def _where_live(x, m, sk, gap, masked):
+    """``x`` (a band's scores or probabilities) with ``masked`` where the key
+    is later than the row, in the last ``m`` chunks; the chunks before them
+    are below the diagonal and are not touched."""
+    if not m:
+        return x
+    sq, w = x.shape
+    t = w - m * sk
+    live = (jax.lax.broadcasted_iota(jnp.int32, (sq, m * sk), 1)
+            - jax.lax.broadcasted_iota(jnp.int32, (sq, m * sk), 0)) <= gap - t
+    tail = jnp.where(live, x[:, t:], masked)
+    return tail if t == 0 else jnp.concatenate([x[:, :t], tail], axis=1)
+
+
+def _lane_sums(p):
+    """(sq, w) -> (sq, _LANES) whose lanes add up to the rows' sums: whole
+    registers added, the one reduction across lanes left to the finalize (a
+    lane reduction costs a row as much as eight score columns)."""
+    w = p.shape[1]
+    if w % _LANES:
+        return jnp.broadcast_to(
+            jnp.sum(p, axis=-1, keepdims=True) * (1.0 / _LANES),
+            (p.shape[0], _LANES))
+    lanes = p[:, :_LANES]
+    for i in range(1, w // _LANES):
+        lanes = lanes + p[:, i * _LANES:(i + 1) * _LANES]
+    return lanes
+
+
+def _dot(a, b, contract):
+    return jax.lax.dot_general(a, b, ((contract[:1], contract[1:]), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                   acc_ref, m_ref, l_ref, *,
-                   bq, bk, nk, scale, causal, q_offset, kv_offset):
+                   acc_ref, m_ref, l_ref, *, tiles,
+                   bq, bk, sq, sk, nk, scale, causal, q_offset, kv_offset):
     import jax.experimental.pallas as pl
 
     iq, ik = pl.program_id(1), pl.program_id(2)
-    q_start = q_offset + iq * bq
-    k_start = kv_offset + ik * bk
 
     @pl.when(ik == 0)
     def _init():
@@ -152,144 +330,94 @@ def _fa_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    skip, needs_mask = _causal_bounds(causal, q_start, k_start, bq, bk)
+    # a row with no live key at all (its m stays NEG_INF, so exp(s - m) of a
+    # masked score would be 1) exists only when the keys start after the
+    # queries; the offsets are static, so no cell pays for the guard
+    dead_rows = causal and kv_offset > q_offset
 
-    @pl.when(jnp.logical_not(skip))
-    def _step():
+    def band(a, n, m, gap):
+        rows, w = _rows(a, sq), n * sk
         # inputs stay in their storage dtype (bf16 at real scales): the MXU
         # takes bf16 x bf16 -> fp32 natively; upcasting first would force
         # the ~4x-slower fp32 matmul path
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # (bq, bk) f32
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            mask = kpos <= qpos
-            s = jnp.where(jnp.logical_or(jnp.logical_not(needs_mask), mask),
-                          s, NEG_INF)
-        m_prev = m_ref[...]                     # (bq, LANES), lanes equal
-        l_prev = l_ref[...]
+        s = _dot(q_ref[0, rows, :], k_ref[0, :w, :], (1, 1))    # (sq, w) f32
+        s = _where_live(s, m, sk, gap, NEG_INF)
+        m_prev = m_ref[rows, :]                 # (sq, LANES), lanes equal
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # masked scores contribute ZERO even when the whole row is masked
-        # (m_new == NEG_INF would make exp(s - m_new) = 1 otherwise)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new[:, :1]))
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[...] = (acc_ref[...] * alpha[:, :1]
-                        + jax.lax.dot_general(
-                            p.astype(v_ref.dtype), v_ref[0],
-                            (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32))
-        m_ref[...] = m_new
-        l_ref[...] = l_new
+        alpha = jnp.exp((m_prev - m_new) * scale)
+        p = jnp.exp((s - m_new[:, :1]) * scale)
+        if dead_rows:
+            p = _where_live(p, m, sk, gap, 0.0)
+        l_ref[rows, :] = l_ref[rows, :] * alpha + _lane_sums(p)
+        acc_ref[rows, :] = (acc_ref[rows, :] * alpha[:, :1]
+                            + _dot(p.astype(v_ref.dtype), v_ref[0, :w, :],
+                                   (1, 0)))
+        m_ref[rows, :] = m_new
 
-    # finalize ONCE, at this q block's last live KV step (computable from the
-    # causal geometry; nk-1 when not causal or when the diagonal lies beyond
-    # the kv range) — not a per-step write-through
-    if causal:
-        last_live = jnp.clip((q_start + bq - 1 - kv_offset) // bk, 0, nk - 1)
-    else:
-        last_live = nk - 1
+    _walk(band, tiles, _first_gap(iq, ik, bq, bk, q_offset, kv_offset),
+          bq, bk, sq, sk, causal)
+
+    # finalize ONCE, at this q tile's last live kv step (nk-1 when not
+    # causal or when the diagonal lies beyond the kv range)
+    last_live = (_last_live_kv(iq, bq, bk, nk, q_offset, kv_offset)
+                 if causal else nk - 1)
 
     @pl.when(ik == last_live)
     def _finalize():
-        l_cur = jnp.maximum(l_ref[..., :1], 1e-30)
+        l_cur = jnp.maximum(jnp.sum(l_ref[...], axis=-1, keepdims=True),
+                            1e-30)
         o_ref[0] = (acc_ref[...] / l_cur).astype(o_ref.dtype)
-        lse_ref[0] = jnp.broadcast_to(m_ref[..., :1] + jnp.log(l_cur),
-                                      (bq, _STAT_LANES))
+        lse_ref[0] = jnp.broadcast_to(
+            m_ref[..., :1] * scale + jnp.log(l_cur), (bq, _STAT_LANES))
 
 
-def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                      dq_ref, dq_acc, *,
-                      bq, bk, nk, scale, causal, q_offset, kv_offset):
+def _fa_bwd_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
+                   dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *, tiles,
+                   bq, bk, sq, sk, nq, nk, scale, causal, q_offset,
+                   kv_offset):
     import jax.experimental.pallas as pl
 
-    iq, ik = pl.program_id(1), pl.program_id(2)
-    q_start = q_offset + iq * bq
-    k_start = kv_offset + ik * bk
+    ik, iq = pl.program_id(1), pl.program_id(2)   # q tiles INNERMOST
 
-    @pl.when(ik == 0)
-    def _init():
+    @pl.when(jnp.logical_and(ik == 0, iq == 0))
+    def _init_head():
         dq_acc[...] = jnp.zeros_like(dq_acc)
-
-    skip, needs_mask = _causal_bounds(causal, q_start, k_start, bq, bk)
-
-    @pl.when(jnp.logical_not(skip))
-    def _step():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(jnp.logical_or(jnp.logical_not(needs_mask),
-                                         kpos <= qpos), s, NEG_INF)
-        lse = lse_ref[0][:, :1]                 # (bq, 1)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        dp = jax.lax.dot_general(
-            g_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bq, bk)
-        ds = p * (dp - delta_ref[0][:, :1]) * scale
-        dq_acc[...] += jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        last_live = jnp.clip((q_start + bq - 1 - kv_offset) // bk, 0, nk - 1)
-    else:
-        last_live = nk - 1
-
-    @pl.when(ik == last_live)
-    def _finalize():
-        dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
-
-
-def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref, dk_acc, dv_acc, *,
-                       bq, bk, nq, scale, causal, q_offset, kv_offset):
-    import jax.experimental.pallas as pl
-
-    ik, iq = pl.program_id(1), pl.program_id(2)   # q blocks INNERMOST here
-    q_start = q_offset + iq * bq
-    k_start = kv_offset + ik * bk
 
     @pl.when(iq == 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    skip, needs_mask = _causal_bounds(causal, q_start, k_start, bq, bk)
+    def band(a, n, m, gap):
+        rows, w = _rows(a, sq), n * sk
+        head_rows = _rows(iq * (bq // sq) + a, sq)
+        q, g = q_ref[0, rows, :], g_ref[0, rows, :]
+        k, v = k_ref[0, :w, :], v_ref[0, :w, :]
+        s = _dot(q, k, (1, 1))                               # (sq, w) f32
+        # masked on p, not on s: a dead row's lse is about NEG_INF * scale,
+        # and exp(masked score - that) would be 1
+        p = _where_live(jnp.exp(s * scale - lse_ref[0, rows, :][:, :1]),
+                        m, sk, gap, 0.0)
+        ds = p * (_dot(g, v, (1, 1)) - delta_ref[0, rows, :][:, :1])
+        dv_acc[:w, :] += _dot(p.astype(g.dtype), g, (0, 0))       # (w, D)
+        ds = ds.astype(q.dtype)
+        dk_acc[:w, :] += _dot(ds, q, (0, 0))
+        dq_acc[head_rows, :] += _dot(ds, k, (1, 0))               # (sq, D)
 
-    @pl.when(jnp.logical_not(skip))
-    def _step():
-        s = jax.lax.dot_general(
-            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale
-        if causal:
-            qpos = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-            s = jnp.where(jnp.logical_or(jnp.logical_not(needs_mask),
-                                         kpos <= qpos), s, NEG_INF)
-        lse = lse_ref[0][:, :1]
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse))
-        dv_acc[...] += jax.lax.dot_general(
-            p.astype(g_ref.dtype), g_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # (bk, D)
-        dp = jax.lax.dot_general(
-            g_ref[0], v_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1]) * scale         # (bq, bk)
-        dk_acc[...] += jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[0], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+    _walk(band, tiles, _first_gap(iq, ik, bq, bk, q_offset, kv_offset),
+          bq, bk, sq, sk, causal)
 
-    # every causal kv block's LAST live q block is the final one (later q
-    # rows attend to all earlier kv), so finalize exactly once at iq == nq-1
+    # every kv tile's LAST live q tile is the final one (later rows see all
+    # earlier keys), so dk/dv are complete exactly at iq == nq - 1
     @pl.when(iq == nq - 1)
-    def _finalize():
-        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+    def _finalize_kv():
+        dk_ref[0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    # and a q tile's dq is complete once the last kv tile has met it
+    @pl.when(ik == nk - 1)
+    def _finalize_q():
+        dq_ref[0] = (dq_acc[_rows(iq, bq), :] * scale).astype(dq_ref.dtype)
 
 
 def _fold(x):
@@ -315,27 +443,75 @@ def _blocks(lq, lk, block_q, block_k):
     return fit(block_q, lq), fit(block_k, lk)
 
 
+def _plan(sub, lq, lk, block_q, block_k, causal, q_offset, kv_offset):
+    """Tile, bands and schedule of a call, from its shapes alone."""
+    bq, bk = _blocks(lq, lk, block_q, block_k)
+    sq, sk = _sub_block(bq, sub), _sub_block(bk, sub)
+    return dict(bq=bq, bk=bk, sq=sq, sk=sk, tiles=_schedule(
+        lq, lk, bq, bk, sq, sk, causal, q_offset, kv_offset))
+
+
+def flash_work(lq, lk, d, block_q=1024, block_k=1024, causal=True,
+               q_offset=0, kv_offset=0):
+    """What the kernels' static schedules execute for one (batch, head), by
+    direction: the (sq, sk) sub-block pairs skipped, run unmasked and run
+    masked, and the matrix FLOPs of the pairs that run (two products a pair
+    forward, five backward, 2 * sq * sk * d each). Read from ``_schedule``,
+    which the kernels are built from; they have no other schedule."""
+    work = {}
+    for direction, products in (("forward", 2), ("backward", 5)):
+        plan = _plan(_SUB[direction], lq, lk, block_q, block_k, causal,
+                     q_offset, kv_offset)
+        sq, sk = plan["sq"], plan["sk"]
+        bands = [(kind, c) for tile, c in plan["tiles"].items()
+                 for kind in tile]
+        unmasked = sum(c * (n - m) for (n, m), c in bands)
+        masked = sum(c * m for (n, m), c in bands)
+        work[direction] = {
+            "sub_block": (sq, sk), "unmasked": unmasked, "masked": masked,
+            "skipped": (lq // sq) * (lk // sk) - unmasked - masked,
+            "flops": products * 2.0 * sq * sk * d * (unmasked + masked)}
+    return work
+
+
 def _fa_forward(q, k, v, causal, q_offset, kv_offset, block_q, block_k,
                 interpret):
+    return _fa_forward_call(q, k, v, causal, q_offset, kv_offset, block_q,
+                            block_k, interpret, _SUB["forward"])
+
+
+# jitted, like _fa_backward_call: a model calls attention once a layer with
+# the same shapes, and a jitted callee is traced and lowered once a program,
+# not once a layer (the kernels' bodies are most of a step program's tracing)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _fa_forward_call(q, k, v, causal, q_offset, kv_offset, block_q, block_k,
+                     interpret, sub):
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, lq, h, d = q.shape
     lk = k.shape[1]
-    bq, bk = _blocks(lq, lk, block_q, block_k)
+    plan = _plan(sub, lq, lk, block_q, block_k, causal, q_offset, kv_offset)
+    bq, bk = plan["bq"], plan["bk"]
+    nk = lk // bk
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
-    scale = 1.0 / math.sqrt(d)
-    grid = (b * h, lq // bq, lk // bk)          # kv INNERMOST: scratch carries
+    grid = (b * h, lq // bq, nk)                # kv INNERMOST: scratch carries
+
+    def kv_tile(bh, iq, ik):                    # a skipped tile: no new DMA
+        if causal:
+            ik = jax.lax.min(ik, _last_live_kv(iq, bq, bk, nk, q_offset,
+                                               kv_offset))
+        return bh, ik, 0
 
     forward = pl.pallas_call(
-        functools.partial(_fa_fwd_kernel, bq=bq, bk=bk, nk=lk // bk,
-                          scale=scale, causal=causal,
+        functools.partial(_fa_fwd_kernel, **plan, nk=nk,
+                          scale=1.0 / math.sqrt(d), causal=causal,
                           q_offset=q_offset, kv_offset=kv_offset),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, iq, ik: (bh, ik, 0)),
-            pl.BlockSpec((1, bk, d), lambda bh, iq, ik: (bh, ik, 0)),
+            pl.BlockSpec((1, bk, d), kv_tile),
+            pl.BlockSpec((1, bk, d), kv_tile),
         ],
         out_specs=[
             pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0)),
@@ -348,8 +524,8 @@ def _fa_forward(q, k, v, causal, q_offset, kv_offset, block_q, block_k,
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, d), jnp.float32),        # acc
-            pltpu.VMEM((bq, _LANES), jnp.float32),   # running max
-            pltpu.VMEM((bq, _LANES), jnp.float32),   # running sum
+            pltpu.VMEM((bq, _LANES), jnp.float32),   # running max (raw)
+            pltpu.VMEM((bq, _LANES), jnp.float32),   # running sum, by lane
         ],
         interpret=interpret,
     )
@@ -359,15 +535,60 @@ def _fa_forward(q, k, v, causal, q_offset, kv_offset, block_q, block_k,
     return jnp.swapaxes(out.reshape(b, h, lq, d), 1, 2), lse
 
 
-def _fa_backward(q, k, v, out, lse, g, causal, q_offset, kv_offset,
-                 block_q, block_k, interpret):
+@functools.partial(jax.jit, static_argnames=(
+    "causal", "q_offset", "kv_offset", "block_q", "block_k", "kv_dtype",
+    "interpret", "sub"))
+def _fa_backward_call(qf, kf, vf, gf, lse, delta, causal, q_offset,
+                      kv_offset, block_q, block_k, kv_dtype, interpret, sub):
+    """dq, dk, dv of folded (B*H, L, D) operands in one fused call; dk/dv
+    come out as ``kv_dtype``."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    (bh, lq, d), lk = qf.shape, kf.shape[1]
+    plan = _plan(sub, lq, lk, block_q, block_k, causal, q_offset, kv_offset)
+    bq, bk = plan["bq"], plan["bk"]
+    nq, nk = lq // bq, lk // bk
+
+    def q_tile(bh, ik, iq):                     # a skipped tile: no new DMA
+        if causal:
+            iq = jax.lax.max(iq, _first_live_q(ik, bq, bk, nq, q_offset,
+                                               kv_offset))
+        return bh, iq, 0
+
+    q_spec = pl.BlockSpec((1, bq, d), q_tile)
+    row_spec = pl.BlockSpec((1, bq, _STAT_LANES), q_tile)
+    k_spec = pl.BlockSpec((1, bk, d), lambda bh, ik, iq: (bh, ik, 0))
+    # dq's tiles leave during the last kv tile's steps; until then the
+    # output's block index stands still, so nothing is written back
+    dq_spec = pl.BlockSpec(
+        (1, bq, d), lambda bh, ik, iq: (bh, jnp.where(ik == nk - 1, iq, 0), 0))
+
+    backward = pl.pallas_call(
+        functools.partial(_fa_bwd_kernel, **plan, nq=nq, nk=nk,
+                          scale=1.0 / math.sqrt(d), causal=causal,
+                          q_offset=q_offset, kv_offset=kv_offset),
+        grid=(bh, nk, nq),
+        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        out_specs=[dq_spec, k_spec, k_spec],
+        out_shape=[jax.ShapeDtypeStruct(qf.shape, qf.dtype),
+                   jax.ShapeDtypeStruct(kf.shape, kv_dtype),
+                   jax.ShapeDtypeStruct(vf.shape, kv_dtype)],
+        scratch_shapes=[pltpu.VMEM((lq, d), jnp.float32),    # dq, whole head
+                        pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_DQ_RESIDENT_BYTES + _BWD_TILE_BYTES),
+        interpret=interpret,
+    )
+    with jax.named_scope("flash_attention"):
+        return backward(qf, kf, vf, gf, lse, delta)
+
+
+def _fa_backward(q, k, v, out, lse, g, causal, q_offset, kv_offset,
+                 block_q, block_k, interpret):
     b, lq, h, d = q.shape
     lk = k.shape[1]
-    bq, bk = _blocks(lq, lk, block_q, block_k)
-    scale = 1.0 / math.sqrt(d)
     qf, kf, vf, gf = _fold(q), _fold(k), _fold(v), _fold(g)
     # delta_i = sum_d o_i * do_i — the softmax-jacobian row term; a single
     # fused elementwise+reduce, no reason to put it in the kernel. Stored
@@ -376,45 +597,27 @@ def _fa_backward(q, k, v, out, lse, g, causal, q_offset, kv_offset,
                     axis=-1)                              # (B*H, Lq)
     delta = jnp.broadcast_to(delta[..., None],
                              (*delta.shape, _STAT_LANES))
+    call = functools.partial(_fa_backward_call, causal=causal,
+                             kv_offset=kv_offset, block_q=block_q,
+                             block_k=block_k, interpret=interpret,
+                             sub=_SUB["backward"])
 
-    q_spec = pl.BlockSpec((1, bq, d), lambda bh, iq, ik: (bh, iq, 0))
-    k_spec = pl.BlockSpec((1, bk, d), lambda bh, iq, ik: (bh, ik, 0))
-    row_spec = pl.BlockSpec((1, bq, _STAT_LANES),
-                            lambda bh, iq, ik: (bh, iq, 0))
-
-    backward_dq = pl.pallas_call(
-        functools.partial(_fa_bwd_dq_kernel, bq=bq, bk=bk, nk=lk // bk,
-                          scale=scale, causal=causal,
-                          q_offset=q_offset, kv_offset=kv_offset),
-        grid=(b * h, lq // bq, lk // bk),       # kv innermost: dq carries
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        interpret=interpret,
-    )
-
-    # second pass: kv block fixed, q blocks innermost (dk/dv carry)
-    q_spec2 = pl.BlockSpec((1, bq, d), lambda bh, ik, iq: (bh, iq, 0))
-    k_spec2 = pl.BlockSpec((1, bk, d), lambda bh, ik, iq: (bh, ik, 0))
-    row_spec2 = pl.BlockSpec((1, bq, _STAT_LANES),
-                             lambda bh, ik, iq: (bh, iq, 0))
-    backward_dkv = pl.pallas_call(
-        functools.partial(_fa_bwd_dkv_kernel, bq=bq, bk=bk, nq=lq // bq,
-                          scale=scale, causal=causal,
-                          q_offset=q_offset, kv_offset=kv_offset),
-        grid=(b * h, lk // bk, lq // bq),
-        in_specs=[q_spec2, k_spec2, k_spec2, q_spec2, row_spec2, row_spec2],
-        out_specs=[k_spec2, k_spec2],
-        out_shape=[jax.ShapeDtypeStruct(kf.shape, k.dtype),
-                   jax.ShapeDtypeStruct(vf.shape, v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
-        interpret=interpret,
-    )
-    with jax.named_scope("flash_attention"):
-        dq = backward_dq(qf, kf, vf, gf, lse, delta)
-        dk, dv = backward_dkv(qf, kf, vf, gf, lse, delta)
+    # the rows of q whose float32 dq fits the resident accumulator
+    bq, _ = _blocks(lq, lk, block_q, block_k)
+    rows = max(_DQ_RESIDENT_BYTES // (4 * max(d, _LANES)) // bq, 1) * bq
+    if lq <= rows:
+        dq, dk, dv = call(qf, kf, vf, gf, lse, delta, q_offset=q_offset,
+                          kv_dtype=k.dtype)
+    else:
+        # a longer lq: q chunks, each a fused call at its own offset; their
+        # dk/dv partial sums stay float32 until they are added
+        parts = [call(qf[:, r:r + rows], kf, vf, gf[:, r:r + rows],
+                      lse[:, r:r + rows], delta[:, r:r + rows],
+                      q_offset=q_offset + r, kv_dtype=jnp.float32)
+                 for r in range(0, lq, rows)]
+        dq = jnp.concatenate([p[0] for p in parts], axis=1)
+        dk = sum(p[1] for p in parts).astype(k.dtype)
+        dv = sum(p[2] for p in parts).astype(v.dtype)
 
     unfold = lambda x, l: jnp.swapaxes(x.reshape(b, h, l, d), 1, 2)
     return unfold(dq, lq), unfold(dk, lk), unfold(dv, lk)
@@ -572,7 +775,7 @@ def flash_attention_fn(block_q: int = 1024, block_k: int | None = None,
                        recompute_block: int | None = None,
                        mesh=None, spec=None):
     """Returns attn(q, k, v, causal=True, q_offset=0, kv_offset=0) backed by
-    the Pallas FlashAttention-2 kernels (forward AND backward — the backward
+    the Pallas FlashAttention-2 kernels (forward AND one fused backward — it
     recomputes scores from the stashed logsumexp, it does not re-run a full
     blockwise forward).
 
@@ -584,13 +787,23 @@ def flash_attention_fn(block_q: int = 1024, block_k: int | None = None,
     batch rows and heads, so sharding those two axes needs no collective.
     Leave both unset on one device or inside an already-manual program.
 
+    ``block_q``/``block_k`` are the GRID's tile: upper bounds on what one
+    grid step holds in VMEM, clamped to the sequence lengths (and shrunk to
+    a gcd where they do not divide them, ``_blocks``). They are not the
+    granularity of the causal mask: inside a tile the kernels walk
+    sub-blocks (``_SUB``: 512 wide forward, 256 backward, where the tile
+    allows) and skip, run bare or mask each by where the diagonal lies.
+    1024 x 1024 tiles keep the grid's steps few (each step costs about
+    0.35 us and a K/V fetch); the sub-block widths come from chip timings
+    at B4/L2048/H16/D128 (beside ``_SUB``). The backward keeps float32 dQ
+    for a whole head in VMEM
+    and cuts a longer lq into chunks by the operands' shapes alone
+    (``_fa_backward``).
+
     ``interpret=None`` auto-selects interpreter mode off-TPU so the same
     code runs in the CPU test mesh. ``recompute_block`` is a legacy alias
     for ``block_k`` (the round-2 kernel's recompute granularity); passing
-    both is an error rather than a silent override (ADVICE r3). ``block_k``
-    defaults to 1024 — a round-4 on-chip sweep at B8/L2048/H16/D64 measured
-    1024x1024 ~20% faster fwd+bwd than the round-3 512x512 default (blocks
-    clamp to the sequence length, so short sequences are unaffected).
+    both is an error rather than a silent override (ADVICE r3).
     """
     if recompute_block is not None:
         if block_k is not None:
