@@ -229,3 +229,57 @@ def test_loader_propagates_worker_errors():
     except RuntimeError:
         raised = True
     assert raised
+
+
+def _synthetic_one_shot(num, shape, num_classes, proto_seed, sample_seed):
+    """The form ``_synthetic`` had until it drew in chunks: the whole set's
+    noise from one ``rng.normal`` call, five full-size temporaries."""
+    proto_rng = np.random.default_rng(proto_seed)
+    rng = np.random.default_rng(sample_seed)
+    h, w, c = shape
+    protos = proto_rng.normal(
+        0.0, 1.0, size=(num_classes, 4, 4, c)).astype(np.float32)
+    protos = np.repeat(np.repeat(protos, (h + 3) // 4, axis=1),
+                       (w + 3) // 4, axis=2)[:, :h, :w, :]
+    labels = rng.integers(0, num_classes, size=num).astype(np.int32)
+    noise = rng.normal(0.0, 0.6, size=(num, h, w, c)).astype(np.float32)
+    imgs = np.clip((protos[labels] + noise + 3.0) / 6.0, 0.0, 1.0)
+    return (imgs * 255).astype(np.uint8), labels
+
+
+@pytest.mark.parametrize("shape,classes,num", [
+    ((32, 32, 3), 10, 1500),     # CIFAR: 682 rows a chunk
+    ((28, 28, 1), 10, 6001),     # MNIST: 2674
+    ((224, 224, 3), 50, 31),     # ImageNet: 13 (the one-shot form's
+                                 # upsampled prototypes: 0.6 MB a class)
+])
+def test_synthetic_equals_the_one_shot_form_bitwise(shape, classes, num):
+    from tpu_dist.data.datasets import _SYNTH_CHUNK_ELEMS, _synthetic
+
+    rows = _SYNTH_CHUNK_ELEMS // int(np.prod(shape))
+    assert rows >= 1 and num > rows and num % rows  # a ragged last chunk
+    ds = _synthetic(num, shape, classes, 5, 6, "synth")
+    images, labels = _synthetic_one_shot(num, shape, classes, 5, 6)
+    assert ds.images.dtype == np.uint8 and ds.labels.dtype == np.int32
+    np.testing.assert_array_equal(ds.labels, labels)
+    np.testing.assert_array_equal(ds.images, images)
+    assert ds.images.std() > 20  # noise and prototypes, not a constant
+
+
+def test_synthetic_peak_allocation_is_the_result_plus_a_few_chunks():
+    import tracemalloc
+
+    from tpu_dist.data.datasets import _SYNTH_CHUNK_ELEMS, _synthetic
+
+    num, shape = 16384, (32, 32, 3)   # one-shot: 403 MB of float64 noise
+    tracemalloc.start()
+    try:
+        ds = _synthetic(num, shape, 10, 5, 6, "synth")
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    chunk_f64 = 8 * _SYNTH_CHUNK_ELEMS
+    assert ds.images.nbytes == num * 3072
+    # a chunk holds its float64 draw, the float32 cast and the gathered
+    # prototypes at once: two chunks' worth of float64; three is the bound
+    assert peak - ds.images.nbytes < 3 * chunk_f64, peak

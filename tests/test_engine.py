@@ -430,3 +430,88 @@ def test_windowed_eval_matches_host_eval(tmp_path):
     tr._val_data_dev = None  # force the host-fed path on the same state
     acc_host = tr.validate(0)
     assert acc_dev == acc_host
+
+
+def _toy_trainer(tmp, **kw):
+    from tpu_dist.configs import TrainConfig
+    from tpu_dist.engine import Trainer
+
+    kw = {"dataset": "synthetic-cifar10", "arch": "resnet18", **kw}
+    return Trainer(TrainConfig(
+        batch_size=64, synth_train_size=128, synth_val_size=64, seed=5,
+        print_freq=100, checkpoint_dir=str(tmp), **kw))
+
+
+def _assert_same_bits(got, want):
+    """Leaf for leaf the same dtype, shape and bytes. The one exception is
+    a ViT's ``pos_embed``: ``initializers.normal(0.02)`` is
+    ``random.normal`` (sqrt(2) x erf_inv) times 0.02, and one program
+    rounds the two factors' product once where two programs round twice:
+    a last bit, in a quarter of the leaf."""
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        path = jax.tree_util.keystr(path)
+        assert a.dtype == b.dtype and a.shape == b.shape, path
+        a, b = np.asarray(a), np.asarray(b)
+        if path.endswith("['pos_embed']"):
+            assert np.abs(a.view(np.int32).astype(np.int64)
+                          - b.view(np.int32)).max() <= 1, path
+        else:
+            assert a.tobytes() == b.tobytes(), path
+
+
+@pytest.mark.parametrize("arch,kw", [
+    ("resnet50", dict(optimizer="sgd", precision="bf16")),
+    ("resnet18", dict(optimizer="fused_sgd")),
+    ("resnet18", dict(optimizer="sgd", precision="bf16_params",
+                      loss_scale=1024.0)),
+    ("vit_tiny", dict(optimizer="adamw", loss_scale=2.0 ** 15)),
+])
+def test_trainer_state_is_bitwise_the_eager_one(tmp_path, arch, kw):
+    """The state ``Trainer()`` makes in ONE compiled program equals, on the
+    CPU backend, what eager ``model.init`` + storage cast + ``tx.init`` +
+    loss scale gave, bit for bit, and is born replicated over the mesh."""
+    from tpu_dist.ops import LossScaleState
+
+    tr = _toy_trainer(tmp_path, arch=arch, **kw)
+    h, w, c = tr.train_ds.image_shape
+    variables = tr.model.init(
+        {"params": tr.rng, "dropout": tr.rng},
+        jnp.zeros((2, h, w, c), jnp.float32), train=False)
+    eager = (variables["params"], variables.get("batch_stats", {}))
+    want = TrainState.create(
+        tr.policy.cast_params_for_storage(eager[0]), eager[1], tr.tx,
+        LossScaleState.create(tr.cfg.loss_scale)
+        if tr.cfg.loss_scale else None)
+    assert len(jax.tree.leaves(tr.state)) > 8
+    _assert_same_bits(tr.state, want)
+    for leaf in jax.tree.leaves(tr.state):
+        assert leaf.sharding.is_equivalent_to(replicated(tr.mesh), leaf.ndim)
+    # the one definition of "initialise this model", called on its own
+    _assert_same_bits(init_model(tr.model, tr.rng, (2, h, w, c)), eager)
+
+
+# what Trainer() may compile: the state's program, the PRNG key's, the
+# device-resident rows' placement; eager flax init alone made 56 for
+# resnet18 and 203 for resnet50
+_BUILD_COMPILES_AT_MOST = 6
+
+
+@pytest.mark.parametrize("variant,k", [("jit", 2), ("shard_map", 1)])
+def test_trainer_constructor_makes_a_handful_of_compilations(tmp_path,
+                                                             variant, k):
+    from tpu_dist.obs import trace
+
+    tr = _toy_trainer(tmp_path, variant=variant, steps_per_dispatch=k)
+    info = tr.build_info
+    assert 1 <= info["build_compiles"] <= _BUILD_COMPILES_AT_MOST, info
+    # the rows are placed on the device only for the windowed path
+    parts = ["data", "init"] + ["place"] * tr.device_data
+    assert tr.device_data == (k > 1)
+    assert list(info["build_s"]) == ["total"] + parts
+    assert info["build_s"]["total"] >= sum(info["build_s"][p] for p in parts)
+    spans = trace.ring().snapshot()
+    build = [s for s in spans if s.name == "train.build"][-1]
+    assert [s.name for s in spans if s.parent == build.sid] \
+        == ["build." + p for p in parts]

@@ -477,6 +477,46 @@ def test_image_engine_ledger_smoke(tmp_path):
     assert any("phase time share" in ln for ln in lines)
 
 
+def test_run_start_carries_the_build_and_the_report_prints_it(tmp_path):
+    """``Trainer.fit()``'s run_start record carries what the constructor
+    cost (``build_s``: the train.build span and its parts) and how many
+    backend compilations it made; tools/ledger_report prints both from the
+    recorded ledger, and the spans land beside it."""
+    from tools.ledger_report import summarize
+    from tpu_dist.configs import TrainConfig
+    from tpu_dist.engine.loop import Trainer
+    from tpu_dist.obs import SPANS_SUFFIX
+
+    path = str(tmp_path / "img.jsonl")
+    tr = Trainer(TrainConfig(
+        arch="lenet", dataset="synthetic-mnist", epochs=1, batch_size=16,
+        print_freq=100, seed=0, synth_train_size=64, synth_val_size=32,
+        steps_per_dispatch=2, checkpoint_dir=str(tmp_path / "ck"),
+        ledger_path=path))
+    tr.fit()
+    recs = read_ledger(path)
+    (start,) = [r for r in recs if r["event"] == "run_start"]
+    assert start["build_s"] == tr.build_info["build_s"]
+    assert list(start["build_s"]) == ["total", "data", "init", "place"]
+    assert 1 <= start["build_compiles"] == tr.build_info["build_compiles"]
+    lines = []
+    summary = summarize(recs, out=lines.append)
+    (line,) = [ln for ln in lines if ln.startswith("build: ")]
+    assert f"{start['build_compiles']} backend compilations" in line
+    assert all(f"{part} " in line for part in ("data", "init", "place"))
+    assert summary["run"]["build_s"] == start["build_s"]
+    assert summary["run"]["build_compiles"] == start["build_compiles"]
+    names = [json.loads(ln)["name"] for ln in open(path + SPANS_SUFFIX)]
+    assert {"train.build", "build.data", "build.init",
+            "build.place"} <= set(names)
+    # a ledger recorded before the constructor was measured still renders
+    for r in recs:
+        r.pop("build_s", None), r.pop("build_compiles", None)
+    lines = []
+    summarize(recs, out=lines.append)
+    assert not [ln for ln in lines if ln.startswith("build: ")]
+
+
 def test_lm_engine_ledger_smoke(tmp_path):
     """Acceptance twin for the LM engine, windowed (K>1) path included —
     plus the live-metrics acceptance: a curl-equivalent scrape of the

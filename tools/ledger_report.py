@@ -585,6 +585,18 @@ def summarize(records, out=print):
                           ("kind", "devices", "mesh", "process_count",
                            "peak_tflops", "peak_is_nominal", "jax_version",
                            "plan_hash", "plan_source", "plan_knobs")}
+        # what the engine's constructor cost before this record (the image
+        # Trainer's train.build span and its parts) and how many backend
+        # compilations it made: a handful, or an eager init is back
+        if r.get("build_s"):
+            parts = dict(r["build_s"])
+            total = parts.pop("total", None)
+            out("build: "
+                + (f"{total:.1f}s" if total is not None else "?s") + " ("
+                + ", ".join(f"{k} {v:.1f}s" for k, v in parts.items())
+                + f"), {r.get('build_compiles')} backend compilations")
+            summary["run"].update(build_s=r["build_s"],
+                                  build_compiles=r.get("build_compiles"))
     # resolved step plan (tpu_dist.plan): which tuned/loaded plan drove the
     # step compilation — the tuner's measured-refinement loop reads this
     # back (tools/tune.py --ledger-summary keys trials on run.plan_hash)

@@ -72,25 +72,45 @@ class ArrayDataset:
         return _native.gather_batch(self.images, self.labels, indices)
 
 
+# elements of noise drawn at a time by _synthetic: 16 MiB of float64, so the
+# set's peak is its uint8 result plus some tens of MB whatever its size
+_SYNTH_CHUNK_ELEMS = 1 << 21
+
+
 def _synthetic(num: int, shape: Tuple[int, int, int], num_classes: int,
                proto_seed: int, sample_seed: int, name: str) -> ArrayDataset:
     """Deterministic learnable synthetic data: per-class low-frequency pattern
     + per-sample noise. Class prototypes depend only on ``proto_seed`` so the
     train and val splits share one distribution; samples/noise differ via
     ``sample_seed``. Class signal is strong enough that a CNN separates it in
-    a few steps (used by convergence tests, SURVEY.md §4)."""
+    a few steps (used by convergence tests, SURVEY.md §4).
+
+    The noise is drawn, added, clipped and written into the uint8 result a
+    bounded chunk of rows at a time. A ``Generator`` fills sequentially, so
+    chunked draws continue ONE stream: the arrays are bitwise those of a
+    single ``rng.normal(size=(num, h, w, c))`` (3.2 GB of float64 and five
+    more full-size temporaries for 131,072 CIFAR rows; 1.2 MB an image at
+    224 x 224), whose cost was page faults, not arithmetic."""
     proto_rng = np.random.default_rng(proto_seed)
     rng = np.random.default_rng(sample_seed)
     h, w, c = shape
-    # low-frequency class prototypes: upsampled 4x4 random grids
+    # low-frequency class prototypes: 4x4 random grids, upsampled a chunk's
+    # rows at a time (1000 classes at 224 x 224 would be 600 MB whole)
     protos = proto_rng.normal(0.0, 1.0, size=(num_classes, 4, 4, c)).astype(np.float32)
-    protos = np.repeat(np.repeat(protos, (h + 3) // 4, axis=1), (w + 3) // 4, axis=2)
-    protos = protos[:, :h, :w, :]
+    # labels first, then the noise: the order of draws is the stream's
     labels = rng.integers(0, num_classes, size=num).astype(np.int32)
-    noise = rng.normal(0.0, 0.6, size=(num, h, w, c)).astype(np.float32)
-    imgs = protos[labels] + noise
-    imgs = np.clip((imgs + 3.0) / 6.0, 0.0, 1.0)
-    images = (imgs * 255).astype(np.uint8)
+    images = np.empty((num, h, w, c), np.uint8)
+    rows = max(1, _SYNTH_CHUNK_ELEMS // (h * w * c))
+    for lo in range(0, num, rows):
+        chunk = labels[lo:lo + rows]
+        x = rng.normal(0.0, 0.6, size=(len(chunk), h, w, c)).astype(np.float32)
+        x += np.repeat(np.repeat(protos[chunk], (h + 3) // 4, axis=1),
+                       (w + 3) // 4, axis=2)[:, :h, :w, :]
+        x += 3.0
+        x /= 6.0
+        np.clip(x, 0.0, 1.0, out=x)
+        x *= 255
+        images[lo:lo + rows] = x  # the float -> uint8 cast of astype
     mean = np.full((c,), 0.5, np.float32)
     std = np.full((c,), 0.25, np.float32)
     return ArrayDataset(images, labels, mean, std, num_classes, name)

@@ -139,8 +139,12 @@ class LMTrainer:
         # compiled program: eager flax init dispatches some sixty tiny
         # programs, which on the chip is most of a cold start's compile
         # count (87 -> 11 in the smoke's LM phase). On the CPU backend the
-        # values are bitwise the eager ones (dense, int8 and MoE checked);
-        # on the chip they differ in the last bits, as any fused program may.
+        # values are bitwise the eager ones (as Trainer's: engine/state.py
+        # init_model) but for leaves drawn as random.normal times a constant
+        # deviation, here the two embeddings: one program rounds the product
+        # of the normal's own sqrt(2) and the deviation once where eager
+        # calls round twice, a last bit in part of the leaf. On the chip
+        # they differ in the last bits, as any fused program may.
         params = jax.jit(lambda key: self.decode_model.init(
             {"params": key}, np.zeros((1, cfg.seq_len), np.int32),
             train=False)["params"])(jax.random.PRNGKey(seed))

@@ -58,6 +58,22 @@ class Trainer:
     """
 
     def __init__(self, cfg: configs.TrainConfig, mesh=None):
+        # construction is one span with its three heavy parts beneath it
+        # (train.build > build.data, build.init, build.place); fit()'s
+        # run_start record carries their seconds and how many backend
+        # compilations the constructor made (a handful: an eager init
+        # creeping back in reads in the hundreds)
+        compiles = trace.backend_compiles()
+        self._build_tracer = trace.StepTracer("build.")
+        with trace.ring().span("train.build") as built:
+            self._build(cfg, mesh)
+        self.build_info = {
+            "build_s": {"total": round(built.seconds, 3),
+                        **{k: round(v, 3)
+                           for k, v in self._build_tracer.pop().items()}},
+            "build_compiles": trace.backend_compiles() - compiles}
+
+    def _build(self, cfg: configs.TrainConfig, mesh) -> None:
         # step plan (tpu_dist.plan): the `plan` knob rewrites the
         # plan-owned config fields (incl. variant) and flips the
         # trace-time kernel switches BEFORE anything below reads them
@@ -92,9 +108,11 @@ class Trainer:
         # data set has been measured (_build_steps)
         self.plan = plan_from_config(cfg, dict(self.mesh.shape))
         self.policy = make_policy(cfg.precision)
-        self.train_ds, self.val_ds = load_dataset(
-            cfg.dataset, cfg.data, cfg.synth_train_size, cfg.synth_val_size,
-            seed=cfg.seed if cfg.seed is not None else 1234)
+        with self._build_tracer.span("data"):
+            self.train_ds, self.val_ds = load_dataset(
+                cfg.dataset, cfg.data, cfg.synth_train_size,
+                cfg.synth_val_size,
+                seed=cfg.seed if cfg.seed is not None else 1234)
         self.num_classes = self.train_ds.num_classes
 
         nprocs = jax.process_count()
@@ -156,22 +174,6 @@ class Trainer:
         seed = cfg.seed if cfg.seed is not None else 0
         self.rng = jax.random.PRNGKey(seed)
         h, w, c = self.train_ds.image_shape
-        params, batch_stats = init_model(
-            self.model, self.rng, (2, h, w, c))
-        params = self.policy.cast_params_for_storage(params)
-        if cfg.pretrained:  # existence checked first-line in __init__
-            pre_params, pre_stats, pre_meta = ckpt.load_warmstart(
-                cfg.pretrained)
-            params, n_p, skipped = ckpt.graft_params(params, pre_params)
-            batch_stats, n_s, _ = ckpt.graft_params(batch_stats, pre_stats)
-            if n_p == 0:
-                raise ValueError(
-                    f"--pretrained {cfg.pretrained} (arch "
-                    f"{pre_meta.get('arch', '?')!r}) shares no tensors with "
-                    f"{cfg.arch!r} — wrong checkpoint?")
-            self.log(f"=> warm-started {n_p} param tensors (+{n_s} BN stats)"
-                     f" from {cfg.pretrained}"
-                     + (f"; fresh init kept for {skipped}" if skipped else ""))
 
         # ceil: the sampler pads to full batches, so an epoch really runs
         # ceil(N/batch) optimizer steps — floor would fire LR decay early
@@ -189,11 +191,28 @@ class Trainer:
                 cfg.lr_step_epochs, schedule=self.schedule,
                 kind=cfg.optimizer, b1=cfg.adam_b1, b2=cfg.adam_b2,
                 eps=cfg.adam_eps)
-        loss_scale = (LossScaleState.create(cfg.loss_scale)
-                      if cfg.loss_scale else None)
-        state = TrainState.create(params, batch_stats, self.tx, loss_scale)
-        # replicate state across the mesh explicitly
-        self.state = jax.device_put(state, replicated(self.mesh))
+
+        def make_state(key):
+            params, batch_stats = init_model(self.model, key, (2, h, w, c))
+            return TrainState.create(
+                self.policy.cast_params_for_storage(params), batch_stats,
+                self.tx, (LossScaleState.create(cfg.loss_scale)
+                          if cfg.loss_scale else None))
+
+        with self._build_tracer.span("init"):
+            # the whole state (parameters, batch statistics, the storage
+            # cast, tx.init, the loss scale) is born in ONE compiled
+            # program of the PRNG key, replicated over the mesh where it
+            # comes out. Eager, the same work was some 230 one-operation
+            # compilations for ResNet-50, then a copy of the whole tree
+            # from the default device. What equals the eager values, on
+            # the CPU and on the chip: init_model's docstring, which
+            # LMTrainer's jitted init (lm_loop.py) shares.
+            self.state = jax.jit(
+                make_state, out_shardings=replicated(self.mesh))(self.rng)
+            if cfg.pretrained:  # existence checked first-line in __init__
+                self._graft_pretrained()
+            jax.block_until_ready(self.state)
 
         augment = self.train_ds.name.startswith(("imagenet", "synth-imagenet"))
         self.transform = make_transform(
@@ -245,19 +264,26 @@ class Trainer:
             # whole training set resident in HBM (rows packed into i32 words
             # for native 32-bit gathers), replicated per chip; per-step
             # batches are gathered on device from an index window
-            self._train_data_dev = (
-                jax.device_put(pack_images_for_device(self.train_ds.images),
-                               replicated(self.mesh)),
-                jax.device_put(self.train_ds.labels.astype(np.int32),
-                               replicated(self.mesh)))
-            # the val set rides along in HBM too (same placement rules):
-            # the whole distributed eval becomes ONE dispatch per epoch
-            if val_rides:
-                self._val_data_dev = (
-                    jax.device_put(pack_images_for_device(self.val_ds.images),
-                                   replicated(self.mesh)),
-                    jax.device_put(self.val_ds.labels.astype(np.int32),
+            with self._build_tracer.span("place"):
+                self._train_data_dev = (
+                    jax.device_put(
+                        pack_images_for_device(self.train_ds.images),
+                        replicated(self.mesh)),
+                    jax.device_put(self.train_ds.labels.astype(np.int32),
                                    replicated(self.mesh)))
+                # the val set rides along in HBM too (same placement
+                # rules): the whole distributed eval becomes ONE dispatch
+                # per epoch
+                if val_rides:
+                    self._val_data_dev = (
+                        jax.device_put(
+                            pack_images_for_device(self.val_ds.images),
+                            replicated(self.mesh)),
+                        jax.device_put(self.val_ds.labels.astype(np.int32),
+                                       replicated(self.mesh)))
+                # the span ends when the rows have landed
+                jax.block_until_ready(
+                    (self._train_data_dev, self._val_data_dev))
 
         self.batch_sharding = batch_sharding(self.mesh)
         self.best_acc1 = 0.0
@@ -290,7 +316,7 @@ class Trainer:
                     "--resume checkpoint is from a different model geometry ("
                     + ", ".join(f"{k}: checkpoint {a} vs run {b}"
                                 for k, (a, b) in hard_pre.items()) + ")")
-            self.state, meta = ckpt.load_checkpoint(cfg.resume, state)
+            self.state, meta = ckpt.load_checkpoint(cfg.resume, self.state)
             self.state = jax.device_put(self.state, replicated(self.mesh))
             self.start_epoch = meta.get("epoch", 0)
             self.best_acc1 = meta.get("best_acc1", 0.0)
@@ -372,6 +398,28 @@ class Trainer:
         self._fused_quant = cfg.quant == "int8" and fused_quant_active()
 
     # ------------------------------------------------------------------
+    def _graft_pretrained(self) -> None:
+        """Warm start: the donor's tensors over the fresh state's where
+        path and shape match (fresh optimizer state: no ``tx.init`` here
+        depends on a parameter's value)."""
+        cfg = self.cfg
+        pre_params, pre_stats, pre_meta = ckpt.load_warmstart(cfg.pretrained)
+        params, n_p, skipped = ckpt.graft_params(self.state.params,
+                                                 pre_params)
+        batch_stats, n_s, _ = ckpt.graft_params(self.state.batch_stats,
+                                                pre_stats)
+        if n_p == 0:
+            raise ValueError(
+                f"--pretrained {cfg.pretrained} (arch "
+                f"{pre_meta.get('arch', '?')!r}) shares no tensors with "
+                f"{cfg.arch!r} — wrong checkpoint?")
+        self.log(f"=> warm-started {n_p} param tensors (+{n_s} BN stats)"
+                 f" from {cfg.pretrained}"
+                 + (f"; fresh init kept for {skipped}" if skipped else ""))
+        self.state = jax.device_put(
+            self.state.replace(params=params, batch_stats=batch_stats),
+            replicated(self.mesh))
+
     def _build_steps(self, eval_transform, val_rides: bool) -> None:
         """THE place the step programs come from: the config's plan with
         the window kind the data allows, compiled against this run's
@@ -858,7 +906,7 @@ class Trainer:
         # SIGTERM becomes a snapshot request this loop drains at its next
         # step boundary (the coordinated-preemption contract)
         self.obs.enable_preempt_snapshot()
-        self.obs.run_start()
+        self.obs.run_start(**self.build_info)
         if self._peer_restored:
             try:
                 mesh_epoch = int(

@@ -241,7 +241,9 @@ class RunObs:
         return self._preempt_event.is_set()
 
     # -- lifecycle ------------------------------------------------------
-    def run_start(self) -> None:
+    def run_start(self, **extra) -> None:
+        """``extra``: what the engine knows of its own start and the record
+        should carry (``Trainer``: ``build_s``, ``build_compiles``)."""
         import jax
 
         self._t0 = time.time()
@@ -283,7 +285,8 @@ class RunObs:
             # this run's step compilation (None = hand-set knobs)
             plan_hash=(self.plan_info or {}).get("hash"),
             plan_source=(self.plan_info or {}).get("source"),
-            plan_knobs=(self.plan_info or {}).get("knobs"))
+            plan_knobs=(self.plan_info or {}).get("knobs"),
+            **extra)
         if self.plan_info:
             self.ledger.emit(
                 "plan", source=self.plan_info.get("source"),

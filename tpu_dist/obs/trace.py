@@ -159,6 +159,30 @@ def ring() -> SpanRing:
     return _RING
 
 
+_compiles: Optional[List[int]] = None
+
+
+def backend_compiles() -> int:
+    """Backend compilations of this process since the first call (which
+    registers the ``jax.monitoring`` listener; a persistent-cache read
+    counts as one, as in ``chip_smoke.py:CompileMeter``). A phase reads it
+    before and after: ``Trainer()``'s ``build_compiles``. One listener a
+    process, like the ring: ``jax.monitoring`` has no public way to take a
+    single listener off again, so a counter an object would leak one each."""
+    global _compiles
+    if _compiles is None:
+        import jax.monitoring
+
+        counter = _compiles = [0]
+
+        def count(event: str, secs: float, **_) -> None:
+            if event == "/jax/core/compile/backend_compile_duration":
+                counter[0] += 1
+
+        jax.monitoring.register_event_duration_secs_listener(count)
+    return _compiles[0]
+
+
 class StepTracer:
     """The trainers' spans: each goes to the ring as ``<prefix><name>`` and
     also accumulates its seconds under its path (``data`` ->
