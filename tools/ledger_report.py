@@ -429,6 +429,11 @@ def decode_section(records, out=print):
             # prefills that wrote a slot's state
             srv["state_bytes"] = last.get("state_bytes")
             srv["state_writes_last"] = last.get("state_writes")
+            # what a sequence costs in cache: K and V bytes a token over the
+            # layers that keep pages, and what the window rings hold (all
+            # slots; 0 for a model without a window layer)
+            srv["kv_bytes_per_token"] = last.get("kv_bytes_per_token")
+            srv["window_bytes"] = last.get("window_bytes")
             # the decode tick one ahead of the host: ticks dispatched
             # while the tick before them was unread, and tokens computed
             # for a slot that had already ended on eos_id (dropped)
@@ -471,6 +476,11 @@ def decode_section(records, out=print):
             out(f"  slot state: {_si(srv['state_bytes'], 'B')} allocated, "
                 f"{srv['state_writes_last'] or 0} prefills wrote a slot's "
                 "state")
+        if srv.get("kv_bytes_per_token"):
+            out(f"  KV cache: {_si(srv['kv_bytes_per_token'], 'B')} a token "
+                "in pages"
+                + (f", {_si(srv['window_bytes'], 'B')} of window rings"
+                   if srv.get("window_bytes") else ""))
         if srv.get("ticks_ahead_last"):
             out(f"  decode tick: {srv['ticks_ahead_last']} of "
                 f"{srv['ticks_last']} ticks dispatched ahead of the host's "

@@ -49,6 +49,21 @@ layout are about pages and have no meaning for slot state: the serving
 engine refuses them for such a model, and a sharded pool refuses a
 slot-state layer here.
 
+Two more kinds (PR 35), for a model whose attention layers do not each keep
+a whole sequence. ``("window", kv_heads, head_dim, group, window)``: a RING
+a slot, ``pages_for(window) + 1`` pages of rows (``ops.paged_attention.
+PagedLayer``'s ring layout) addressed by slot like slot state, so its bytes
+are ``max_slots x (window + page_size)`` rows whatever ``max_len`` is;
+position ``t`` wraps to row ``t % rows``, nothing is allocated or freed.
+``("shared", layer)``: NOTHING; the layer reads layer ``layer``'s pages as
+the same program wrote them a few layers earlier, and its entry here is
+``None``. A ``("pages", kv_heads, head_dim, group, "rows")`` layer keeps
+block-table pages like any other but in the rows layout (a token's KV heads
+side by side), which the grouped in-place read wants. With these a pool may
+hold full-length pages for ONE layer of many: ``kv_bytes_per_token`` in
+``stats()`` is what a token costs in pages, ``window_bytes`` what the rings
+hold.
+
 The allocator is HOST-side state (plain Python ints): page grants happen
 at admission time on the scheduler thread, never inside a jitted program —
 the device programs only ever see block tables as arrays.
@@ -189,7 +204,13 @@ class PagedKVPool:
         # place by every tick, never shared between sequences
         self._layers: List = []
         self.state_bytes = 0
+        self.window_bytes = 0            # the rings, all slots
+        self.kv_bytes_per_token = 0      # K and V of one token, all pages
         for kind, *spec in layout:
+            if kind == "shared":
+                # reads layer spec[0]'s pages; keeps nothing
+                self._layers.append(None)
+                continue
             if kind == "slot_state":
                 if mesh is not None:
                     raise NotImplementedError(
@@ -202,8 +223,35 @@ class PagedKVPool:
                 self._layers.append(state)
                 continue
             heads, hdim = spec[0], spec[1]
+            # K and V of one token in this layer (int8: + a float32 scale a
+            # head)
+            token_bytes = 2 * heads * (
+                hdim + 4 if kv_quant == "int8"
+                else hdim * jnp.dtype(dtype).itemsize)
+            if kind == "window" or "rows" in spec[3:]:
+                if mesh is not None or kv_quant != "none":
+                    raise NotImplementedError(
+                        "a window ring or a rows-layout KV layer in an "
+                        "sp-sharded or int8 pool: its read "
+                        "(ops.paged_attention.grouped_read) takes unsharded "
+                        "bf16 or fp32 rows")
+                # a ring: one page more than the window holds, a slot
+                ring = (pages_for(spec[3], page_size) + 1
+                        if kind == "window" else 0)
+                shape = (max_slots * ring if ring else rows, page_size,
+                         heads * hdim)
+                self._layers.append(PagedLayer(
+                    zeros(shape, dtype), zeros(shape, dtype), read=read,
+                    ring=ring))
+                if ring:
+                    self.window_bytes += (max_slots * ring * page_size
+                                          * token_bytes)
+                else:
+                    self.kv_bytes_per_token += token_bytes
+                continue
             shape = (rows, page_size, heads, hdim)
             sshape = (rows, page_size, heads)
+            self.kv_bytes_per_token += token_bytes
             if kv_quant == "int8":
                 self._layers.append(PagedLayer(
                     zeros(shape, jnp.int8), zeros(shape, jnp.int8),
@@ -518,7 +566,7 @@ class PagedKVPool:
         return tuple(self._layers)
 
     def page_layers(self) -> tuple:
-        """The layers that hold pages."""
+        """The layers that hold pages (block-table pages or rings)."""
         return tuple(l for l in self._layers if isinstance(l, PagedLayer))
 
     def adopt(self, new_layers) -> None:
@@ -541,4 +589,6 @@ class PagedKVPool:
                 "cow_copies": self.cow_copies,
                 "alloc_total": self.alloc_total,
                 "kv_quant": self.kv_quant,
-                "state_bytes": self.state_bytes}
+                "state_bytes": self.state_bytes,
+                "window_bytes": self.window_bytes,
+                "kv_bytes_per_token": self.kv_bytes_per_token}

@@ -17,7 +17,9 @@ Composition (each piece usable alone):
   convention) — mixed-length sequences share HBM without fragmentation —
   and, for the layers whose ``model.cache_layout()`` says ``slot_state``
   (a state-space layer's recurrent state and convolution tail), with
-  per-slot arrays beside the pages (the class docstring has the rules);
+  per-slot arrays beside the pages; a ``window`` layer keeps a ring a
+  slot, a ``shared`` layer nothing (the class docstring has the four
+  kinds and their rules);
 * two jitted programs serve all traffic: ``prefill`` (one admit's prompt,
   padded to a length bucket, writing its pages and sampling the first
   token) and ``decode_tick`` (the packed slot set, one token per active
@@ -232,8 +234,14 @@ class _Flight:
 
 
 def _slot_state_layers(layout) -> int:
-    """How many layers of a model's ``cache_layout()`` keep slot state."""
-    return sum(kind == "slot_state" for kind, *_ in layout)
+    """How many layers of a model's ``cache_layout()`` keep slot state (an
+    entry with no array, a layer that keeps nothing, is not one)."""
+    return sum(kind == "slot_state" and bool(spec[0])
+               for kind, *spec in layout)
+
+
+def _kinds(layout, kind: str) -> int:
+    return sum(k == kind for k, *_ in layout)
 
 
 def _default_buckets(max_len: int) -> Tuple[int, ...]:
@@ -284,12 +292,17 @@ def _prefill_program(model, temperature, top_k, top_p, sp_mesh=None):
         logits, new_layers = model.apply(
             {"params": params}, prompt, train=False,
             paged=paged, paged_prefill=True)
-        last = jnp.take_along_axis(
+        # a model whose last layers cache nothing runs them on the prompt's
+        # last live row alone and returns that row's logits, [1, 1, V];
+        # what the traced program returned is kept for the span, a bucket
+        head_rows[prompt.shape[1]] = logits.shape[1]
+        last = logits[:, 0] if logits.shape[1] == 1 else jnp.take_along_axis(
             logits, jnp.reshape(length - 1, (1, 1, 1)).astype(jnp.int32),
             axis=1)[:, 0]
         nxt, rng = _sample(last, temperature, rng, top_k, top_p)
         return nxt[0].astype(jnp.int32), new_layers, rng
 
+    head_rows = prefill.head_rows = {}      # bucket -> rows of logits
     return prefill
 
 
@@ -569,21 +582,32 @@ class ServeEngine:
     ``step()`` once per scheduler iteration (evict -> admit+prefill ->
     decode tick), each returning the requests that finished.
 
-    Which models: the dense ``TransformerLM`` and the hybrid state-space /
-    attention ``HybridLM``. The engine asks a model one question, once:
-    ``model.cache_layout()``, one entry a layer, ``("pages", kv_heads,
-    head_dim, query heads a KV head)`` or ``("slot_state", {name: (shape a
-    slot, dtype)})``, and builds its pool from the answer. Slot state is
+    Which models: the dense ``TransformerLM``, the hybrid state-space /
+    attention ``HybridLM`` and the decoder-hybrid-decoder ``Phi4FlashLM``.
+    The engine asks a model one question, once: ``model.cache_layout()``,
+    one entry a layer of FOUR kinds, and builds its pool from the answer:
+    ``("pages", kv_heads, head_dim, query heads a KV head[, "rows"])``, K
+    and V rows behind the block tables; ``("slot_state", {name: (shape a
+    slot, dtype)})``; ``("window", kv_heads, head_dim, group, window)``, a
+    ring a slot of ``window + page_size`` rows whose bytes do not depend on
+    ``max_len``, written by prefill (the prompt's last ring's worth of
+    rows) and by every tick at ``position % rows``; and ``("shared",
+    layer)``, a layer that keeps nothing and is handed layer ``layer``'s
+    pages as the same program (prefill or tick) wrote them. A model may run
+    its last layers on a prompt's last live row only and return a prefill's
+    logits as ``[1, 1, V]`` (``serve.prefill``'s ``cross_rows``). Slot state is
     addressed by slot, not by block table: prefill writes a slot's rows at
     the prompt's true length (the programs hand the model ``live`` and
     ``slots`` beside the block tables), every tick updates every decoding
     slot's row in place and holds the others, a call that feeds position 0
     starts from zeros (no reset at admission; eviction frees pages only),
     and chunked prefill carries the state from chunk to chunk in the
-    slot's own row. Refused by name for a model with slot state:
-    ``prefix_cache`` (shared pages bring no recurrent state), ``spec_k > 0``
-    (a rejected draft would need the state rolled back) and ``mesh=`` (the
-    sp-sharded pool shards by page); refused for every model: MoE blocks.
+    slot's own row. Refused by name for a model with slot state or rings:
+    ``prefix_cache`` (shared pages bring neither recurrent state nor a
+    ring's rows), ``spec_k > 0`` (a rejected draft would need state and
+    ring rolled back) and ``mesh=`` (the sp-sharded pool shards by page);
+    for a model with rings or a shared layer also ``prefill_chunk`` (their
+    reads take one query a row); refused for every model: MoE blocks.
     """
 
     def __init__(self, model, params, config: Optional[ServeConfig] = None,
@@ -627,8 +651,13 @@ class ServeEngine:
         # (arrays a slot, written by prefill and updated by every tick)
         layout = model.cache_layout()
         self.state_layers = _slot_state_layers(layout)
-        if self.state_layers:
-            self._refuse_over_slot_state(cfg, mesh)
+        self.window_layers = _kinds(layout, "window")
+        # layers that read ONE layer's pages in a tick: it and its readers
+        readers = _kinds(layout, "shared")
+        self.shared_readers = readers + bool(readers)
+        self.window = max((spec[3] for kind, *spec in layout
+                           if kind == "window"), default=0)
+        self._refuse_over_slot_state(cfg, mesh, layout)
         self.pool = self._pool_for(layout, model.dtype, cfg.attn_read, mesh)
         self.max_pages_per_seq = self.pool.pages_needed(self.max_len)
         # speculative decoding: a draft proposes cfg.spec_k tokens per tick
@@ -656,8 +685,7 @@ class ServeEngine:
             # bandwidth optimization for the big base arenas; the draft's
             # are small by construction
             draft_layout = self.draft_model.cache_layout()
-            if _slot_state_layers(draft_layout):
-                self._refuse_over_slot_state(cfg, None)
+            self._refuse_over_slot_state(cfg, None, draft_layout)
             self.draft_pool = self._pool_for(
                 draft_layout, self.draft_model.dtype, "exact", None)
         elif draft_model is not None:
@@ -730,10 +758,11 @@ class ServeEngine:
         # ops.paged_attention applies when the program is traced, asked
         # once here for the one program this engine dispatches (the
         # speculative tick is named by its verify window, Lq = k + 1)
-        groups = [group for kind, *_, group in layout if kind == "pages"]
+        paged = [spec for kind, *spec in layout
+                 if kind in ("pages", "window")]
         self.tick_read = decode_read(
             self.pool.page_layers()[0], 1 + cfg.spec_k, self.sp_mesh,
-            groups[0]) if groups else "none"
+            paged[0][2], paged[0][1]) if paged else "none"
         self.state_writes = 0        # prefills that wrote a slot's state
         # the plain tick's decode state, resident on the device: (flat block
         # tables, each slot's last token, its position), every row on the
@@ -776,23 +805,42 @@ class ServeEngine:
             max_slots=cfg.max_slots)
 
     @staticmethod
-    def _refuse_over_slot_state(cfg: ServeConfig, mesh) -> None:
-        """What has no meaning yet for a model that keeps slot state, each
-        refused by name rather than served wrong."""
-        if cfg.prefix_cache:
+    def _refuse_over_slot_state(cfg: ServeConfig, mesh, layout) -> None:
+        """What has no meaning yet for a model that keeps something a slot
+        (recurrent state, a window ring) or whose layers read another
+        layer's pages, each refused by name rather than served wrong."""
+        rings, readers = _kinds(layout, "window"), _kinds(layout, "shared")
+        # what a slot keeps beside the pages: its name, what a prefix hit
+        # would not bring of it, what a rejected draft would have to restore
+        kept = [words for words, n in (
+            (("slot state", "the recurrent state at the end of the shared "
+              "prefix", "the recurrent state"), _slot_state_layers(layout)),
+            (("window rings", "the window rings' rows",
+              "the rings' overwritten rows"), rings)) if n]
+        if kept:
+            held, lacks, restores = (list(col) for col in zip(*kept))
+            held = " and ".join(held)
+        if cfg.prefix_cache and kept:
             raise NotImplementedError(
-                "prefix_cache over slot state: a hit on shared pages brings "
-                "K and V but not the recurrent state at the end of the "
-                "shared prefix")
-        if cfg.spec_k > 0:
+                f"prefix_cache over {held}: a hit on shared pages brings "
+                "the K and V of the layers that keep pages"
+                + (" (the layer that others read among them)"
+                   if readers else "") + " but not " + " nor ".join(lacks))
+        if cfg.spec_k > 0 and kept:
             raise NotImplementedError(
-                "speculative decoding (spec_k > 0) over slot state: a "
-                "rejected draft would need the recurrent state rolled back "
-                "to the accepted token")
-        if mesh is not None:
+                f"speculative decoding (spec_k > 0) over {held}: a "
+                "rejected draft would need " + " and ".join(restores)
+                + " rolled back to the accepted token")
+        if mesh is not None and kept:
             raise NotImplementedError(
-                "an sp-sharded pool (mesh=) over slot state: the arenas "
-                "shard by page, and per-slot state has no page")
+                f"an sp-sharded pool (mesh=) over {held}: the arenas "
+                "shard by page, and what a slot keeps has no page")
+        if cfg.prefill_chunk > 0 and (rings or readers):
+            raise NotImplementedError(
+                "chunked prefill (prefill_chunk > 0) over window rings or "
+                "a layer whose pages other layers read: their read takes "
+                "one query a row, and a chunk's rows would need the ring "
+                "as it stood at each of them")
 
     # -- admission --------------------------------------------------------
     def submit(self, req: DecodeRequest) -> bool:
@@ -1187,7 +1235,9 @@ class ServeEngine:
         with self._span("serve.prefill", rid=req.rid,
                         trace_id=self._trace_id(req.rid), prompt_len=p,
                         bucket=bucket, shared_len=shared_len,
-                        state_layers=self.state_layers):
+                        state_layers=self.state_layers,
+                        window_layers=self.window_layers,
+                        shared_readers=self.shared_readers) as span:
             with self._span("prefill.dispatch", first_call=self._first_call(
                     ("prefill", bucket))):
                 program = _prefill_program(self.model, self.cfg.temperature,
@@ -1205,6 +1255,9 @@ class ServeEngine:
                     jnp.int32(p), jnp.int32(shared_len), jnp.asarray(padded),
                     self._rng, jnp.int32(slot_idx))
                 self.pool.adopt(new_layers)
+                # rows of the prompt the model's last layers and head ran
+                # on, as the traced program had them (the bucket, or 1)
+                span.attrs["cross_rows"] = program.head_rows.get(bucket)
                 self.prefill_token_work += bucket
                 self.state_writes += bool(self.state_layers)
                 if self.draft_pool is not None:
@@ -1511,6 +1564,11 @@ class ServeEngine:
                  "read": self.tick_read,
                  "live_pages": sum(self.pool.pages_needed(s.position + 1)
                                    for _, s in slots),
+                 # the rows themselves, and those of them a window layer's
+                 # read sees (0 without one)
+                 "live_tokens": sum(s.position + 1 for _, s in slots),
+                 "window_tokens": sum(min(s.position + 1, self.window)
+                                      for _, s in slots),
                  "state_slots": len(slots) if self.state_layers else 0,
                  "ahead": ahead}
         if self.tracer is not None:
@@ -1778,6 +1836,8 @@ class ServeEngine:
                          chunks_pending=self.chunks_pending,
                          chunk_ticks=self.chunk_ticks,
                          state_bytes=st["state_bytes"],
+                         window_bytes=st["window_bytes"],
+                         kv_bytes_per_token=st["kv_bytes_per_token"],
                          state_writes=self.state_writes,
                          ticks_ahead=self.ticks_ahead,
                          overrun_tokens=self.overrun_tokens,

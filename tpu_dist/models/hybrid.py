@@ -15,7 +15,8 @@ logits ``x E^T``.
 Mamba mixer over ``h[0..L)``: ``[u, z] = W_in h``; ``u =
 silu(conv1d_causal(u; k = d_conv, depthwise, bias))``; ``[d, B, C] = W_x u``
 (dt_rank, d_state, d_state), each through its own RMSNorm (Jamba's three
-inner norms); ``delta = softplus(W_dt d + b_dt)``; ``A = -exp(A_log)``; the
+inner norms; ``MambaMixer.inner_norms``, which ``models.phi4flash`` turns
+off); ``delta = softplus(W_dt d + b_dt)``; ``A = -exp(A_log)``; the
 selective scan (``ops.selective_scan``); output ``W_out(y * silu(z))``.
 RMSNorm, softplus, exp and the scan's state are float32 whatever ``dtype``
 is; ``W_dt`` runs in float32 too (its output sits at the bias, -7 .. -2,
@@ -108,6 +109,12 @@ class MambaMixer(nn.Module):
     eps: float
     dtype: jnp.dtype
     quant: str
+    # the three RMSNorms on dt, B and C (the Jamba family has them; plain
+    # Mamba-1, as models.phi4flash runs it, does not)
+    inner_norms: bool = True
+    # also return the scan's output y (with its D u, before the gate): what
+    # a gated memory unit of a later layer reads
+    hand_on: bool = False
 
     @nn.compact
     def __call__(self, h, paged):
@@ -155,9 +162,10 @@ class MambaMixer(nn.Module):
             dbc = dense(self.dt_rank + 2 * n, "x_proj")(u)
             dt, bmat, cmat = jnp.split(
                 dbc, [self.dt_rank, self.dt_rank + n], axis=-1)
-            dt = RMSNorm(self.eps, name="dt_norm")(dt)
-            bmat = RMSNorm(self.eps, name="b_norm")(bmat)
-            cmat = RMSNorm(self.eps, name="c_norm")(cmat)
+            if self.inner_norms:
+                dt = RMSNorm(self.eps, name="dt_norm")(dt)
+                bmat = RMSNorm(self.eps, name="b_norm")(bmat)
+                cmat = RMSNorm(self.eps, name="c_norm")(cmat)
             delta = jax.nn.softplus(
                 dense(d_inner, "dt_proj", jnp.float32)(dt)
                 + dt_bias.astype(jnp.float32))
@@ -178,9 +186,10 @@ class MambaMixer(nn.Module):
                            new.astype(old.dtype))))
                 new_state = {"ssm": put(state["ssm"], s_last),
                              "conv": put(state["conv"], new_tail)}
-            y = (y.astype(jnp.float32)
-                 * jax.nn.silu(z.astype(jnp.float32))).astype(self.dtype)
-            return dense(d_model, "out_proj")(y), new_state
+            gated = (y.astype(jnp.float32)
+                     * jax.nn.silu(z.astype(jnp.float32))).astype(self.dtype)
+            out = dense(d_model, "out_proj")(gated)
+            return (out, new_state, y) if self.hand_on else (out, new_state)
 
 
 class HybridBlock(nn.Module):

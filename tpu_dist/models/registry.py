@@ -76,6 +76,11 @@ _REGISTRY: Dict[str, Tuple[Callable, str]] = {
     # backward pass and tp/fsdp specs for it are not built. Imported on
     # first use, so that nothing that never asks for it pays for it.
     "hybrid_lm": (lambda **kw: _hybrid_lm(**kw), "lm"),
+    # the decoder-hybrid-decoder block (models.phi4flash: Mamba-1, window
+    # and full differential attention, cross layers over one shared KV
+    # layer, gated memory units). Serving and the plain forward, like
+    # hybrid_lm; no trainer holds it.
+    "phi4flash_lm": (lambda **kw: _phi4flash_lm(**kw), "lm"),
 }
 
 
@@ -83,6 +88,12 @@ def _hybrid_lm(**kw):
     from tpu_dist.models.hybrid import HybridLM
 
     return HybridLM(**kw)
+
+
+def _phi4flash_lm(**kw):
+    from tpu_dist.models.phi4flash import Phi4FlashLM
+
+    return Phi4FlashLM(**kw)
 
 
 model_names = sorted(_REGISTRY)  # reference 1.dataparallel.py:23-24 equivalent

@@ -109,6 +109,52 @@ def blockwise_attention_fn(block_size: int = 512):
     return attn
 
 
+def window_attention(q, k, v, window: int):
+    """Causal attention inside a sliding window, in plain JAX: key ``t`` is
+    visible to query ``s`` iff ``s - window < t <= s``. ``q``/``k`` (B, L, H,
+    D), ``v`` (B, L, H, Dv) (the value width is its own), float32 scores and
+    softmax, the weights rounded to ``v``'s dtype before the weighted sum as
+    ``full_attention`` does. Banded: the queries go in blocks of ``window``
+    rows, and a block multiplies against its own keys and the block before
+    them (every key one of its rows can see), so the work and the scores'
+    memory are ``2 * window`` a row whatever ``L`` is, not ``L``.
+
+    The Pallas flash kernels below have no window bound (their schedule
+    places a band against the causal diagonal only); this is what a window
+    layer's PREFILL runs (``models.phi4flash``). The decode read of a
+    window is ``ops.paged_attention``'s."""
+    b, l, h, d = q.shape
+    blk = min(window, l)
+    pad = -l % blk
+    if pad:
+        q, k, v = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for x in (q, k, v))
+    nb = (l + pad) // blk
+    blocks = lambda x: x.reshape(b, nb, blk, h, x.shape[-1])
+    qb, kb, vb = blocks(q), blocks(k), blocks(v)
+    r = jnp.arange(blk)[:, None]
+    if nb == 1:
+        live = jnp.arange(blk)[None, :] <= r                     # (blk, blk)
+        live = live[None]
+    else:
+        before = lambda x: jnp.concatenate(
+            [jnp.zeros_like(x[:, :1]), x[:, :-1]], axis=1)
+        kb = jnp.concatenate([before(kb), kb], axis=2)   # (b, nb, 2 blk, h, .)
+        vb = jnp.concatenate([before(vb), vb], axis=2)
+        # column c of block i is key (i - 1) * blk + c; row r is query
+        # i * blk + r: its age is blk + r - c, in [0, window) (blk == window)
+        c = jnp.arange(2 * blk)[None, :]
+        live = (c > r) & (c <= r + blk)
+        first = jnp.arange(nb)[:, None, None] == 0       # no block before it
+        live = live[None] & ~(first & (c < blk)[None])           # (nb, ., .)
+    s = jnp.einsum("bnqhd,bnkhd->bnhqk", qb, kb,
+                   preferred_element_type=jnp.float32) / jnp.sqrt(d).astype(
+                       jnp.float32)
+    w = jax.nn.softmax(jnp.where(live[None, :, None], s, -jnp.inf), axis=-1)
+    out = jnp.einsum("bnhqk,bnkhd->bnqhd", w.astype(v.dtype), vb)
+    return out.reshape(b, nb * blk, h, v.shape[-1])[:, :l]
+
+
 # ---------------------------------------------------------------------------
 # Pallas flash attention (FlashAttention-2 schedule, forward + backward)
 # ---------------------------------------------------------------------------

@@ -95,13 +95,23 @@ class PagedLayer:
     fp32. ``quant`` ("none" | "int8") and ``read`` ("exact" | "flash")
     ride in the pytree *aux data*: they are static, participate in jit
     cache keys, and can never be confused for traced values.
+
+    Two more layouts, both read by :func:`grouped_attend`. ROWS: ``k``/``v``
+    are ``(pages, page_size, kv_heads * head_dim)``, a token's KV heads side
+    by side in one lane-wide row (what a grouped read on the MXU wants, and
+    what a head count that is no whole sublane tile needs). A RING
+    (``ring`` > 0, static like the other two): rows again, ``ring`` pages a
+    SLOT and no trash page; slot ``b`` owns pages ``b * ring .. (b + 1) *
+    ring - 1`` and position ``t`` lives at row ``t % (ring * page_size)``
+    of them, so a window layer holds ``window + page_size`` tokens a slot
+    however long its sequence grows.
     """
 
     def __init__(self, k, v, k_scale=None, v_scale=None, *,
-                 quant: str = "none", read: str = "exact"):
+                 quant: str = "none", read: str = "exact", ring: int = 0):
         self.k, self.v = k, v
         self.k_scale, self.v_scale = k_scale, v_scale
-        self.quant, self.read = quant, read
+        self.quant, self.read, self.ring = quant, read, ring
 
     @property
     def num_pages(self) -> int:
@@ -114,18 +124,18 @@ class PagedLayer:
     def replace(self, **kw) -> "PagedLayer":
         fields = dict(k=self.k, v=self.v, k_scale=self.k_scale,
                       v_scale=self.v_scale, quant=self.quant,
-                      read=self.read)
+                      read=self.read, ring=self.ring)
         fields.update(kw)
         return PagedLayer(**fields)
 
     def tree_flatten(self):
         return ((self.k, self.v, self.k_scale, self.v_scale),
-                (self.quant, self.read))
+                (self.quant, self.read, self.ring))
 
     @classmethod
     def tree_unflatten(cls, aux, children):
         k, v, ks, vs = children
-        return cls(k, v, ks, vs, quant=aux[0], read=aux[1])
+        return cls(k, v, ks, vs, quant=aux[0], read=aux[1], ring=aux[2])
 
 
 # ---------------------------------------------------------------------------
@@ -605,14 +615,33 @@ def paged_decode_attention(q, k_arena, v_arena, block_tables, lengths, *,
 
 
 def decode_read(layer: PagedLayer, lq: int, sp_mesh=None,
-                group: int = 1) -> str:
+                group: int = 1, head_dim: int = None) -> str:
     """Which way a non-prefill read of ``layer`` goes, from what the inputs
     show and nothing else: ``"pages"`` (:func:`paged_decode_attention`) for
     one query a row over unsharded bf16 or fp32 arenas whose head_dim fills
     the lanes, ``"gathered"`` for everything else — the Lq > 1 verify and
     chunk windows, int8 pages, sp-sharded arenas, heads narrower than a
     lane row (every toy model of tests/test_serve.py), and grouped heads
-    (``group`` query heads a KV head: the kernel reads one head for one)."""
+    (``group`` query heads a KV head: the kernel reads one head for one).
+
+    A layer laid out in ROWS (3-D arenas, :class:`PagedLayer`; rings too)
+    is read one query a row by :func:`grouped_attend`: ``"pages"``
+    (:func:`paged_grouped_decode_attention`, any ``group``) where a head of
+    ``head_dim`` fills whole lane rows and a page whole sublane tiles,
+    else ``"gathered"``. Nothing else is built over that layout: a wider
+    window, int8 rows or an sp mesh is refused by name."""
+    if layer.k.ndim == 3:
+        if lq != 1 or layer.quant != "none" or sp_mesh is not None:
+            raise NotImplementedError(
+                "a KV layer laid out in rows (a window ring, a layer that "
+                "other layers read) is read one query a row from unsharded "
+                "bf16 or fp32 pages: no verify or chunk window (Lq > 1), no "
+                "int8 pages, no sp mesh")
+        _, page, f = layer.k.shape
+        d = head_dim or f
+        tile = 32 // layer.k.dtype.itemsize      # rows a sublane tile
+        return ("pages" if d % 128 == 0 and f % d == 0 and page % tile == 0
+                else "gathered")
     if (lq != 1 or layer.quant != "none" or sp_mesh is not None
             or group != 1):
         return "gathered"
@@ -624,6 +653,258 @@ def decode_read(layer: PagedLayer, lq: int, sp_mesh=None,
     whole_tiles = (layer.k.dtype.itemsize == 4 or h % 8 == 0
                    or h in (2, 4))
     return "pages" if d % 128 == 0 and whole_tiles else "gathered"
+
+
+# ---------------------------------------------------------------------------
+# grouped in-place decode read (rows layout: block-table pages and rings)
+# ---------------------------------------------------------------------------
+#
+# The kernel above reads one KV head for one query head on the VPU. A layer
+# whose query heads share KV heads (``group`` of them a KV head) has ``group``
+# times the arithmetic a byte, which the VPU cannot hide behind the DMA, and
+# its KV head count need not tile the sublanes (10 bf16 heads do not). So its
+# pages are ROWS: a token's ``kv_heads * D`` values side by side, whole lane
+# rows whatever the head count, and the read runs on the MXU with every head
+# in ONE product a chunk: the queries are laid out block-diagonally, row
+# ``h`` of ``q_bd`` [H, kv_heads * D] holding query head ``h`` in the lanes of
+# its KV head ``h // group`` and zeros elsewhere, so ``q_bd K^T`` [H, T] is
+# every head's scores over a chunk's T tokens, and ``P V`` [H, kv_heads * D]
+# holds head ``h``'s output in the lanes of its KV head (the other lanes are
+# other heads' values under this head's weights: computed, not read). The
+# walk is the kernel above's: block table and lengths prefetched, whole pages
+# fetched by async copies into a two-slot buffer, a chunk ahead.
+#
+# A ring (``window`` set) is the same walk over a slot's own pages: position
+# ``t`` sits at row ``t % ring_rows``, so with the query at position ``pos``
+# row ``r`` holds the key of age ``(pos - r) mod ring_rows`` (the latest
+# position at that row), live iff it was written (``r <= pos``) and lies
+# inside the window (age < ``window``). Attention over a set of keys does not
+# care in which order the rows hold them.
+
+_GROUPED_CHUNK_PAGES = 16    # pages a chunk (a constant of the kernel)
+
+
+def _live_rows(rows, pos, length, window, ring_rows):
+    """Which KV rows a query at ``pos`` reads: below ``length`` and, in a
+    ring, of an age inside the window. Shapes broadcast."""
+    live = rows < length
+    if window is not None:
+        live &= jax.lax.rem(pos - rows + ring_rows, ring_rows) < window
+    return live
+
+
+def _grouped_decode_kernel(bt_ref, len_ref, pos_ref, q_ref, k_hbm, v_hbm,
+                           o_ref, kbuf, vbuf, sem, parity_ref, *, page_size,
+                           chunk_pages, kv_heads, window, ring_rows,
+                           precision):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    tokens = chunk_pages * page_size             # rows a chunk
+    h, d = q_ref.shape[1], q_ref.shape[2]
+    group = h // kv_heads
+
+    def copies(row, chunk, slot, op):
+        first = chunk * chunk_pages
+        live = jnp.minimum(pl.cdiv(len_ref[row], page_size) - first,
+                           chunk_pages)
+
+        def page_copy(j, _):
+            page = bt_ref[row, first + j]
+            dst = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            for i, (arena, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(
+                    arena.at[page], buf.at[slot, dst], sem.at[i, slot])
+                if op == "start":
+                    cp.start()
+                else:
+                    cp.wait()
+            return 0
+
+        jax.lax.fori_loop(0, live, page_copy, 0)
+
+    @pl.when(b == 0)
+    def _first():
+        # rows of a chunk past a slot's last page are never fetched; their
+        # scores are masked, and the zeros here keep 0 * (whatever the
+        # buffer held) out of the weighted sum
+        kbuf[...] = jnp.zeros_like(kbuf)
+        vbuf[...] = jnp.zeros_like(vbuf)
+        parity_ref[0] = 0
+        copies(0, 0, 0, "start")
+
+    length = len_ref[b]
+    pos = pos_ref[b]
+    n_chunks = pl.cdiv(length, tokens)           # >= 1: lengths are >= 1
+    parity = parity_ref[0]
+    # the block-diagonal queries: head h in the lanes of KV head h // group
+    q = q_ref[0]                                                   # (H, D)
+    q_bd = jnp.concatenate([q] * kv_heads, axis=1)                 # (H, F)
+    own = (jax.lax.broadcasted_iota(jnp.int32, q_bd.shape, 1) // d
+           == jax.lax.broadcasted_iota(jnp.int32, q_bd.shape, 0) // group)
+    q_bd = jnp.where(own, q_bd, jnp.zeros_like(q_bd))
+
+    def chunk_step(c, carry):
+        m, l, acc = carry
+        slot = (parity + c) % 2
+        last = c == n_chunks - 1
+
+        @pl.when(jnp.logical_not(last))
+        def _():
+            copies(b, c + 1, 1 - slot, "start")
+
+        @pl.when(jnp.logical_and(last, b + 1 < nb))
+        def _():
+            copies(b + 1, 0, 1 - slot, "start")
+
+        copies(b, c, slot, "wait")
+        s = jax.lax.dot_general(
+            q_bd, kbuf[slot], (((1,), (1,)), ((), ())), precision=precision,
+            preferred_element_type=jnp.float32)                    # (H, T)
+        rows = c * tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (1, tokens), 1)
+        s = jnp.where(_live_rows(rows, pos, length, window, ring_rows),
+                      s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))  # (H, 1)
+        alpha = jnp.exp(m - m_new)
+        # a chunk with no live row at all (a ring's last page can be one)
+        # leaves m where it was: its weights are zeros, not exp(0)
+        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_new))
+        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_new = acc * alpha + jax.lax.dot_general(
+            p.astype(vbuf.dtype), vbuf[slot], (((1,), (0,)), ((), ())),
+            precision=precision, preferred_element_type=jnp.float32)
+        return m_new, l_new, acc_new
+
+    m0 = jnp.full((h, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((h, 1), jnp.float32)
+    acc0 = jnp.zeros((h, kv_heads * d), jnp.float32)
+    _, l, acc = jax.lax.fori_loop(0, n_chunks, chunk_step, (m0, l0, acc0))
+    # head h's own lanes of its row
+    head = jax.lax.broadcasted_iota(jnp.int32, (h, d), 0) // group
+    out = jnp.zeros((h, d), jnp.float32)
+    for j in range(kv_heads):
+        out = jnp.where(head == j, acc[:, j * d:(j + 1) * d], out)
+    o_ref[0] = (out / l).astype(o_ref.dtype)
+    parity_ref[0] = (parity + n_chunks) % 2
+
+
+def paged_grouped_decode_attention(q, k_arena, v_arena, block_tables,
+                                   positions, *, kv_heads: int, scale: float,
+                                   window: int = None,
+                                   interpret: bool | None = None):
+    """One query a row over its LIVE rows of a ROWS-layout arena, read in
+    place: ``q`` (B, H, D) with ``H = kv_heads * group`` (query heads ``j *
+    group .. (j + 1) * group - 1`` read KV head ``j``), arenas (pages,
+    page_size, kv_heads * D), ``block_tables`` (B, P) i32, ``positions``
+    (B,) i32 the queries' own positions (keys at ``t <= position`` are
+    read). ``window``: the table is a RING of ``P * page_size`` rows
+    (position ``t`` at row ``t % rows``) and only keys at ``t > position -
+    window`` are read. ``scale`` multiplies the scores (a model's own: the
+    rows may hold heads padded wider than the softmax's). Float32 scores,
+    statistics and accumulator; returns float32 (B, H, D); forward-only.
+    ``interpret=None`` auto-selects interpreter mode off-TPU."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, d = q.shape
+    page_size, f = k_arena.shape[1:]
+    table_rows = block_tables.shape[1] * page_size
+    chunk_pages = min(_GROUPED_CHUNK_PAGES, block_tables.shape[1])
+    buf = (2, chunk_pages * page_size, f)
+    full = k_arena.dtype == jnp.float32
+    kernel = functools.partial(
+        _grouped_decode_kernel, page_size=page_size, chunk_pages=chunk_pages,
+        kv_heads=kv_heads, window=window, ring_rows=table_rows,
+        precision=jax.lax.Precision.HIGHEST if full else None)
+    positions = positions.astype(jnp.int32)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, h, d), lambda i, bt, ln, ps: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, h, d), lambda i, bt, ln, ps: (i, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM(buf, k_arena.dtype),
+                pltpu.VMEM(buf, v_arena.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((1,), jnp.int32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), jnp.float32),
+        interpret=pallas_interpret(interpret),
+    )(block_tables.astype(jnp.int32),
+      # rows to walk: a ring is walked whole once it has wrapped
+      jnp.clip(positions + 1, 1, table_rows), positions,
+      (q.astype(jnp.float32) * scale).astype(k_arena.dtype),
+      k_arena, v_arena)
+    return out
+
+
+def grouped_gathered_attention(q, k_arena, v_arena, block_tables, positions,
+                               *, kv_heads: int, scale: float,
+                               window: int = None):
+    """:func:`paged_grouped_decode_attention`'s result by a gathered copy of
+    every table entry and one masked softmax: the read of a rows layout whose
+    shapes the kernel's tiling does not take (toy widths), and what the
+    kernel is tested against."""
+    b, h, d = q.shape
+    rows = block_tables.shape[1] * k_arena.shape[1]
+    gk = gather_pages(k_arena, block_tables).reshape(b, rows, kv_heads, d)
+    gv = gather_pages(v_arena, block_tables).reshape(b, rows, kv_heads, d)
+    qg = (q.astype(jnp.float32) * scale).astype(k_arena.dtype).reshape(
+        b, kv_heads, h // kv_heads, d)
+    s = jnp.einsum("bjgd,bkjd->bjgk", qg, gk,
+                   preferred_element_type=jnp.float32)
+    pos = positions.astype(jnp.int32)[:, None]
+    live = _live_rows(jnp.arange(rows, dtype=jnp.int32)[None, :], pos,
+                      jnp.minimum(pos + 1, rows), window, rows)
+    w = jax.nn.softmax(jnp.where(live[:, None, None, :], s, -jnp.inf), axis=-1)
+    return jnp.einsum("bjgk,bkjd->bjgd", w.astype(gv.dtype), gv,
+                      preferred_element_type=jnp.float32).reshape(b, h, d)
+
+
+def ring_block_tables(slots, ring: int):
+    """(B,) slot indices -> (B, ring) the pages of each slot's ring."""
+    return (slots.astype(jnp.int32)[:, None] * ring
+            + jnp.arange(ring, dtype=jnp.int32)[None, :])
+
+
+def grouped_write(layer: PagedLayer, k, v, block_tables, positions, valid):
+    """Scatter ``k``/``v`` (B, L, kv_heads * D) into a rows-layout layer at
+    ``positions`` (B, L) through ``block_tables`` (a ring's own, from
+    :func:`ring_block_tables`, or the scheduler's); rows where ``valid`` is
+    False are written nowhere. A ring wraps: position ``t`` lands at row
+    ``t % (ring * page_size)`` of its slot."""
+    n, page = layer.k.shape[:2]
+    if layer.ring:
+        positions = positions % (layer.ring * page)
+    flat = jnp.where(valid, flat_slot_index(block_tables, positions, page),
+                     n * page).reshape(-1)              # out of range: dropped
+
+    def put(arena, x):
+        rows = arena.reshape((n * page,) + arena.shape[2:])
+        return rows.at[flat].set(
+            x.reshape((-1,) + x.shape[2:]).astype(arena.dtype),
+            mode="drop").reshape(arena.shape)
+
+    return layer.replace(k=put(layer.k, k), v=put(layer.v, v))
+
+
+def grouped_read(q, layer: PagedLayer, block_tables, positions, *,
+                 kv_heads: int, scale: float, window: int = None):
+    """One query a row (``q`` (B, H, D)) over a rows-layout layer, in place
+    where :func:`decode_read` says the shapes allow it."""
+    how = decode_read(layer, 1, None, q.shape[1] // kv_heads, q.shape[2])
+    read = (paged_grouped_decode_attention if how == "pages"
+            else grouped_gathered_attention)
+    return read(q, layer.k, layer.v, block_tables, positions,
+                kv_heads=kv_heads, scale=scale, window=window)
 
 
 # ---------------------------------------------------------------------------
