@@ -173,7 +173,8 @@ def test_tracer_span_nesting_and_accumulation():
     assert tr.pop() == {}
     # the same spans are in the process-wide ring, under the prefix, and
     # the sums are the ring's own lengths
-    mine = trace.ring().tail(3)
+    # (a full garbage collection landing here is a span of its own)
+    mine = [sp for sp in trace.ring().tail(8) if sp.name != "host.gc"][-3:]
     assert [sp.name for sp in mine] == ["train.decode", "train.data",
                                         "train.data"]
     assert ph["data"] == pytest.approx(

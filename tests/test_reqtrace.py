@@ -267,3 +267,98 @@ def test_serve_spans_tile_admit_to_finish_and_drain_sheds():
                       key=lambda s: s["start"])
         for a, b in zip(decs, decs[1:]):
             assert a["end"] == b["start"]
+
+
+# ------------------------------- per-request stall counters (PR 38)
+def _row(rid, tokens, decode_s, prefill, pauses):
+    return {"rid": rid, "tokens": tokens, "decode_s": decode_s,
+            "behind_prefill_s": prefill, "behind_gc_s": pauses}
+
+
+def test_decode_split_adds_up_and_names_the_slow_twentieth():
+    from tools.request_report import decode_split
+
+    # twenty requests of 11 tokens: gaps of 10 ms, but one stood behind two
+    # admissions (30 ms) and one inside a 120 ms collection
+    rows = [_row(i, 11, 0.100, 0.0, 0.0) for i in range(18)]
+    rows += [_row(18, 11, 0.130, 0.030, 0.0), _row(19, 11, 0.220, 0.0, 0.120)]
+    got = decode_split(rows)
+    assert got["all"] == {
+        "requests": 20, "mean_gap_s": round(2.15 / 200, 6),
+        "decode_s": 2.15, "ticks_s": 2.0, "behind_prefill_s": 0.03,
+        "behind_gc_s": 0.12}
+    # nearest rank of 20 at 95%: the 19th, so the two slowest are the tail
+    assert got["p95_mean_gap_s"] == 0.013
+    assert got["at_or_above_p95"] == {
+        "requests": 2, "mean_gap_s": 0.0175, "decode_s": 0.35,
+        "ticks_s": 0.2, "behind_prefill_s": 0.03, "behind_gc_s": 0.12}
+    # one-token requests have no gap; a ledger without the counters, no split
+    assert decode_split([_row(0, 1, 0.0, 0.0, 0.0)]) is None
+    assert decode_split([_row(0, 9, 0.1, None, None)]) is None
+
+
+def test_fixture_without_the_counters_has_no_decode_split():
+    recs = FleetLedger.discover(FIX).merged()
+    ta = requests_summary(recs)["tail_attribution"]
+    assert ta["decode_split"] is None
+    assert all(r["behind_prefill_s"] is None
+               for r in requests_summary(recs)["per_request"])
+
+
+def test_live_engine_counters_reach_the_ledger_and_both_reports():
+    """``behind_prefill_s``/``behind_gc_s`` ride the ``request`` event and
+    the reqtrace root span, ``prefill_own_s``/``gc_pause_s`` the
+    ``kv_cache`` event; ``request_report`` splits the decode time by them
+    and ``ledger_report`` prints the engine's totals."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from tools.ledger_report import decode_section
+    from tools.request_report import render
+    from tpu_dist.engine.serve import (DecodeRequest, ServeConfig,
+                                       ServeEngine)
+    from tpu_dist.models.transformer import tiny_lm
+
+    lm = tiny_lm(vocab_size=64, num_layers=1, d_model=32, num_heads=2,
+                 max_len=32)
+    params = lm.init({"params": jax.random.PRNGKey(0)},
+                     jnp.zeros((1, 32), jnp.int32), train=False)["params"]
+    cap = []
+    clock = itertools.count()
+    eng = ServeEngine(lm, params, ServeConfig(
+        max_slots=2, page_size=4, num_pages=16, trace_window_ticks=4),
+        ledger=Ledger(None, sinks=(cap.append,)),
+        now_fn=lambda: float(next(clock)))
+    eng.submit(DecodeRequest(0, np.array([1, 2, 3], np.int32), 12))
+    for _ in range(3):
+        eng.step()
+    eng.submit(DecodeRequest(1, np.array([4, 5], np.int32), 5))
+    done = {c.rid: c for c in eng.run()}
+    assert done[0].behind_prefill_s > 0 == done[1].behind_prefill_s
+    events = {r["rid"]: r for r in cap if r["event"] == "request"}
+    roots = {r["rid"]: r for r in cap
+             if r["event"] == "span" and r["name"] == "request"}
+    for rid, c in done.items():
+        for rec in (events[rid], roots[rid]):
+            assert rec["behind_prefill_s"] == c.behind_prefill_s
+            assert rec["behind_gc_s"] == round(c.behind_gc_s, 6)
+    kv = [r for r in cap if r["event"] == "kv_cache"][-1]
+    st = eng.stats()
+    assert kv["prefill_own_s"] == st["prefill_own_s"] > 0
+    assert 0.0 <= kv["gc_pause_s"] <= st["gc_pause_s"]
+    split = requests_summary(cap)["tail_attribution"]["decode_split"]
+    assert split["all"]["requests"] == 2
+    assert split["all"]["behind_prefill_s"] == done[0].behind_prefill_s
+    assert split["all"]["decode_s"] == pytest.approx(sum(
+        c.finish_ts - c.first_token_ts for c in done.values()))
+    lines = []
+    render(requests_summary(cap), cap, out=lines.append, waterfalls=0)
+    assert any("by what held the next token back" in ln for ln in lines)
+    assert any(ln.lstrip().startswith("all requests (2): mean gap")
+               and "behind admissions" in ln and "in collections" in ln
+               for ln in lines), lines
+    lines = []
+    decode_section(cap, out=lines.append)
+    assert any("KV cache:" in ln and "admissions held the decoding slots"
+               in ln and "garbage collections" in ln for ln in lines), lines

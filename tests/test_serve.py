@@ -802,7 +802,10 @@ def test_step_spans_nest_and_every_token_has_a_time(span_lm, mode):
     while eng.queue or any(s is not None for s in eng.slots):
         comps += eng.step()
         n_steps += 1
-    spans = [sp for sp in trace.ring().snapshot() if sp.sid > mark.sid]
+    # a full garbage collection that lands in the run is a span too, on
+    # the real clock (tests/test_trace_gc.py): not this test's
+    spans = [sp for sp in trace.ring().snapshot()
+             if sp.sid > mark.sid and sp.name != "host.gc"]
     by_sid = {sp.sid: sp for sp in spans}
     kids = {}
     for sp in spans:                      # ring order: by closing time
@@ -835,8 +838,17 @@ def test_step_spans_nest_and_every_token_has_a_time(span_lm, mode):
         want = (eng.tracer.trace_id(pf.attrs["rid"]) if mode == "traced"
                 else pf.attrs["rid"])
         assert pf.attrs["trace_id"] == want
-        assert names(pf.sid) in (["prefill.dispatch", "prefill.wait"],
+        assert names(pf.sid) in (["prefill.dispatch", "prefill.behind",
+                                  "prefill.wait"],
                                  *([["prefill.dispatch"]] if chunked else []))
+        # a prefill that was waited for says when its program was issued
+        # and how long the tick in flight held it back: its child's length
+        waited = names(pf.sid)[-1] == "prefill.wait"
+        assert ({"issued", "behind_s"} <= set(pf.attrs)) == waited
+        if waited:
+            send, behind, _ = sorted(kids[pf.sid], key=lambda k: k.start)
+            assert send.start < pf.attrs["issued"] < send.end
+            assert pf.attrs["behind_s"] == behind.end - behind.start
     assert len(prefills) == (2 + 3 if chunked else 2)   # ceil(13/8)+ceil(18/8)
     assert sum(sp.attrs["n"] for sp in spans
                if sp.name == "serve.admit") == 2
@@ -870,6 +882,105 @@ def test_step_spans_nest_and_every_token_has_a_time(span_lm, mode):
         if mode != "speculative":           # one token a tick
             assert len(mine) == c.n_generated - 1
             assert len(set(c.token_ts)) == c.n_generated
+
+
+# ------------------- a prefill apart from the tick it queues behind (PR 38)
+def _staggered(eng, arrivals):
+    """Step ``eng``, submitting ``arrivals[step]`` before that step; the
+    completions by rid, and the run's spans."""
+    from tpu_dist.obs import trace
+
+    with trace.ring().span("mark") as mark:
+        pass
+    arrivals, done, step = dict(arrivals), {}, 0
+    while arrivals or eng.queue or any(s is not None for s in eng.slots):
+        for req in arrivals.pop(step, ()):
+            assert eng.submit(req)
+        for c in eng.step():
+            done[c.rid] = c
+        step += 1
+        assert step < 200
+    return done, [sp for sp in trace.ring().snapshot()
+                  if sp.sid > mark.sid and sp.name != "host.gc"]
+
+
+def test_prefill_behind_blocks_on_the_tick_in_flight_and_on_nothing_else(
+        span_lm, monkeypatch):
+    """``prefill.behind`` sits between the dispatch and the wait whether a
+    tick is in flight or not; with one, it blocks on that tick's tokens
+    (the newest flight's), with none it makes no runtime call at all."""
+    from tpu_dist.engine import serve
+
+    lm, params = span_lm
+    eng = ServeEngine(lm, params, ServeConfig(max_slots=2, page_size=8,
+                                              num_pages=16))
+    blocked = []
+    real = jax.block_until_ready
+
+    def recording(x):
+        blocked.append((x, [f.nxt for f in eng._flights]))
+        return real(x)
+
+    monkeypatch.setattr(serve.jax, "block_until_ready", recording)
+    p = (np.arange(9, dtype=np.int32) * 5 + 2) % V
+    done, spans = _staggered(eng, {0: [DecodeRequest(0, p, 8)],
+                                   3: [DecodeRequest(1, p[:5], 4)]})
+    assert sorted(done) == [0, 1]
+    kids = {}
+    for sp in spans:
+        kids.setdefault(sp.parent, []).append(sp)
+    prefills = sorted((sp for sp in spans if sp.name == "serve.prefill"),
+                      key=lambda sp: sp.start)
+    assert [pf.attrs["rid"] for pf in prefills] == [0, 1]
+    for pf in prefills:
+        assert [k.name for k in sorted(kids[pf.sid], key=lambda k: k.start)
+                ] == ["prefill.dispatch", "prefill.behind", "prefill.wait"]
+        behind = next(k for k in kids[pf.sid] if k.name == "prefill.behind")
+        assert pf.attrs["behind_s"] == behind.end - behind.start >= 0.0
+        assert pf.start <= pf.attrs["issued"] <= behind.start
+    # request 0 met an idle engine; request 1 the tick dispatched a pass
+    # earlier, the only one in flight, and waited for that one
+    assert len(blocked) == 1
+    waited_for, in_flight = blocked[0]
+    assert len(in_flight) == 1 and waited_for is in_flight[0]
+
+
+@pytest.mark.parametrize("mode", ["plain", "chunked"])
+def test_a_request_counts_the_other_requests_prefills_it_stood_behind(
+        span_lm, mode):
+    """``Completion.behind_prefill_s`` is the sum of the own times,
+    (end - ``issued``) - ``behind_s``, of the OTHER requests' prefills that
+    ended between its first and its last token, and never holds its own:
+    exact on a virtual clock that moves by one a read."""
+    lm, params = span_lm
+    clock = itertools.count()
+    eng = ServeEngine(lm, params, ServeConfig(**_SPAN_MODES[mode]),
+                      now_fn=lambda: float(next(clock)))
+    r = np.random.default_rng(38)
+    reqs = [DecodeRequest(i, r.integers(0, V, (n,)).astype(np.int32), m)
+            for i, (n, m) in enumerate([(8, 22), (5, 3), (18, 9), (7, 1),
+                                        (9, 6)])]
+    done, spans = _staggered(eng, {0: reqs[:1], 2: reqs[1:2], 5: reqs[2:4],
+                                   9: reqs[4:]})
+    assert sorted(done) == [0, 1, 2, 3, 4]
+    waited = [sp for sp in spans if sp.name == "serve.prefill"
+              and "behind_s" in sp.attrs]
+    assert sorted(sp.attrs["rid"] for sp in waited) == [0, 1, 2, 3, 4]
+    own = {sp.attrs["rid"]: sp.end - sp.attrs["issued"]
+           - sp.attrs["behind_s"] for sp in waited}
+    assert all(v > 0 for v in own.values())
+    ended = {sp.attrs["rid"]: sp.end for sp in waited}
+    assert eng.stats()["prefill_own_s"] == sum(own.values())
+    for c in done.values():
+        others = [rid for rid in own if rid != c.rid
+                  and c.first_token_ts < ended[rid] < c.finish_ts]
+        assert c.behind_prefill_s == sum(own[rid] for rid in others), c.rid
+        assert c.behind_gc_s >= 0.0
+    # request 0 decodes through every later admission; a request that ends
+    # on its first token stood behind nothing
+    assert done[0].finish_ts > max(ended.values())
+    assert done[0].behind_prefill_s == sum(own.values()) - own[0]
+    assert done[3].n_generated == 1 and done[3].behind_prefill_s == 0.0
 
 
 # ------------------------------------ the tick one ahead of the host (PR 27)

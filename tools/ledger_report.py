@@ -434,6 +434,11 @@ def decode_section(records, out=print):
             # slots; 0 for a model without a window layer)
             srv["kv_bytes_per_token"] = last.get("kv_bytes_per_token")
             srv["window_bytes"] = last.get("window_bytes")
+            # what stood between a decoding request and its next tick: the
+            # admissions' own time (prefills apart from the tick they
+            # queued behind) and the process's garbage collections
+            srv["prefill_own_s"] = last.get("prefill_own_s")
+            srv["gc_pause_s"] = last.get("gc_pause_s")
             # the decode tick one ahead of the host: ticks dispatched
             # while the tick before them was unread, and tokens computed
             # for a slot that had already ended on eos_id (dropped)
@@ -480,7 +485,11 @@ def decode_section(records, out=print):
             out(f"  KV cache: {_si(srv['kv_bytes_per_token'], 'B')} a token "
                 "in pages"
                 + (f", {_si(srv['window_bytes'], 'B')} of window rings"
-                   if srv.get("window_bytes") else ""))
+                   if srv.get("window_bytes") else "")
+                + (f"; admissions held the decoding slots "
+                   f"{srv['prefill_own_s']:.3f}s, garbage collections "
+                   f"{srv['gc_pause_s']:.3f}s"
+                   if srv.get("prefill_own_s") is not None else ""))
         if srv.get("ticks_ahead_last"):
             out(f"  decode tick: {srv['ticks_ahead_last']} of "
                 f"{srv['ticks_last']} ticks dispatched ahead of the host's "

@@ -106,6 +106,11 @@ def attribute_root(root: dict, trace: dict) -> dict:
         "ttft_s": root.get("ttft_s"),
         "tokens": tokens,
         "tpot_s": (round(decode_s / tokens, 6) if tokens else None),
+        # of the decode time: seconds behind OTHER requests' admissions and
+        # inside garbage collections (engine.serve's per-request counters;
+        # None on a ledger written before them)
+        "behind_prefill_s": root.get("behind_prefill_s"),
+        "behind_gc_s": root.get("behind_gc_s"),
         "spans": len(kids),
         "sum_check_ok": abs(residue) <= SUM_TOL,
         "ts": root.get("ts"),
@@ -144,6 +149,36 @@ def _tail_point(rows: List[dict], metric: str, parts) -> Dict[str, dict]:
     return out
 
 
+def decode_split(rows: List[dict]) -> Optional[dict]:
+    """What held the next token back between a request's first and last:
+    its decode seconds split into decode ticks, other requests' admissions
+    (``behind_prefill_s``) and garbage collections (``behind_gc_s``), over
+    all requests that carry the counters and over those at or above the
+    95th percentile of the mean gap between tokens, the ones a gap SLO is
+    judged on. None on a ledger without the counters."""
+    pool = [r for r in rows if r.get("behind_prefill_s") is not None
+            and r.get("behind_gc_s") is not None and (r["tokens"] or 0) > 1]
+    if not pool:
+        return None
+    gap = lambda r: r["decode_s"] / (r["tokens"] - 1)
+
+    def block(sel: List[dict]) -> dict:
+        decode = sum(r["decode_s"] for r in sel)
+        prefill = sum(r["behind_prefill_s"] for r in sel)
+        pauses = sum(r["behind_gc_s"] for r in sel)
+        return {"requests": len(sel),
+                "mean_gap_s": round(decode / sum(r["tokens"] - 1
+                                                 for r in sel), 6),
+                "decode_s": round(decode, 6),
+                "ticks_s": round(decode - prefill - pauses, 6),
+                "behind_prefill_s": round(prefill, 6),
+                "behind_gc_s": round(pauses, 6)}
+
+    p95 = _pctl(sorted(gap(r) for r in pool), 95)
+    return {"all": block(pool), "p95_mean_gap_s": round(p95, 6),
+            "at_or_above_p95": block([r for r in pool if gap(r) >= p95])}
+
+
 def tail_attribution(rows: List[dict]) -> dict:
     """The headline block: TTFT decomposes into queue+prefill, TPOT into
     decode-per-token; ``shares`` are the fleet-wide category fractions of
@@ -162,6 +197,7 @@ def tail_attribution(rows: List[dict]) -> dict:
         "requests": len(rows),
         "ttft": _tail_point(rows, "ttft_s", ("queue_s", "prefill_s")),
         "tpot": _tail_point(rows, "tpot_s", ("decode_s", "tokens")),
+        "decode_split": decode_split(rows),
         "shares": shares,
         "coverage": round(attributed / total, 6) if total else None,
         "sum_check": {
@@ -358,6 +394,24 @@ def render(summary: dict, records, out=print, waterfalls: int = 3) -> None:
                 split = "  ".join(f"{k}={p[k]}" for k in parts)
                 out(f"    {q}: {p[metric + '_s']:.6g}s  rid={p['rid']}  "
                     f"{split}")
+        split = ta.get("decode_split")
+        if split:
+            out("  decode time, first token -> finish, by what held the "
+                "next token back:")
+            for label, b in (
+                    ("all requests", split["all"]),
+                    (f"mean gap >= p95 ({split['p95_mean_gap_s']:.6g}s)",
+                     split["at_or_above_p95"])):
+                share = (lambda x: f" ({x / b['decode_s']:.1%})"
+                         if b["decode_s"] else "")
+                parts = "  ".join(
+                    f"{name} {b[key]:.6g}s{share(b[key])}"
+                    for name, key in (("ticks", "ticks_s"),
+                                      ("behind admissions",
+                                       "behind_prefill_s"),
+                                      ("in collections", "behind_gc_s")))
+                out(f"    {label} ({b['requests']}): mean gap "
+                    f"{b['mean_gap_s']:.6g}s  {parts}")
     if summary["slo_exemplars"]:
         out("  slo breaches -> exemplar traces:")
         for b in summary["slo_exemplars"]:

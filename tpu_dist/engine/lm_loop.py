@@ -65,6 +65,7 @@ class LMTrainer:
         from tpu_dist.plan.compile import resolve_config_plan
         cfg, self._plan_info = resolve_config_plan(cfg)
         self.cfg = cfg
+        trace.gc_seconds()       # full collections are host.gc spans from here
         if cfg.resume and not os.path.exists(cfg.resume):
             raise FileNotFoundError(f"--resume checkpoint not found: {cfg.resume}")
         if cfg.pretrained and not os.path.exists(cfg.pretrained):

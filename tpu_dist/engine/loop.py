@@ -64,6 +64,7 @@ class Trainer:
         # compilations the constructor made (a handful: an eager init
         # creeping back in reads in the hundreds)
         compiles = trace.backend_compiles()
+        trace.gc_seconds()       # full collections are host.gc spans from here
         self._build_tracer = trace.StepTracer("build.")
         with trace.ring().span("train.build") as built:
             self._build(cfg, mesh)

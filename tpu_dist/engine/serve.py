@@ -136,6 +136,11 @@ class Completion:
     # device_get that brought it): token_ts[0] is first_token_ts,
     # token_ts[-1] is finish_ts
     token_ts: np.ndarray
+    # of first_token_ts..finish_ts: the seconds this request stood behind
+    # OTHER requests' admissions (their prefills' own time, engine clock)
+    # and inside Python's garbage collections (real seconds always)
+    behind_prefill_s: float = 0.0
+    behind_gc_s: float = 0.0
 
     @property
     def queue_wait_s(self) -> float:
@@ -221,6 +226,13 @@ class _Slot:
     win_ticks: int = 0
     win_tokens: int = 0
     win_drafted: int = 0
+    # the engine's prefill_own_s and obs.trace.gc_seconds() as they stood
+    # at the first token (this request's own prefill counted already), and
+    # how far each had moved when finish_ts was stamped
+    own_mark: float = 0.0
+    gc_mark: float = 0.0
+    behind_prefill_s: float = 0.0
+    behind_gc_s: float = 0.0
 
 
 @dataclass
@@ -764,6 +776,11 @@ class ServeEngine:
             self.pool.page_layers()[0], 1 + cfg.spec_k, self.sp_mesh,
             paged[0][2], paged[0][1]) if paged else "none"
         self.state_writes = 0        # prefills that wrote a slot's state
+        # what admissions cost the decoding slots: the sum over all
+        # prefills of a prefill's own time (_note_prefill), beside the
+        # process's seconds in garbage collections since this engine began
+        self.prefill_own_s = 0.0
+        self._gc_start = trace.gc_seconds()
         # the plain tick's decode state, resident on the device: (flat block
         # tables, each slot's last token, its position), every row on the
         # trash page at position 0 to begin with; which slot each of its
@@ -964,7 +981,8 @@ class ServeEngine:
         first). Every pass still hands each decoding request one token, but
         a finished sequence is evicted, and its slot refilled, one tick
         later than a synchronous loop would, and a prefill queues behind
-        the tick in flight. Timestamps are taken when the host HOLDS a
+        the tick in flight (``prefill.behind`` times that wait apart from
+        the prefill's own). Timestamps are taken when the host HOLDS a
         token's value. ``run()``, ``drain()`` and any loop that steps while
         a slot is occupied leave no tick unread:
         a tick is in flight only while some slot is still occupied."""
@@ -1089,7 +1107,9 @@ class ServeEngine:
                 admit_ts=slot.admit_ts, start_ts=slot.start_ts,
                 first_token_ts=slot.first_token_ts,
                 finish_ts=slot.finish_ts,
-                token_ts=slot.token_ts[:slot.generated].copy())
+                token_ts=slot.token_ts[:slot.generated].copy(),
+                behind_prefill_s=slot.behind_prefill_s,
+                behind_gc_s=slot.behind_gc_s)
             self.completed += 1
             out.append(comp)
             if self.ledger is not None:
@@ -1101,7 +1121,9 @@ class ServeEngine:
                     finish_ts=round(comp.finish_ts, 6),
                     prompt_len=comp.prompt_len,
                     tenant=slot.req.tenant,
-                    ttft_s=round(comp.ttft_s, 6))
+                    ttft_s=round(comp.ttft_s, 6),
+                    behind_prefill_s=round(comp.behind_prefill_s, 6),
+                    behind_gc_s=round(comp.behind_gc_s, 6))
             if self.tracer is not None:
                 # the root span: this (job, attempt)'s whole view of the
                 # request, admit->finish. Emitted at eviction, after every
@@ -1116,6 +1138,9 @@ class ServeEngine:
                                queue_wait_s=round(comp.queue_wait_s, 6),
                                tokens=comp.n_generated,
                                prompt_len=comp.prompt_len,
+                               behind_prefill_s=round(
+                                   comp.behind_prefill_s, 6),
+                               behind_gc_s=round(comp.behind_gc_s, 6),
                                tenant=slot.req.tenant, **tr.attrs())
         return out
 
@@ -1249,11 +1274,12 @@ class ServeEngine:
                 # audit is off)
                 register_audit_program("serve_prefill", program,
                                        allowed=len(self.buckets))
-                tok, new_layers, self._rng = program(
-                    self.params, self.pool.layers(),
-                    jnp.asarray(self.pool.flat_block_table(bt[None])),
-                    jnp.int32(p), jnp.int32(shared_len), jnp.asarray(padded),
-                    self._rng, jnp.int32(slot_idx))
+                args = (self.params, self.pool.layers(),
+                        jnp.asarray(self.pool.flat_block_table(bt[None])),
+                        jnp.int32(p), jnp.int32(shared_len),
+                        jnp.asarray(padded), self._rng, jnp.int32(slot_idx))
+                span.attrs["issued"] = self._now()
+                tok, new_layers, self._rng = program(*args)
                 self.pool.adopt(new_layers)
                 # rows of the prompt the model's last layers and head ran
                 # on, as the traced program had them (the bucket, or 1)
@@ -1279,6 +1305,7 @@ class ServeEngine:
                     self.prompt_pages += self.pool.pages_needed(p)
                     self.shared_prompt_pages += len(shared)
                 self.prefills += 1
+            self._wait_behind(span)
             with self._span("prefill.wait"):
                 # the scheduler IS the drain boundary: the first token
                 # decides done/eos and the TTFT stamp before the next iteration
@@ -1287,7 +1314,8 @@ class ServeEngine:
             now = self._now()
             slot = self._new_slot(req, prompt, shared + fresh, bt, enq_ts,
                                   start_ts, generated=1, first_token_ts=now,
-                                  cow_pending=cow, win_start_ts=now)
+                                  cow_pending=cow, win_start_ts=now,
+                                  gc_mark=trace.gc_seconds())
             slot.buf[p] = tok
             slot.token_ts[0] = now
             if (slot.generated >= req.max_new_tokens
@@ -1309,6 +1337,36 @@ class ServeEngine:
                                pages_shared=len(shared),
                                shared_len=shared_len, cow=cow is not None,
                                tenant=req.tenant, **tr.attrs())
+        self._note_prefill(span, slot)
+
+    def _wait_behind(self, span) -> None:
+        """``prefill.behind``, between a prefill's dispatch and its wait:
+        block until the newest tick in flight has landed and do nothing
+        else. The prefill's program is queued behind that tick already
+        (:meth:`step`), and the host was going to block for both in
+        ``prefill.wait``: the device's order is the same, and
+        ``prefill.wait`` now holds the admission alone. With no tick in
+        flight the span closes at once, and still exists. Its seconds are
+        ``span``'s (the ``serve.prefill``'s) ``behind_s``."""
+        with self._span("prefill.behind") as behind:
+            if self._flights:
+                # distlint: disable=DL002 -- the host blocks for this tick in prefill.wait anyway; here it is timed apart
+                jax.block_until_ready(self._flights[-1].nxt)
+        span.attrs["behind_s"] = behind.seconds
+
+    def _note_prefill(self, span, slot: _Slot) -> None:
+        """Count a closed ``serve.prefill`` span's own time, what this
+        admission cost every decoding slot: (end - ``issued``) -
+        ``behind_s``. Where a tick was in flight the device began the
+        prefill as ``prefill.behind`` ended; where it had landed already,
+        at ``issued`` or later: the host's lines between count in, so the
+        reading can be high against the device's time, never low. The
+        request's own prefill is in ``slot.own_mark`` and so out of its
+        ``behind_prefill_s``."""
+        self.prefill_own_s += (span.start + span.seconds
+                               - span.attrs["issued"]
+                               - span.attrs["behind_s"])
+        slot.own_mark = self.prefill_own_s
 
     # -- chunked prefill ---------------------------------------------------
     def _begin_chunked(self, slot_idx, req, prompt, fresh, enq_ts, start_ts,
@@ -1360,12 +1418,17 @@ class ServeEngine:
         with self._span("serve.prefill", rid=s.req.rid, trace_id=s.trace_id,
                         prompt_len=p, bucket=chunk, shared_len=s.shared_len,
                         chunk_start=s.chunk_next,
-                        state_layers=self.state_layers):
+                        state_layers=self.state_layers) as span:
             with self._span("prefill.dispatch",
                             first_call=self._first_call("chunk_prefill")):
+                issued = self._now()
                 tok = self._dispatch_chunk(slot_idx, s)
             if tok is None:
-                return      # not the last chunk: nothing to wait for yet
+                # not the last chunk: nothing to wait for yet, and its
+                # device time lands in the next tick.wait, not here
+                return
+            span.attrs["issued"] = issued
+            self._wait_behind(span)
             with self._span("prefill.wait"):
                 # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
                 tok = int(jax.device_get(tok))
@@ -1374,6 +1437,7 @@ class ServeEngine:
             s.generated = 1
             s.first_token_ts = s.token_ts[0] = now
             s.win_start_ts = now
+            s.gc_mark = trace.gc_seconds()
             if s.generated >= s.req.max_new_tokens or tok == cfg.eos_id:
                 s.done = True
                 s.finish_ts = now
@@ -1391,6 +1455,7 @@ class ServeEngine:
                                shared_len=s.shared_len,
                                cow=s.cow_pending is not None,
                                tenant=s.req.tenant, **tr.attrs())
+        self._note_prefill(span, s)
 
     def _dispatch_chunk(self, slot_idx: int, s: _Slot):
         """Dispatch the slot's next prefill chunk; on the final chunk also
@@ -1473,7 +1538,7 @@ class ServeEngine:
         padded[0, :p] = prompt
         with self._span("serve.prefill", rid=req.rid,
                         trace_id=self._trace_id(req.rid), prompt_len=p,
-                        bucket=bucket, shared_len=shared_len):
+                        bucket=bucket, shared_len=shared_len) as span:
             with self._span("prefill.dispatch", first_call=self._first_call(
                     ("sp_prefill", bucket))):
                 program = _sp_prefill_program(
@@ -1482,11 +1547,12 @@ class ServeEngine:
                 # specializes per sp bucket, same contract as serve_prefill
                 register_audit_program("serve_sp_prefill", program,
                                        allowed=max(len(self.sp_buckets), 1))
-                tok, new_layers, self._rng = program(
-                    self.params, self.pool.layers(),
-                    jnp.asarray(self.pool.flat_block_table(bt[None])),
-                    jnp.int32(p), jnp.int32(shared_len), jnp.asarray(padded),
-                    self._rng)
+                args = (self.params, self.pool.layers(),
+                        jnp.asarray(self.pool.flat_block_table(bt[None])),
+                        jnp.int32(p), jnp.int32(shared_len),
+                        jnp.asarray(padded), self._rng)
+                span.attrs["issued"] = self._now()
+                tok, new_layers, self._rng = program(*args)
                 self.pool.adopt(new_layers)
                 if self.cfg.prefix_cache:
                     self.pool.register_prefix(prompt, bt_pages,
@@ -1499,6 +1565,7 @@ class ServeEngine:
                 # scheduler waited behind, so that's what the virtual clock
                 # charges
                 self.prefill_token_work += bucket // self.sp_n
+            self._wait_behind(span)
             with self._span("prefill.wait"):
                 # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
                 tok = int(jax.device_get(tok))
@@ -1507,7 +1574,8 @@ class ServeEngine:
                                   start_ts, generated=1, first_token_ts=now,
                                   cow_pending=cow, shared_len=shared_len,
                                   n_fresh=len(fresh), n_shared=len(shared),
-                                  win_start_ts=now)
+                                  win_start_ts=now,
+                                  gc_mark=trace.gc_seconds())
             slot.buf[p] = tok
             slot.token_ts[0] = now
             if (slot.generated >= req.max_new_tokens
@@ -1527,6 +1595,7 @@ class ServeEngine:
                                pages_shared=len(shared),
                                shared_len=shared_len, cow=cow is not None,
                                tenant=req.tenant, **tr.attrs())
+        self._note_prefill(span, slot)
 
     def _resolve_cow(self, active) -> None:
         """Fork every pending shared frontier page before this tick's
@@ -1621,6 +1690,8 @@ class ServeEngine:
         if s.generated >= s.req.max_new_tokens or tok == self.cfg.eos_id:
             s.done = True
             s.finish_ts = now
+            s.behind_prefill_s = self.prefill_own_s - s.own_mark
+            s.behind_gc_s = trace.gc_seconds() - s.gc_mark
 
     def _decoding_next(self) -> List[Tuple[int, _Slot]]:
         """Who decodes in the next tick to dispatch. A slot mid-chunked-
@@ -1841,9 +1912,17 @@ class ServeEngine:
                          state_writes=self.state_writes,
                          ticks_ahead=self.ticks_ahead,
                          overrun_tokens=self.overrun_tokens,
+                         prefill_own_s=round(self.prefill_own_s, 6),
+                         gc_pause_s=round(self.gc_pause_s, 6),
                          slots=len(self.slots), tick=self.ticks)
 
     # -- introspection ----------------------------------------------------
+    @property
+    def gc_pause_s(self) -> float:
+        """Seconds the process spent in Python's garbage collections since
+        this engine was built (``obs.trace.gc_seconds``; real seconds)."""
+        return trace.gc_seconds() - self._gc_start
+
     @property
     def occupancy(self) -> float:
         """Mean active-slot share across decode ticks — the utilization
@@ -1897,6 +1976,11 @@ class ServeEngine:
                 # that had ended on eos_id (dropped, never emitted)
                 "ticks_ahead": self.ticks_ahead,
                 "overrun_tokens": self.overrun_tokens,
+                # what the admissions cost every decoding slot (the sum of
+                # the prefills' own time), and this process's seconds in
+                # garbage collections since the engine was built
+                "prefill_own_s": round(self.prefill_own_s, 6),
+                "gc_pause_s": round(self.gc_pause_s, 6),
                 "sp_prefills": self.sp_prefills,
                 "chunk_ticks": self.chunk_ticks,
                 "chunks_pending": self.chunks_pending,

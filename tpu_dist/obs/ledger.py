@@ -76,7 +76,10 @@ EVENT_SCHEMA = {
     # one COMPLETED serving request (engine.serve): the serving-SLO
     # record — timestamps are engine-clock (real seconds by default,
     # virtual units under an injected clock); ttft_s/prompt_len ride as
-    # extras
+    # extras, and so do behind_prefill_s/behind_gc_s: of first token ->
+    # finish, the seconds behind OTHER requests' admissions and inside
+    # garbage collections (tools/request_report.py splits the decode time
+    # by them, from the reqtrace `request` span that carries the same)
     "request": ("rid", "tokens", "queue_wait_s", "admit_ts",
                 "first_token_ts", "finish_ts"),
     # paged KV pool pressure snapshot (engine.serve, periodic + final):
@@ -85,7 +88,9 @@ EVENT_SCHEMA = {
     # trend, sharded_devices the sp-mesh width of the pool (1 when
     # unsharded) and chunks_pending the chunked-prefill backlog (the
     # chunk-queue depth ledger_report trends); high_water_used/slots/
-    # tick/chunk_ticks ride as extras
+    # tick/chunk_ticks ride as extras, with prefill_own_s/gc_pause_s (what
+    # admissions and garbage collections cost the decoding slots so far:
+    # ledger_report's `KV cache:` line)
     "kv_cache": ("pages_free", "pages_used", "active_seqs",
                  "shared_pages", "cow_copies", "prefix_hits",
                  "sharded_devices", "chunks_pending"),

@@ -18,6 +18,8 @@ of one request carry its identifier. Every span the program opens
 
 Timestamps are ``time.monotonic`` in the trainers and the engine's own
 ``now_fn`` in ``ServeEngine`` (virtual under a test's virtual clock).
+:func:`gc_seconds` times Python's garbage collections and puts the full
+ones into the same ring as ``host.gc`` spans.
 
 :class:`StepTracer` is the trainers' view: the same spans, plus the
 per-step sums of seconds (``pop()``) that the ledger's ``step`` record
@@ -29,6 +31,7 @@ ledger's step numbering, and :func:`profile_session` starts a
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import threading
@@ -181,6 +184,48 @@ def backend_compiles() -> int:
 
         jax.monitoring.register_event_duration_secs_listener(count)
     return _compiles[0]
+
+
+_gc_watch: Optional[list] = None
+
+
+def gc_seconds() -> float:
+    """Seconds this process has spent inside Python's garbage collections
+    since the first call (which installs ONE ``gc.callbacks`` entry, like
+    :func:`backend_compiles`' listener; the engines' constructors make that
+    call). Every collection is timed with two ``time.monotonic`` reads. A
+    collection of the OLDEST generation (the full ones, tens to hundreds of
+    milliseconds on an engine's heap) is also a ring span ``host.gc``
+    (``generation``, ``collected``), opened and closed on the thread it
+    interrupts, so its ``parent`` is the span it fell into
+    (``tick.emit``, ``serve.admit``, ``train.dispatch``); the younger
+    generations run many times a second and leave the counter only. The
+    span is stamped on ``time.monotonic`` whatever clock an engine was
+    given: beside a test's virtual clock its times mean nothing, its
+    seconds here still do."""
+    global _gc_watch
+    if _gc_watch is None:
+        watch = _gc_watch = [0.0, None, None]   # seconds, start, open span
+        oldest = len(gc.get_stats()) - 1
+
+        def on_gc(phase: str, info: dict) -> None:
+            if phase == "start":
+                if info["generation"] >= oldest:
+                    watch[2] = _RING.span(
+                        "host.gc", generation=info["generation"])
+                    watch[2].__enter__()
+                watch[1] = time.monotonic()
+                return
+            if watch[1] is None:      # installed inside this collection
+                return
+            watch[0] += time.monotonic() - watch[1]
+            sp, watch[2] = watch[2], None
+            if sp is not None:
+                sp.attrs["collected"] = info["collected"]
+                sp.__exit__(None, None, None)
+
+        gc.callbacks.append(on_gc)
+    return _gc_watch[0]
 
 
 class StepTracer:
