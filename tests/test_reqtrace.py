@@ -362,3 +362,7 @@ def test_live_engine_counters_reach_the_ledger_and_both_reports():
     decode_section(cap, out=lines.append)
     assert any("KV cache:" in ln and "admissions held the decoding slots"
                in ln and "garbage collections" in ln for ln in lines), lines
+    # one request a step: none of its prefills ran ahead, and the line says
+    assert kv["prefills_ahead"] == st["prefills_ahead"] == 0
+    assert any("KV cache:" in ln and "(0 prefills issued ahead of the last "
+               "one's read)" in ln for ln in lines), lines

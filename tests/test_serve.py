@@ -847,8 +847,19 @@ def test_step_spans_nest_and_every_token_has_a_time(span_lm, mode):
         assert ({"issued", "behind_s"} <= set(pf.attrs)) == waited
         if waited:
             send, behind, _ = sorted(kids[pf.sid], key=lambda k: k.start)
-            assert send.start < pf.attrs["issued"] < send.end
+            if pf.attrs["ahead"]:
+                # called inside the prefill before it: the device can
+                # have begun it when that one had landed, not earlier
+                assert pf.attrs["issued"] == pf.start
+            else:
+                assert send.start < pf.attrs["issued"] < send.end
             assert pf.attrs["behind_s"] == behind.end - behind.start
+    # both requests are in the queue at the first step: the second
+    # monolithic prefill is called ahead of the first one's read
+    assert [pf.attrs["ahead"] for pf in prefills
+            if "behind_s" in pf.attrs] == (
+        [0, 0] if chunked else [0, 1])
+    assert eng.stats()["prefills_ahead"] == (0 if chunked else 1)
     assert len(prefills) == (2 + 3 if chunked else 2)   # ceil(13/8)+ceil(18/8)
     assert sum(sp.attrs["n"] for sp in spans
                if sp.name == "serve.admit") == 2
@@ -1159,3 +1170,251 @@ def test_tick_spans_say_which_ticks_ran_ahead_and_tokens_are_stamped_held():
         for t, tk in zip(c.token_ts[1:], mine):
             assert waits[tk.sid].end <= t <= tk.end
         assert np.all(np.diff(c.token_ts) > 0)
+
+
+# ------------------------- a step's admissions as a pipeline (PR 39)
+class _OneAtATime(ServeEngine):
+    """The loop before PR 39: every admission's first token is read before
+    the next admission is planned, so nothing is ever issued ahead."""
+
+    def _prefill(self, adm, plans):
+        assert super()._prefill(adm, iter(())) is None
+        return next(plans, None)
+
+
+def _bucket_requests(seed, lens=(3, 20, 9, 13, 6), vocab=V):
+    """One request a length, of different prefill buckets (8, 16, 32)."""
+    r = np.random.default_rng(seed)
+    return [DecodeRequest(i, r.integers(0, vocab, (n,)).astype(np.int32),
+                          4 + i) for i, n in enumerate(lens)]
+
+
+def _one_a_step(eng, reqs):
+    """Feed ``eng`` one request a step: nothing can be issued ahead (but
+    under ``refill="drain"``, where arrivals wait for the batch to end and
+    are then admitted together)."""
+    done, _ = _staggered(eng, {k: [r] for k, r in enumerate(reqs)})
+    assert eng.cfg.refill == "drain" or eng.stats()["prefills_ahead"] == 0
+    return done
+
+
+_PIPELINE_CASES = {
+    "plain": dict(),
+    # requests 3 and 4 repeat request 1's prompt: admitted in the same step,
+    # they must still find its pages
+    "prefix_cache": dict(prefix_cache=True),
+    "speculative": dict(spec_k=3),
+    "drain": dict(refill="drain"),
+}
+
+
+@pytest.mark.parametrize("case", list(_PIPELINE_CASES))
+def test_a_steps_admissions_pipelined_serve_the_same_tokens(case):
+    """Five requests of different buckets in the queue before ONE step:
+    prefill k + 1 is called before prefill k's token is read, and every
+    completion is a plain greedy decode's, the same engine's fed one
+    request a step, and the one-at-a-time loop's."""
+    lm, params = _lm_and_params(seed=39)
+    fwd = jax.jit(lambda p, x: lm.apply({"params": p}, x, train=False))
+    cfg = dict(max_slots=5, page_size=4, num_pages=64,
+               **_PIPELINE_CASES[case])
+    reqs = _bucket_requests(39)
+    if case == "prefix_cache":
+        for k in (3, 4):
+            reqs[k] = DecodeRequest(k, reqs[1].prompt, reqs[k].max_new_tokens)
+    eng = ServeEngine(lm, params, ServeConfig(**cfg))
+    pages0 = eng.pool.pages_free
+    for r in reqs:
+        assert eng.submit(r)
+    eng.step()
+    assert not eng.queue and eng.prefills == 5        # ONE step took all
+    assert eng.stats()["prefills_ahead"] == 4
+    done = {c.rid: c for c in eng.run()}
+    assert sorted(done) == [0, 1, 2, 3, 4]
+    assert eng.pool.pages_free == pages0
+    apart = _one_a_step(ServeEngine(lm, params, ServeConfig(**cfg)), reqs)
+    sync = _OneAtATime(lm, params, ServeConfig(**cfg))
+    synced = {c.rid: c for c in sync.run(reqs)}
+    assert sync.stats()["prefills_ahead"] == 0
+    for r in reqs:
+        want = plain_greedy(fwd, params, r.prompt, r.max_new_tokens, L)
+        for got in (done, apart, synced):
+            np.testing.assert_array_equal(want, got[r.rid].tokens,
+                                          f"{case} {r.rid}")
+    if case == "prefix_cache":
+        # each repeat shares every full page of the 20-token prompt
+        full = reqs[1].prompt.size // 4
+        assert eng.shared_prompt_pages == sync.shared_prompt_pages
+        assert eng.shared_prompt_pages >= 2 * full > 0
+        assert eng.pool.cow_copies == sync.pool.cow_copies
+
+
+def test_sampled_tokens_pipelined_are_the_one_at_a_time_loops():
+    """``temperature > 0``: the random key rides through the prefills in
+    admission order and then through the ticks, as in the loop that reads
+    each first token before it plans the next admission, so the sampled
+    tokens are that loop's. (Fed one request a step the ticks come between
+    the prefills and the key's path is another: nothing to compare.)"""
+    lm, params = _lm_and_params(seed=39)
+    cfg = ServeConfig(max_slots=5, page_size=4, num_pages=64,
+                      temperature=0.9, top_k=8)
+    reqs = _bucket_requests(7)
+    runs = []
+    for cls in (ServeEngine, _OneAtATime):
+        eng = cls(lm, params, cfg, rng=jax.random.PRNGKey(5))
+        runs.append({c.rid: c.tokens for c in eng.run(reqs)})
+        assert eng.stats()["prefills_ahead"] == (4 if cls is ServeEngine
+                                                 else 0)
+    for r in reqs:
+        np.testing.assert_array_equal(runs[0][r.rid], runs[1][r.rid])
+    # and they are sampled: not the greedy stream
+    greedy = {c.rid: c.tokens for c in ServeEngine(
+        lm, params, ServeConfig(max_slots=5, page_size=4,
+                                num_pages=64)).run(reqs)}
+    assert any(not np.array_equal(greedy[r.rid], runs[0][r.rid])
+               for r in reqs)
+
+
+@pytest.mark.parametrize("prefix_cache", [False, True])
+def test_pool_pressure_on_the_third_admission_lands_the_first_two(
+        prefix_cache):
+    """Pages for two of five requests: the third's allocation fails while
+    the second's prefill is out ahead; the first two land, the rest stay
+    queued in order, no page is leaked (with ``prefix_cache`` the third
+    repeats the first's prompt: its share is handed back), and the backlog
+    is served as slots and pages come free."""
+    lm, params = _lm_and_params(seed=39)
+    reqs = [DecodeRequest(i, ((np.arange(9, dtype=np.int32) * (3 + 2 * (
+        i % 2 if prefix_cache else i)) + 1) % V), 3) for i in range(5)]
+    need = 3                                   # cdiv(9 + 3, 4) pages each
+    eng = ServeEngine(lm, params, ServeConfig(
+        max_slots=5, page_size=4, num_pages=2 * need,
+        prefix_cache=prefix_cache))
+    for r in reqs:
+        assert eng.submit(r)
+    eng.step()
+    assert eng.prefills == 2 and eng.stats()["prefills_ahead"] == 1
+    assert [r.rid for r, _ in eng.queue] == [2, 3, 4]
+    landed = [s for s in eng.slots if s is not None]
+    assert len(landed) == 2 and all(s.generated >= 1 for s in landed)
+    st = eng.pool.stats()
+    assert st["pages_free"] == 0 and st["shared_pages"] == 0
+    done = {c.rid: c for c in eng.run()}
+    assert sorted(done) == [0, 1, 2, 3, 4] and not eng.queue
+    st = eng.pool.stats()
+    assert st["pages_free"] == 2 * need and st["shared_pages"] == 0
+    fwd = jax.jit(lambda p, x: lm.apply({"params": p}, x, train=False))
+    for r in reqs:
+        np.testing.assert_array_equal(
+            plain_greedy(fwd, params, r.prompt, 3, L), done[r.rid].tokens)
+
+
+def _recorded_prefills(monkeypatch):
+    """Patch the prefill program and ``jax.device_get`` to log ("call", k)
+    when prefill k's program is called and ("read", k) when ITS first
+    token is fetched; returns the log."""
+    from tpu_dist.engine import serve
+
+    events, order, keep = [], {}, []
+    real_program, real_get = serve._prefill_program, jax.device_get
+
+    def program_for(*a):
+        program = real_program(*a)
+
+        def call(*args):
+            out = program(*args)
+            keep.append(out[0])               # ids stay unique while kept
+            order[id(out[0])] = len(order)
+            events.append(("call", order[id(out[0])]))
+            return out
+
+        call.head_rows = program.head_rows
+        return call
+
+    def get(x):
+        if id(x) in order:
+            events.append(("read", order[id(x)]))
+        return real_get(x)
+
+    monkeypatch.setattr(serve, "_prefill_program", program_for)
+    monkeypatch.setattr(serve.jax, "device_get", get)
+    return events
+
+
+def test_prefill_k_plus_1_is_called_before_token_k_is_read(span_lm,
+                                                           monkeypatch):
+    """Order and depth of one step's admissions, on a virtual clock that
+    moves by one a read: program k + 1 is called before token k is read,
+    tokens are read in admission order, never more than one program is out
+    beyond the one being read, ``_admit`` returns with none unread; the
+    spans say which ran ahead, and their own times tile the step."""
+    lm, params = span_lm
+    events = _recorded_prefills(monkeypatch)
+    clock = itertools.count()
+    eng = ServeEngine(lm, params, ServeConfig(
+        max_slots=5, page_size=4, num_pages=64),
+        now_fn=lambda: float(next(clock)))
+    real_admit, unread_after = eng._admit, []
+
+    def admit():
+        n = real_admit()
+        unread_after.append(sum(e == "call" for e, _ in events)
+                            - sum(e == "read" for e, _ in events))
+        return n
+
+    eng._admit = admit
+    reqs = _bucket_requests(11)
+    # the first request meets an idle engine; the other four one step later
+    # a busy one with a tick in flight, together
+    done, spans = _staggered(eng, {0: reqs[:1], 2: reqs[1:]})
+    assert sorted(done) == [0, 1, 2, 3, 4]
+    assert events == [("call", 0), ("read", 0),
+                      ("call", 1), ("call", 2), ("read", 1),
+                      ("call", 3), ("read", 2),
+                      ("call", 4), ("read", 3), ("read", 4)]
+    out = 0
+    for what, _ in events:
+        out += 1 if what == "call" else -1
+        assert 0 <= out <= 2
+    assert unread_after and not any(unread_after)
+    prefills = sorted((sp for sp in spans if sp.name == "serve.prefill"),
+                      key=lambda sp: sp.start)
+    assert [pf.attrs["rid"] for pf in prefills] == [0, 1, 2, 3, 4]
+    assert [pf.attrs["ahead"] for pf in prefills] == [0, 0, 1, 1, 1]
+    st = eng.stats()
+    assert st["prefills_ahead"] == sum(pf.attrs["ahead"] for pf in prefills)
+    kids = {}
+    for sp in spans:
+        kids.setdefault(sp.parent, []).append(sp)
+    for before, pf in zip(prefills, prefills[1:]):
+        send, behind, wait = sorted(kids[pf.sid], key=lambda k: k.start)
+        assert [send.name, behind.name, wait.name] == [
+            "prefill.dispatch", "prefill.behind", "prefill.wait"]
+        assert pf.attrs["behind_s"] == behind.end - behind.start
+        if pf.attrs["ahead"]:
+            assert before.end < pf.attrs["issued"] == pf.start
+        else:
+            assert send.start < pf.attrs["issued"] < send.end
+    # own time ends with the span; one step's do not overlap and add up
+    own = [(pf.end - (pf.end - pf.attrs["issued"] - pf.attrs["behind_s"]),
+            pf.end) for pf in prefills]
+    assert all(a < b for a, b in own)
+    assert all(own[k][1] <= own[k + 1][0] for k in range(len(own) - 1))
+    assert st["prefill_own_s"] == sum(b - a for a, b in own)
+    # request 0 decodes through the burst and stands behind all four; each
+    # of the burst's stands behind those admitted after it
+    for c in done.values():
+        later = [b - a for (a, b), pf in zip(own, prefills)
+                 if pf.attrs["rid"] > c.rid and pf.end < c.finish_ts]
+        assert c.behind_prefill_s == sum(later), c.rid
+
+
+def test_no_prefill_runs_ahead_when_requests_arrive_one_a_step(span_lm):
+    lm, params = span_lm
+    eng = ServeEngine(lm, params, ServeConfig(
+        max_slots=5, page_size=4, num_pages=64))
+    done, spans = _staggered(eng, {k: [r] for k, r in enumerate(
+        _bucket_requests(12))})
+    assert len(done) == 5 and eng.stats()["prefills_ahead"] == 0
+    assert [sp.attrs["ahead"] for sp in spans
+            if sp.name == "serve.prefill"] == [0] * 5

@@ -102,7 +102,9 @@ def serve_recorded(eng, log, arrivals):
 
         setattr(eng, name, wrapped)
 
-    wrap("_prefill", lambda out, i, req, *a, **kw: [(0, req.rid)])
+    # a step's prefills are issued one ahead of the read (PR 39): the
+    # program's call, not the landing, is what the device runs in order
+    wrap("_issue_prefill", lambda out, adm, send: [(0, adm.req.rid)])
     # a chunk that is not a prompt's last samples nothing (returns None)
     wrap("_dispatch_chunk",
          lambda tok, i, s: None if tok is None else [(0, s.req.rid)])
@@ -192,6 +194,30 @@ def test_requests_admitted_while_others_decode(log):
     assert max(sp.attrs["state_slots"] for sp in ticks) == 3
     assert all(sp.attrs["state_slots"] == len(sp.attrs["rids"])
                for sp in ticks)
+
+
+def test_a_steps_admissions_pipelined_over_slot_state(log):
+    """Four requests of four buckets (8, 16, 32, 64) in the queue before
+    ONE step: each prefill's program is called before the last one's token
+    is read and writes its own slot's state. Every sampled row is the
+    reference's, with all four admitted together and fed one a step."""
+    w = weights(seed=6)
+    tokens = {}
+    for arrivals, ahead in (({0: requests([5, 11, 20, 40], new=4, seed=7)},
+                             3),
+                            ({k: [r] for k, r in enumerate(
+                                requests([5, 11, 20, 40], new=4, seed=7))},
+                             0)):
+        del log[:]
+        eng = engine(w, max_slots=4)
+        done, rows = serve_recorded(eng, log, arrivals)
+        assert len(done) == 4
+        assert eng.stats()["prefills_ahead"] == ahead
+        assert eng.stats()["state_writes"] == 4
+        check_against_reference(done, rows, w)
+        tokens[ahead] = {rid: c.tokens for rid, c in done.items()}
+    for rid in tokens[0]:
+        np.testing.assert_array_equal(tokens[0][rid], tokens[3][rid])
 
 
 def test_a_reused_slot_holds_nothing_of_its_last_occupant(log):

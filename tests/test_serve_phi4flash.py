@@ -95,6 +95,30 @@ def test_mixed_lengths_joining_and_leaving_with_the_tick_ahead(log):
     assert max(sp.attrs["state_slots"] for sp in ticks) == 3
 
 
+def test_a_steps_admissions_pipelined_over_rings_and_the_shared_layer(log):
+    """Four requests of four buckets, from under a window to over three,
+    in the queue before ONE step: each prefill's program is called before
+    the last one's token is read, and writes its own slot's rings and
+    state beside the one page layer. Every sampled row is the reference's,
+    with all four admitted together and fed one a step."""
+    w = weights(seed=7)
+    tokens = {}
+    for arrivals, ahead in (({0: requests([6, 30, 13, 40], new=5, seed=9)},
+                             3),
+                            ({k: [r] for k, r in enumerate(
+                                requests([6, 30, 13, 40], new=5, seed=9))},
+                             0)):
+        del log[:]
+        eng = engine(w, max_slots=4)
+        done, rows = serve_recorded(eng, log, arrivals)
+        assert len(done) == 4
+        assert eng.stats()["prefills_ahead"] == ahead
+        check_against_reference(done, rows, w)
+        tokens[ahead] = {rid: c.tokens for rid, c in done.items()}
+    for rid in tokens[0]:
+        np.testing.assert_array_equal(tokens[0][rid], tokens[3][rid])
+
+
 @pytest.mark.parametrize("prompt", [
     WINDOW - 1, WINDOW, WINDOW + 1,          # around the window's edge
     WINDOW + PAGE,                           # the ring's

@@ -439,6 +439,9 @@ def decode_section(records, out=print):
             # queued behind) and the process's garbage collections
             srv["prefill_own_s"] = last.get("prefill_own_s")
             srv["gc_pause_s"] = last.get("gc_pause_s")
+            # prefills whose program was called while the prefill before
+            # them, of the same step, was still unread
+            srv["prefills_ahead_last"] = last.get("prefills_ahead")
             # the decode tick one ahead of the host: ticks dispatched
             # while the tick before them was unread, and tokens computed
             # for a slot that had already ended on eos_id (dropped)
@@ -487,8 +490,11 @@ def decode_section(records, out=print):
                 + (f", {_si(srv['window_bytes'], 'B')} of window rings"
                    if srv.get("window_bytes") else "")
                 + (f"; admissions held the decoding slots "
-                   f"{srv['prefill_own_s']:.3f}s, garbage collections "
-                   f"{srv['gc_pause_s']:.3f}s"
+                   f"{srv['prefill_own_s']:.3f}s"
+                   + (f" ({srv['prefills_ahead_last']} prefills issued "
+                      "ahead of the last one's read)"
+                      if srv.get("prefills_ahead_last") is not None else "")
+                   + f", garbage collections {srv['gc_pause_s']:.3f}s"
                    if srv.get("prefill_own_s") is not None else ""))
         if srv.get("ticks_ahead_last"):
             out(f"  decode tick: {srv['ticks_ahead_last']} of "

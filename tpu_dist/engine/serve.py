@@ -32,6 +32,13 @@ Composition (each piece usable alone):
   (``ServeEngine._tick_plain`` has the rules: who ends by budget is known
   before the dispatch, an ``eos_id`` end is seen one tick late and its
   extra token dropped);
+* a step's monolithic admissions run as a **pipeline of depth one**:
+  admission k + 1 is planned and its prefill program called before
+  admission k's first token is read (``ServeEngine._admit``), so a burst's
+  prefills follow each other on the device while the host plans, uploads
+  and reads; the device's order of programs, the random key's path and
+  each request's stamps are what a one-at-a-time loop gives, and nothing
+  is unread when ``_admit`` returns;
 * **speculative decoding** (``spec_k > 0``): a small draft model over the
   shared base proposes k greedy tokens per slot and ONE jitted program per
   tick both drafts and verifies — the draft scan rides its own page arenas
@@ -94,7 +101,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from tpu_dist._compat import shard_map
 from tpu_dist.engine.generate import (_quantize_for_decode, _refuse_wo_tree,
                                       _sample, prepare_draft)
-from tpu_dist.engine.kv_cache import PagedKVPool, PrefixMatch
+from tpu_dist.engine.kv_cache import PagedKVPool
 from tpu_dist.obs import trace
 from tpu_dist.obs.reqtrace import RequestTracer
 from tpu_dist.ops.paged_attention import cow_fork_pages, decode_read
@@ -243,6 +250,39 @@ class _Flight:
     nxt: jax.Array                       # (slots,) its sampled tokens
     slots: List[Tuple[int, _Slot]]       # who decodes in it
     ahead: int   # 1: dispatched while the tick before it was still unread
+
+
+@dataclass
+class _Admission:
+    """A request on its way from the queue into a slot: what
+    :meth:`ServeEngine._planned` decided for it and, for a monolithic
+    prefill, what :meth:`ServeEngine._issue_prefill` leaves for the
+    landing in :meth:`ServeEngine._prefill`."""
+
+    slot_idx: int
+    req: DecodeRequest
+    prompt: np.ndarray
+    enq_ts: float
+    start_ts: float              # when it left the queue
+    kind: str                    # "prefill" | "chunked" | "sp"
+    bucket: Optional[int]        # the prefill's (or sp) bucket; chunked: None
+    pages: List[int]             # shared first, then the fresh ones
+    n_shared: int
+    shared_len: int              # prompt rows resident on shared pages
+    # a shared frontier page to fork before the first decode write:
+    # (block-table slot, the shared page, its reserved destination)
+    cow: Optional[Tuple[int, int, int]]
+    bt_pages: List[int]          # what the block table reads through
+    block_table: np.ndarray
+    # a monolithic prefill once its program is called
+    tok: Optional[jax.Array] = None   # the first token, unread
+    issued: float = 0.0          # the clock just before the program's call
+    ahead: int = 0   # 1: called while the prefill before it was unread
+    cross_rows: Optional[int] = None
+
+    @property
+    def n_fresh(self) -> int:
+        return len(self.pages) - self.n_shared
 
 
 def _slot_state_layers(layout) -> int:
@@ -799,6 +839,7 @@ class ServeEngine:
         self._dev_rows: List[Optional[_Slot]] = [None] * cfg.max_slots
         self._flights: Deque[_Flight] = deque()
         self.ticks_ahead = 0         # ticks dispatched one ahead of a read
+        self.prefills_ahead = 0      # prefills called ahead of a read
         self.overrun_tokens = 0      # computed for a slot already ended
         self._live_pages_sum = 0
         self._wait_ema: Optional[float] = None
@@ -1145,14 +1186,48 @@ class ServeEngine:
         return out
 
     def _admit(self) -> int:
-        """Fill free slots from the queue; returns how many it admitted."""
+        """Fill free slots from the queue; returns how many it admitted.
+
+        The monolithic prefills of one call run as a pipeline of depth
+        one: admission k + 1 is planned and its program called inside
+        admission k's ``prefill.dispatch`` (:meth:`_prefill`), before k's
+        first token is read, so the device goes from one prefill to the
+        next while the host plans, uploads and reads. The device's order
+        of programs (tick in flight, prefill 1, prefill 2, ..., next
+        tick), the random key's path through them, each request's stamps
+        and the order of planning (k's ``register_prefix`` before k + 1's
+        ``share_prefix``) are a one-at-a-time loop's; a call with one
+        admission makes that loop's runtime calls in its order. At most
+        one program is out beyond the one being read, and none when this
+        returns. A chunked or sequence-parallel admission keeps its
+        synchronous form and is never issued ahead."""
         if self.cfg.refill == "drain" and any(
                 s is not None for s in self.slots):
             return 0  # static batching: refill only once the batch drained
         admitted = 0
+        plans = self._planned()
+        adm = next(plans, None)
+        while adm is not None:
+            admitted += 1
+            if adm.kind == "prefill":
+                adm = self._prefill(adm, plans)
+                continue
+            if adm.kind == "sp":
+                self._prefill_sp(adm)
+            else:
+                self._begin_chunked(adm)
+            adm = next(plans, None)
+        return admitted
+
+    def _planned(self):
+        """The admissions this step can make, free slot by free slot in
+        queue order, each planned only when it is asked for (so after the
+        one before it was issued and had registered its prefix): prefix
+        match, pages, out of the queue, the queue span. Pool pressure
+        ends it and leaves the request queued."""
         for i in range(len(self.slots)):
             if not self.queue:
-                break
+                return
             if self.slots[i] is not None:
                 continue
             req, enq_ts = self.queue[0]
@@ -1198,9 +1273,8 @@ class ServeEngine:
             if fresh is None:
                 if match is not None:
                     self.pool.unshare(match)
-                break  # pool pressure: leave it queued, decode on
+                return  # pool pressure: leave it queued, decode on
             self.queue.popleft()
-            admitted += 1
             now = self._now()
             self._observe_wait(now - enq_ts)
             if self.tracer is not None:
@@ -1216,14 +1290,30 @@ class ServeEngine:
                                queue_depth=len(self.queue),
                                tenant=req.tenant, **tr.attrs())
             if use_sp:
-                self._prefill_sp(i, req, prompt, fresh, enq_ts, now, match,
-                                 sp_bucket)
+                kind, bucket = "sp", sp_bucket
             elif use_chunk:
-                self._begin_chunked(i, req, prompt, fresh, enq_ts, now,
-                                    match)
+                kind, bucket = "chunked", None
             else:
-                self._prefill(i, req, prompt, fresh, enq_ts, now, match)
-        return admitted
+                kind, bucket = "prefill", next(
+                    b for b in self.buckets if b >= p)
+            shared = list(match.pages) if match is not None else []
+            cow = None
+            if match is not None and match.partial:
+                # the block table reads through the SHARED frontier page at
+                # slot match.full; the last fresh page is its reserved CoW
+                # destination, forked right before this sequence's first
+                # decode write (_resolve_cow)
+                cow = (match.full, shared[-1], fresh[-1])
+                bt_pages = shared + fresh[:-1]
+            else:
+                bt_pages = shared + fresh
+            bt = np.full((self.max_pages_per_seq,), self.pool.num_pages,
+                         np.int32)                   # unassigned -> trash
+            bt[:len(bt_pages)] = bt_pages
+            yield _Admission(
+                i, req, prompt, enq_ts, now, kind, bucket, shared + fresh,
+                len(shared), match.cov if match is not None else 0, cow,
+                bt_pages, bt)
 
     def _new_slot(self, req, prompt, pages, bt, enq_ts, start_ts,
                   **fields) -> _Slot:
@@ -1236,85 +1326,107 @@ class ServeEngine:
         slot.buf[:p] = prompt
         return slot
 
-    def _prefill(self, slot_idx, req, prompt, fresh, enq_ts, start_ts,
-                 match: Optional[PrefixMatch] = None):
-        p = prompt.size
-        bucket = next(b for b in self.buckets if b >= p)
-        shared = list(match.pages) if match is not None else []
-        shared_len = match.cov if match is not None else 0
-        cow = None
-        if match is not None and match.partial:
-            # the block table reads through the SHARED frontier page at
-            # slot match.full; the last fresh page is its reserved CoW
-            # destination, forked right before this sequence's first
-            # decode write (_resolve_cow)
-            cow = (match.full, shared[-1], fresh[-1])
-            bt_pages = shared + fresh[:-1]
-        else:
-            bt_pages = shared + fresh
-        bt = np.full((self.max_pages_per_seq,), self.pool.num_pages,
-                     np.int32)                       # unassigned -> trash
-        bt[:len(bt_pages)] = bt_pages
+    def _issue_prefill(self, adm: _Admission, send) -> None:
+        """The first half of a monolithic admission: everything up to and
+        including the program's call (and the draft's, the prefix index,
+        the counters), inside the open ``prefill.dispatch`` span ``send``.
+        The first token stays on the device in ``adm.tok``."""
+        prompt, bt, shared_len = adm.prompt, adm.block_table, adm.shared_len
+        p, bucket = prompt.size, adm.bucket
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :p] = prompt
+        if self._first_call(("prefill", bucket)):
+            send.attrs["first_call"] = True
+        program = _prefill_program(self.model, self.cfg.temperature,
+                                   self.cfg.top_k, self.cfg.top_p,
+                                   self.sp_mesh)
+        # recompile sentry (analysis.proglint PL005): prefill
+        # specializes per bucket BY DESIGN, so its allowed trace-cache
+        # size is the bucket-ladder length, not 1 (no-op when the
+        # audit is off)
+        register_audit_program("serve_prefill", program,
+                               allowed=len(self.buckets))
+        args = (self.params, self.pool.layers(),
+                jnp.asarray(self.pool.flat_block_table(bt[None])),
+                jnp.int32(p), jnp.int32(shared_len),
+                jnp.asarray(padded), self._rng, jnp.int32(adm.slot_idx))
+        adm.issued = self._now()
+        adm.tok, new_layers, self._rng = program(*args)
+        self.pool.adopt(new_layers)
+        # rows of the prompt the model's last layers and head ran
+        # on, as the traced program had them (the bucket, or 1)
+        adm.cross_rows = program.head_rows.get(bucket)
+        self.prefill_token_work += bucket
+        self.state_writes += bool(self.state_layers)
+        if self.draft_pool is not None:
+            # the draft's prompt rows, through the same block table
+            # (the pools share page indices); shared rows were written
+            # by the earlier prefix owner's draft prefill, so the mask
+            # matches
+            dprog = _draft_prefill_program(self.draft_model)
+            self.draft_pool.adopt(dprog(
+                self.draft_params, self.draft_pool.layers(),
+                jnp.asarray(bt[None]), jnp.int32(p),
+                jnp.int32(shared_len), jnp.asarray(padded)))
+        if self.cfg.prefix_cache:
+            # index this prompt's freshly-written pages for future
+            # sharers (shared slots are already indexed by their
+            # original writer)
+            self.pool.register_prefix(prompt, adm.bt_pages,
+                                      skip_slots=adm.n_shared)
+            self.prompt_pages += self.pool.pages_needed(p)
+            self.shared_prompt_pages += adm.n_shared
+        self.prefills += 1
+
+    def _prefill(self, adm: _Admission, plans) -> Optional[_Admission]:
+        """Land ``adm``'s monolithic prefill and return the admission
+        planned after it (None: no more this step).
+
+        ``prefill.dispatch`` calls ``adm``'s program where the admission
+        before it has not already, then asks ``plans`` for the next
+        admission and, if that one is monolithic too, calls ITS program:
+        it is then **ahead** (``serve.prefill`` attribute ``ahead`` 1,
+        ``prefills_ahead``), queued on the device behind this one while
+        the host reads this one's token. ``prefill.behind`` blocks on the
+        tick in flight for a step's first admission and finds it landed
+        for the later ones. For a prefill issued ahead ``issued`` is its
+        own span's start, the first clock read after its predecessor
+        landed: the moment the device can have begun it as far as the host
+        knows. So the own times of one step's admissions ((end -
+        ``issued``) - ``behind_s``) lie end to end without overlap, each
+        within its span; a step's first one holds what was left of the
+        tick in flight where that landed while the host issued the second
+        (it reads high there, never low)."""
+        req, prompt = adm.req, adm.prompt
+        p = prompt.size
         with self._span("serve.prefill", rid=req.rid,
                         trace_id=self._trace_id(req.rid), prompt_len=p,
-                        bucket=bucket, shared_len=shared_len,
+                        bucket=adm.bucket, shared_len=adm.shared_len,
                         state_layers=self.state_layers,
                         window_layers=self.window_layers,
-                        shared_readers=self.shared_readers) as span:
-            with self._span("prefill.dispatch", first_call=self._first_call(
-                    ("prefill", bucket))):
-                program = _prefill_program(self.model, self.cfg.temperature,
-                                           self.cfg.top_k, self.cfg.top_p,
-                                           self.sp_mesh)
-                # recompile sentry (analysis.proglint PL005): prefill
-                # specializes per bucket BY DESIGN, so its allowed trace-cache
-                # size is the bucket-ladder length, not 1 (no-op when the
-                # audit is off)
-                register_audit_program("serve_prefill", program,
-                                       allowed=len(self.buckets))
-                args = (self.params, self.pool.layers(),
-                        jnp.asarray(self.pool.flat_block_table(bt[None])),
-                        jnp.int32(p), jnp.int32(shared_len),
-                        jnp.asarray(padded), self._rng, jnp.int32(slot_idx))
-                span.attrs["issued"] = self._now()
-                tok, new_layers, self._rng = program(*args)
-                self.pool.adopt(new_layers)
-                # rows of the prompt the model's last layers and head ran
-                # on, as the traced program had them (the bucket, or 1)
-                span.attrs["cross_rows"] = program.head_rows.get(bucket)
-                self.prefill_token_work += bucket
-                self.state_writes += bool(self.state_layers)
-                if self.draft_pool is not None:
-                    # the draft's prompt rows, through the same block table
-                    # (the pools share page indices); shared rows were written
-                    # by the earlier prefix owner's draft prefill, so the mask
-                    # matches
-                    dprog = _draft_prefill_program(self.draft_model)
-                    self.draft_pool.adopt(dprog(
-                        self.draft_params, self.draft_pool.layers(),
-                        jnp.asarray(bt[None]), jnp.int32(p),
-                        jnp.int32(shared_len), jnp.asarray(padded)))
-                if self.cfg.prefix_cache:
-                    # index this prompt's freshly-written pages for future
-                    # sharers (shared slots are already indexed by their
-                    # original writer)
-                    self.pool.register_prefix(prompt, bt_pages,
-                                              skip_slots=len(shared))
-                    self.prompt_pages += self.pool.pages_needed(p)
-                    self.shared_prompt_pages += len(shared)
-                self.prefills += 1
+                        shared_readers=self.shared_readers,
+                        ahead=adm.ahead) as span:
+            with self._span("prefill.dispatch", first_call=False) as send:
+                if adm.tok is None:
+                    self._issue_prefill(adm, send)
+                following = next(plans, None)
+                if following is not None and following.kind == "prefill":
+                    self._issue_prefill(following, send)
+                    following.ahead = 1
+                    self.prefills_ahead += 1
+            span.attrs["issued"] = span.start if adm.ahead else adm.issued
+            span.attrs["cross_rows"] = adm.cross_rows
             self._wait_behind(span)
             with self._span("prefill.wait"):
                 # the scheduler IS the drain boundary: the first token
                 # decides done/eos and the TTFT stamp before the next iteration
                 # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
-                tok = int(jax.device_get(tok))
+                tok = int(jax.device_get(adm.tok))
             now = self._now()
-            slot = self._new_slot(req, prompt, shared + fresh, bt, enq_ts,
-                                  start_ts, generated=1, first_token_ts=now,
-                                  cow_pending=cow, win_start_ts=now,
+            slot = self._new_slot(req, prompt, adm.pages, adm.block_table,
+                                  adm.enq_ts, adm.start_ts, generated=1,
+                                  first_token_ts=now, cow_pending=adm.cow,
+                                  win_start_ts=now,
                                   gc_mark=trace.gc_seconds())
             slot.buf[p] = tok
             slot.token_ts[0] = now
@@ -1322,7 +1434,7 @@ class ServeEngine:
                     or tok == self.cfg.eos_id):
                 slot.done = True
                 slot.finish_ts = now
-            self.slots[slot_idx] = slot
+            self.slots[adm.slot_idx] = slot
             if self.tracer is not None:
                 # prefill span: queue-exit -> first token, carrying the knobs
                 # that explain a slow one (bucket padding, fresh vs shared
@@ -1331,13 +1443,16 @@ class ServeEngine:
                 tid, sid, par = tr.ids(req.rid, "prefill")
                 tr.ledger.emit("span", trace_id=tid, span_id=sid,
                                parent_id=par, name="prefill", rid=req.rid,
-                               start=round(start_ts, 6), end=round(now, 6),
-                               bucket=bucket, prompt_len=p,
-                               pages_fresh=len(fresh),
-                               pages_shared=len(shared),
-                               shared_len=shared_len, cow=cow is not None,
+                               start=round(adm.start_ts, 6),
+                               end=round(now, 6),
+                               bucket=adm.bucket, prompt_len=p,
+                               pages_fresh=adm.n_fresh,
+                               pages_shared=adm.n_shared,
+                               shared_len=adm.shared_len,
+                               cow=adm.cow is not None,
                                tenant=req.tenant, **tr.attrs())
         self._note_prefill(span, slot)
+        return following
 
     def _wait_behind(self, span) -> None:
         """``prefill.behind``, between a prefill's dispatch and its wait:
@@ -1369,8 +1484,7 @@ class ServeEngine:
         slot.own_mark = self.prefill_own_s
 
     # -- chunked prefill ---------------------------------------------------
-    def _begin_chunked(self, slot_idx, req, prompt, fresh, enq_ts, start_ts,
-                       match: Optional[PrefixMatch] = None):
+    def _begin_chunked(self, adm: _Admission):
         """Admit a long prompt WITHOUT running its prefill: the slot parks
         with ``chunk_next >= 0`` (outside the decode tick's active set) and
         :meth:`_chunk_tick` feeds it one fixed-size chunk per scheduler
@@ -1378,28 +1492,17 @@ class ServeEngine:
         the decode stream instead of one full-prompt stall. First token,
         prefix registration, and the prefill span all land on the FINAL
         chunk (the pages only hold the whole prompt then)."""
-        p = prompt.size
-        chunk = self.cfg.prefill_chunk
-        shared = list(match.pages) if match is not None else []
-        shared_len = match.cov if match is not None else 0
-        cow = None
-        if match is not None and match.partial:
-            cow = (match.full, shared[-1], fresh[-1])
-            bt_pages = shared + fresh[:-1]
-        else:
-            bt_pages = shared + fresh
-        bt = np.full((self.max_pages_per_seq,), self.pool.num_pages,
-                     np.int32)
-        bt[:len(bt_pages)] = bt_pages
-        self.slots[slot_idx] = self._new_slot(
-            req, prompt, shared + fresh, bt, enq_ts, start_ts, generated=0,
-            cow_pending=cow,
+        p, chunk = adm.prompt.size, self.cfg.prefill_chunk
+        self.slots[adm.slot_idx] = self._new_slot(
+            adm.req, adm.prompt, adm.pages, adm.block_table, adm.enq_ts,
+            adm.start_ts, generated=0, cow_pending=adm.cow,
             # start at the chunk holding the first NON-shared row (a
             # fully-shared prompt still runs its last chunk: writes are
             # masked, but the final chunk's logits are where the first
             # token comes from)
-            chunk_next=min(shared_len, p - 1) // chunk * chunk,
-            shared_len=shared_len, n_fresh=len(fresh), n_shared=len(shared))
+            chunk_next=min(adm.shared_len, p - 1) // chunk * chunk,
+            shared_len=adm.shared_len, n_fresh=adm.n_fresh,
+            n_shared=adm.n_shared)
 
     def _chunk_tick(self) -> None:
         """At most ONE prefill chunk per scheduler iteration — the knob
@@ -1418,7 +1521,7 @@ class ServeEngine:
         with self._span("serve.prefill", rid=s.req.rid, trace_id=s.trace_id,
                         prompt_len=p, bucket=chunk, shared_len=s.shared_len,
                         chunk_start=s.chunk_next,
-                        state_layers=self.state_layers) as span:
+                        state_layers=self.state_layers, ahead=0) as span:
             with self._span("prefill.dispatch",
                             first_call=self._first_call("chunk_prefill")):
                 issued = self._now()
@@ -1516,29 +1619,20 @@ class ServeEngine:
         return tok
 
     # -- sequence-parallel prefill -----------------------------------------
-    def _prefill_sp(self, slot_idx, req, prompt, fresh, enq_ts, start_ts,
-                    match: Optional[PrefixMatch], bucket: int):
+    def _prefill_sp(self, adm: _Admission):
         """Monolithic-shaped admission, sequence-parallel execution: the
         prompt pads to an sp bucket and every device prefills ITS shard
         under ring attention, scattering K/V into the pages the striped
         allocation placed on it (_sp_prefill_program has the mechanics)."""
+        req, prompt, bucket = adm.req, adm.prompt, adm.bucket
+        bt, shared_len = adm.block_table, adm.shared_len
         p = prompt.size
-        shared = list(match.pages) if match is not None else []
-        shared_len = match.cov if match is not None else 0
-        cow = None
-        if match is not None and match.partial:
-            cow = (match.full, shared[-1], fresh[-1])
-            bt_pages = shared + fresh[:-1]
-        else:
-            bt_pages = shared + fresh
-        bt = np.full((self.max_pages_per_seq,), self.pool.num_pages,
-                     np.int32)
-        bt[:len(bt_pages)] = bt_pages
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :p] = prompt
         with self._span("serve.prefill", rid=req.rid,
                         trace_id=self._trace_id(req.rid), prompt_len=p,
-                        bucket=bucket, shared_len=shared_len) as span:
+                        bucket=bucket, shared_len=shared_len,
+                        ahead=0) as span:
             with self._span("prefill.dispatch", first_call=self._first_call(
                     ("sp_prefill", bucket))):
                 program = _sp_prefill_program(
@@ -1555,10 +1649,10 @@ class ServeEngine:
                 tok, new_layers, self._rng = program(*args)
                 self.pool.adopt(new_layers)
                 if self.cfg.prefix_cache:
-                    self.pool.register_prefix(prompt, bt_pages,
-                                              skip_slots=len(shared))
+                    self.pool.register_prefix(prompt, adm.bt_pages,
+                                              skip_slots=adm.n_shared)
                     self.prompt_pages += self.pool.pages_needed(p)
-                    self.shared_prompt_pages += len(shared)
+                    self.shared_prompt_pages += adm.n_shared
                 self.prefills += 1
                 self.sp_prefills += 1
                 # each device touches bucket/n rows: that's the wall the
@@ -1570,10 +1664,11 @@ class ServeEngine:
                 # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
                 tok = int(jax.device_get(tok))
             now = self._now()
-            slot = self._new_slot(req, prompt, shared + fresh, bt, enq_ts,
-                                  start_ts, generated=1, first_token_ts=now,
-                                  cow_pending=cow, shared_len=shared_len,
-                                  n_fresh=len(fresh), n_shared=len(shared),
+            slot = self._new_slot(req, prompt, adm.pages, bt, adm.enq_ts,
+                                  adm.start_ts, generated=1,
+                                  first_token_ts=now, cow_pending=adm.cow,
+                                  shared_len=shared_len,
+                                  n_fresh=adm.n_fresh, n_shared=adm.n_shared,
                                   win_start_ts=now,
                                   gc_mark=trace.gc_seconds())
             slot.buf[p] = tok
@@ -1582,18 +1677,20 @@ class ServeEngine:
                     or tok == self.cfg.eos_id):
                 slot.done = True
                 slot.finish_ts = now
-            self.slots[slot_idx] = slot
+            self.slots[adm.slot_idx] = slot
             if self.tracer is not None:
                 tr = self.tracer
                 tid, sid, par = tr.ids(req.rid, "prefill")
                 tr.ledger.emit("span", trace_id=tid, span_id=sid,
                                parent_id=par, name="prefill", rid=req.rid,
-                               start=round(start_ts, 6), end=round(now, 6),
+                               start=round(adm.start_ts, 6),
+                               end=round(now, 6),
                                mode="sp", sp_devices=self.sp_n,
                                bucket=bucket, prompt_len=p,
-                               pages_fresh=len(fresh),
-                               pages_shared=len(shared),
-                               shared_len=shared_len, cow=cow is not None,
+                               pages_fresh=adm.n_fresh,
+                               pages_shared=adm.n_shared,
+                               shared_len=shared_len,
+                               cow=adm.cow is not None,
                                tenant=req.tenant, **tr.attrs())
         self._note_prefill(span, slot)
 
@@ -1911,6 +2008,7 @@ class ServeEngine:
                          kv_bytes_per_token=st["kv_bytes_per_token"],
                          state_writes=self.state_writes,
                          ticks_ahead=self.ticks_ahead,
+                         prefills_ahead=self.prefills_ahead,
                          overrun_tokens=self.overrun_tokens,
                          prefill_own_s=round(self.prefill_own_s, 6),
                          gc_pause_s=round(self.gc_pause_s, 6),
@@ -1976,6 +2074,9 @@ class ServeEngine:
                 # that had ended on eos_id (dropped, never emitted)
                 "ticks_ahead": self.ticks_ahead,
                 "overrun_tokens": self.overrun_tokens,
+                # monolithic prefills whose program was called while the
+                # prefill before them, of the same step, was still unread
+                "prefills_ahead": self.prefills_ahead,
                 # what the admissions cost every decoding slot (the sum of
                 # the prefills' own time), and this process's seconds in
                 # garbage collections since the engine was built

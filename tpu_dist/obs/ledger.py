@@ -89,8 +89,9 @@ EVENT_SCHEMA = {
     # unsharded) and chunks_pending the chunked-prefill backlog (the
     # chunk-queue depth ledger_report trends); high_water_used/slots/
     # tick/chunk_ticks ride as extras, with prefill_own_s/gc_pause_s (what
-    # admissions and garbage collections cost the decoding slots so far:
-    # ledger_report's `KV cache:` line)
+    # admissions and garbage collections cost the decoding slots so far)
+    # and prefills_ahead (prefills called before the last one's token was
+    # read): ledger_report's `KV cache:` line
     "kv_cache": ("pages_free", "pages_used", "active_seqs",
                  "shared_pages", "cow_copies", "prefix_hits",
                  "sharded_devices", "chunks_pending"),
