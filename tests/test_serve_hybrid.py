@@ -296,15 +296,34 @@ def test_int8_kv_pages_serve_the_attention_layers(log):
     assert worst > ATOL
 
 
+def _nemotron_h():
+    """The Mamba-2 / latent-expert model's toy size and its parameters."""
+    import test_nemotron_h_lm as nh
+
+    model = nh.toy_model()
+    return model, nh.engine_params(model, nh.ref.make_weights(
+        nh.TOY, jax.random.PRNGKey(1)))
+
+
 @pytest.mark.parametrize("fields,mechanism", [
     (dict(prefix_cache=True), "prefix_cache"),
     (dict(spec_k=2), "speculative"),
     (dict(mesh=True), "sp-sharded"),
+    # Mamba-2 state is slot state too, and expert layers keep nothing
+    (dict(prefix_cache=True, nemotron_h=True),
+     "prefix_cache over slot state.*Mamba-1 or Mamba-2 layer's"),
+    (dict(spec_k=2, nemotron_h=True),
+     "speculative decoding.*Mamba-2's a head"),
+    (dict(mesh=True, nemotron_h=True), "sp-sharded pool.*slot state"),
 ])
 def test_what_has_no_meaning_over_slot_state_is_refused_by_name(fields,
                                                                 mechanism):
-    model = toy_model()
-    params = engine_params(model, weights())
+    fields = dict(fields)
+    if fields.pop("nemotron_h", None):
+        model, params = _nemotron_h()
+    else:
+        model = toy_model()
+        params = engine_params(model, weights())
     kw = {}
     if fields.pop("mesh", None):
         from tpu_dist.parallel.mesh import SP_AXIS, make_mesh

@@ -428,7 +428,12 @@ def decode_section(records, out=print):
             # cache_layout has slot-state layers): bytes allocated, and
             # prefills that wrote a slot's state
             srv["state_bytes"] = last.get("state_bytes")
+            srv["state_bytes_per_slot"] = last.get("state_bytes_per_slot")
             srv["state_writes_last"] = last.get("state_writes")
+            # a model with routed expert layers: assignments that landed on
+            # the experts held here, and held experts hit a layer and tick
+            srv["expert_rows_last"] = last.get("expert_rows")
+            srv["experts_hit_mean"] = last.get("experts_hit_mean")
             # what a sequence costs in cache: K and V bytes a token over the
             # layers that keep pages, and what the window rings hold (all
             # slots; 0 for a model without a window layer)
@@ -481,9 +486,15 @@ def decode_section(records, out=print):
                    f"last {srv['chunks_pending_last']}"
                    if srv.get("chunks_pending_max") is not None else ""))
         if srv.get("state_bytes"):
-            out(f"  slot state: {_si(srv['state_bytes'], 'B')} allocated, "
-                f"{srv['state_writes_last'] or 0} prefills wrote a slot's "
-                "state")
+            out(f"  slot state: {_si(srv['state_bytes'], 'B')} allocated"
+                + (f" ({_si(srv['state_bytes_per_slot'], 'B')} a slot)"
+                   if srv.get("state_bytes_per_slot") else "")
+                + f", {srv['state_writes_last'] or 0} prefills wrote a "
+                "slot's state")
+        if srv.get("experts_hit_mean") is not None:
+            out(f"  experts: {srv['expert_rows_last']} assignments landed on "
+                f"held experts; {srv['experts_hit_mean']:.1f} held experts "
+                "hit a routed layer and tick")
         if srv.get("kv_bytes_per_token"):
             out(f"  KV cache: {_si(srv['kv_bytes_per_token'], 'B')} a token "
                 "in pages"

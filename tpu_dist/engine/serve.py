@@ -250,6 +250,8 @@ class _Flight:
     nxt: jax.Array                       # (slots,) its sampled tokens
     slots: List[Tuple[int, _Slot]]       # who decodes in it
     ahead: int   # 1: dispatched while the tick before it was still unread
+    # a model with routed expert layers: (3,) i32, unread beside the tokens
+    counts: Optional[jax.Array] = None
 
 
 @dataclass
@@ -279,6 +281,7 @@ class _Admission:
     issued: float = 0.0          # the clock just before the program's call
     ahead: int = 0   # 1: called while the prefill before it was unread
     cross_rows: Optional[int] = None
+    counts: Optional[jax.Array] = None   # as _Flight.counts
 
     @property
     def n_fresh(self) -> int:
@@ -294,6 +297,34 @@ def _slot_state_layers(layout) -> int:
 
 def _kinds(layout, kind: str) -> int:
     return sum(k == kind for k, *_ in layout)
+
+
+def _routed_layers(model) -> Tuple[int, int]:
+    """``(expert layers, experts held in each)`` by the model's own answer
+    (``routed_layers()``); (0, 0) for a model without routed experts."""
+    return getattr(model, "routed_layers", lambda: (0, 0))()
+
+
+def _apply_counted(model, params, *args, **kwargs):
+    """``model.apply`` for a serving program: ``(outputs, counts)``. Where
+    the model has routed expert layers, ``counts`` is what they sowed into
+    ``expert_counts``, summed over the layers, as one (3,) int32 array:
+    ``rows`` (assignments of live rows that landed on held experts),
+    ``hit`` (held experts with at least one) and ``grouped`` (calls of the
+    grouped product: the form the program was traced in, 0 where every
+    layer took the masked dense one). For every other model the call is
+    the plain one and ``counts`` is None: its program returns nothing
+    more than it did."""
+    if not _routed_layers(model)[0]:
+        return model.apply({"params": params}, *args, **kwargs), None
+    out, sown = model.apply({"params": params}, *args,
+                            mutable=["expert_counts"], **kwargs)
+    flat = jax.tree_util.tree_flatten_with_path(sown["expert_counts"])[0]
+    total = lambda name: sum(
+        v for path, v in flat
+        if any(getattr(k, "key", None) == name for k in path))
+    return out, jnp.stack([total("rows"), total("hit"),
+                           total("grouped")]).astype(jnp.int32)
 
 
 def _default_buckets(max_len: int) -> Tuple[int, ...]:
@@ -341,8 +372,8 @@ def _prefill_program(model, temperature, top_k, top_p, sp_mesh=None):
                  "positions": jnp.zeros((1,), jnp.int32),
                  "lengths": lengths, "valid": valid, "sp_mesh": sp_mesh,
                  "live": lengths, "slots": jnp.asarray(slot, jnp.int32)[None]}
-        logits, new_layers = model.apply(
-            {"params": params}, prompt, train=False,
+        (logits, new_layers), counts = _apply_counted(
+            model, params, prompt, train=False,
             paged=paged, paged_prefill=True)
         # a model whose last layers cache nothing runs them on the prompt's
         # last live row alone and returns that row's logits, [1, 1, V];
@@ -352,7 +383,9 @@ def _prefill_program(model, temperature, top_k, top_p, sp_mesh=None):
             logits, jnp.reshape(length - 1, (1, 1, 1)).astype(jnp.int32),
             axis=1)[:, 0]
         nxt, rng = _sample(last, temperature, rng, top_k, top_p)
-        return nxt[0].astype(jnp.int32), new_layers, rng
+        out = (nxt[0].astype(jnp.int32), new_layers, rng)
+        # a model with routed experts: its counters beside the token
+        return out if counts is None else (*out, counts)
 
     head_rows = prefill.head_rows = {}      # bucket -> rows of logits
     return prefill
@@ -386,11 +419,12 @@ def _tick_program(model, temperature, top_k, top_p, sp_mesh=None):
                  "positions": positions, "lengths": positions + 1,
                  "sp_mesh": sp_mesh,
                  "live": (positions > 0).astype(jnp.int32)}
-        logits, new_layers = model.apply(
-            {"params": params}, tokens[:, None], train=False,
+        (logits, new_layers), counts = _apply_counted(
+            model, params, tokens[:, None], train=False,
             pos_offset=positions, paged=paged)
         nxt, rng = _sample(logits[:, 0], temperature, rng, top_k, top_p)
-        return nxt.astype(jnp.int32), new_layers, rng
+        out = (nxt.astype(jnp.int32), new_layers, rng)
+        return out if counts is None else (*out, counts)
 
     return tick
 
@@ -635,7 +669,10 @@ class ServeEngine:
     decode tick), each returning the requests that finished.
 
     Which models: the dense ``TransformerLM``, the hybrid state-space /
-    attention ``HybridLM`` and the decoder-hybrid-decoder ``Phi4FlashLM``.
+    attention ``HybridLM``, the decoder-hybrid-decoder ``Phi4FlashLM`` and
+    the Mamba-2 / latent-expert / attention ``NemotronHLM``, whose routed
+    layers take rows, drop nothing and compute the part their held experts
+    give (one rank of an expert-parallel group, without the exchange).
     The engine asks a model one question, once: ``model.cache_layout()``,
     one entry a layer of FOUR kinds, and builds its pool from the answer:
     ``("pages", kv_heads, head_dim, query heads a KV head[, "rows"])``, K
@@ -659,7 +696,16 @@ class ServeEngine:
     ring's rows), ``spec_k > 0`` (a rejected draft would need state and
     ring rolled back) and ``mesh=`` (the sp-sharded pool shards by page);
     for a model with rings or a shared layer also ``prefill_chunk`` (their
-    reads take one query a row); refused for every model: MoE blocks.
+    reads take one query a row). A model with routed expert layers says so
+    (``routed_layers()``: how many, and the experts each holds here); its
+    tick and prefill programs then return, beside the tokens and in the
+    same ``device_get``, two int32 counters summed over those layers
+    (``expert_rows``, ``experts_hit``: span attributes, ``stats()``, the
+    ``kv_cache`` event) and the form the program was traced in
+    (``grouped_calls``: the grouped products it runs, 0 in the masked
+    dense form), and no other model's programs return anything more. Refused: a model with no ``cache_layout()`` at all, which is
+    ``MoETransformerLM`` (its ``MoEBlock`` takes no ``paged`` and its
+    capacity-factor dispatch drops rows by group).
     """
 
     def __init__(self, model, params, config: Optional[ServeConfig] = None,
@@ -668,11 +714,17 @@ class ServeEngine:
                  now_fn: Callable[[], float] = time.monotonic,
                  rng: Optional[jax.Array] = None, mesh=None):
         config = config if config is not None else ServeConfig()
-        if getattr(model, "num_experts", 0):
+        if not hasattr(model, "cache_layout"):
             raise NotImplementedError(
-                "paged serving covers the dense TransformerLM family; the "
-                "MoE capacity-factor dispatch needs its own scheduling "
-                "story (ROADMAP item 4)")
+                f"{type(model).__name__} has no cache_layout(): the engine "
+                "asks a model what each layer keeps for a sequence and "
+                "hands its blocks `paged=`. MoETransformerLM answers "
+                "neither: its MoEBlock attends through the flax decode "
+                "cache, and its capacity-factor dispatch drops rows by "
+                "group, so a served token would depend on who shares its "
+                "tick (ROADMAP D3). An expert model whose routed layer "
+                "takes rows and drops nothing is served "
+                "(models.nemotron_h)")
         cfg = config
         if cfg.quant != "none":
             model, params = _quantize_for_decode(model, params, cfg.quant)
@@ -709,6 +761,12 @@ class ServeEngine:
         self.shared_readers = readers + bool(readers)
         self.window = max((spec[3] for kind, *spec in layout
                            if kind == "window"), default=0)
+        # routed expert layers, and the experts each holds here (0, 0 for
+        # a model without them): what the tick's and the prefill's two
+        # counters are summed over
+        self.expert_layers, self.experts_held = _routed_layers(model)
+        self.expert_rows = 0         # assignments that landed on held experts
+        self._experts_hit_sum = 0    # held experts hit, summed over the ticks
         self._refuse_over_slot_state(cfg, mesh, layout)
         self.pool = self._pool_for(layout, model.dtype, cfg.attn_read, mesh)
         self.max_pages_per_seq = self.pool.pages_needed(self.max_len)
@@ -872,7 +930,10 @@ class ServeEngine:
         # would not bring of it, what a rejected draft would have to restore
         kept = [words for words, n in (
             (("slot state", "the recurrent state at the end of the shared "
-              "prefix", "the recurrent state"), _slot_state_layers(layout)),
+              "prefix (a Mamba-1 or Mamba-2 layer's, with its "
+              "convolution's tail)", "the recurrent state (Mamba-1's a "
+              "channel and state, Mamba-2's a head)"),
+             _slot_state_layers(layout)),
             (("window rings", "the window rings' rows",
               "the rings' overwritten rows"), rings)) if n]
         if kept:
@@ -1351,7 +1412,8 @@ class ServeEngine:
                 jnp.int32(p), jnp.int32(shared_len),
                 jnp.asarray(padded), self._rng, jnp.int32(adm.slot_idx))
         adm.issued = self._now()
-        adm.tok, new_layers, self._rng = program(*args)
+        adm.tok, new_layers, self._rng, *counts = program(*args)
+        adm.counts = counts[0] if counts else None
         self.pool.adopt(new_layers)
         # rows of the prompt the model's last layers and head ran
         # on, as the traced program had them (the bucket, or 1)
@@ -1421,7 +1483,14 @@ class ServeEngine:
                 # the scheduler IS the drain boundary: the first token
                 # decides done/eos and the TTFT stamp before the next iteration
                 # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
-                tok = int(jax.device_get(adm.tok))
+                # (the counters of a model with routed experts ride the same
+                # transfer; every other model's read is the token's alone)
+                got = jax.device_get(adm.tok if adm.counts is None
+                                     else (adm.tok, adm.counts))
+                tok, counts = (got, None) if adm.counts is None else got
+                tok = int(tok)
+            if counts is not None:
+                span.attrs.update(self._count_experts(counts))
             now = self._now()
             slot = self._new_slot(req, prompt, adm.pages, adm.block_table,
                                   adm.enq_ts, adm.start_ts, generated=1,
@@ -1741,6 +1810,21 @@ class ServeEngine:
             attrs["trace_ids"] = [s.trace_id for _, s in slots]
         return attrs
 
+    def _count_experts(self, counts, tick: bool = False) -> dict:
+        """A program's expert counters as its span's attributes, and into
+        the engine's totals (``experts_hit`` of the ticks alone: a
+        prefill's rows hit nearly every expert whatever the load).
+        ``grouped_calls`` is the program's own word on the routed layers'
+        form: the grouped products it ran, 0 in the masked dense form."""
+        rows, hit, grouped = (int(c) for c in counts)
+        self.expert_rows += rows
+        if tick:
+            self._experts_hit_sum += hit
+        return {"expert_rows": rows, "experts_hit": hit,
+                "grouped_calls": grouped,
+                "expert_layers": self.expert_layers,
+                "experts_held": self.experts_held}
+
     def _count_tick(self, attrs: dict) -> None:
         self.ticks += 1
         self._occupancy_sum += len(attrs["rids"]) / max(len(self.slots), 1)
@@ -1874,7 +1958,12 @@ class ServeEngine:
                 # ends by budget was known before the dispatch, and an
                 # eos_id is acted on one tick late
                 # distlint: disable=DL002 -- reads the tick dispatched a pass earlier; the next tick is already queued behind it
-                nxt = np.asarray(jax.device_get(flight.nxt))
+                got = jax.device_get(flight.nxt if flight.counts is None
+                                     else (flight.nxt, flight.counts))
+                nxt, counts = (got, None) if flight.counts is None else got
+                nxt = np.asarray(nxt)
+            if counts is not None:
+                tick.attrs.update(self._count_experts(counts, tick=True))
             with self._span("tick.emit"):
                 now = self._now()
                 for i, s in landing:
@@ -1915,13 +2004,14 @@ class ServeEngine:
         # trash page), so ANY cache growth is a retrace hazard: allowed=1
         register_audit_program("serve_tick", program)
         block_tables, tokens, positions = self._dev
-        nxt, new_layers, self._rng = program(
+        nxt, new_layers, self._rng, *counts = program(
             self.params, self.pool.layers(), block_tables, tokens,
             positions, self._rng)
         self.pool.adopt(new_layers)
         self._dev = (block_tables, nxt, _advance_positions(positions))
         self._flights.append(
-            _Flight(nxt, active, ahead=int(bool(self._flights))))
+            _Flight(nxt, active, ahead=int(bool(self._flights)),
+                    counts=counts[0] if counts else None))
 
     def _tick_spec(self, active) -> None:
         """One speculative iteration: k draft proposals + one base verify
@@ -2004,6 +2094,9 @@ class ServeEngine:
                          chunks_pending=self.chunks_pending,
                          chunk_ticks=self.chunk_ticks,
                          state_bytes=st["state_bytes"],
+                         state_bytes_per_slot=self.state_bytes_per_slot,
+                         expert_rows=self.expert_rows,
+                         experts_hit_mean=self.experts_hit_mean,
                          window_bytes=st["window_bytes"],
                          kv_bytes_per_token=st["kv_bytes_per_token"],
                          state_writes=self.state_writes,
@@ -2020,6 +2113,23 @@ class ServeEngine:
         """Seconds the process spent in Python's garbage collections since
         this engine was built (``obs.trace.gc_seconds``; real seconds)."""
         return trace.gc_seconds() - self._gc_start
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Slot state one sequence holds beside its pages (a state-space
+        layer's recurrent state and convolution tail, all layers)."""
+        return self.pool.state_bytes // max(len(self.slots), 1)
+
+    @property
+    def experts_hit_mean(self) -> Optional[float]:
+        """Held experts with at least one row, a routed layer and decode
+        tick (of ``experts_held``): the weights a tick's routed products
+        NEED of those they read. None for a model without routed
+        experts."""
+        if not self.expert_layers or not self.ticks:
+            return None
+        return round(self._experts_hit_sum
+                     / (self.ticks * self.expert_layers), 6)
 
     @property
     def occupancy(self) -> float:
@@ -2069,6 +2179,12 @@ class ServeEngine:
                     if self.ticks else None),
                 "rejected": self.rejected, "prefills": self.prefills,
                 "state_writes": self.state_writes,
+                "state_bytes_per_slot": self.state_bytes_per_slot,
+                # a model with routed expert layers: assignments of live
+                # rows that landed on held experts (ticks and prefills),
+                # and the held experts hit a layer and tick
+                "expert_rows": self.expert_rows,
+                "experts_hit_mean": self.experts_hit_mean,
                 # plain ticks dispatched while the tick before them was
                 # still unread, and tokens such a tick computed for a slot
                 # that had ended on eos_id (dropped, never emitted)

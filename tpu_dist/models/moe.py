@@ -11,6 +11,18 @@ Load-balancing: the Switch auxiliary loss (fraction-of-tokens x mean-gate
 per expert) is ``sow``n into the 'intermediates' collection under
 ``aux_loss``; the LM train step picks every sown aux_loss up generically and
 adds ``aux_weight`` times their sum to the objective.
+
+What is served and what is not. This module is the TRAINED path: a capacity
+factor, ``router_top_k`` 1 or 2, softmax scores, ``(G, S, E, C)`` one-hot
+dispatch. ``ServeEngine`` refuses ``MoETransformerLM`` (its ``MoEBlock``
+takes no ``paged``, the model answers no ``cache_layout()``, and a dropped
+row would make a served token depend on who shares its tick); it runs
+through ``engine.generate`` alone. The engine does serve an expert model
+whose routed layer takes rows and drops nothing:
+``models.nemotron_h`` over ``ops.routed_experts`` (sigmoid scores, any
+``top_k`` of any router width, a shared expert, the experts HELD here as one
+rank of an expert-parallel group; no capacity factor, no exchange across
+the ``expert`` mesh axis, no trainer).
 """
 
 from __future__ import annotations

@@ -81,6 +81,11 @@ _REGISTRY: Dict[str, Tuple[Callable, str]] = {
     # layer, gated memory units). Serving and the plain forward, like
     # hybrid_lm; no trainer holds it.
     "phi4flash_lm": (lambda **kw: _phi4flash_lm(**kw), "lm"),
+    # one-sublayer blocks by a pattern string (models.nemotron_h: Mamba-2,
+    # latent mixture-of-experts held as one rank of an expert-parallel
+    # group, grouped-head attention). Serving and the plain forward; no
+    # trainer holds it.
+    "nemotron_h_lm": (lambda **kw: _nemotron_h_lm(**kw), "lm"),
 }
 
 
@@ -94,6 +99,12 @@ def _phi4flash_lm(**kw):
     from tpu_dist.models.phi4flash import Phi4FlashLM
 
     return Phi4FlashLM(**kw)
+
+
+def _nemotron_h_lm(**kw):
+    from tpu_dist.models.nemotron_h import NemotronHLM
+
+    return NemotronHLM(**kw)
 
 
 model_names = sorted(_REGISTRY)  # reference 1.dataparallel.py:23-24 equivalent

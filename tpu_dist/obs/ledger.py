@@ -91,7 +91,12 @@ EVENT_SCHEMA = {
     # tick/chunk_ticks ride as extras, with prefill_own_s/gc_pause_s (what
     # admissions and garbage collections cost the decoding slots so far)
     # and prefills_ahead (prefills called before the last one's token was
-    # read): ledger_report's `KV cache:` line
+    # read): ledger_report's `KV cache:` line; state_bytes_per_slot (slot
+    # state one sequence holds beside its pages) and, for a model with
+    # routed expert layers, expert_rows (assignments of live rows that
+    # landed on the experts held here, ticks and prefills, cumulative) and
+    # experts_hit_mean (held experts with at least one row, a routed layer
+    # and decode tick; None without such layers): its `experts:` line
     "kv_cache": ("pages_free", "pages_used", "active_seqs",
                  "shared_pages", "cow_copies", "prefix_hits",
                  "sharded_devices", "chunks_pending"),

@@ -282,6 +282,20 @@ def moe_expert_matmul(spec: str, acts: jax.Array, w: jax.Array,
 _MOE_EXPERT_LEAVES = ("w_in", "w_out")
 
 
+#: experts of a 3D tensor quantized at a time: eagerly, the float32 copy and
+#: the rounding's temporaries of a whole 0.7 GB expert tensor stand beside
+#: each other (4 GB on top of the tree being quantized); a scale belongs to
+#: one expert and output channel, so blocks of experts give the same values
+_EXPERT_BLOCK = 16
+
+
+def _quantize_experts(v):
+    parts = [quantize_int8(v[i:i + _EXPERT_BLOCK], (1,))
+             for i in range(0, v.shape[0], _EXPERT_BLOCK)]
+    return (jnp.concatenate([q for q, _ in parts]),
+            jnp.concatenate([s for _, s in parts]))
+
+
 def _quantize_tree(tree):
     out = {}
     for k, v in tree.items():
@@ -293,7 +307,7 @@ def _quantize_tree(tree):
             q, s = quantize_int8(v, (0,))
             out[k], out[k + "_scale"] = q, s
         elif k in _MOE_EXPERT_LEAVES and getattr(v, "ndim", 0) == 3:
-            q, s = quantize_int8(v, (1,))  # (E, in, out): amax over in
+            q, s = _quantize_experts(v)  # (E, in, out): amax over in
             out[k], out[k + "_scale"] = q, s
         else:
             out[k] = v  # embeddings, norms, biases, cls/pos tokens

@@ -17,6 +17,20 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tpu_dist.parallel.mesh import EXPERT_AXIS
 
 
+def expert_share(num_experts: int, of: int, index: int) -> tuple:
+    """``(held_lo, held_n)``: the contiguous block of a layer's
+    ``num_experts`` routed experts that rank ``index`` of ``of`` holds, as
+    ``P(axis, None, None)`` over the leading experts dimension lays them
+    out. What a served expert layer is told of its place in the group
+    (``models.nemotron_h``): it routes over all ``num_experts`` and computes
+    its own block's part."""
+    if num_experts % of or not 0 <= index < of:
+        raise ValueError(f"{num_experts} experts over {of} ranks, rank "
+                         f"{index}: whole equal blocks only")
+    held_n = num_experts // of
+    return index * held_n, held_n
+
+
 def _moe_leaf_spec(key: str, leaf, axis: str,
                    model_axis: str | None) -> P:
     """Spec for one MoE param leaf: expert weights shard their leading
