@@ -8,9 +8,9 @@ chip time. Each case is a kernel the trainers or the server really call, at
 the widths they call it with (chip_smoke.py's LM: 8 layers, d1024, 8 heads
 of 128, L2048, V32000, batch 8; the LM training cell's 4 x 2048 x 16 heads
 of 128; also 16 heads of 64 at L16384, prefills of 256 and 1024, the
-serving pool's pages, the serving cell's decode read, and the hybrid LM's
-selective scan at 5120 channels). Interpret-mode
-tests cannot see any of this.
+serving pool's pages, the serving cell's decode read, the hybrid LM's
+selective scan at 5120 channels, and the expert cell's routed products over
+the hit list). Interpret-mode tests cannot see any of this.
 
 A compile that passes is not a chip run: it says nothing about results or
 times (``python chip_smoke.py`` is that proof).
@@ -106,6 +106,21 @@ def _selective_scan(l):
          ((1, n, ch), f32), ((1,), jnp.int32)]
 
 
+def _hit_experts(rows):
+    """The expert cell's routed products over the hit list: 128 held
+    experts of 1024 -> 2688 -> 1024 in bf16, a tick's 64 rows and the
+    longest prefill bucket that takes the kernel."""
+    from tpu_dist.ops.routed_experts import _hit_experts as call
+
+    held, latent, width = 128, 1024, 2688
+    return (lambda u, g, ids, n, w_in, w_out: call(
+        u, g, ids, n, w_in, w_out, interpret=False)), \
+        [((rows, latent), jnp.bfloat16), ((rows, held), jnp.float32),
+         ((held,), jnp.int32), ((1,), jnp.int32),
+         ((held, latent, width), jnp.bfloat16),
+         ((held, width, latent), jnp.bfloat16)]
+
+
 def _quant_matmul(m, k, n):
     from tpu_dist.ops.pallas_quant import fused_quant_matmul
 
@@ -144,6 +159,8 @@ CASES = {
         lambda: _paged_decode(16, 2048, 128, 16, 128),
     "selective_scan_l1024_c5120": lambda: _selective_scan(1024),
     "selective_scan_l256_c5120": lambda: _selective_scan(256),
+    "hit_experts_r64_e128_1024x2688": lambda: _hit_experts(64),
+    "hit_experts_r256_e128_1024x2688": lambda: _hit_experts(256),
     "quant_matmul_mlp_16384x1024x4096": lambda: _quant_matmul(16384, 1024, 4096),
     "quant_matmul_decode_8x1024x4096": lambda: _quant_matmul(8, 1024, 4096),
     "quant_matmul_head_16384x1024x32000":

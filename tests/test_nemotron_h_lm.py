@@ -314,6 +314,32 @@ def test_int8_wo_quantises_the_experts_and_the_router_stays_float32():
         model.clone(quant="int8").apply({"params": params}, toks)
 
 
+@pytest.mark.parametrize("holds,kernels", [
+    ("stored", 6), ("int8_wo", 0), ("prequantized", 0), ("cast", 0)])
+def test_layer_takes_the_kernel_form_only_over_weights_as_stored(holds,
+                                                                 kernels):
+    """The layer picks the routed products' form from what it holds: the
+    experts as they lie in HBM go to the kernel over the hit list (one call
+    in each of the toy's six expert layers); fake-quantised, dequantised or
+    cast on the way in they keep the einsum form, which XLA fuses the
+    producer into."""
+    from tpu_dist.ops.quant import wo_quantize_params
+
+    model = toy_model()
+    params = engine_params(model, lively_weights())
+    if holds == "int8_wo":
+        model = model.clone(quant="int8_wo")
+    elif holds == "prequantized":
+        model, params = model.clone(quant="int8_wo"), wo_quantize_params(
+            params)
+    elif holds == "cast":
+        model = model.clone(dtype=jnp.bfloat16)      # float32 parameters
+    toks = jnp.zeros((1, 24), jnp.int32)
+    text = str(jax.make_jaxpr(
+        lambda p: model.apply({"params": p}, toks))(params))
+    assert text.count("name=hit_experts") == kernels
+
+
 def test_registry_lists_the_model():
     from tpu_dist.models.registry import create_model, model_kind
 

@@ -702,8 +702,8 @@ class ServeEngine:
     same ``device_get``, two int32 counters summed over those layers
     (``expert_rows``, ``experts_hit``: span attributes, ``stats()``, the
     ``kv_cache`` event) and the form the program was traced in
-    (``grouped_calls``: the grouped products it runs, 0 in the masked
-    dense form), and no other model's programs return anything more. Refused: a model with no ``cache_layout()`` at all, which is
+    (``grouped_calls``: the grouped products it runs, 0 in the form over
+    the hit list), and no other model's programs return anything more. Refused: a model with no ``cache_layout()`` at all, which is
     ``MoETransformerLM`` (its ``MoEBlock`` takes no ``paged`` and its
     capacity-factor dispatch drops rows by group).
     """
@@ -1815,7 +1815,8 @@ class ServeEngine:
         the engine's totals (``experts_hit`` of the ticks alone: a
         prefill's rows hit nearly every expert whatever the load).
         ``grouped_calls`` is the program's own word on the routed layers'
-        form: the grouped products it ran, 0 in the masked dense form."""
+        form: the grouped products it ran, 0 in the form over the hit
+        list."""
         rows, hit, grouped = (int(c) for c in counts)
         self.expert_rows += rows
         if tick:

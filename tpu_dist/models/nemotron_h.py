@@ -231,6 +231,10 @@ class LatentExperts(nn.Module):
                               (held_n, self.latent, self.expert_dim))
             w_out = self.param("w_out", nn.initializers.lecun_normal(),
                                (held_n, self.expert_dim, self.latent))
+            # the experts as they lie in HBM go to the routed products as
+            # they are; what is computed from them on the way (dequantised,
+            # fake-quantised, cast) is for XLA to fuse into the products
+            stored = (w_in.dtype == self.dtype and self.quant == "none")
             if self.has_variable("params", "w_in_scale"):
                 # pre-quantized weight-only decode (ops.quant.
                 # wo_quantize_params): the experts live int8 in HBM
@@ -251,7 +255,7 @@ class LatentExperts(nn.Module):
                          < live.astype(jnp.int32)[:, None]).reshape(b * l)
             mixed, n_rows, n_hit = routed_experts(
                 u.reshape(b * l, self.latent), idx, w, rows_live, w_in,
-                w_out, held_lo)
+                w_out, held_lo, stored)
             self.sow("expert_counts", "rows", n_rows)
             self.sow("expert_counts", "hit", n_hit)
             self.sow("expert_counts", "grouped",

@@ -18,11 +18,20 @@ the partial sum is the layer's result. Rows past a prompt's live length
 function, picked from the static row count alone:
 
 * up to :data:`DENSE_ROWS` rows (a decode tick: one row a slot; the prefill
-  buckets up to it): every held expert runs over every row and a ``[rows,
-  held]`` matrix of weights, zero where a row did not choose the expert,
-  folds the result. A tick's products are bound by reading the experts'
-  weights once, which a grouped product has to do too, and nothing can be
-  dropped by construction.
+  buckets up to it): every HIT expert (held, and chosen by some live row)
+  runs over every row, and a ``[rows, held]`` matrix of weights, zero where
+  a row did not choose the expert, folds the result, so nothing can be
+  dropped by construction and nothing is sorted. ONE Pallas call walks the
+  compacted list of the hit experts and does both products an expert: the
+  weights of an expert no row chose are never read (a third of the held
+  experts at the serving cell's load) and the ``[held, rows, width]``
+  hidden values never leave vector memory. With few rows the products are
+  bound by reading the hit experts' weights once, and the call reads them
+  at 78-89% of the HBM rate at a tick's 64 rows (PR 41's table below). A
+  layer whose weights are COMPUTED on the way in (dequantised int8, a
+  cast) says so (``stored=False``) and keeps the two einsums over every
+  held expert: XLA fuses the producer into them, where a kernel would have
+  it write the 1.4 GB of bfloat16 out first.
 * more rows (the longer prefill buckets, a long plain forward): the ``rows
   x top_k`` assignments are sorted by expert, those of absent experts and
   dead rows parked after the held ones, and the held groups run as one
@@ -46,27 +55,35 @@ import jax.numpy as jnp
 
 from tpu_dist.runtime import pallas_interpret
 
-#: the most rows the masked dense form takes, set from the chip
-#: (``benchmarks/kernels/routed_experts_bench.py`` at the published expert,
-#: 1024 -> 2688 -> 1024, 128 held of 512, top 22, on one v5e; PERF.md section
-#: 6, PR 40), ms a layer at 64 / 256 / 512 / 1024 / 2048 rows: the dense form
-#: 2.7 / 3.6 / 5.0 / 8.9 / 16.7 (it reads the 1.41 GB of weights once and
-#: then grows with the MXU's work: it multiplies 23 times what the routing
-#: needs); the sorted form 4.1 / 4.2 / 4.6 / 5.5 / 7.3 (its two ``gmm``
-#: products 3.0 / 3.3 / 3.5 / 4.3 / 5.7, the sort, the counts and the two
-#: gathers around them 0.9-1.6). ``jax.lax.ragged_dot`` in ``gmm``'s place
-#: took 5.8 / 9.4 / 9.8 / 10.6 / 11.9 for the pair
-DENSE_ROWS = 512
+#: the most rows the form over the hit list takes, set from the chip at the
+#: published expert (1024 -> 2688 -> 1024, 128 held of 512, top 22, one v5e;
+#: ``benchmarks/kernels/routed_tick_bench.py``, calls issued back to back;
+#: PERF.md section 6, PR 41). ms a layer at 64 / 128 / 256 / 512 rows with
+#: every held expert hit, as a prefill's rows hit them: the kernel 1.93 /
+#: 1.94 / 2.08 / 3.93 (from 512 rows an expert's products, 5.6 GFLOP,
+#: outweigh its 11 MB), the sorted form 2.54 / 2.66 / 2.77 / 3.18 (its
+#: products go with the assignments, a twenty-third of the kernel's), the
+#: einsum form 1.94 / 1.94 / 2.59 / 4.01 whatever is hit; the kernel's time
+#: follows the hit count (0.55 / 0.57 / 0.63 / 1.14 with a quarter hit). So
+#: the kernel up to 256 rows and the sorted form from the 512 bucket on (512
+#: until PR 41: PR 40's blocking calls read the einsum form 5.0 against the
+#: sorted one's 4.6 there, and 8.9 / 16.7 against 5.5 / 7.3 at 1024 / 2048;
+#: ``jax.lax.ragged_dot`` in ``gmm``'s place took 5.8-11.9 for the pair)
+DENSE_ROWS = 256
 #: ``gmm``'s (rows, contraction, output) tile: the best of those tried at
 #: the published expert (rows of 256 and 512 cost 0.3-2.7 ms more a pair,
 #: 128-wide tiles five times as much); a dimension that a tile does not
 #: divide is masked by the kernel
 _GMM_TILING = (128, 1024, 896)
+#: the hit list's kernel: its tile of an expert's width (2688 = 3 x 896; a
+#: whole expert a step is 0-4% faster from 256 rows and no faster at 64, for
+#: three times the buffers)
+_HIT_WIDTH_TILE = 896
 
 
 def grouped_calls(rows: int) -> int:
     """Calls of the grouped product that :func:`routed_experts` makes over
-    ``rows`` rows: 0 in the masked dense form, 2 in the sorted one."""
+    ``rows`` rows: 0 in the form over the hit list, 2 in the sorted one."""
     return 0 if rows <= DENSE_ROWS else 2
 
 
@@ -81,12 +98,105 @@ def route(logits, b_sel, top_k: int, scale: float):
         return idx.astype(jnp.int32), w
 
 
-def routed_experts(u, idx, w, live, w_in, w_out, held_lo: int):
+def _hit_list(sizes):
+    """``sizes`` [held] (live assignments an expert) -> the experts with at
+    least one, ascending, then the last of them again up to ``held``
+    entries (zeros where none is hit)."""
+    slot = jnp.arange(sizes.shape[0], dtype=jnp.int32)
+    hit = sizes > 0
+    # hit expert j stands at place (hit ones below it): no sort, no scatter
+    place = jnp.cumsum(hit.astype(jnp.int32)) - 1
+    ids = jnp.sum(jnp.where(hit & (place == slot[:, None]), slot, 0), axis=1)
+    return jnp.where(slot <= place[-1], ids, jnp.max(jnp.where(hit, slot, 0)))
+
+
+def _hit_experts_kernel(ids_ref, n_ref, u_ref, g_ref, w_in_ref, w_out_ref,
+                        o_ref):
+    """One step of the walk over the hit list: hit expert ``ids[e]``, width
+    tile ``t``. The block specs brought that expert's tile of both matrices;
+    ``o_ref`` [rows, latent] float32 stays resident over the whole grid."""
+    import jax.experimental.pallas as pl
+
+    e = pl.program_id(0)
+
+    @pl.when((e == 0) & (pl.program_id(1) == 0))
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(e < n_ref[0])
+    def _expert():
+        u = u_ref[...]
+        h = jnp.dot(u, w_in_ref[0],
+                    preferred_element_type=jnp.float32).astype(u.dtype)
+        # column ids[e] of the [rows, held] weights, without a dynamic lane
+        # index: the other columns masked out of a sum over the lanes
+        g = g_ref[...]
+        mine = jax.lax.broadcasted_iota(jnp.int32, g.shape, 1) == ids_ref[e]
+        gate = jnp.sum(jnp.where(mine, g, 0.0), axis=1, keepdims=True)
+        h = jnp.square(jax.nn.relu(h)) * gate.astype(h.dtype)
+        o_ref[...] += jnp.dot(h, w_out_ref[0],
+                              preferred_element_type=jnp.float32)
+
+
+def _hit_experts(u, by_expert, hit_ids, n_hit, w_in, w_out, interpret=None):
+    """``sum over the hit experts j of (relu(u W1_j)^2 * by_expert[:, j])
+    W2_j`` as ONE Pallas call: grid ``(held, width tiles)`` over the
+    compacted ``hit_ids`` [held] (ascending, the last hit one repeated past
+    ``n_hit`` [1]); a step past ``n_hit`` names the block the last real step
+    named, so the pipeline copies nothing for it, and skips its body."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, latent = u.shape
+    held_n, _, width = w_in.shape
+    tile = _HIT_WIDTH_TILE if width % _HIT_WIDTH_TILE == 0 else width
+    tiles = width // tile
+    # whole sublane tiles of the narrowest operand (bfloat16: 16 rows)
+    padded = rows + -rows % 16
+    if padded != rows:
+        u = jnp.pad(u, ((0, padded - rows), (0, 0)))
+        by_expert = jnp.pad(by_expert, ((0, padded - rows), (0, 0)))
+    whole = lambda e, t, ids, n: (0, 0)
+    # past the hit list: the last real step's tile too, not only its expert
+    at = lambda e, t, n: jnp.where(e < n[0], t, tiles - 1)
+    out = pl.pallas_call(
+        _hit_experts_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(held_n, tiles),
+            in_specs=[
+                pl.BlockSpec((padded, latent), whole),
+                pl.BlockSpec((padded, held_n), whole),
+                pl.BlockSpec((1, latent, tile),
+                             lambda e, t, ids, n: (ids[e], 0, at(e, t, n))),
+                pl.BlockSpec((1, tile, latent),
+                             lambda e, t, ids, n: (ids[e], at(e, t, n), 0)),
+            ],
+            out_specs=pl.BlockSpec((padded, latent), whole)),
+        out_shape=jax.ShapeDtypeStruct((padded, latent), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # two buffers of each matrix's tile (7.3 MB at the published
+            # expert) beside the rows, the float32 result and a hidden tile:
+            # the compiler's default holds them up to 512 rows, and the
+            # benches force the form over more
+            vmem_limit_bytes=16 * 2**20 + 24 * padded * latent),
+        interpret=pallas_interpret(interpret),
+        name="hit_experts",
+    )(hit_ids, n_hit, u, by_expert, w_in, w_out)
+    return out[:rows]
+
+
+def routed_experts(u, idx, w, live, w_in, w_out, held_lo: int,
+                   stored: bool = True):
     """``u`` [rows, latent] through the held experts ``w_in`` [held, latent,
     f] and ``w_out`` [held, f, latent] (experts ``held_lo .. held_lo +
     held`` of the router's), under ``idx``/``w`` from :func:`route` and
-    ``live`` [rows] bool. Returns ``(out [rows, latent] f32, expert_rows,
-    experts_hit)``."""
+    ``live`` [rows] bool. ``stored``: the two are arrays as they lie in HBM;
+    False where they are computed on the way in (a dequantisation, a cast),
+    which XLA fuses into the einsum form's products and would have to write
+    out whole before a kernel (the kernel is forward-only, as the served
+    model is: whoever differentiates through the layer passes False too).
+    Returns ``(out [rows, latent] f32, expert_rows, experts_hit)``."""
     rows, latent = u.shape
     held_n, k = w_in.shape[0], idx.shape[1]
     with jax.named_scope("moe_router"):
@@ -103,12 +213,17 @@ def routed_experts(u, idx, w, live, w_in, w_out, held_lo: int):
             by_expert = jnp.sum(jnp.where(
                 key[:, :, None] == jnp.arange(held_n, dtype=jnp.int32),
                 w[:, :, None], 0.0), axis=1)                # [rows, held]
+            hit_ids = _hit_list(sizes) if stored else None
         with jax.named_scope("routed_experts"):
-            h = jnp.einsum("rd,edf->erf", u, w_in)
-            h = (jnp.square(jax.nn.relu(h))
-                 * by_expert.T[:, :, None].astype(h.dtype))
-            out = jnp.einsum("erf,efd->rd", h, w_out,
-                             preferred_element_type=jnp.float32)
+            if stored:
+                out = _hit_experts(u, by_expert, hit_ids, counts[1][None],
+                                   w_in, w_out)
+            else:
+                h = jnp.einsum("rd,edf->erf", u, w_in)
+                h = (jnp.square(jax.nn.relu(h))
+                     * by_expert.T[:, :, None].astype(h.dtype))
+                out = jnp.einsum("erf,efd->rd", h, w_out,
+                                 preferred_element_type=jnp.float32)
         return (out, *counts)
     with jax.named_scope("moe_router"):
         order = jnp.argsort(key.reshape(-1), stable=True)
