@@ -10,7 +10,7 @@ of 128, L2048, V32000, batch 8; the LM training cell's 4 x 2048 x 16 heads
 of 128; also 16 heads of 64 at L16384, prefills of 256 and 1024, the
 serving pool's pages, the serving cell's decode read, the hybrid LM's
 selective scan at 5120 channels, and the expert cell's routed products over
-the hit list). Interpret-mode tests cannot see any of this.
+the hit list and its attention layer's grouped in-place read). Interpret-mode tests cannot see any of this.
 
 A compile that passes is not a chip run: it says nothing about results or
 times (``python chip_smoke.py`` is that proof).
@@ -92,6 +92,19 @@ def _paged_decode(b, num_pages, max_pages, h, d):
          ((b, max_pages), jnp.int32), ((b,), jnp.int32)]
 
 
+def _grouped_decode(b, num_pages, max_pages, kv, group, d):
+    """The grouped in-place read of a ROWS layer (16-token bf16 pages, a
+    token's KV heads side by side), one query a slot: the expert cell's
+    attention layer, 32 query heads over 2 KV heads of 128."""
+    from tpu_dist.ops.paged_attention import paged_grouped_decode_attention
+
+    arena = ((num_pages + 1, 16, kv * d), jnp.bfloat16)
+    return (lambda q, k, v, bt, pos: paged_grouped_decode_attention(
+        q, k, v, bt, pos, kv_heads=kv, scale=d ** -0.5, interpret=False)), \
+        [((b, kv * group, d), jnp.bfloat16), arena, arena,
+         ((b, max_pages), jnp.int32), ((b,), jnp.int32)]
+
+
 def _selective_scan(l):
     """The hybrid LM's prefill scan at the published Mamba widths: one
     prompt, 5120 channels, 16 states, bf16 activations."""
@@ -157,6 +170,8 @@ CASES = {
     "paged_int8_b32_l4096": lambda: _paged_int8(32, 4096),
     "paged_decode_b16_p128_h16_d128":
         lambda: _paged_decode(16, 2048, 128, 16, 128),
+    "grouped_decode_b64_p256_kv2_g16_d128":
+        lambda: _grouped_decode(64, 8192, 256, 2, 16, 128),
     "selective_scan_l1024_c5120": lambda: _selective_scan(1024),
     "selective_scan_l256_c5120": lambda: _selective_scan(256),
     "hit_experts_r64_e128_1024x2688": lambda: _hit_experts(64),

@@ -103,8 +103,11 @@ def test_decode_read_takes_the_rows_layout_by_its_tiles(dtype, page, d, how):
     layer = pa.PagedLayer(jnp.zeros((3, page, 2 * d), dtype),
                           jnp.zeros((3, page, 2 * d), dtype))
     assert pa.decode_read(layer, 1, None, 4, d) == how
+    # a wider window over block-table rows is gathered and viewed by head
+    # (``paged_attend``); over a ring it is refused
+    assert pa.decode_read(layer, 2, None, 4, d) == "gathered"
     with pytest.raises(NotImplementedError, match="one query a row"):
-        pa.decode_read(layer, 2, None, 4, d)
+        pa.decode_read(layer.replace(ring=3), 2, None, 4, d)
 
 
 def test_paged_layer_carries_its_ring_through_jit():

@@ -37,24 +37,28 @@ from tpu_dist.engine.serve import (DecodeRequest, ServeConfig,  # noqa: E402
                                    ServeEngine)
 
 RTOL, ATOL = 3e-4, 1e-4
+# the toy with the attention layer's PUBLISHED head shape: 2 KV heads of 128
+# under 32 query heads, which the grouped in-place read's tiling takes
+WIDE = {**TOY, "num_attention_heads": 32, "head_dim": 128}
 
 
-def weights(seed=1):
-    return ref.make_weights(TOY, jax.random.PRNGKey(seed))
+def weights(seed=1, sizes=TOY):
+    return ref.make_weights(sizes, jax.random.PRNGKey(seed))
 
 
-def engine(w, **cfg):
-    model = toy_model()
+def engine(w, sizes=TOY, **cfg):
+    model = toy_model(num_heads=sizes["num_attention_heads"],
+                      head_dim=sizes["head_dim"])
     fields = dict(max_slots=2, page_size=4, num_pages=64, max_len=64)
     return ServeEngine(model, engine_params(model, w),
                        ServeConfig(**{**fields, **cfg}))
 
 
-def check_against_reference(done, rows, w, rtol=RTOL, atol=ATOL):
-    programs = ref.layer_programs(TOY)
+def check_against_reference(done, rows, w, rtol=RTOL, atol=ATOL, sizes=TOY):
+    programs = ref.layer_programs(sizes)
     for rid, c in done.items():
         want = np.asarray(ref.forward(
-            w, jnp.asarray(c.tokens[None]), TOY, programs)[0])
+            w, jnp.asarray(c.tokens[None]), sizes, programs)[0])
         got = np.stack(rows[rid])
         assert got.shape[0] == c.n_generated
         # row t of the reference predicts token t + 1
@@ -178,10 +182,44 @@ def test_chunked_prefill_carries_the_state_from_chunk_to_chunk(log, chunk):
     check_against_reference(done, rows, w)
 
 
+@pytest.mark.parametrize("sizes,page,chunk,read", [
+    (WIDE, 8, 0, "pages"), (TOY, 4, 0, "gathered"), (WIDE, 8, 16, "pages")],
+    ids=["published_heads", "toy_heads", "published_heads_chunked"])
+def test_the_tick_reads_the_rows_in_place_where_a_head_fills_the_lanes(
+        log, sizes, page, chunk, read):
+    """The attention layers' pages lie in rows, and the tick reads them
+    through ``paged_attend``: the grouped kernel (interpreted here) at the
+    published head shape, its gathered twin at the toy's, each saying so in
+    every tick's span; a prompt in chunks reads the same rows gathered. All
+    give the reference's logits."""
+    w = weights(seed=15, sizes=sizes)
+    eng = engine(w, sizes, max_slots=3, page_size=page, prefill_chunk=chunk)
+    assert eng.tick_read == read
+    assert all(layer.k.ndim == 3 for layer in eng.pool.page_layers())
+    done, rows = serve_recorded(
+        eng, log, {0: requests([9, 21], new=5, seed=16),
+                   2: requests([14, 30], new=5, seed=17, first_rid=2)})
+    assert len(done) == 4 and (eng.chunk_ticks > 0) == (chunk > 0)
+    check_against_reference(done, rows, w, sizes=sizes)
+    st = eng.stats()
+    assert st["read"] == read and st["ticks_by_read"] == {read: st["ticks"]}
+    assert all(sp.attrs["read"] == read for sp in _ticks(eng))
+
+
+def test_int8_pages_are_refused_by_name():
+    """The one capability the rows layout gives up: this model's pages in
+    int8 (0.13 GB of the cell's 11, so nothing was to be saved). The pool
+    builds no int8 rows and says so, as for the phi-4 model."""
+    with pytest.raises(NotImplementedError,
+                       match="rows-layout KV layer in an sp-sharded or "
+                       "int8 pool"):
+        engine(weights(), kv_quant="int8")
+
+
 def test_the_pools_bytes_are_what_the_layout_says():
     """``M`` layers hold slots x ([heads, channels, states] float32 + three
     rows of the convolution's channels), ``E`` layers nothing, and the two
-    ``*`` layers pages."""
+    ``*`` layers pages, a token's two KV heads side by side in a row."""
     eng = engine(weights(), max_slots=3)
     st = eng.stats()
     state = 8 * 16 * 16 * 4 + 3 * (8 * 16 + 2 * 2 * 16) * 4
@@ -194,7 +232,7 @@ def test_the_pools_bytes_are_what_the_layout_says():
                      "dict", "dict", "dict", "dict", "PagedLayer", "dict"]
     assert [len(l) for l in eng.pool.layers() if isinstance(l, dict)] \
         == [2, 0, 2, 0, 0, 2, 0, 2, 0, 0]
-    assert eng.pool.page_layers()[0].k.shape == (65, 4, 2, 16)   # KV heads
+    assert eng.pool.page_layers()[0].k.shape == (65, 4, 2 * 16)  # KV heads
     assert eng.pool.layers()[0]["ssm"].shape == (3, 8, 16, 16)
     assert st["kv_bytes_per_token"] == 2 * 2 * (2 * 16 * 4)
     assert st["expert_rows"] == 0 and st["experts_hit_mean"] is None

@@ -676,8 +676,15 @@ class ServeEngine:
     The engine asks a model one question, once: ``model.cache_layout()``,
     one entry a layer of FOUR kinds, and builds its pool from the answer:
     ``("pages", kv_heads, head_dim, query heads a KV head[, "rows"])``, K
-    and V rows behind the block tables; ``("slot_state", {name: (shape a
-    slot, dtype)})``; ``("window", kv_heads, head_dim, group, window)``, a
+    and V rows behind the block tables (with ``"rows"`` a token's KV heads
+    lie side by side in 3-D arenas, which ``ops.paged_attention.
+    paged_attend`` takes by their rank: the tick reads them in place with
+    the grouped kernel where a head fills the lanes, a prefill chunk's
+    window (Lq > 1) gathers the slot's rows and views them by head, and
+    ``kv_quant="int8"`` is refused, the pool building no int8 rows; the
+    expert model's attention layer is one, the phi-4 model's full layer
+    another, read from its own model file); ``("slot_state", {name: (shape
+    a slot, dtype)})``; ``("window", kv_heads, head_dim, group, window)``, a
     ring a slot of ``window + page_size`` rows whose bytes do not depend on
     ``max_len``, written by prefill (the prompt's last ring's worth of
     rows) and by every tick at ``position % rows``; and ``("shared",

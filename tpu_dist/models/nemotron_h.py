@@ -56,7 +56,15 @@ refuses ``spec_k`` over slot state, and no weight of it is made here.
 
 Serving (``paged=``, as ``HybridLM`` takes it): :meth:`NemotronHLM.
 cache_layout` answers ``slot_state`` with the two arrays above for ``M``,
-``pages`` for ``*`` and ``slot_state`` with no array for ``E``. A prefill
+``pages`` laid out in ROWS for ``*`` (a token's KV heads side by side,
+``ops.paged_attention.PagedLayer``) and ``slot_state`` with no array for
+``E``. ``ops.paged_attention.paged_attend``, which ``GroupedAttention``
+reaches, takes the rows layer by its rank: the tick reads it in place with
+the grouped kernel where a head fills the lanes (the published 2 KV heads
+of 128 under 32 query heads do; a toy head does not and takes the gathered
+twin), a prefill chunk's window (Lq > 1) gathers the slot's rows and views
+them by head, and int8 pages (``kv_quant="int8"``) are refused by the pool,
+which builds no int8 rows. A prefill
 runs the final norm and the head on each prompt's LAST LIVE row only and
 returns logits ``[B, 1, V]``. Where a call can mutate the ``expert_counts``
 collection (the engine's tick and prefill programs ask for it), each ``E``
@@ -363,7 +371,7 @@ class NemotronHLM(nn.Module):
                           d_in + 2 * self.n_groups * self.d_state),
                          self.dtype)}),
             "attention": ("pages", self.num_kv_heads, self.head_dim,
-                          self.num_heads // self.num_kv_heads),
+                          self.num_heads // self.num_kv_heads, "rows"),
             "experts": ("slot_state", {})}
         return tuple(entry[t] for t in self.layer_types)
 

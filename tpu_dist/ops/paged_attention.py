@@ -31,7 +31,10 @@ in ``engine.kv_cache``, the scheduler in ``engine.serve``):
   ``cache_index`` forces every batch row to the same position. Which read
   it takes is :func:`decode_read`'s rule, made from the inputs alone: one
   query a row over unsharded bf16/fp32 arenas of lane-wide heads reads in
-  place, everything else gathers. The same non-prefill path generalizes
+  place, everything else gathers. A layer laid out in ROWS (3-D arenas: a
+  grouped-head layer such as the expert model's) goes through the same
+  entry by its rank: the tick reads it with the grouped in-place kernel,
+  a wider window gathers its rows. The same non-prefill path generalizes
   to Lq > 1 (gathered) as the speculative-decoding
   VERIFY read: row ``b`` carries ``Lq`` queries at positions
   ``pos[b]..pos[b]+Lq-1`` (the last real token plus the draft proposals),
@@ -96,10 +99,12 @@ class PagedLayer:
     ride in the pytree *aux data*: they are static, participate in jit
     cache keys, and can never be confused for traced values.
 
-    Two more layouts, both read by :func:`grouped_attend`. ROWS: ``k``/``v``
+    Two more layouts, both read by :func:`grouped_read`. ROWS: ``k``/``v``
     are ``(pages, page_size, kv_heads * head_dim)``, a token's KV heads side
     by side in one lane-wide row (what a grouped read on the MXU wants, and
-    what a head count that is no whole sublane tile needs). A RING
+    what a head count that is no whole sublane tile needs); behind the
+    scheduler's block tables with the trash page, it is also what
+    :func:`paged_attend` takes in place of the 4-D layout. A RING
     (``ring`` > 0, static like the other two): rows again, ``ring`` pages a
     SLOT and no trash page; slot ``b`` owns pages ``b * ring .. (b + 1) *
     ring - 1`` and position ``t`` lives at row ``t % (ring * page_size)``
@@ -625,18 +630,25 @@ def decode_read(layer: PagedLayer, lq: int, sp_mesh=None,
     (``group`` query heads a KV head: the kernel reads one head for one).
 
     A layer laid out in ROWS (3-D arenas, :class:`PagedLayer`; rings too)
-    is read one query a row by :func:`grouped_attend`: ``"pages"``
+    is read one query a row by :func:`grouped_read`: ``"pages"``
     (:func:`paged_grouped_decode_attention`, any ``group``) where a head of
     ``head_dim`` fills whole lane rows and a page whole sublane tiles,
-    else ``"gathered"``. Nothing else is built over that layout: a wider
-    window, int8 rows or an sp mesh is refused by name."""
+    else ``"gathered"``. A wider window (Lq > 1: a prefill chunk, a verify
+    window) over a block-table rows layer is ``"gathered"`` too:
+    :func:`paged_attend` gathers the slot's rows and views them by head.
+    Refused by name: Lq > 1 over a ring (a chunk's rows would need the ring
+    as it stood at each of them), int8 rows, an sp mesh."""
     if layer.k.ndim == 3:
-        if lq != 1 or layer.quant != "none" or sp_mesh is not None:
+        if (layer.quant != "none" or sp_mesh is not None
+                or (lq != 1 and layer.ring)):
             raise NotImplementedError(
                 "a KV layer laid out in rows (a window ring, a layer that "
-                "other layers read) is read one query a row from unsharded "
-                "bf16 or fp32 pages: no verify or chunk window (Lq > 1), no "
-                "int8 pages, no sp mesh")
+                "other layers read, a grouped-head layer read in place) is "
+                "read from unsharded bf16 or fp32 pages, and a ring one "
+                "query a row: no int8 pages, no sp mesh, no verify or chunk "
+                "window (Lq > 1) over a ring")
+        if lq != 1:
+            return "gathered"
         _, page, f = layer.k.shape
         d = head_dim or f
         tile = 32 // layer.k.dtype.itemsize      # rows a sublane tile
@@ -920,6 +932,39 @@ def _quantize_rows(x):
     return q, scale[..., 0].astype(jnp.float32)
 
 
+def _attend_prompt(q, k, v, attn_fn):
+    """Causal self-attention over the prompt itself — exactly the training
+    contraction, so flash/blockwise plug-ins keep working (grouped heads:
+    the pages hold the KV heads, the contraction gets them broadcast to
+    the query heads)."""
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    return attn_fn(q, k, v)
+
+
+def _rows_attend(q, k, v, layer, bt, positions, write_pos, valid, sp_mesh,
+                 *, prefill: bool, attn_fn):
+    """:func:`paged_attend` over a ROWS layer behind the scheduler's block
+    tables: ``q`` (B, Lq, H, D), ``k``/``v`` (B, Lq, kv_heads, D)."""
+    b, lq, kv, d = k.shape
+    decode_read(layer, lq, sp_mesh)      # a ring, int8 rows, an sp mesh
+    new_layer = grouped_write(layer, k.reshape(b, lq, kv * d),
+                              v.reshape(b, lq, kv * d), bt, write_pos, valid)
+    if prefill:
+        return _attend_prompt(q, k, v, attn_fn), new_layer
+    with jax.named_scope("paged_read"):
+        if lq == 1:
+            out = grouped_read(q[:, 0], new_layer, bt, positions,
+                               kv_heads=kv, scale=1.0 / math.sqrt(d))
+            return out[:, None].astype(q.dtype), new_layer
+        # a chunk or verify window: the slot's rows gathered and viewed by
+        # head, each local query at its own causal horizon
+        gk, gv = (gather_pages(x, bt).reshape(b, -1, kv, d)
+                  for x in (new_layer.k, new_layer.v))
+        return masked_attention(q, gk, gv, positions), new_layer
+
+
 def paged_attend(q, k, v, paged: dict, *, prefill: bool, attn_fn, dtype):
     """One layer's paged-cache attention step; the delegate
     ``models.transformer.attend_maybe_cached`` calls when a ``paged`` pack
@@ -946,6 +991,18 @@ def paged_attend(q, k, v, paged: dict, *, prefill: bool, attn_fn, dtype):
     the trash page: a draft can overrun the end of a short request, and
     an unmasked overrun would clamp into a LIVE page).
 
+    A layer laid out in ROWS (3-D arenas: the pool builds them where a
+    model's ``cache_layout()`` entry ends in ``"rows"``; the expert model's
+    attention layer is one) goes the same way by the rank of the arena it
+    is handed and nothing else: written through :func:`grouped_write`
+    (masked rows land nowhere), a prefill attends within the prompt as
+    above, the tick (Lq == 1) reads through :func:`grouped_read` at
+    ``positions`` (in place where :func:`decode_read` says the shapes tile,
+    the gathered twin where not), and a wider window (Lq > 1: a prefill
+    chunk) gathers the slot's rows, views them ``(B, T, kv_heads,
+    head_dim)`` and runs :func:`masked_attention` as the 4-D layout does.
+    Int8 rows, an sp mesh and a ring are :func:`decode_read`'s refusals.
+
     Returns ``(out, new_layer)`` — the functionally-updated arenas thread
     back out through the model call.
     """
@@ -970,6 +1027,10 @@ def paged_attend(q, k, v, paged: dict, *, prefill: bool, attn_fn, dtype):
         valid = jnp.ones((b, lq), dtype=bool)
     if paged.get("valid") is not None:
         valid = valid & paged["valid"]
+
+    if layer.k.ndim == 3:
+        return _rows_attend(q, k, v, layer, bt, positions, write_pos, valid,
+                            sp_mesh, prefill=prefill, attn_fn=attn_fn)
 
     if sp_mesh is None:
         def write(arena, vals):
@@ -1000,13 +1061,7 @@ def paged_attend(q, k, v, paged: dict, *, prefill: bool, attn_fn, dtype):
 
     group = q.shape[2] // k.shape[2]
     if prefill:
-        # causal self-attention over the prompt itself — exactly the
-        # training contraction, so flash/blockwise plug-ins keep working
-        # (grouped heads: the pages hold the KV heads, the contraction
-        # gets them broadcast to the query heads)
-        if group > 1:
-            k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-        return attn_fn(q, k, v), new_layer
+        return _attend_prompt(q, k, v, attn_fn), new_layer
 
     # the paged read: the in-place kernel, or the gather of every slot's
     # pages and everything that consumes the gathered rows (cast or dequant,
