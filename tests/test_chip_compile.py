@@ -10,7 +10,9 @@ of 128, L2048, V32000, batch 8; the LM training cell's 4 x 2048 x 16 heads
 of 128; also 16 heads of 64 at L16384, prefills of 256 and 1024, the
 serving pool's pages, the serving cell's decode read, the hybrid LM's
 selective scan at 5120 channels, and the expert cell's routed products over
-the hit list and its attention layer's grouped in-place read). Interpret-mode tests cannot see any of this.
+the hit list, its attention layer's grouped in-place read and its Mamba-2
+mixers' one-step update over the live rows, alone and in the cell's whole
+tick program). Interpret-mode tests cannot see any of this.
 
 A compile that passes is not a chip run: it says nothing about results or
 times (``python chip_smoke.py`` is that proof).
@@ -134,6 +136,25 @@ def _hit_experts(rows):
          ((held, width, latent), jnp.bfloat16)]
 
 
+def _ssd_step_live(slots):
+    """The expert cell's one-step Mamba-2 update over the live rows: 128
+    heads of 64 channels, 128 states in 8 groups, bf16 activations, the
+    float32 state donated as the tick program donates it."""
+    from tpu_dist.ops import ssd
+
+    h, p, n, g = 128, 64, 128, 8
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+    state = ((slots, h, p, n), f32)
+    tile = ssd.head_tile(jax.ShapeDtypeStruct(*state), g)
+    assert tile == 32
+    return (lambda x, dt, a, b, c, d, s, live, fresh: ssd.ssd_step_live(
+        x, dt, a, b, c, d, s, ssd.live_rows(live), fresh, tile,
+        interpret=False)), \
+        [((slots, h, p), bf16), ((slots, h), f32), ((h,), f32),
+         ((slots, g, n), bf16), ((slots, g, n), bf16), ((h,), f32), state,
+         ((slots,), i32), ((slots,), i32)]
+
+
 def _quant_matmul(m, k, n):
     from tpu_dist.ops.pallas_quant import fused_quant_matmul
 
@@ -176,6 +197,7 @@ CASES = {
     "selective_scan_l256_c5120": lambda: _selective_scan(256),
     "hit_experts_r64_e128_1024x2688": lambda: _hit_experts(64),
     "hit_experts_r256_e128_1024x2688": lambda: _hit_experts(256),
+    "ssd_step_live_b64_h128_p64_n128": lambda: _ssd_step_live(64),
     "quant_matmul_mlp_16384x1024x4096": lambda: _quant_matmul(16384, 1024, 4096),
     "quant_matmul_decode_8x1024x4096": lambda: _quant_matmul(8, 1024, 4096),
     "quant_matmul_head_16384x1024x32000":
@@ -307,3 +329,79 @@ def test_timed_programs_carry_the_programs_scopes_for_v5e(v5e, monkeypatch):
     step = op_names(compiled)
     for scope in ("jvp(loss)/", "transpose(jvp(loss))/", "/optimizer/"):
         assert sum(scope in n for n in step) >= 3, scope
+
+
+def test_the_expert_cells_tick_updates_its_live_rows_state_in_place(
+        v5e, monkeypatch):
+    """The expert cell's whole tick program (its own configuration and
+    engine settings) compiled for one v5e: one ``ssd_step`` Mosaic call a
+    Mamba-2 layer, each taking a state ARGUMENT of the program as its operand
+    and aliasing it to the result the program hands back, and no other
+    instruction that passes over a ``[slots, H, P, N]`` float32 array: no
+    fusion, no copy, no cast (ISSUE 45: the parent's five
+    ``multiply_reduce_fusion`` over ``f32[64,128,64,128]`` read and wrote
+    2.7 GB a tick whatever the slots held)."""
+    import re
+
+    from benchmarks.families.nemotron_h_lm_server import model_fields
+    from tpu_dist.engine.kv_cache import PagedKVPool
+    from tpu_dist.engine.serve import _tick_program
+    from tpu_dist.models.nemotron_h import NemotronHLM
+    from tpu_dist.ops.flash_attention import flash_attention_fn
+
+    cell = _cellbench()._cell(
+        "nemotron-3-super-120b-a12b-ep4.serve-assistant")
+    s, srv = cell.config, cell.workload["serve"]
+    chip = SingleDeviceSharding(v5e[0])
+    model = NemotronHLM(**model_fields(s), dtype=jnp.bfloat16,
+                        attn_fn=flash_attention_fn(block_k=1024))
+    shapes = lambda tree: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip), tree)
+    params = shapes(jax.eval_shape(lambda k: jax.tree_util.tree_map(
+        lambda x: x.astype(jnp.bfloat16) if x.ndim > 1 else x, model.init(
+            {"params": k}, jnp.zeros((1, 8), jnp.int32))["params"]),
+        jax.random.PRNGKey(0)))
+    n = srv["max_slots"]
+    layers = shapes(jax.eval_shape(lambda: PagedKVPool(
+        model.cache_layout(), srv["num_pages"], srv["page_size"],
+        dtype=jnp.bfloat16, max_slots=n).layers()))
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+    rng = jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=chip)
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        text = _tick_program(model, 0.0, 0, 0.0, None).lower(
+            params, layers, i32(n, srv["max_len"] // srv["page_size"]),
+            i32(n), i32(n), rng).compile().as_text()
+    mixers = s["hybrid_override_pattern"].count("M")
+    state = (f"f32[{n},{s['mamba_num_heads']},{s['mamba_head_dim']},"
+             f"{s['ssm_state_size']}]")
+    entry = text[text.index("\nENTRY "):]
+    passes = [line.strip() for line in text.splitlines()
+              if re.search(r" = \(?[^=]*" + re.escape(state), line)]
+    calls = [line for line in passes if "custom-call(" in line]
+    assert len(calls) == mixers and all(
+        line.startswith("%ssd_step") and "/ssm_step/" in line
+        for line in calls)
+    params_in = set()
+    for line in calls:
+        # the state operand is the program's own argument, aliased to the
+        # call's state result
+        index = int(re.search(r"\{1\}: \((\d+), \{\}\)", line).group(1))
+        operand = re.findall(r"%[\w.\-]+", line[line.index("custom-call("):]
+                             )[index]
+        defined = re.search(
+            re.escape(operand) + r" = " + re.escape(state)
+            + r"\S* parameter\((\d+)\)", entry)
+        assert defined, operand
+        params_in.add(int(defined.group(1)))
+    assert len(params_in) == mixers
+    # beside the calls: the arguments themselves, the results picked out of
+    # the calls' tuples and the program's own result tuple, which move
+    # nothing
+    others = [line for line in passes if line not in calls]
+    assert len(others) == 2 * mixers + 1 and all(
+        re.search(r" (parameter|get-tuple-element|tuple)\(", line)
+        for line in others), others
+    # and the program hands those results back under the arguments' buffers
+    aliased = re.search(r"input_output_alias=\{(.*?)\}, entry", text).group(1)
+    assert all(f"({i}, {{}}, may-alias)" in aliased for i in params_in)

@@ -29,7 +29,8 @@ from benchmarks.reference import nemotron_h as ref  # noqa: E402
 from tpu_dist.models.nemotron_h import (NemotronHLM, layer_types,  # noqa: E402
                                         nemotron_h_lm)
 from tpu_dist.ops import routed_experts as rx  # noqa: E402
-from tpu_dist.ops.ssd import ssd_scan, ssd_step  # noqa: E402
+from tpu_dist.ops.ssd import (head_tile, live_rows, ssd_scan,  # noqa: E402
+                              ssd_step, ssd_step_live)
 
 TOY = dict(hidden_size=64, num_hidden_layers=12,
            hybrid_override_pattern="MEME*EMEME*E", num_attention_heads=4,
@@ -159,6 +160,66 @@ def test_one_step_form_continues_the_chunked_one():
                                    want_y[[0, 2], t], rtol=2e-5, atol=2e-5)
     np.testing.assert_allclose(np.asarray(s)[[0, 2]], want_s[[0, 2]],
                                rtol=2e-5, atol=2e-5)
+
+
+LIVE = {"none": [], "one_row": [2], "scattered_half": [0, 2, 3, 6],
+        "all_rows": list(range(8)), "last_row_only": [7]}
+
+
+@pytest.mark.parametrize("fresh", [False, True], ids=["carried", "fresh"])
+@pytest.mark.parametrize("live", sorted(LIVE))
+def test_the_kernel_over_the_live_rows_is_the_one_step_form(live, fresh):
+    """``ssd_step_live`` (interpreted here; two head tiles of two groups
+    each) against ``ssd_step``: a live row's ``y`` and state agree to float32
+    rounding (1e-6 of the largest value: the sum over ``n`` runs in the
+    backend's order, and the products may fuse otherwise), a fresh one starts
+    from zero whatever its slot holds, and a row that sits out keeps its
+    state BIT FOR BIT and answers ``y == 0``; with no row live every state is
+    what went in, the block the walk names on its first step included."""
+    rows, (h, p, g, n) = 8, (8, 16, 4, 128)
+    a = {k: v[:, 0] if k in ("x", "dt", "B", "C") else v
+         for k, v in _ssd_inputs(rows, 1, seed=len(live), h=h, p=p, g=g,
+                                 n=n).items()}
+    on = np.zeros(rows, bool)
+    on[LIVE[live]] = True
+    # the fresh row is a live one where there is one (a row that sits out
+    # is never fresh: the mixer says ``fresh & live``)
+    new = np.zeros(rows, bool)
+    new[LIVE[live][-1:]] = fresh
+    assert head_tile(a["s0"], g) == h
+    y, s = jax.jit(lambda a, on, new: ssd_step_live(
+        a["x"], a["dt"], a["A"], a["B"], a["C"], a["D"], a["s0"],
+        live_rows(on), new, tile=4))(a, jnp.asarray(on), jnp.asarray(new))
+    want_y, want_s = ssd_step(
+        a["x"], jnp.where(on[:, None], a["dt"], 0.0), a["A"], a["B"], a["C"],
+        a["D"], jnp.where(new[:, None, None, None], 0.0, a["s0"]))
+    y, s, want_y, want_s = map(np.asarray, (y, s, want_y, want_s))
+    np.testing.assert_array_equal(s[~on], np.asarray(a["s0"])[~on])
+    np.testing.assert_array_equal(y[~on], 0.0)
+    for got, want in ((y[on], want_y[on]), (s[on], want_s[on])):
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=1e-6 * max(np.abs(want).max(initial=0), 1))
+    if fresh and on.any():
+        # nothing of the slot's old state is left in a fresh row's
+        assert np.abs(s[new] - a["s0"][new]).max() > 0.5
+
+
+@pytest.mark.parametrize("shape,dtype,groups,want", [
+    ((64, 128, 64, 128), jnp.float32, 8, 32),    # the published mixer: 1 MB
+    ((8, 8, 16, 128), jnp.float32, 2, 8),        # small enough whole
+    ((8, 24, 64, 128), jnp.float32, 3, 24),      # whole groups of 8 heads
+    ((8, 48, 64, 128), jnp.float32, 3, 16),      # 32 would split a group
+    ((8, 8, 16, 16), jnp.float32, 2, 0),         # the toy's 16 states
+    ((8, 8, 12, 128), jnp.float32, 2, 0),        # channels off the sublanes
+    ((8, 8, 16, 192), jnp.float32, 2, 0),        # states off the lanes
+    ((8, 8, 16, 128), jnp.bfloat16, 2, 0),       # a state that is not f32
+    ((8, 2, 4096, 128), jnp.float32, 2, 0),      # one group over the block
+], ids=["published", "small", "three_groups", "whole_groups_only",
+        "toy_states", "odd_channels", "odd_states", "bf16_state",
+        "group_too_large"])
+def test_the_kernel_takes_a_state_by_its_shape_alone(shape, dtype, groups,
+                                                     want):
+    assert head_tile(jax.ShapeDtypeStruct(shape, dtype), groups) == want
 
 
 # -------------------------------------------------------- the routed layer

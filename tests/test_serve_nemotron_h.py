@@ -40,6 +40,9 @@ RTOL, ATOL = 3e-4, 1e-4
 # the toy with the attention layer's PUBLISHED head shape: 2 KV heads of 128
 # under 32 query heads, which the grouped in-place read's tiling takes
 WIDE = {**TOY, "num_attention_heads": 32, "head_dim": 128}
+# the toy with the Mamba-2 mixers' PUBLISHED state width: 128 states fill
+# the lanes, which the tick's kernel over the live rows takes
+STATES = {**TOY, "ssm_state_size": 128}
 
 
 def weights(seed=1, sizes=TOY):
@@ -48,7 +51,8 @@ def weights(seed=1, sizes=TOY):
 
 def engine(w, sizes=TOY, **cfg):
     model = toy_model(num_heads=sizes["num_attention_heads"],
-                      head_dim=sizes["head_dim"])
+                      head_dim=sizes["head_dim"],
+                      d_state=sizes["ssm_state_size"])
     fields = dict(max_slots=2, page_size=4, num_pages=64, max_len=64)
     return ServeEngine(model, engine_params(model, w),
                        ServeConfig(**{**fields, **cfg}))
@@ -165,6 +169,42 @@ def test_a_reused_slot_holds_nothing_of_its_last_occupant(log):
     assert len(done) == 3 and eng.prefills == 3
     assert float(jnp.abs(eng.pool.layers()[0]["ssm"]).max()) > 0
     check_against_reference(done, rows, w)
+
+
+def test_the_tick_walks_the_live_rows_where_the_states_fill_the_lanes(
+        log, monkeypatch):
+    """At the published 128 states the tick updates the live rows' state in
+    place with ``ssd_step_live`` (interpreted here), one call a Mamba-2
+    layer: two slots that five requests join, leave and reuse give the
+    reference's logits, and the greedy tokens of the same requests through
+    the plain form (the shape rule answering 0, as it does for the toy's 16
+    states)."""
+    from tpu_dist.ops import ssd
+
+    def arrivals():
+        return {0: requests([9, 30], new=7, seed=22),
+                3: requests([5], new=4, seed=23, first_rid=2),
+                6: requests([27, 11], new=6, seed=24, first_rid=3)}
+
+    calls, real = [], ssd.ssd_step_live
+    monkeypatch.setattr(ssd, "ssd_step_live", lambda *a, **kw: (
+        calls.append(a[-1]), real(*a, **kw))[1])
+    w = weights(seed=21, sizes=STATES)
+    eng = engine(w, STATES)
+    done, rows = serve_recorded(eng, log, arrivals())
+    assert len(done) == 5 and eng.ticks_ahead > 0
+    # traced once: the four mixers, each the whole of its 8 heads a step
+    assert calls == [8] * STATES["hybrid_override_pattern"].count("M")
+    check_against_reference(done, rows, w, sizes=STATES)
+    assert min(sp.attrs["state_slots"] for sp in _ticks(eng)) == 1
+
+    monkeypatch.setattr(ssd, "head_tile", lambda s, groups: 0)
+    del log[:], calls[:]
+    serve._tick_program.cache_clear()
+    plain, _ = serve_recorded(engine(w, STATES), log, arrivals())
+    assert not calls
+    for rid, c in done.items():
+        np.testing.assert_array_equal(c.tokens, plain[rid].tokens)
 
 
 @pytest.mark.parametrize("chunk", [8, 16])

@@ -64,7 +64,12 @@ the grouped kernel where a head fills the lanes (the published 2 KV heads
 of 128 under 32 query heads do; a toy head does not and takes the gathered
 twin), a prefill chunk's window (Lq > 1) gathers the slot's rows and views
 them by head, and int8 pages (``kv_quant="int8"``) are refused by the pool,
-which builds no int8 rows. A prefill
+which builds no int8 rows. The tick's Mamba-2 layers update the state of
+the rows that decode IN PLACE (``ops.ssd.ssd_step_live``: a slot that sits
+out is neither read nor written) where the state fills the lanes (the
+published 128 float32 states do, ``ops.ssd.head_tile``; the toy's 16 take
+the plain step over every row), all from ONE list of the live rows, made in
+:meth:`NemotronHLM.__call__` once a tick program. A prefill
 runs the final norm and the head on each prompt's LAST LIVE row only and
 returns logits ``[B, 1, V]``. Where a call can mutate the ``expert_counts``
 collection (the engine's tick and prefill programs ask for it), each ``E``
@@ -116,7 +121,8 @@ class Mamba2Mixer(nn.Module):
     @nn.compact
     def __call__(self, h, paged):
         from tpu_dist.ops.selective_scan import causal_conv1d
-        from tpu_dist.ops.ssd import ssd_scan, ssd_step
+        from tpu_dist.ops.ssd import (head_tile, ssd_scan, ssd_step,
+                                      ssd_step_live)
 
         b, l, d_model = h.shape
         nh, p, n, g, k = (self.heads, self.head_dim, self.d_state,
@@ -143,7 +149,7 @@ class Mamba2Mixer(nn.Module):
             # (``hybrid.MambaMixer``'s rules: a row that starts a sequence
             # starts from zero state, a row the call carries but does not
             # feed keeps what it holds)
-            new_state = None
+            new_state, tile = None, 0
             if paged is None:
                 live = jnp.full((b,), l, jnp.int32)
                 s0 = jnp.zeros((b, nh, p, n), jnp.float32)
@@ -154,8 +160,14 @@ class Mamba2Mixer(nn.Module):
                 rows = (lambda x: x) if slots is None else (
                     lambda x: jnp.take(x, slots, axis=0))
                 fresh = (paged["positions"] == 0) & (live > 0)
-                s0 = jnp.where(fresh[:, None, None, None], 0.0,
-                               rows(state["ssm"]))
+                # the tick (its live rows come listed: every row IS a slot)
+                # where the kernel takes the state's shape: the live rows'
+                # state is updated in place, and nothing here passes over
+                # the whole array
+                tile = (head_tile(state["ssm"], g)
+                        if paged.get("live_rows") is not None else 0)
+                s0 = state["ssm"] if tile else jnp.where(
+                    fresh[:, None, None, None], 0.0, rows(state["ssm"]))
                 tail = jnp.where(fresh[:, None, None], 0,
                                  rows(state["conv"]))
 
@@ -167,8 +179,13 @@ class Mamba2Mixer(nn.Module):
             delta = jax.nn.softplus(dt.astype(jnp.float32)
                                     + dt_bias.astype(jnp.float32))
             a = -jnp.exp(a_log.astype(jnp.float32))
-            if paged is not None and l == 1:
-                # the tick: one token a slot, every slot's row in place
+            if tile:
+                y, s_last = ssd_step_live(
+                    x[:, 0], delta[:, 0], a, bmat[:, 0], cmat[:, 0], d_skip,
+                    s0, paged["live_rows"], fresh, tile)
+                y = y[:, None]
+            elif paged is not None and l == 1:
+                # one token a row, a row that sits out keeps its state
                 delta = jnp.where((live > 0)[:, None], delta[:, 0], 0.0)
                 y, s_last = ssd_step(x[:, 0], delta, a, bmat[:, 0],
                                      cmat[:, 0], d_skip, s0)
@@ -387,6 +404,12 @@ class NemotronHLM(nn.Module):
                {k: paged.get(k) for k in (
                    "block_tables", "positions", "lengths", "valid", "sp_mesh",
                    "live", "slots")})
+        if (paged is not None and tokens.shape[1] == 1
+                and ctx["slots"] is None and "mamba2" in self.layer_types):
+            # the tick: its live rows listed once for every Mamba-2 layer
+            from tpu_dist.ops.ssd import live_rows
+
+            ctx["live_rows"] = live_rows(ctx["live"])
         new_layers = []
         for i, kind in enumerate(self.layer_types):
             blk = NemotronHBlock(

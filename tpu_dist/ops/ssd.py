@@ -27,8 +27,21 @@ A server needs of it what it needs of ``ops.selective_scan``: it starts from
 a state handed in (``s0``: a slot that continues), it stops at each row's
 own ``lengths`` (``dt`` is 0 at and past them, so ``exp(0) = 1`` keeps the
 state and nothing is added: the state at the true length reaches the end),
-and it runs one token a slot per tick (:func:`ssd_step`, under the
-``ssm_step`` scope as Mamba-1's is).
+and it runs one token a slot per tick, under the ``ssm_step`` scope as
+Mamba-1's does. The tick has two forms of that step, picked from what the
+call can see (:func:`head_tile`: the state's type and shape):
+
+* :func:`ssd_step_live`, where every row of the state IS a slot and the
+  state is float32 with whole lane tiles of states: ONE Pallas call a layer
+  walks the compacted list of the rows that decode (:func:`live_rows`, made
+  once a tick program for all its layers) and updates their state IN PLACE;
+  a slot that sits out is neither read nor written. The published mixer's
+  state is 4.19 MB a slot and layer, so a tick moves what its live rows
+  hold and no longer all 64 slots' 2.7 GB (PERF.md section 6, PR 45, has
+  the chip's table by live rows and head tile).
+* :func:`ssd_step`, plain XLA over every row (``dt = 0`` keeps a row's
+  state): the reference the kernel is tested against, and what every other
+  shape and caller runs.
 
 The state is laid out ``[batch, H, P, N]``: the ``N`` states on the TPU's
 lanes (128 published), a head's channels on the sublanes.
@@ -36,8 +49,21 @@ lanes (128 published), a head's channels on the sublanes.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+
+from tpu_dist.ops.routed_experts import _hit_list
+from tpu_dist.runtime import pallas_interpret
+
+#: the most bytes of state one step of :func:`ssd_step_live` holds (its head
+#: tile: whole groups, see :func:`head_tile`): 32 heads at the published
+#: mixer. On one v5e 64 heads a step are within 3% of it at every live
+#: count and 16 are 7-22% slower (``benchmarks/kernels/ssd_step_bench.py``;
+#: PERF.md section 6, PR 45), and the step's body is traced once a head of
+#: the tile
+_STEP_BLOCK_BYTES = 2**20
 
 
 def _heads(x, heads: int):
@@ -112,4 +138,138 @@ def ssd_step(x_t, dt_t, A, B_t, C_t, D, s):
              * _heads(f32(B_t), h)[:, :, None, :])
         y = (jnp.sum(s * _heads(f32(C_t), h)[:, :, None, :], axis=-1)
              + f32(D)[:, None] * x)
+        return y.astype(x_t.dtype), s
+
+
+def live_rows(live):
+    """``live`` [rows] (nonzero: the row decodes this tick) -> ``(ids [rows]
+    i32, n [1] i32)``: the live rows ascending, then the last of them again
+    up to ``rows`` entries (zeros where none is live), and how many there
+    are. No sort, no scatter."""
+    live = live.astype(jnp.int32) > 0
+    return _hit_list(live), jnp.sum(live.astype(jnp.int32))[None]
+
+
+def head_tile(s, groups: int) -> int:
+    """The heads one step of :func:`ssd_step_live` takes from a state ``s``
+    [rows, H, P, N], or 0 where the kernel does not take the shape: float32
+    state, the ``N`` states whole lane tiles, a head's ``P`` channels whole
+    sublane tiles, and the largest number of whole groups' heads that
+    divides ``H`` and holds at most :data:`_STEP_BLOCK_BYTES`."""
+    _, h, p, n = s.shape
+    if s.dtype != jnp.float32 or n % 128 or p % 8 or h % groups:
+        return 0
+    per = h // groups
+    return max((t for t in range(per, h + 1, per)
+                if h % t == 0 and 4 * t * p * n <= _STEP_BLOCK_BYTES),
+               default=0)
+
+
+def _ssd_step_kernel(ids_ref, n_ref, fresh_ref, x_ref, dt_ref, a_ref, d_ref,
+                     b_ref, c_ref, s_ref, _, y_ref, o_ref):
+    """One step of the walk over the live rows: row ``ids[i]``, head tile
+    ``t``. ``x_ref``/``y_ref`` [P, tile] hold the tile's heads on the lanes,
+    ``s_ref``/``o_ref`` [tile, P, N] one block of the state, which the call
+    aliases: a block no step names is neither read nor written."""
+    import jax.experimental.pallas as pl
+
+    i, t = pl.program_id(0), pl.program_id(1)
+    tile, p, n = s_ref.shape[1:]
+    per = tile // b_ref.shape[2]
+
+    @pl.when((i == 0) & (t == 0) & (n_ref[0] == 0))
+    def _none():
+        # no row is live: the one block the walk names goes back as it came
+        o_ref[...] = s_ref[...]
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(i < n_ref[0])
+    def _row():
+        x, dt = x_ref[0, 0], dt_ref[0, 0]               # [P, tile], [1, tile]
+        decay, dx = jnp.exp(dt * a_ref[0]), dt * x
+        fresh = fresh_ref[ids_ref[i]] != 0
+        lane = jax.lax.broadcasted_iota(jnp.int32, (p, tile), 1)
+        # the tile's heads walked at trace time: a loop the compiler keeps
+        # (``fori_loop`` over the heads, their rows of the block and their
+        # columns indexed by the traced head) ran 1.6 times as long on the
+        # chip in each of four forms (PERF.md section 6, PR 45)
+        y = jnp.zeros((p, tile), jnp.float32)
+        for h in range(tile):
+            b, c = b_ref[0, 0, h // per], c_ref[0, 0, h // per]     # [1, N]
+            s = jnp.where(fresh, 0.0, s_ref[0, h])                  # [P, N]
+            s = decay[:, h:h + 1] * s + dx[:, h:h + 1] * b
+            o_ref[0, h] = s
+            y = jnp.where(lane == h, jnp.sum(s * c, axis=1, keepdims=True),
+                          y)
+        y_ref[0, 0] = y + d_ref[0] * x
+
+
+def ssd_step_live(x_t, dt_t, A, B_t, C_t, D, s, rows, fresh, tile: int,
+                  interpret=None):
+    """:func:`ssd_step` over the rows that ``rows`` (:func:`live_rows`)
+    lists, the state updated IN PLACE: ONE Pallas call, grid ``(rows, head
+    tiles)`` over the compacted list, the state's block ``(1, tile, P, N)``
+    named by ``ids[i]`` for input and output and the two aliased, so a row
+    that sits out keeps its bits without being read or written (its ``y`` is
+    zero). A step past ``n`` names the block the last real step named, so
+    the pipeline copies nothing for it, and skips its body. ``fresh`` [rows]
+    (nonzero: the row starts from zero state whatever it holds); ``tile``
+    from :func:`head_tile`. All in float32, term by term as :func:`ssd_step`
+    has them."""
+    return _step_call(x_t, dt_t, A, B_t, C_t, D, s, rows, fresh, tile=tile,
+                      interpret=pallas_interpret(interpret))
+
+
+# jitted, so that a program traces and lowers the kernel once and not once a
+# layer (as the flash calls are, PR 29); the mode is settled before, not
+# inside the cache
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _step_call(x_t, dt_t, A, B_t, C_t, D, s, rows, fresh, *, tile, interpret):
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    ids, n = rows
+    slots, h, p, d_state = s.shape
+    tiles, groups = h // tile, B_t.shape[1] * tile // h
+    f32 = lambda v: v.astype(jnp.float32)
+    # a tile's heads on the lanes: x and y as [rows, tiles, P, tile]
+    by_tile = lambda v: f32(v).reshape(*v.shape[:-1], tiles, -1)
+    at = lambda i, t, n: jnp.where(i < n[0], t, tiles - 1)
+    row = lambda i, t, ids, n, fresh: (ids[i], at(i, t, n), 0, 0)
+    head = lambda i, t, ids, n, fresh: (at(i, t, n), 0, 0)
+    group = lambda i, t, ids, n, fresh: (ids[i], at(i, t, n), 0, 0, 0)
+    with jax.named_scope("ssm_step"):
+        y, s = pl.pallas_call(
+            _ssd_step_kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(slots, tiles),
+                in_specs=[
+                    pl.BlockSpec((1, 1, p, tile), row),
+                    pl.BlockSpec((1, 1, 1, tile), row),
+                    pl.BlockSpec((1, 1, tile), head),
+                    pl.BlockSpec((1, 1, tile), head),
+                    pl.BlockSpec((1, 1, groups, 1, d_state), group),
+                    pl.BlockSpec((1, 1, groups, 1, d_state), group),
+                    pl.BlockSpec((1, tile, p, d_state), row),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                ],
+                out_specs=[pl.BlockSpec((1, 1, p, tile), row),
+                           pl.BlockSpec((1, tile, p, d_state), row)]),
+            out_shape=[jax.ShapeDtypeStruct((slots, tiles, p, tile),
+                                            jnp.float32),
+                       jax.ShapeDtypeStruct(s.shape, s.dtype)],
+            # the state, and the zeros that a row the walk does not visit
+            # answers with
+            input_output_aliases={9: 1, 10: 0},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=interpret,
+            name="ssd_step",
+        )(ids, n, fresh.astype(jnp.int32),
+          jnp.swapaxes(by_tile(jnp.swapaxes(x_t, 1, 2)), 1, 2),
+          by_tile(dt_t)[:, :, None], by_tile(A)[:, None], by_tile(D)[:, None],
+          f32(B_t).reshape(slots, tiles, groups, 1, d_state),
+          f32(C_t).reshape(slots, tiles, groups, 1, d_state), s,
+          jnp.zeros((slots, tiles, p, tile), jnp.float32))
+        y = jnp.swapaxes(y, 2, 3).reshape(slots, h, p)
         return y.astype(x_t.dtype), s
