@@ -15,7 +15,7 @@ The pins that matter:
 * every consumer speaks the events: the ledger schema, the Prometheus
   series, the trace_merge decision markers, ledger_report's decision
   section, the fleet stitcher's decision<->scale<->applied join, and
-  bench_track's reaction-lag gate;
+  the fleet run's own reaction lag (``headline.json``);
 * the ACCEPTANCE scenario (``scripts/fleet_autoscale.json``: 3 hosts,
   one parked standby, a diurnal curve with an overload burst) runs end
   to end and — read from ``tools/fleet_report.py --json`` — shows
@@ -467,38 +467,33 @@ def test_supervisor_retune_stamps_applied_with_reproducible_hash(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bench_track: the reaction-lag gate (lower is better, abstains pre-history)
+# headline.json: the reaction lag (burst onset to the first up decision)
 
-def test_bench_track_gates_autoscale_lag(tmp_path):
-    sys.path.insert(0, ROOT)
-    from tools.bench_track import load_points, track
+def _burst(tick):
+    return {"type": "burst", "tick": tick, "ticks": 4, "rate": 2.0}
 
-    def _headline(name, **fleet):
-        doc = {"metric": "fleet_sim_goodput", "value": 0.3,
-               "unit": "ratio",
-               "fleet": {"goodput_ratio": 0.3, "hosts": 3, **fleet}}
-        p = tmp_path / name
-        p.write_text(json.dumps(doc))
-        return str(p)
 
-    # pre-autoscale history abstains: no field, no judgment
-    pts = load_points([_headline("old.json"),
-                       _headline("new.json", autoscale_lag_ticks=8)])
-    m = track(pts, threshold_pct=5.0)["metrics"]["fleet_sim_goodput"]
-    assert m["autoscale_lag_latest"] == 8
-    assert m["autoscale_lag_best_prior"] is None
-    assert not m["autoscale_lag_regressed"]
-    # a real regression against the trailing best fails the gate
-    pts = load_points([_headline("a.json", autoscale_lag_ticks=4),
-                       _headline("b.json", autoscale_lag_ticks=8)])
-    rep = track(pts, threshold_pct=5.0)
-    assert rep["metrics"]["fleet_sim_goodput"]["autoscale_lag_regressed"]
-    assert not rep["ok"]
-    # a zero-lag best abstains (relative regression is undefined at 0)
-    pts = load_points([_headline("z.json", autoscale_lag_ticks=0),
-                       _headline("y.json", autoscale_lag_ticks=8)])
-    m = track(pts, threshold_pct=5.0)["metrics"]["fleet_sim_goodput"]
-    assert not m["autoscale_lag_regressed"]
+def _decided(tick, direction):
+    return {"tick": tick, "direction": direction}
+
+
+@pytest.mark.parametrize("events, decisions, lag", [
+    # a run without a burst has nothing to react to
+    ([{"type": "kill", "tick": 5}], [_decided(9, "up")], None),
+    # a burst the policy never answered
+    ([_burst(10)], [_decided(30, "down")], None),
+    ([_burst(10)], [_decided(17, "up")], 7),
+    # the FIRST burst's onset and the FIRST up decision, whatever the
+    # order the scenario lists them in and whatever came between
+    ([_burst(40), {"type": "kill", "tick": 2}, _burst(12)],
+     [_decided(8, "down"), _decided(15, "up"), _decided(44, "up")], 3),
+    ([_burst(10)], [_decided(10, "up")], 0),
+], ids=["no_burst", "no_up", "up_after_burst", "first_of_several",
+        "same_tick"])
+def test_autoscale_lag_ticks(events, decisions, lag):
+    from tpu_dist.sim.runner import autoscale_lag_ticks
+
+    assert autoscale_lag_ticks(events, decisions) == lag
 
 
 # ---------------------------------------------------------------------------

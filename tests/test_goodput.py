@@ -256,8 +256,8 @@ def test_ledger_report_goodput_section_and_cli_discovery(job_dir, capsys):
 
 
 def test_ledger_report_decode_section(tmp_path, capsys):
-    """Per-request serving latency from decode events (the decode_bench
-    satellite's ledger half): nearest-rank p50/p99 + tok/s."""
+    """Per-request serving latency from decode events (engine.generate
+    with a ledger): nearest-rank p50/p99 + tok/s."""
     from tools.ledger_report import summarize
 
     recs = [{"event": "decode", "ts": 10.0 + i, "pid": 0, "tokens": 100,
@@ -501,39 +501,6 @@ def test_healthz_reports_last_step_age(tmp_path):
         srv.close()
 
 
-@pytest.mark.slow
-def test_decode_bench_per_request_cli(tmp_path):
-    """Full decode_bench CLI at a tiny geometry: per-request latency
-    percentiles + request tok/s in the headline JSON, one decode ledger
-    event per request (slow: a fresh-process jax import + compile; the
-    percentile math and the report section are covered no-jax above)."""
-    import subprocess
-    import sys as _sys
-
-    led = str(tmp_path / "dec.jsonl")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [_sys.executable, "tools/decode_bench.py", "--batch", "2",
-         "--prompt-len", "8", "--steps", "4", "--vocab-size", "64",
-         "--d-model", "32", "--num-layers", "1", "--num-heads", "2",
-         "--skip-full", "--trials", "1", "--requests", "3",
-         "--ledger", led],
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        env=env, capture_output=True, text=True, timeout=300)
-    assert out.returncode == 0, out.stderr
-    head = json.loads(out.stdout.strip().splitlines()[-1])
-    assert head["requests"] == 3
-    assert head["latency_ms"]["p50_ms"] > 0
-    assert head["latency_ms"]["p99_ms"] >= head["latency_ms"]["p50_ms"]
-    assert head["request_tokens_per_sec"] > 0
-    recs = read_ledger(led)
-    assert len([r for r in recs if r["event"] == "decode"]) == 3
-    from tools.ledger_report import summarize
-
-    summary = summarize(recs, out=lambda s: None)
-    assert summary["decode"]["requests"] == 3
-
-
 # ------------------------------------------ ACCEPTANCE: 2-attempt LM smoke
 
 def test_two_attempt_lm_smoke_goodput_slo_flightrec(tmp_path):
@@ -615,138 +582,3 @@ def test_two_attempt_lm_smoke_goodput_slo_flightrec(tmp_path):
     assert [s["attempt"] for s in starts] == [0, 1]
     assert all(s["job_id"] == "run" for s in starts)
     assert starts[1]["resumed_from"] == cfg2.resume
-
-
-@pytest.mark.slow  # tier-1 budget (PR 14): the serve-trace-replay
-# mechanics this CLI drives are pinned in-budget at the engine level
-# (test_serve.py continuous-vs-static schedule math) and end to end by the
-# fleet acceptance (test_fleet.py::test_fleet_ci_scenario_acceptance),
-# which replays Poisson traffic through the same ServeEngine across three
-# supervised processes
-def test_decode_bench_trace_replay_cli(tmp_path):
-    """The throughput-under-load acceptance pin, on the real CLI surface:
-    `decode_bench --trace` replays one seeded Poisson trace through the
-    continuous-batching engine AND static drain-batching at equal slot
-    capacity, and the headline JSON's `serving` block must show continuous
-    strictly ahead on completed-requests-per-tick and occupancy (both are
-    deterministic schedule arithmetic — the wall req/s rides along for the
-    dashboards). Tiny geometry: the pin is the comparison, not the scale."""
-    import subprocess
-    import sys as _sys
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    out = subprocess.run(
-        [_sys.executable, "tools/decode_bench.py", "--batch", "2",
-         "--prompt-len", "8", "--steps", "4", "--vocab-size", "64",
-         "--d-model", "32", "--num-layers", "1", "--num-heads", "2",
-         "--skip-full", "--trials", "1", "--requests", "0",
-         "--trace", "12", "--min-prompt", "4", "--max-prompt", "12",
-         "--min-out", "2", "--max-out", "12", "--serve-slots", "3",
-         "--page-size", "8"],
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        env=env, capture_output=True, text=True, timeout=300)
-    assert out.returncode == 0, out.stderr[-2000:]
-    head = json.loads(out.stdout.strip().splitlines()[-1])
-    srv = head["serving"]
-    assert srv["requests"] == 12 and srv["completed"] == 12
-    static = srv["static"]
-    assert static["completed"] == 12
-    # the perf pin: strictly more completed work per tick, busier slots
-    assert srv["requests_per_tick"] > static["requests_per_tick"], srv
-    assert srv["occupancy"] > static["occupancy"], srv
-    assert srv["requests_per_sec"] > 0 and srv["tokens_per_sec"] > 0
-    assert srv["ttft_ms"]["p99"] >= srv["ttft_ms"]["p50"] > 0
-    assert srv["tpot_ms"]["p50"] > 0
-    # and bench_track judges the serving number like data_s: a regressed
-    # replay fails the gate, pre-serving history abstains
-    from tools.bench_track import load_points, track
-
-    hp = tmp_path / "head.json"
-    hp.write_text(json.dumps(head))
-    points = load_points([str(hp)])
-    assert points[0]["serving_rpt"] == srv["requests_per_tick"]
-    report = track(points, threshold_pct=5.0)
-    m = report["metrics"][head["metric"]]
-    assert m["serving_latest"] == srv["requests_per_tick"]
-    assert m["serving_best_prior"] is None  # abstains: no prior history
-    worse = dict(head, serving=dict(srv, requests_per_tick=srv[
-        "requests_per_tick"] * 0.5))
-    wp = tmp_path / "worse.json"
-    wp.write_text(json.dumps(worse))
-    report = track(load_points([str(hp), str(wp)]), threshold_pct=5.0)
-    assert report["metrics"][head["metric"]]["serving_regressed"]
-
-
-def test_decode_bench_long_context_acceptance_cli(tmp_path):
-    """ISSUE 19 acceptance, on the real CLI surface: the checked-in
-    mixed-traffic trace (tools/traces/longcontext_mix.json — 14 short chat
-    requests + one 16384-token admit in flight) replays through chunked
-    prefill under the virtual cost-model clock, and the headline JSON must
-    show (a) short-request TPOT p99 within 25% of the no-long-prompt
-    baseline — the whole point of chunking: interference is bounded by
-    chunk/tick_floor (128/1024 = 12.5%), not prompt_len/tick_floor
-    (1600%) — and (b) a context longer than ONE device's page budget
-    served end-to-end on a 4-device cpu sp submesh. Both numbers are
-    deterministic schedule arithmetic (virtual clock, seeded trace), so
-    the bounds are exact pins, not flaky wall-clock measurements.
-    bench_track then gates ttft_long_p99 and tpot_interference_pct like
-    data_s: lower is better, pre-long-context history abstains."""
-    import subprocess
-    import sys as _sys
-
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    out = subprocess.run(
-        [_sys.executable, "tools/decode_bench.py",
-         "--long-context", "tools/traces/longcontext_mix.json",
-         "--vocab-size", "256", "--d-model", "32", "--num-layers", "1",
-         "--num-heads", "2", "--serve-slots", "4", "--page-size", "64",
-         "--prefill-chunk", "128", "--sp-capacity", "4"],
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        env=env, capture_output=True, text=True, timeout=300)
-    assert out.returncode == 0, out.stderr[-2000:]
-    head = json.loads(out.stdout.strip().splitlines()[-1])
-    assert head["metric"] == "lm_longcontext_serving"
-    srv = head["serving"]
-    assert srv["mode"] == "long_context"
-    assert srv["requests"] == 15 and srv["completed"] == 15
-    assert srv["long_requests"] == 1
-    # (a) the interference pin: a 16k admit in flight costs the short
-    # requests' TPOT p99 at most 25% — chunked prefill's acceptance bound
-    assert srv["tpot_interference_pct"] is not None
-    assert srv["tpot_interference_pct"] <= 25.0, srv
-    assert srv["ttft_long_p99"] is not None and srv["ttft_long_p99"] > 0
-    assert srv["tpot_baseline_p99"] > 0
-    # the 16384-token prompt really went through the chunk path
-    assert srv["chunk_ticks"] >= 16384 // 128
-    # (b) the sp capacity pin: context > one device's page budget, served
-    sp = srv["sp_capacity"]
-    assert sp["exceeds_single_device"], sp
-    assert sp["context_tokens"] > sp["device_token_budget"]
-    assert sp["completed"] == 1 and sp["sp_prefills"] == 1
-    assert sp["devices"] == 4
-    # bench_track: both tail numbers gate lower-is-better with abstention
-    from tools.bench_track import load_points, track
-
-    hp = tmp_path / "head.json"
-    hp.write_text(json.dumps(head))
-    points = load_points([str(hp)])
-    assert points[0]["serving_ttfl"] == srv["ttft_long_p99"]
-    assert points[0]["serving_tip"] == srv["tpot_interference_pct"]
-    # kv_cache is null in long mode: requests_per_tick is the value
-    assert points[0]["value"] == srv["requests_per_tick"]
-    report = track(points, threshold_pct=5.0)
-    m = report["metrics"]["lm_longcontext_serving"]
-    assert m["ttft_long_best_prior"] is None      # abstains: no history
-    assert m["interference_best_prior"] is None
-    assert report["ok"]
-    worse = dict(head, serving=dict(
-        srv, ttft_long_p99=srv["ttft_long_p99"] * 1.5,
-        tpot_interference_pct=srv["tpot_interference_pct"] + 30.0))
-    wp = tmp_path / "worse.json"
-    wp.write_text(json.dumps(worse))
-    report = track(load_points([str(hp), str(wp)]), threshold_pct=5.0)
-    m = report["metrics"]["lm_longcontext_serving"]
-    assert m["ttft_long_regressed"] and m["interference_regressed"]
-    assert not report["ok"]
-    assert not report["ok"]

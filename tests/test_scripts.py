@@ -150,17 +150,6 @@ def test_tool_lm_convergence(tmp_path):
     assert "steps_to_ppl_20" in out
 
 
-def test_tool_data_rate(tmp_path):
-    out = run_script(tmp_path, "../tools/data_rate.py",
-                     ["--images", "32", "--size", "64", "--batch", "16",
-                      "--seconds", "0.5", "--prefetch-batches", "4",
-                      "--prefetch-mb", "1", "--step-ms", "5",
-                      "--root", os.path.join(str(tmp_path), "ifolder")])
-    assert "host_data_path_images_per_sec" in out
-    # the round-9 DevicePrefetcher overlap probe rides the same JSON
-    assert "overlap_efficiency" in out and "inline_copy_s" in out
-
-
 @pytest.mark.slow  # tier-1 budget (PR 7): 14s end-to-end sampler run; the sampler/peak-HBM mechanics stay covered by test_telemetry.py units
 def test_telemetry_csv_and_peak_hbm_column(tmp_path):
     """--telemetry-csv samples the 500ms device/host CSV (reference

@@ -6,9 +6,9 @@ The pins that matter:
   an allocator change, never a model change;
 * mixed-length sequences fit a pool the contiguous per-slot allocator
   provably cannot (the fragmentation win paged caches exist for);
-* continuous batching strictly beats static drain-batching on completed
-  requests per tick AND occupancy at equal slot capacity (deterministic:
-  both numbers are schedule math, not wall clocks);
+* the continuous schedule's completions per tick and occupancy over one
+  request set are pinned (deterministic: both numbers are schedule math,
+  not wall clocks);
 * a forced overload sheds new work through SLO-aware admission control,
   emitting `slo` + rejection events that reach the flight recorder and the
   Prometheus gauges through the NORMAL sink fan-out (zero new plumbing);
@@ -97,20 +97,45 @@ def test_paged_greedy_bit_identical_to_generate():
     _assert_serve_matches_generate(lm, params)
 
 
-@pytest.mark.slow  # tier-1 budget (PR 16): dtype twin of the fp32 pin —
-# the paged==contiguous discipline stays in-budget via the mixed-length
-# test_paged_greedy_bit_identical_to_generate
+# bfloat16 is the dtype every serving cell runs
 def test_paged_greedy_bit_identical_bf16():
     lm, params = _lm_and_params(seed=5, dtype=jnp.bfloat16)
     _assert_serve_matches_generate(lm, params, n_reqs=1)
 
 
-@pytest.mark.slow  # tier-1 budget (PR 16): quant twin; the int8_wo paged
-# serving path stays pinned bit-for-bit against quantized generate
-# in-budget by test_spec_decode_bit_identical_int8_wo
+# int8_wo is the weight form every lower-precision control runs
 def test_paged_greedy_bit_identical_int8_wo():
     lm, params = _lm_and_params(seed=6)
     _assert_serve_matches_generate(lm, params, quant="int8_wo", n_reqs=1)
+
+
+@pytest.mark.parametrize("max_len", [24, 64, 100, 2048, 4096])
+def test_default_buckets_cover_every_legal_prompt(max_len):
+    """The prefill ladder ascends without repeats and ends in ``max_len``,
+    so the longest prompt ``submit()`` lets in has a bucket; at the two
+    toy sizes an engine serves such a prompt, through the ladder's last
+    bucket, to the contiguous cache's tokens."""
+    from tpu_dist.engine.serve import _default_buckets
+
+    ladder = _default_buckets(max_len)
+    assert list(ladder) == sorted(set(ladder)) and ladder[-1] == max_len
+    assert all(b & (b - 1) == 0 for b in ladder[:-1])     # powers of two
+    if max_len > 64:
+        return
+    lm = tiny_lm(vocab_size=V, num_layers=1, d_model=32, num_heads=2,
+                 max_len=max_len)
+    params = lm.init({"params": jax.random.PRNGKey(8)},
+                     jnp.zeros((1, max_len), jnp.int32), train=False)["params"]
+    new = 2
+    prompt = ((np.arange(max_len - 1 - new, dtype=np.int32) * 7 + 3) % V)
+    eng = ServeEngine(lm, params, ServeConfig(
+        max_slots=1, page_size=8, num_pages=8))
+    assert eng.buckets == ladder and prompt.size > ladder[-2]
+    comps = eng.run([DecodeRequest(0, prompt, new)])
+    assert eng.rejected == 0 and ("prefill", max_len) in eng._dispatched
+    np.testing.assert_array_equal(
+        _greedy_refs(lm, params, [prompt], [new])[0], comps[0].tokens)
+    assert eng.pool.pages_free == eng.pool.num_pages
 
 
 def test_paged_sampling_is_deterministic_given_rng():
@@ -172,26 +197,24 @@ def test_mixed_lengths_fit_where_contiguous_cannot():
 
 
 # ------------------------------------------------- perf pin
-def test_continuous_batching_beats_static_drain():
-    """Equal capacity, same request set: iteration-level refill completes
-    strictly more requests per decode tick at strictly higher occupancy
-    than drain-batching (both numbers are pure schedule arithmetic —
-    deterministic on any machine)."""
+def test_continuous_batching_schedule_pin():
+    """Twelve requests through four slots, every slot refilled the step
+    after its sequence ends: 116 tokens, 12 of them the prefills' own, so
+    104 slot-ticks. The schedule packs them into 32 ticks (26 is the
+    least four slots could take) at an occupancy of 104 / (4 x 32); both
+    numbers are pure schedule arithmetic, deterministic on any machine."""
     lm, params = _lm_and_params(seed=10)
     rng = np.random.default_rng(0)
-    reqs = lambda: [DecodeRequest(
+    reqs = [DecodeRequest(
         i, rng.integers(0, V, (int(rng.integers(2, 8)),)).astype(np.int32),
         int(rng.integers(2, 20))) for i in range(12)]
-    stats = {}
-    for refill in ("continuous", "drain"):
-        rng = np.random.default_rng(0)   # same trace both modes
-        eng = ServeEngine(lm, params, ServeConfig(
-            max_slots=4, page_size=8, num_pages=64, refill=refill))
-        comps = eng.run(reqs())
-        assert len(comps) == 12
-        stats[refill] = (len(comps) / eng.ticks, eng.occupancy)
-    assert stats["continuous"][0] > stats["drain"][0], stats
-    assert stats["continuous"][1] > stats["drain"][1], stats
+    assert sum(r.max_new_tokens for r in reqs) == 116
+    eng = ServeEngine(lm, params, ServeConfig(
+        max_slots=4, page_size=8, num_pages=64))
+    comps = eng.run(reqs)
+    assert len(comps) == 12
+    assert eng.ticks == 32
+    assert eng.occupancy == 104 / (4 * 32) == 0.8125
 
 
 # ------------------------------------------------- admission + overload
@@ -211,6 +234,33 @@ def test_admission_rejects_impossible_requests():
                if r["event"] == "admit"]
     assert reasons == ["too_long", "exceeds_pool"]
     assert eng.rejected == 2
+
+
+@pytest.mark.parametrize("prompt_len, new, pages, reason", [
+    (0, 4, 8, "too_long"),               # nothing to continue
+    (4, 0, 8, "too_long"),               # nothing to generate
+    (L - 3, 4, 8, "too_long"),           # one token past max_len
+    (L - 4, 4, 8, None),                 # max_len exactly
+    (17, 4, 5, "exceeds_pool"),          # six pages of four, five held
+    (16, 4, 5, None),                    # the whole pool, to the page
+], ids=["empty_prompt", "zero_new", "past_max_len", "at_max_len",
+        "past_pool", "at_pool"])
+def test_submit_checks_a_requests_geometry_at_the_door(prompt_len, new,
+                                                       pages, reason):
+    """``submit()`` refuses what no slot could ever serve, with the reason
+    on the ``admit`` event, and queues the largest request that one can:
+    the bounds are ``max_len`` and the pool, both inclusive."""
+    lm, params = _lm_and_params(seed=11)
+    led_records = []
+    eng = ServeEngine(lm, params, ServeConfig(
+        max_slots=1, page_size=4, num_pages=pages),
+        ledger=Ledger(None, sinks=(led_records.append,)))
+    took = eng.submit(DecodeRequest(0, np.arange(prompt_len, dtype=np.int32),
+                                    new))
+    admit, = [r for r in led_records if r["event"] == "admit"]
+    assert took == admit["accepted"] == (reason is None)
+    assert admit.get("reason") == reason
+    assert (eng.rejected, len(eng.queue)) == ((0, 1) if took else (1, 0))
 
 
 def test_overload_sheds_emits_slo_and_fires_flightrec(tmp_path):
@@ -592,7 +642,6 @@ def test_chunked_prefill_bit_identical_fp32():
         np.testing.assert_array_equal(refs[c.rid], c.tokens)
     # ceil(13/8) + ceil(18/8) chunk dispatches, one per iteration
     assert eng.chunk_ticks == 2 + 3
-    assert eng.prefill_token_work == 5 * 8
     assert eng.chunks_pending == 0
     assert eng.pool.pages_free == eng.pool.num_pages
 
@@ -617,8 +666,7 @@ def test_chunked_prefill_interleaves_with_decode():
     """The scheduling contract itself: while a long prompt chunks in, the
     already-decoding request keeps emitting one token per iteration — the
     chunk rides the SAME scheduler step as the decode tick, it never
-    stalls the stream (the TPOT-interference bound decode_bench
-    measures). Deterministic: pure schedule math, no clocks."""
+    stalls the stream. Deterministic: pure schedule math, no clocks."""
     lm, params = _lm_and_params(seed=24)
     eng = ServeEngine(lm, params, ServeConfig(
         max_slots=2, page_size=4, num_pages=32, prefill_chunk=4))
@@ -915,6 +963,24 @@ def _staggered(eng, arrivals):
                   if sp.sid > mark.sid and sp.name != "host.gc"]
 
 
+def _late_burst(eng, reqs):
+    """Feed ``eng`` the first request alone and the rest together once it
+    is mid-decode with a tick in flight: ONE step admits as many as there
+    are free slots, each but the first called ahead of a read. The
+    completions by rid."""
+    assert eng.submit(reqs[0])
+    done = {c.rid: c for _ in range(2) for c in eng.step()}
+    assert eng._flights and not done
+    for r in reqs[1:]:
+        assert eng.submit(r)
+    took = min(len(reqs) - 1, eng.slots.count(None))
+    done.update((c.rid, c) for c in eng.step())
+    assert eng.prefills == 1 + took > 2
+    assert eng.stats()["prefills_ahead"] == took - 1
+    done.update((c.rid, c) for c in eng.run())
+    return done
+
+
 def test_prefill_behind_blocks_on_the_tick_in_flight_and_on_nothing_else(
         span_lm, monkeypatch):
     """``prefill.behind`` sits between the dispatch and the wait whether a
@@ -1063,7 +1129,12 @@ _AHEAD_CASES = {
     # int8 pages move a logit by a few 1e-2: on these weights no greedy
     # choice is that close, so the tokens are the plain decode's
     "int8_kv": lambda: _gpt2_case(42, dict(kv_quant="int8")),
-    "drain": lambda: _gpt2_case(40, dict(refill="drain")),
+    # one request alone for two steps, then four arrive together: three
+    # are admitted in ONE step while the first is mid-decode, its tick in
+    # flight (fed by _late_burst)
+    "late_burst": lambda: _gpt2_case(40, dict(max_slots=4), [
+        DecodeRequest(0, np.array([5, 6, 7], np.int32), 12),
+        *_mixed_requests(40)[1:]]),
     # ends the host cannot foresee: see the test
     "eos": lambda: _gpt2_case(40, {}),
 }
@@ -1087,7 +1158,8 @@ def test_engine_one_tick_ahead_serves_the_plain_decodes_tokens(case):
                                 width, eos) for r in reqs}
     eng = ServeEngine(model, params, ServeConfig(**cfg, eos_id=eos))
     pages0 = eng.pool.pages_free
-    comps = eng.run(reqs)
+    comps = (list(_late_burst(eng, reqs).values()) if case == "late_burst"
+             else eng.run(reqs))
     assert sorted(c.rid for c in comps) == [r.rid for r in reqs]
     for c in comps:
         np.testing.assert_array_equal(refs[c.rid], c.tokens, str(c.rid))
@@ -1190,11 +1262,9 @@ def _bucket_requests(seed, lens=(3, 20, 9, 13, 6), vocab=V):
 
 
 def _one_a_step(eng, reqs):
-    """Feed ``eng`` one request a step: nothing can be issued ahead (but
-    under ``refill="drain"``, where arrivals wait for the batch to end and
-    are then admitted together)."""
+    """Feed ``eng`` one request a step: nothing can be issued ahead."""
     done, _ = _staggered(eng, {k: [r] for k, r in enumerate(reqs)})
-    assert eng.cfg.refill == "drain" or eng.stats()["prefills_ahead"] == 0
+    assert eng.stats()["prefills_ahead"] == 0
     return done
 
 
@@ -1204,7 +1274,8 @@ _PIPELINE_CASES = {
     # they must still find its pages
     "prefix_cache": dict(prefix_cache=True),
     "speculative": dict(spec_k=3),
-    "drain": dict(refill="drain"),
+    # the comparison engine takes the burst while a slot is mid-decode
+    "late_burst": dict(),
 }
 
 
@@ -1213,7 +1284,8 @@ def test_a_steps_admissions_pipelined_serve_the_same_tokens(case):
     """Five requests of different buckets in the queue before ONE step:
     prefill k + 1 is called before prefill k's token is read, and every
     completion is a plain greedy decode's, the same engine's fed one
-    request a step, and the one-at-a-time loop's."""
+    request a step (``late_burst``: one, then four together into the busy
+    engine), and the one-at-a-time loop's."""
     lm, params = _lm_and_params(seed=39)
     fwd = jax.jit(lambda p, x: lm.apply({"params": p}, x, train=False))
     cfg = dict(max_slots=5, page_size=4, num_pages=64,
@@ -1232,7 +1304,8 @@ def test_a_steps_admissions_pipelined_serve_the_same_tokens(case):
     done = {c.rid: c for c in eng.run()}
     assert sorted(done) == [0, 1, 2, 3, 4]
     assert eng.pool.pages_free == pages0
-    apart = _one_a_step(ServeEngine(lm, params, ServeConfig(**cfg)), reqs)
+    apart = (_late_burst if case == "late_burst" else _one_a_step)(
+        ServeEngine(lm, params, ServeConfig(**cfg)), reqs)
     sync = _OneAtATime(lm, params, ServeConfig(**cfg))
     synced = {c.rid: c for c in sync.run(reqs)}
     assert sync.stats()["prefills_ahead"] == 0

@@ -358,7 +358,7 @@ def decisions_section(records, out=print):
 
 def decode_section(records, out=print):
     """The serving-SLO section: per-request latency percentiles and tok/s
-    over the `decode` events (engine.generate / tools/decode_bench), plus
+    over the `decode` events (engine.generate), plus
     the continuous-batching view over `request`/`admit`/`kv_cache` events
     (engine.serve): queue-wait and TTFT percentiles, admission rejections,
     and batch occupancy from the pool-pressure snapshots."""
@@ -789,7 +789,7 @@ def summarize(records, out=print):
         summary["last_eval"] = {k: last.get(k)
                                 for k in ("epoch", "loss", "ppl", "acc1")}
 
-    # serving-SLO view over decode events (generate / decode_bench)
+    # serving-SLO view over decode events (engine.generate)
     summary["decode"] = decode_section(records, out=out)
     summary["requests"] = requests_section(records, out=out)
     # program-audit verdicts (analysis.proglint): which step/serve

@@ -183,8 +183,7 @@ def tail_attribution(rows: List[dict]) -> dict:
     """The headline block: TTFT decomposes into queue+prefill, TPOT into
     decode-per-token; ``shares`` are the fleet-wide category fractions of
     total latency; ``coverage`` the attributed share (1.0 minus residue)
-    — the bench_track-gated number, ~1.0 by construction on any ledger
-    that didn't lose spans."""
+    — ~1.0 by construction on any ledger that didn't lose spans."""
     total = sum(r["latency_s"] for r in rows)
     shares = {}
     for cat in (*reqtrace.CATEGORIES, "residue"):

@@ -88,9 +88,9 @@ from contextlib import contextmanager
 
 @contextmanager
 def numpy_fallback():
-    """Force the pure-numpy path inside the block (benchmark/debug hook —
-    tools/data_rate.py compares the two implementations with it), however
-    the lazy-load cache is organized internally."""
+    """Force the pure-numpy path inside the block (debug hook: a test
+    compares the two implementations with it), however the lazy-load
+    cache is organized internally."""
     global _lib, _tried
     saved = (_lib, _tried)
     _lib, _tried = None, True

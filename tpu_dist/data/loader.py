@@ -161,7 +161,7 @@ class DevicePrefetcher:
     :meth:`stats` reports the overlap ledger: ``put_s`` (producer seconds
     spent staging uploads — the un-overlapped copy cost), ``wait_s``
     (consumer seconds actually blocked), and the achieved overlap
-    efficiency, which tools/data_rate.py turns into a standalone number.
+    efficiency.
     """
 
     def __init__(self, iterable, sharding=None, depth: int = 2,

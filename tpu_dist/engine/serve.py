@@ -59,8 +59,8 @@ Composition (each piece usable alone):
   prompts cost ~0 fresh pages per request; shared decode is bit-identical
   to unshared because shared rows are the original writer's bits, re-read
   not re-written;
-* admission control is SLO-aware: hard queue-depth and free-page
-  watermarks reject at submit time, and an EMA of queue wait (the
+* admission control is SLO-aware: a hard queue-depth cap rejects at
+  submit time, and an EMA of queue wait (the
   ``GoodputMonitor`` hysteresis pattern) sheds new work while the backlog
   breaches the floor — emitting the standard ``slo`` ledger event, which
   auto-triggers the flight recorder through the existing sink fan-out;
@@ -108,6 +108,9 @@ from tpu_dist.ops.paged_attention import cow_fork_pages, decode_read
 from tpu_dist.parallel.mesh import SP_AXIS
 from tpu_dist.parallel.ring_attention import ring_attention_fn
 from tpu_dist.plan.compile import check_audit_sentry, register_audit_program
+
+# weight of the newest queue wait in the EMA that SLO shedding reads
+_SLO_ALPHA = 0.5
 
 
 @dataclass
@@ -173,12 +176,8 @@ class ServeConfig:
     top_k: int = 0
     top_p: float = 0.0
     eos_id: Optional[int] = None
-    prefill_buckets: Tuple[int, ...] = ()   # () = powers of 2 up to max_len
-    refill: str = "continuous"   # continuous | drain (static-batch baseline)
     queue_depth_max: int = 64    # hard admission cap
-    free_page_watermark: float = 0.0   # reject below this free fraction
     slo_queue_wait_s: float = 0.0      # EMA floor; 0 disables shedding
-    slo_alpha: float = 0.5
     slo_min_samples: int = 2
     kv_event_every: int = 0      # ticks between kv_cache events (0 = final)
     spec_k: int = 0              # draft tokens per tick (0 = plain decode)
@@ -808,12 +807,7 @@ class ServeEngine:
         elif draft_model is not None:
             raise ValueError("draft_model given but cfg.spec_k == 0: set "
                              "spec_k to the draft window size")
-        # max_len always terminates the bucket ladder: a custom list that
-        # stops short of a legal prompt must widen to max_len, not crash
-        # the admit (and leak its granted pages) on a missing bucket
-        self.buckets = tuple(sorted({self.max_len, *(
-            b for b in (cfg.prefill_buckets or _default_buckets(self.max_len))
-            if b <= self.max_len)}))
+        self.buckets = _default_buckets(self.max_len)
         # sp prefill needs buckets whose shards hold WHOLE pages: the
         # striped prompt allocation places block-table slot t on device
         # (t*page_size)//shard_len, which is only well-defined when
@@ -856,11 +850,8 @@ class ServeEngine:
         self.prefills = 0
         self.sp_prefills = 0
         # chunked-prefill accounting: chunk dispatches interleaved with
-        # the decode stream, plus the cumulative prefill TOKEN work — the
-        # per-step delta is the virtual cost-model clock's prefill term
-        # (tools/decode_bench.py --long-context)
+        # the decode stream (the kv_cache event's chunk-occupancy trend)
         self.chunk_ticks = 0
-        self.prefill_token_work = 0
         # speculative accounting: emitted tokens vs active-slot tick
         # opportunities — accepted_per_tick = spec_emitted/spec_slot_ticks
         # (identically 1.0 for plain decode; > 1.0 is speculation's win)
@@ -995,10 +986,6 @@ class ServeEngine:
         if len(self.queue) >= self.cfg.queue_depth_max:
             self._emit_admit(req, now, False, "queue_full")
             return False
-        free_frac = self.pool.pages_free / max(self.pool.num_pages, 1)
-        if free_frac < self.cfg.free_page_watermark:
-            self._emit_admit(req, now, False, "page_watermark")
-            return False
         self.queue.append((req, now))
         self._emit_admit(req, now, True, None)
         return True
@@ -1028,7 +1015,7 @@ class ServeEngine:
                        tenant=req.tenant, **tr.attrs())
 
     def _observe_wait(self, wait: float) -> None:
-        a = self.cfg.slo_alpha
+        a = _SLO_ALPHA
         self._wait_ema = (wait if self._wait_ema is None
                           else a * wait + (1 - a) * self._wait_ema)
         self._wait_samples += 1
@@ -1057,7 +1044,7 @@ class ServeEngine:
         scheduler iteration restores the hysteresis loop's downswing."""
         if not self.shedding or self.queue or self._wait_ema is None:
             return
-        self._wait_ema *= (1 - self.cfg.slo_alpha)
+        self._wait_ema *= (1 - _SLO_ALPHA)
         if self._wait_ema <= self.cfg.slo_queue_wait_s:
             self._in_breach = False
             self.shedding = False
@@ -1269,9 +1256,6 @@ class ServeEngine:
         one program is out beyond the one being read, and none when this
         returns. A chunked or sequence-parallel admission keeps its
         synchronous form and is never issued ahead."""
-        if self.cfg.refill == "drain" and any(
-                s is not None for s in self.slots):
-            return 0  # static batching: refill only once the batch drained
         admitted = 0
         plans = self._planned()
         adm = next(plans, None)
@@ -1425,7 +1409,6 @@ class ServeEngine:
         # rows of the prompt the model's last layers and head ran
         # on, as the traced program had them (the bucket, or 1)
         adm.cross_rows = program.head_rows.get(bucket)
-        self.prefill_token_work += bucket
         self.state_writes += bool(self.state_layers)
         if self.draft_pool is not None:
             # the draft's prompt rows, through the same block table
@@ -1582,8 +1565,7 @@ class ServeEngine:
 
     def _chunk_tick(self) -> None:
         """At most ONE prefill chunk per scheduler iteration — the knob
-        that bounds how much prefill compute any decode tick waits behind
-        (the TPOT-interference contract tools/decode_bench.py measures).
+        that bounds how much prefill compute any decode tick waits behind.
         Lowest slot index first: admission order, no starvation."""
         for i, s in enumerate(self.slots):
             if s is not None and not s.done and s.chunk_next >= 0:
@@ -1657,7 +1639,6 @@ class ServeEngine:
             jnp.asarray(tokens), jnp.int32(slot_idx))
         self.pool.adopt(new_layers)
         self.chunk_ticks += 1
-        self.prefill_token_work += chunk
         if start + chunk < p:
             s.chunk_next = start + chunk
             return None
@@ -1731,10 +1712,6 @@ class ServeEngine:
                     self.shared_prompt_pages += adm.n_shared
                 self.prefills += 1
                 self.sp_prefills += 1
-                # each device touches bucket/n rows: that's the wall the
-                # scheduler waited behind, so that's what the virtual clock
-                # charges
-                self.prefill_token_work += bucket // self.sp_n
             self._wait_behind(span)
             with self._span("prefill.wait"):
                 # distlint: disable=DL002 -- iteration-level scheduling syncs once per admit by design
@@ -2209,7 +2186,6 @@ class ServeEngine:
                 "sp_prefills": self.sp_prefills,
                 "chunk_ticks": self.chunk_ticks,
                 "chunks_pending": self.chunks_pending,
-                "prefill_token_work": self.prefill_token_work,
                 "occupancy": round(self.occupancy, 6),
                 "spec_k": self.cfg.spec_k,
                 "spec_emitted": self.spec_emitted,

@@ -70,8 +70,8 @@ EVENT_SCHEMA = {
     # one generate() call (engine.generate with a ledger passed in)
     "decode": ("tokens", "seconds", "throughput"),
     # serving admission decision (engine.serve): one per submit();
-    # accepted=False carries a `reason` extra (queue_full|page_watermark|
-    # slo_shedding|too_long|exceeds_pool) — the overload forensics
+    # accepted=False carries a `reason` extra (queue_full|slo_shedding|
+    # too_long|exceeds_pool) — the overload forensics
     "admit": ("rid", "accepted", "queue_depth", "pages_free"),
     # one COMPLETED serving request (engine.serve): the serving-SLO
     # record — timestamps are engine-clock (real seconds by default,

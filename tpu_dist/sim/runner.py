@@ -31,7 +31,7 @@ Outputs under ``out_dir``::
     fleet.jsonl     # the runner's own ledger: scenario + fleet events
     host<N>/        # each host's attempt ledgers + .sup sibling + sidecars
     report.json     # the stitched FleetLedger report
-    headline.json   # bench_track-shaped point carrying fleet.goodput_ratio
+    headline.json   # the run's own summary: fleet.goodput_ratio, the lag
 
 ``python -m tpu_dist.sim.runner --scenario scripts/fleet_ci.json --out
 /tmp/fleet`` is the CLI; ``tools/fleet_report.py`` renders the result.
@@ -428,16 +428,9 @@ class FleetSim:
         fleet_ledger.close()
         with open(os.path.join(self.out, "report.json"), "w") as f:
             json.dump(report, f, indent=1, default=str)
-        # the bench_track-shaped point: fleet.goodput_ratio is the gated
-        # number (tools/bench_track.py abstains on pre-fleet history);
-        # autoscale_lag_ticks — burst onset to the first up decision —
-        # rides along as the lower-is-better reaction-time gate
-        burst0 = min((int(ev["tick"]) for ev in sc.events
-                      if ev["type"] == "burst"), default=None)
-        up0 = next((d["tick"] for d in self.decisions
-                    if d["direction"] == "up"), None)
-        lag = (up0 - burst0 if burst0 is not None and up0 is not None
-               else None)
+        # the run's summary: fleet.goodput_ratio, and beside it the
+        # autoscaler's reaction time (lower is better)
+        lag = autoscale_lag_ticks(sc.events, self.decisions)
         with open(os.path.join(self.out, "headline.json"), "w") as f:
             json.dump({"metric": "fleet_sim_goodput",
                        "value": acct.get("goodput_ratio"),
@@ -451,6 +444,19 @@ class FleetSim:
                                     if self.policy is not None else {})}},
                       f, indent=1)
         return report
+
+
+def autoscale_lag_ticks(events, decisions) -> Optional[int]:
+    """Ticks from the scenario's first ``burst`` event to the policy's
+    first ``up`` decision; None where the run had no burst or never
+    scaled up."""
+    burst0 = min((int(ev["tick"]) for ev in events
+                  if ev["type"] == "burst"), default=None)
+    up0 = next((d["tick"] for d in decisions
+                if d["direction"] == "up"), None)
+    if burst0 is None or up0 is None:
+        return None
+    return up0 - burst0
 
 
 def main(argv: Optional[List[str]] = None) -> int:
